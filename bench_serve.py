@@ -30,6 +30,13 @@ sides share the trained model, the process, and the machine state:
    clients. Per-config QPS/p50/p99 plus the hedge/retry/breaker
    counters read back from the MERGED ``/metrics`` snapshot land in
    "gateway"; "scaleout_x" = many-backend / one-backend QPS.
+   REFUSED on an accelerator: a chip belongs to one process, this
+   process holds it after phases 1-3, and every ``task=serve`` child
+   would need it — "gateway" then carries the reason, not a number.
+
+Like bench.py this measures an accelerator or nothing: a CPU backend
+is refused with a non-zero exit, a phase that raises ends the run, and
+every result names platform, device kind and device count.
 
 The dispatcher's own observability (queue depth, padded-row waste,
 coalesce ratio — what /metrics exports) is snapshotted per phase into
@@ -50,8 +57,7 @@ BENCH_SERVE_GATEWAY_THREADS, BENCH_SERVE_GATEWAY_TENANTS,
 BENCH_SERVE_GATEWAY_ZIPF (skew exponent),
 BENCH_SERVE_OUT (explicit output path),
 BENCH_SERVE_DIR (output directory, default: repo root),
-BENCH_RUN_DIR / BENCH_MANIFEST_OUT (run-manifest location — the
-manifest lives under the tmp run dir, never the repo root).
+BENCH_MANIFEST_OUT (run-manifest path; default under chiprun_out/).
 """
 
 from __future__ import annotations
@@ -69,14 +75,6 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 SCHEMA = "lightgbm-tpu/bench-serve/v1"
-
-# last builder-verified ON-CHIP serving measurement — the same
-# carry-forward semantics bench.py uses for training throughput: when
-# a run lands off-chip, this rides along marked `stale: true` so the
-# bench gate (analysis/bench_gate.py) never reads a carried number as
-# fresh. None until the first chip serving run lands; update it there
-# and re-pin with `python -m lightgbm_tpu.analysis --refresh-budgets`.
-LAST_TPU_VERIFIED = None
 
 
 def _env_int(name: str, default: int) -> int:
@@ -245,10 +243,13 @@ def _gateway_phase(model_file: str, model_str: str, n_feat: int,
     """Phase 4: 1 vs N real task=serve backend processes behind an
     in-process Gateway, Zipfian tenant replay, counters read back from
     the merged /metrics snapshot. Returns None when disabled
-    (BENCH_SERVE_GATEWAY_BACKENDS empty)."""
+    (BENCH_SERVE_GATEWAY_BACKENDS empty) and a {"refused": reason}
+    record on an accelerator (one process per chip)."""
     import socket
     import subprocess
     import urllib.request
+
+    import jax
 
     from lightgbm_tpu.serving.gateway import Gateway
 
@@ -256,6 +257,13 @@ def _gateway_phase(model_file: str, model_str: str, n_feat: int,
     counts = [int(x) for x in spec.split(",") if x.strip()]
     if not counts:
         return None
+    if jax.default_backend() != "cpu":
+        return {"refused": (
+            f"this process holds the {jax.default_backend()} after "
+            "phases 1-3 and every task=serve backend process would need "
+            "it too (one process per chip); the phase needs a parent "
+            "that never initialises a backend"
+        )}
     n_requests = _env_int("BENCH_SERVE_GATEWAY_REQUESTS", 600)
     n_threads = _env_int("BENCH_SERVE_GATEWAY_THREADS", 6)
     n_tenants = _env_int("BENCH_SERVE_GATEWAY_TENANTS", 4)
@@ -268,11 +276,6 @@ def _gateway_phase(model_file: str, model_str: str, n_feat: int,
     w /= w.sum()
     replay = np.random.RandomState(11).choice(n_tenants,
                                               size=n_requests, p=w)
-
-    env = dict(os.environ)
-    # restart/re-spawn compiles become cache hits across backends
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
-        tempfile.gettempdir(), "lgbmtpu_bench_gateway_cache"))
 
     def free_port() -> int:
         s = socket.socket()
@@ -287,8 +290,7 @@ def _gateway_phase(model_file: str, model_str: str, n_feat: int,
              f"input_model={model_file}", f"serve_port={port}",
              "serve_buckets=16,64", "serve_warmup=true",
              "verbosity=-1"],
-            env=env, stdout=subprocess.DEVNULL,
-            stderr=subprocess.DEVNULL)
+            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
 
     def wait_ready(url: str, proc, timeout: float = 600.0) -> None:
         deadline = time.monotonic() + timeout
@@ -417,7 +419,9 @@ def _gateway_phase(model_file: str, model_str: str, n_feat: int,
 
 
 def run_bench() -> dict:
-    import jax
+    from bench import require_accelerator
+
+    device = require_accelerator("bench_serve")
 
     import lightgbm_tpu as lgb
     from lightgbm_tpu.serving import ModelFleet, ModelRegistry
@@ -517,19 +521,15 @@ def run_bench() -> dict:
     fleet.close()
 
     # ---- phase 4: cross-process scale-out behind the gateway
-    gateway_result = None
+    with tempfile.NamedTemporaryFile(
+            mode="w", suffix=".txt", delete=False) as f:
+        model_file = f.name
+        f.write(bst.model_to_string())
     try:
-        with tempfile.NamedTemporaryFile(
-                mode="w", suffix=".txt", delete=False) as f:
-            model_file = f.name
-            f.write(bst.model_to_string())
-        try:
-            gateway_result = _gateway_phase(
-                model_file, bst.model_to_string(), n_feat, batch)
-        finally:
-            os.unlink(model_file)
-    except Exception as e:  # noqa: BLE001 — scale-out phase must not sink the artifact
-        gateway_result = {"error": f"{type(e).__name__}: {e}"}
+        gateway_result = _gateway_phase(
+            model_file, bst.model_to_string(), n_feat, batch)
+    finally:
+        os.unlink(model_file)
 
     result = {
         "schema": SCHEMA,
@@ -550,20 +550,13 @@ def run_bench() -> dict:
         "model": {"trees": n_trees, "leaves": n_leaves,
                   "features": n_feat, "train_rows": train_rows,
                   "train_s": round(train_s, 2)},
-        "platform": jax.devices()[0].platform,
-        "device_count": jax.device_count(),
+        **device,
         # the observability view of the same run (LatencyStats ring —
         # what /metrics and the stats op report)
         "stats": loaded_reg.stats().get("bench", {}),
         "created_unix": time.time(),
         "run_id": f"{int(time.time())}-{os.getpid()}",
     }
-    if LAST_TPU_VERIFIED:
-        # same staleness rule as bench.py: carried chip numbers are
-        # fresh only when THIS run actually executed on the chip
-        result["last_tpu_verified"] = dict(
-            LAST_TPU_VERIFIED, stale=result["platform"] != "tpu"
-        )
     return result
 
 
@@ -580,20 +573,14 @@ def _next_out_path() -> str:
 
 
 def _manifest_path(out: str) -> str:
-    """Run manifests live under the tmp run dir (BENCH_RUN_DIR — the
-    same dir bench.py uses), never the repo root: the repo root once
-    grew a stale checked-in manifest. The path is stamped into the
-    artifact so the trajectory point still traces back to what ran;
-    BENCH_MANIFEST_OUT overrides for archival."""
+    """Run manifests live under chiprun_out/ (what the chip tool brings
+    back; git-ignored) like bench.py's. The path is stamped into the
+    artifact so the result still traces back to what ran;
+    BENCH_MANIFEST_OUT overrides."""
     if os.environ.get("BENCH_MANIFEST_OUT"):
         return os.environ["BENCH_MANIFEST_OUT"]
-    run_dir = os.environ.get("BENCH_RUN_DIR") or os.path.join(
-        tempfile.gettempdir(), "lightgbm_tpu_bench"
-    )
-    try:
-        os.makedirs(run_dir, exist_ok=True)
-    except OSError:
-        run_dir = tempfile.gettempdir()
+    run_dir = os.path.join(REPO, "chiprun_out")
+    os.makedirs(run_dir, exist_ok=True)
     m = re.search(r"BENCH_SERVE_r(\d+)\.json$", out)
     name = (f"run_manifest_serve_r{m.group(1)}.json" if m
             else "run_manifest_serve.json")
@@ -607,16 +594,13 @@ def main() -> int:
     # metrics snapshot) under the run dir, path stamped into the json
     # so the trajectory point traces back to what ran
     mpath = _manifest_path(out)
-    try:
-        from lightgbm_tpu.obs.manifest import write_manifest
+    from lightgbm_tpu.obs.manifest import write_manifest
 
-        write_manifest(mpath, extra={
-            "bench": "serve", "run_id": result["run_id"],
-            "artifact": out,
-        })
-        result["run_manifest"] = mpath
-    except Exception as e:  # noqa: BLE001 — provenance must not kill the bench
-        sys.stderr.write(f"[bench_serve] run manifest not written: {e}\n")
+    write_manifest(mpath, extra={
+        "bench": "serve", "run_id": result["run_id"],
+        "artifact": out,
+    })
+    result["run_manifest"] = mpath
     with open(out, "w") as f:
         json.dump(result, f, indent=2, sort_keys=True)
         f.write("\n")

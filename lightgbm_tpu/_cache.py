@@ -1,77 +1,37 @@
-"""Persistent XLA compilation cache, enabled by default on TPU.
+"""Persistent XLA compilation cache: the one place that decides where
+compiled executables live.
 
-A cold compile of the fused training step costs ~40 s on a v5e chip
-(BENCH_NOTES r4); the reference's C++ has no such cost, so out of the
-box we cache compiled executables across processes the way the bench
-harness does. Opt out with LGBM_TPU_NO_COMPILE_CACHE=1 or override the
-location with JAX_COMPILATION_CACHE_DIR.
+The fused training step takes tens of seconds to compile cold and the
+reference's C++ has no such cost, so every process that trains or
+serves shares one on-disk cache. ``JAX_COMPILATION_CACHE_DIR`` places
+it from outside (jax reads the variable itself; nothing here overrides
+it). Unset, the cache is ``.jax_cache/`` at the root of the checkout —
+a FIXED path, because the directory is part of the cache key's lookup
+and a location that moves (temp name, pid, host tag) never hits.
+Tests, benches and worker scripts call ``ensure_compile_cache`` rather
+than configuring jax themselves.
 """
 
 from __future__ import annotations
 
 import os
 
-_done = False
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
-def machine_tag() -> str:
-    """Host fingerprint for persistent-cache directories. XLA:CPU AOT
-    entries embed machine features that the cache KEY omits, so an
-    entry written on a different host (the bench/test driver moves
-    between machines) loads here and dies with SIGILL/SIGSEGV after
-    warning "Target machine feature ... is not supported on the host
-    machine" — fingerprinted directories make that impossible."""
-    import hashlib
+def ensure_compile_cache() -> str:
+    """Idempotent; call before the first jit dispatch. Returns the
+    cache directory in use: the one already configured
+    (``JAX_COMPILATION_CACHE_DIR`` or the caller's own
+    ``jax.config.update``) or else ``CACHE_DIR``. Does not touch the
+    backend, so it is safe before ``jax.distributed.initialize``."""
+    import jax
 
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                # x86 says "flags", ARM says "Features" — either is the
-                # ISA-extension list that decides AOT compatibility
-                if line.lower().startswith(("flags", "features")):
-                    return hashlib.sha1(line.encode()).hexdigest()[:10]
-    except OSError:
-        pass
-    import platform
-
-    return platform.machine() or "generic"
-
-
-def ensure_compile_cache() -> None:
-    """Idempotent; call before the first jit dispatch. No-op when the
-    user configured a cache themselves, opted out, or jax isn't on an
-    accelerator (CPU compiles are cheap and tests churn trees)."""
-    global _done
-    if _done:
-        return
-    _done = True
-    if os.environ.get("LGBM_TPU_NO_COMPILE_CACHE", "").lower() in (
-        "1", "true", "yes",
-    ):
-        return
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return  # user-configured; jax already read it
-    try:
-        import jax
-
-        if jax.config.jax_compilation_cache_dir:
-            return
-        if jax.devices()[0].platform not in ("tpu",):
-            return
-        cache_dir = os.path.join(
-            os.path.expanduser("~"), ".cache", "lightgbm_tpu", "jax_cache"
-        )
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 2.0
-            )
-        from . import log
-
-        log.info(
-            f"Persistent XLA compile cache enabled at {cache_dir} "
-            "(LGBM_TPU_NO_COMPILE_CACHE=1 to disable)"
-        )
-    except Exception:  # noqa: BLE001 — never block training on cache setup
-        pass
+    current = jax.config.jax_compilation_cache_dir
+    if current:
+        return current
+    os.makedirs(CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR

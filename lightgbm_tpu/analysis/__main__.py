@@ -9,10 +9,9 @@ contract; the default mode reports and exits 0.
 Budget maintenance:
   --update-budget     rewrite jaxpr_budget.json (+25% headroom)
   --refresh-budgets   rewrite cost_budget.json (+25% headroom on cost
-                      metrics, EXACT wire bytes), bench_budget.json,
-                      and scale_budget.json (EXACT per-rung pins over
-                      the full D-ladder), printing an old->new diff
-                      of each for review
+                      metrics, EXACT wire bytes) and scale_budget.json
+                      (EXACT per-rung pins over the full D-ladder),
+                      printing an old->new diff of each for review
 
 The jax-backed audits need a multi-device CPU mesh; this entry point
 forces `jax_platforms=cpu` with 8 virtual devices (same as
@@ -118,16 +117,6 @@ def main(argv=None) -> int:
             for r in results:
                 if not r.ok:
                     print(r.format())
-            # bench trajectory pins ride the same refresh flow
-            from . import bench_gate
-
-            bold, bnew = bench_gate.refresh_budget()
-            print("bench_budget.json updated:")
-            print(bench_gate.format_budget_diff(bold, bnew))
-            gate = bench_gate.run_gate()
-            failed |= not gate.ok
-            if not gate.ok:
-                print(gate.format())
             # scaling-contract pins too (full D-ladder, exact)
             from .scale_audit import (
                 format_scale_diff,

@@ -49,7 +49,6 @@ from .jaxpr_audit import (
     AuditResult,
     Contract,
     ENTRIES,
-    _core_modules,
     build_entry,
     iter_eqns,
 )
@@ -124,16 +123,6 @@ def collect_wire(closed) -> Tuple[WireRecord, ...]:
 
 
 # ------------------------------------------------------------- compile
-def _jaxpr_as_fun(closed):
-    """jax.core.jaxpr_as_fun across jax versions (shared module probe
-    with jaxpr_audit._jaxpr_types)."""
-    for mod in _core_modules():
-        fn = getattr(mod, "jaxpr_as_fun", None)
-        if fn is not None:
-            return fn(closed)
-    raise RuntimeError("cannot locate jax jaxpr_as_fun")
-
-
 def compile_entry(name: str) -> CostSummary:
     """Lower-and-compile one entry on the current (CPU) backend and
     read its compiled cost/memory analysis + jaxpr wire account. The
@@ -141,13 +130,13 @@ def compile_entry(name: str) -> CostSummary:
     under the interpreter so XLA:CPU can compile them), so a strict
     run traces each entry once across both passes."""
     import jax
+    from jax.extend.core import jaxpr_as_fun
 
     closed = build_entry(name, ENTRIES[name].pallas_interpret)
-    fn = jax.jit(_jaxpr_as_fun(closed))
+    fn = jax.jit(jaxpr_as_fun(closed))
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in closed.in_avals]
     compiled = fn.lower(*args).compile()
-    ca = compiled.cost_analysis()
-    props = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
+    props = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     return CostSummary(
         flops=int(math.ceil(props.get("flops", 0.0))),

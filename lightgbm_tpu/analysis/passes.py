@@ -71,14 +71,6 @@ def _run_cost(pkg_root: Optional[str], show_suppressed: bool) -> PassResult:
     )
 
 
-def _run_bench_gate(pkg_root: Optional[str],
-                    show_suppressed: bool) -> PassResult:
-    from .bench_gate import run_gate
-
-    result = run_gate()
-    return PassResult("bench_gate", result.ok, result.format())
-
-
 def _run_scale(pkg_root: Optional[str], show_suppressed: bool) -> PassResult:
     from .scale_audit import run_scale_audits
 
@@ -116,11 +108,6 @@ PASSES: Dict[str, AnalysisPass] = {
         "cost", True,
         "XLA cost/memory budgets + collective wire-bytes accounting "
         "(cost_audit.py)", _run_cost,
-    ),
-    "bench_gate": AnalysisPass(
-        "bench_gate", False,
-        "BENCH_r*/BENCH_SERVE_r* trajectory regression gate against "
-        "bench_budget.json pins (bench_gate.py)", _run_bench_gate,
     ),
     "scale": AnalysisPass(
         "scale", True,

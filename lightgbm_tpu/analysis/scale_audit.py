@@ -167,17 +167,18 @@ SCALE_ENTRIES: Dict[str, ScaleSpec] = {
 
 
 # --------------------------------------------------------- summarizer
-def _render_spec(names: Dict[int, Tuple[str, ...]], ndim: int) -> str:
-    """shard_map names dict -> "P(None, data)" style string;
+def _render_spec(pspec, ndim: int) -> str:
+    """shard_map PartitionSpec -> "P(None, data)" style string;
     an array with NO bound axes renders as "replicated" (rank-blind:
     that is the property the rules declare)."""
-    if not any(names.get(d) for d in range(ndim)):
+    axes = [
+        () if ax is None else ax if isinstance(ax, tuple) else (ax,)
+        for ax in tuple(pspec) + (None,) * (ndim - len(pspec))
+    ]
+    if not any(axes):
         return "replicated"
-    parts = []
-    for d in range(ndim):
-        ax = names.get(d, ())
-        parts.append("+".join(ax) if ax else "None")
-    return f"P({', '.join(parts)})"
+    return "P(" + ", ".join("+".join(ax) if ax else "None"
+                            for ax in axes) + ")"
 
 
 def _canonical_dims(shape, symbols: Dict[str, int], n_devices: int) -> str:
@@ -200,16 +201,15 @@ def extract_shardings(closed, spec: ScaleSpec,
              if e.primitive.name == "shard_map"]
     for k, eqn in enumerate(smaps):
         prefix = "" if len(smaps) == 1 else f"smap{k}/"
-        for kind, vs, nm in (("in", eqn.invars, eqn.params["in_names"]),
-                             ("out", eqn.outvars, eqn.params["out_names"])):
-            for i, (v, names) in enumerate(zip(vs, nm)):
+        for kind, vs, nm in (("in", eqn.invars, eqn.params["in_specs"]),
+                             ("out", eqn.outvars, eqn.params["out_specs"])):
+            for i, (v, pspec) in enumerate(zip(vs, nm)):
                 aval = getattr(v, "aval", None)
                 if aval is None or not hasattr(aval, "shape"):
                     continue
                 dims = _canonical_dims(aval.shape, spec.symbols, n_devices)
                 name = f"{prefix}{kind}/{i}/{aval.dtype}[{dims}]"
-                items.append((name, _render_spec(dict(names),
-                                                 len(aval.shape))))
+                items.append((name, _render_spec(pspec, len(aval.shape))))
     return tuple(items)
 
 

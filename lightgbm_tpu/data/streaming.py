@@ -33,6 +33,7 @@ import numpy as np
 
 from . import ram_budget_bytes, record_stats, warn_over_budget
 from .. import log
+from .._backend import platform
 from ..config import Config
 from ..dataset import (
     BinnedDataset,
@@ -219,7 +220,7 @@ class StreamedBinnedDataset(BinnedDataset):
         depth = prefetch_depth(
             chunk_bytes, ram_budget_bytes(self.ram_budget_mb)
         )
-        donate = jax.default_backend() != "cpu"
+        donate = platform() != "cpu"
         step = _jitted_step(donate)
         t0 = time.monotonic()
         buf = jnp.zeros((G, npad), dtype=jnp.int32)

@@ -442,9 +442,9 @@ def train(
         and booster._gbdt.fused_eligible()
     )
     if not use_fused:
-        # the sync path costs a ~100 ms host readback per iteration on
-        # the TPU runtime — tell the user WHY they fell off the fused
-        # loop instead of silently training slower (VERDICT r3 weak #5)
+        # the sync path drains the device queue for a host readback
+        # every iteration — tell the user WHY they fell off the fused
+        # loop instead of silently training slower
         if fobj is not None:
             why = "custom fobj"
         elif feval is not None:
@@ -747,7 +747,7 @@ def cv(
         key=lambda cb: getattr(cb, "order", 0),
     )
 
-    # ---- fused cv (VERDICT r4 item 6): every fold's training rides the
+    # ---- fused cv: every fold's training rides the
     # chunked fused device loop, and because the traced step is
     # fold-agnostic (per-fold arrays are jit arguments, boosting.py
     # _FUSED_STEP_CACHE), fold 2..k reuse fold 1's trace+executable —

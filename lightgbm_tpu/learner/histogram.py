@@ -27,34 +27,67 @@ like the reference's GPU path (gpu_hist_t, docs/GPU-Performance.rst).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 HIST_BLK = 2048  # pallas row-block; device row padding is a multiple of this
 CH = 8
 NAT_CH = 5  # useful gh channels packed per slot (g_hi, g_lo, h_hi, h_lo, cnt)
+# scoped-VMEM limit every Pallas kernel is compiled with (pallas_hist
+# passes it as vmem_limit_bytes; a v5e core has 128 MiB). _round_caps /
+# _TAKE_L_CAP below are the compile limits established on the chip AT
+# this value
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _interpret_pallas() -> bool:
     """CI hook: LGBM_TPU_PALLAS_INTERPRET=1 runs the TPU kernels under
-    the pallas interpreter on CPU so kernel drift is caught off-hardware
-    (VERDICT r3 weak #8; the reference analog is running the CUDA tests'
-    logic on the CPU build)."""
+    the pallas interpreter on CPU so kernel drift is caught off-hardware."""
     import os
 
     return os.environ.get("LGBM_TPU_PALLAS_INTERPRET", "") == "1"
 
 
 def _use_pallas() -> bool:
-    if _interpret_pallas():
-        return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
+    from .._backend import on_tpu
+
+    return _interpret_pallas() or on_tpu()
+
+
+_gate_warned: set = set()
+
+
+def _pallas_ok(kernel: str, n_rows: int, fits: bool = True,
+               why: str = "") -> bool:
+    """The one gate in front of every Pallas kernel: the backend runs
+    Pallas (a TPU, or the interpreter under test), the row count is
+    HIST_BLK-aligned, and the caller's VMEM condition `fits` holds.
+    Off-TPU a miss is how the XLA formulations get chosen. ON a TPU a
+    miss means a chip run is about to take the slow formulation, so it
+    warns — once per (kernel, reason) — instead of passing silently."""
+    if not _use_pallas():
         return False
+    if n_rows % HIST_BLK != 0 or n_rows < HIST_BLK:
+        reason = (f"row count {n_rows} is not a positive multiple of "
+                  f"HIST_BLK={HIST_BLK}")
+    elif not fits:
+        reason = why
+    else:
+        return True
+    if (kernel, reason) not in _gate_warned:
+        _gate_warned.add((kernel, reason))
+        from .. import log
+
+        log.warning(
+            f"pallas {kernel} not used: {reason}; running the XLA "
+            "formulation instead (much slower on TPU)"
+        )
+    return False
 
 
 def _use_int4_oh() -> bool:
@@ -114,7 +147,7 @@ def _hist_fallback(bins_fm: jax.Array, gh8: jax.Array, num_bins: int,
 def histogram(bins_fm: jax.Array, gh8: jax.Array, num_bins: int) -> jax.Array:
     """(F, N) int32 bins + (8, N) channels -> (3, F, B) f32 histogram."""
     F, N = bins_fm.shape
-    if _use_pallas() and N % HIST_BLK == 0 and N >= HIST_BLK:
+    if _pallas_ok("hist_tpu", N):
         from .pallas_hist import hist_tpu
 
         return combine_ch(
@@ -141,7 +174,7 @@ def hist_slots(
     visit budget for sharded runs where local segments can exceed N/2.
     """
     F, N = bins_fm.shape
-    if _use_pallas() and N % HIST_BLK == 0 and N >= HIST_BLK:
+    if _pallas_ok("hist_slots_tpu", N):
         from .pallas_hist import hist_slots_tpu
 
         out = hist_slots_tpu(
@@ -235,13 +268,8 @@ def hist_nat_slots(
     F, N = bins_fm.shape
     nat_ch = 3 if quant else NAT_CH
     # VMEM guard: chunk the slot axis so the kernel's grid-constant
-    # output block stays within the scoped budget. Chip-calibrated
-    # compile limits, post-NT-kernel (BENCH_NOTES r4): ch5 S=32
-    # compiles / S=36 fails; ch3 S=64 compiles (6.06 ms; the pre-NT
-    # kernel failed past 48 — removing the in-kernel transpose freed
-    # scoped stack). The W tile, per-feature one-hots and
-    # double-buffered inputs cost roughly 2x the output block again.
-    # The byte formula guards wide feature sets; the empirical
+    # output block stays within its share of the stated scoped limit
+    # (_round_caps). The byte formula guards wide feature sets; the
     # per-channel-count cap guards the slot axis.
     per_slot = nat_ch * F * num_bins * 4
     s_cap, budget = _round_caps(nat_ch)
@@ -250,32 +278,42 @@ def hist_nat_slots(
     # block schedule — charge it against the scoped budget
     budget = max(budget - _oh_scratch_bytes(num_bins, use_i8), 0)
     s_max = max(1, min(budget // max(per_slot, 1), s_cap))
-    if (_use_pallas() and N % HIST_BLK == 0 and N >= HIST_BLK
-            and per_slot <= budget):
+    n_local, ax, mesh = _row_layout(N)
+    if _pallas_ok("hist_nat_tpu", n_local, per_slot <= budget,
+                  f"one slot's output block ({per_slot} B at "
+                  f"{F} columns x {num_bins} bins) exceeds the "
+                  f"{budget} B VMEM budget"):
         from .pallas_hist import hist_nat_tpu
 
         int4 = bool(use_i8 and _use_int4_oh())
-        parts = []
-        for c0 in range(0, num_slots, s_max):
-            sc = min(s_max, num_slots - c0)
-            if c0 == 0 and sc == num_slots:
-                local = slot
-            else:
-                in_chunk = (slot >= c0) & (slot < c0 + sc)
-                local = jnp.where(in_chunk, slot - c0, sc)
-            out = hist_nat_tpu(
-                bins_fm, gh8, local, sc, num_bins,
-                interpret=_interpret_pallas(), nat_ch=nat_ch,
-                int8=use_i8, oh_shift=oh_shift, int4=int4,
-            )  # (sc*nat_ch, F*B)
-            o = out.reshape(sc, nat_ch, F, num_bins)
-            if quant:
-                parts.append(o)
-            else:
-                parts.append(jnp.stack(
-                    [o[:, 0] + o[:, 1], o[:, 2] + o[:, 3], o[:, 4]], axis=1
-                ))
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+        def call(bins_fm, gh8, slot):
+            parts = []
+            for c0 in range(0, num_slots, s_max):
+                sc = min(s_max, num_slots - c0)
+                if c0 == 0 and sc == num_slots:
+                    local = slot
+                else:
+                    in_chunk = (slot >= c0) & (slot < c0 + sc)
+                    local = jnp.where(in_chunk, slot - c0, sc)
+                out = hist_nat_tpu(
+                    bins_fm, gh8, local, sc, num_bins,
+                    interpret=_interpret_pallas(), nat_ch=nat_ch,
+                    int8=use_i8, oh_shift=oh_shift, int4=int4,
+                )  # (sc*nat_ch, F*B)
+                o = out.reshape(sc, nat_ch, F, num_bins)
+                if quant:
+                    parts.append(o)
+                else:
+                    parts.append(jnp.stack(
+                        [o[:, 0] + o[:, 1], o[:, 2] + o[:, 3], o[:, 4]],
+                        axis=1))
+            out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            return out if ax is None else lax.psum(out, ax)
+
+        return _on_rows(
+            call, mesh, (P(None, ax), P(None, ax), P(ax)), P()
+        )(bins_fm, gh8, slot)
     return _hist_nat_fallback(bins_fm, gh8, slot, num_slots, num_bins,
                               quant=quant)
 
@@ -347,11 +385,16 @@ def rs_wire_dtype(local_rows: int, n_ranks: int,
 
 
 def _round_caps(nat_ch: int) -> tuple:
-    """(slot cap, scoped-VMEM budget) for the slot-packed kernels —
-    chip-calibrated compile limits shared by hist_nat_slots and the
-    fused round kernel (see the comment in hist_nat_slots)."""
-    return (32, int(4.6 * 2 ** 20)) if nat_ch >= 5 \
-        else (64, int(5.7 * 2 ** 20))
+    """(slot cap, output-block VMEM budget) for the slot-packed kernels,
+    shared by hist_nat_slots and the fused round kernel. The kernel's
+    scoped-VMEM need is a multiple of its grid-constant output block
+    (double-buffered block + the W tile, per-feature one-hots and
+    (S, blk) partition temporaries, all linear in slots): measured
+    5.2x at ch3 S=45 F=28 B=255 under libtpu 0.0.34 (19.03 MiB), so the
+    block may take a fifth of VMEM_LIMIT_BYTES less the iota scratch.
+    The slot caps bound the matmul M axis (S x channels) and Mosaic
+    compile time, which grows faster than linearly in S."""
+    return (32 if nat_ch >= 5 else 64), VMEM_LIMIT_BYTES // 5
 
 
 def _oh_scratch_bytes(num_bins: int, int8: bool) -> int:
@@ -388,12 +431,11 @@ def can_hist_round(n_rows: int, num_slots: int, num_feat: int,
     fit the scoped-VMEM schedule and caps the re-stream fan-out at
     _ROUND_MAX_CHUNKS."""
     s_max = _round_s_max(num_feat, num_bins, quant, int8)
-    return (
-        _use_pallas()
-        and n_rows % HIST_BLK == 0
-        and n_rows >= HIST_BLK
-        and s_max > 0
-        and num_slots <= _ROUND_MAX_CHUNKS * s_max
+    return _pallas_ok(
+        "hist_round_tpu", n_rows,
+        s_max > 0 and num_slots <= _ROUND_MAX_CHUNKS * s_max,
+        f"{num_slots} slots at {num_feat} columns x {num_bins} bins need "
+        f"more than {_ROUND_MAX_CHUNKS} chunks of {s_max} slots",
     )
 
 
@@ -458,36 +500,90 @@ def hist_round(
 _TAKE_L_CAP = (8 * 2 ** 20) // (HIST_BLK * 4)
 
 
+# (mesh, axis name) while a data-parallel boosting step is being
+# traced OUTSIDE the grower's shard_map: Mosaic kernels cannot be
+# partitioned by GSPMD ("wrap the call in a shard_map"), so the per-row
+# kernels the step calls on mesh-resident arrays — score updates, valid
+# traversal, leaf renewal — wrap themselves per shard. The grower's own
+# shard_map body clears it (its kernels already see one shard).
+_row_mesh: Optional[tuple] = None
+
+
+@contextlib.contextmanager
+def row_mesh(mesh, axis_name: str = "data"):
+    """Trace-time scope naming the data mesh (None clears it)."""
+    global _row_mesh
+    prev = _row_mesh
+    _row_mesh = None if mesh is None or mesh.devices.size <= 1 \
+        else (mesh, axis_name)
+    try:
+        yield
+    finally:
+        _row_mesh = prev
+
+
+def _row_layout(n_rows: int):
+    """(rows one kernel call sees, row PartitionSpec axis or None, mesh).
+    Without an active mesh: the whole array, no wrapping. With one: rows
+    split over the mesh axis when every shard stays HIST_BLK-aligned
+    (the training rows — padded to HIST_BLK x devices), else the call
+    runs replicated on every device (valid sets, padded per dataset)."""
+    if _row_mesh is None:
+        return n_rows, None, None
+    mesh, ax = _row_mesh
+    n = int(mesh.devices.size)
+    if n_rows % (n * HIST_BLK) == 0:
+        return n_rows // n, ax, mesh
+    return n_rows, None, mesh
+
+
+def _on_rows(call, mesh, in_specs, out_specs):
+    """`call` as is, or per shard (replicated when the specs say so)
+    over the active data mesh."""
+    if mesh is None:
+        return call
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def take_cols(tab: jax.Array, idx: jax.Array) -> jax.Array:
     """(k, L) table, (N,) int32 indices -> (k, N) tab[:, idx].
 
-    TPU: one-hot MXU contraction (pallas take_small_tpu, ~0.1 ms at 1M
-    rows) while L fits the VMEM one-hot tile (_TAKE_L_CAP); elsewhere
-    (large L, unaligned N, no TPU): plain take. Negative / >= L indices
-    return 0 on both paths."""
+    Small tables (L <= _TAKE_L_CAP) on TPU: one-hot MXU contraction
+    (pallas take_small_tpu). Large tables — the one-hot costs O(L) per
+    row, so past the cap the XLA gather IS the formulation (the
+    serving forest's T*M node table) — and non-TPU backends: plain
+    take. Negative / >= L indices return 0 on both paths."""
     N = idx.shape[0]
     L = tab.shape[1]
-    if (_use_pallas() and N % HIST_BLK == 0 and N >= HIST_BLK
-            and L <= _TAKE_L_CAP):
+    n_local, ax, mesh = _row_layout(N)
+    if L <= _TAKE_L_CAP and _pallas_ok("take_small_tpu", n_local):
         from .pallas_hist import take_small_tpu
 
-        return take_small_tpu(tab, idx, interpret=_interpret_pallas())
+        def call(t, i):
+            return take_small_tpu(t, i, interpret=_interpret_pallas())
+
+        return _on_rows(call, mesh, (P(), P(ax)), P(None, ax))(tab, idx)
     out = jnp.take(tab, jnp.clip(idx, 0, L - 1), axis=1)
     return jnp.where(((idx >= 0) & (idx < L))[None, :], out, 0.0)
 
 
 def seg_sum(vals: jax.Array, idx: jax.Array, num_out: int) -> jax.Array:
     """(k, N) values + (N,) int32 indices -> (k, num_out) per-index
-    column sums. TPU: one-hot MXU contraction (pallas seg_sum_tpu)
-    while num_out fits the VMEM one-hot tile (_TAKE_L_CAP); elsewhere:
-    XLA scatter-add. Out-of-range indices are dropped on both paths."""
+    column sums. TPU with num_out <= _TAKE_L_CAP: one-hot MXU
+    contraction (pallas seg_sum_tpu); larger outputs and non-TPU
+    backends: XLA scatter-add. Out-of-range indices are dropped on
+    both paths."""
     k, N = vals.shape
-    if (_use_pallas() and N % HIST_BLK == 0 and N >= HIST_BLK
-            and num_out <= _TAKE_L_CAP):
+    n_local, ax, mesh = _row_layout(N)
+    if num_out <= _TAKE_L_CAP and _pallas_ok("seg_sum_tpu", n_local):
         from .pallas_hist import seg_sum_tpu
 
-        return seg_sum_tpu(vals, idx, num_out,
-                           interpret=_interpret_pallas())
+        def call(v, i):
+            out = seg_sum_tpu(v, i, num_out, interpret=_interpret_pallas())
+            return out if ax is None else lax.psum(out, ax)
+
+        return _on_rows(call, mesh, (P(None, ax), P(ax)), P())(vals, idx)
     in_range = (idx >= 0) & (idx < num_out)
     safe = jnp.where(in_range, idx, num_out)  # num_out -> dropped
     return jnp.zeros((k, num_out), vals.dtype).at[:, safe].add(

@@ -34,7 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram import CH, HIST_BLK, NAT_CH
+from .histogram import CH, HIST_BLK, NAT_CH, VMEM_LIMIT_BYTES
 
 
 def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
@@ -164,10 +164,15 @@ def _swar_divisor(oh_shift: int) -> float:
 # after the even/odd plane split (see _swar_onehot4)
 _SWAR4_DIVISOR = 8.0
 
+# every kernel states its scoped-VMEM limit instead of inheriting the
+# compiler's default (which moves between toolchains): the slot caps in
+# histogram._round_caps are compile limits established AT this value
+_VMEM = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 # the histogram grid walks row blocks accumulating into grid-constant
 # output blocks: steps are NOT parallelizable, tell Mosaic so instead
 # of letting it infer (the chip-resident schedule contract, ISSUE 12)
-_ARBITRARY = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+_ARBITRARY = pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                  vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(
@@ -336,7 +341,7 @@ def _round_kernel(
     oh_shift: int, efb: bool, has_cat: bool,
 ):
     """Fused round step: partition decision + slot-packed histograms
-    in ONE data pass (VERDICT r4 item 2).
+    in ONE data pass.
 
     Compile-time contracts (no host callbacks, no f64, jaxpr size
     budget) are enforced by the `hist_round_fused` entry of
@@ -429,7 +434,10 @@ def _round_kernel(
             cat_ref[...], ohfb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )  # (S, blk): mask[s, fb_own[r]]
-        gl = jnp.where(is_cat_s, hits > 0, gl)
+        # mask algebra, not jnp.where: a select between two bool
+        # vectors makes Mosaic (libtpu 0.0.34) truncate i8 -> i1 on a
+        # (S, blk) vector, which it cannot lower
+        gl = (is_cat_s & (hits > 0)) | (~is_cat_s & gl)
 
     # new per-row leaf ids: memberships are disjoint, so summing the
     # masked deltas over the slot axis applies at most one update
@@ -576,6 +584,7 @@ def take_small_tpu(
         out_specs=pl.BlockSpec((k, blk), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((k, N), jnp.float32),
+        compiler_params=_VMEM,
         interpret=interpret,
     )(idx.reshape(1, N), tab)
 
@@ -624,6 +633,7 @@ def seg_sum_tpu(
         out_specs=pl.BlockSpec((k, num_out), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((k, num_out), jnp.float32),
+        compiler_params=_ARBITRARY,
         interpret=interpret,
     )(idx.reshape(1, N), vals)
 
@@ -743,6 +753,7 @@ def hist_slots_tpu(
         functools.partial(_hist_slots_kernel, F=F, B=B, blk=blk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S + 1, CH, F * B), jnp.float32),
+        compiler_params=_ARBITRARY,
         interpret=interpret,
     )(vblock, vslot_s, vlo, vhi, bins_fm, gh8)
     return out
@@ -772,6 +783,7 @@ def hist_tpu(
         ],
         out_specs=pl.BlockSpec((CH, F * B), lambda i: (0, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((CH, F * B), jnp.float32),
+        compiler_params=_ARBITRARY,
         interpret=interpret,
     )(bins_fm, gh8)
     return out.reshape(CH, F, B)

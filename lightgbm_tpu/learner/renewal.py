@@ -5,7 +5,7 @@ residuals of its in-bag rows (RegressionL1loss::RenewTreeOutput,
 regression_objective.hpp:251; gbdt.cpp:418 RenewTreeOutput before
 shrinkage).
 
-TPU formulation (round 5 — VERDICT r4 item 8): the previous version
+TPU formulation (round 5): the previous version
 sorted (leaf, residual) with `lax.sort`, which costs 0.3-2 s at 1M rows
 on this backend (plus minutes of per-shape compile) and knocked the
 renewal objectives off the fast path. This one never sorts: it runs a

@@ -2,9 +2,8 @@
 
 The permuted grower (permuted.py) keeps rows physically leaf-grouped so
 each split costs O(segment) — but maintaining that layout costs one
-full-array gather per split or round (75-120 ms at 1M x 36 channels:
-TPUs have no vector-gather hardware, see BENCH_NOTES.md). This grower
-never moves a row:
+full-array gather per split or round (TPUs have no vector-gather
+hardware). This grower never moves a row:
 
 - the partition is a per-row leaf-id vector updated with elementwise
   `where` (the reference CUDA data_index_to_leaf_index,
@@ -182,9 +181,9 @@ def grow_tree_rounds(
     # elected bundle columns cross the mesh. Single-host (ax is None)
     # voting degenerates to the plain path — there is no wire to save.
     use_voting = bool(spec.voting_k and ax is not None)
-    # per-node extras (VERDICT r4 item 4: extra_trees, ff_bynode, CEGB,
-    # interaction constraints used to fall off the fast path onto the
-    # ~30x-slower sequential permuted grower)
+    # per-node extras: extra_trees, ff_bynode, CEGB, interaction
+    # constraints ride the rounds grower (off it they would fall onto
+    # the much slower sequential permuted grower)
     per_node = bool(spec.extra_trees or spec.ff_bynode or spec.cegb
                     or spec.n_groups)
     if per_node and spec.mono_mode:
@@ -211,7 +210,7 @@ def grow_tree_rounds(
     oh_shift = int8_oh_shift(N, spec.quant_levels) if spec.quant_int8 else 0
     use_int8 = bool(spec.quant_int8 and oh_shift is not None)
     oh_shift = oh_shift or 0
-    # fused partition+histogram kernel (VERDICT r4 item 2): one pass
+    # fused partition+histogram kernel: one pass
     # computes the slot-packed child histograms AND the new row->leaf
     # vector; the separate (G, N) split-column select, membership
     # matmul and partition update disappear. Categorical splits ride
@@ -219,7 +218,7 @@ def grow_tree_rounds(
     # single-feature SWAR one-hot contracted against the per-slot
     # category masks.
     use_fused = can_hist_round(N, S, G, Bc, spec.quant, int8=use_int8)
-    # ---- reduce-scatter histogram wire (VERDICT r4 item 9): the full
+    # ---- reduce-scatter histogram wire: the full
     # psum ships every rank the whole f32 histogram; the reference
     # ships INTEGER histograms through ReduceScatter with per-rank
     # feature ownership (bin.h:63-81, data_parallel_tree_learner

@@ -10,6 +10,7 @@ NumPy fallbacks when no compiler is available.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,13 +20,28 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fastparse.cpp")
-_LIB = os.path.join(_DIR, "_fastparse.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# how this process got the library: "built" (compiled here), "loaded"
+# (a library built from this exact source was already on disk) or
+# "absent" (no compiler / build failed -> NumPy fallbacks). None until
+# the first get_lib(). chip_smoke.py prints it: the NumPy binning
+# fallback is several times slower and must not pass unnoticed.
+_status: Optional[str] = None
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """The library's path embeds a hash of the source it was built
+    from, so freshness is a CONTENT check: a copied tree, a restored
+    backup or an `rsync -t` cannot make a stale build look current the
+    way an mtime comparison could."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"_fastparse.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
     """Compile fastparse to a tmp file and atomically rename into
     place. The rename makes concurrent builders safe WITHOUT a lock:
     each builder — thread or process — writes its own tmp .so (pid +
@@ -36,9 +52,10 @@ def _build() -> bool:
     stall every thread touching the parser)."""
     import time
 
+    from .. import log
     from ..obs.metrics import record_native_build
 
-    tmp = f"{_LIB}.build.{os.getpid()}.{threading.get_ident()}"
+    tmp = f"{lib_path}.build.{os.getpid()}.{threading.get_ident()}"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
         _SRC, "-o", tmp,
@@ -47,19 +64,21 @@ def _build() -> bool:
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=180)
         if r.returncode != 0:
-            from .. import log
-
             record_native_build(time.perf_counter() - t0, ok=False)
             log.warning(
                 f"native fastparse build failed (falling back to numpy "
                 f"parsers): {r.stderr.strip()[-300:]}"
             )
             return False
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
         record_native_build(time.perf_counter() - t0, ok=True)
         return True
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
         record_native_build(time.perf_counter() - t0, ok=False)
+        log.warning(
+            f"native fastparse not built ({type(e).__name__}: {e}); "
+            "falling back to the slower numpy parsers/binning"
+        )
         return False
     finally:
         if os.path.exists(tmp):
@@ -69,33 +88,22 @@ def _build() -> bool:
                 pass
 
 
-def _load_or_build() -> Optional[ctypes.CDLL]:
-    """Build-if-stale + dlopen + bind, called OUTSIDE the module lock
-    (only the _lib/_tried state below is lock-guarded)."""
-    fresh = (
-        os.path.exists(_LIB)
-        and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)
-    )
-    if not fresh and not _build():
-        return None
+def _load_or_build() -> Tuple[Optional[ctypes.CDLL], str]:
+    """Build-if-missing + dlopen + bind, called OUTSIDE the module lock
+    (only the _lib/_tried/_status state below is lock-guarded).
+    Returns (library or None, status)."""
+    lib_path = _lib_path()
+    status = "loaded"
+    if not os.path.exists(lib_path):
+        if not _build(lib_path):
+            return None, "absent"
+        status = "built"
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(lib_path)
     except OSError:
-        return None
-    try:
-        _bind(lib)
-    except AttributeError:
-        # stale cached .so (newer mtime than the source but built
-        # from an older version, e.g. rsync -t / restored backup):
-        # rebuild once, then give up gracefully
-        if not _build():
-            return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-            _bind(lib)
-        except (OSError, AttributeError):
-            return None
-    return lib
+        return None, "absent"
+    _bind(lib)
+    return lib, status
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -103,18 +111,26 @@ def get_lib() -> Optional[ctypes.CDLL]:
     unavailable (no g++ / build failure). Concurrent first callers may
     each run a build (atomic-rename safe); the winner's handle is the
     one cached."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
-    lib = _load_or_build()
+    lib, status = _load_or_build()
     with _lock:
         # prefer a non-None result: a transiently-failing concurrent
         # loader must not cache None over another thread's good handle
         if not _tried or (_lib is None and lib is not None):
             _tried = True
             _lib = lib
+            _status = status
         return _lib
+
+
+def status() -> str:
+    """"built" | "loaded" | "absent" — see `_status`."""
+    get_lib()
+    with _lock:
+        return _status or "absent"
 
 
 def _bind(lib: ctypes.CDLL) -> None:

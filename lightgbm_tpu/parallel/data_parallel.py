@@ -28,24 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .._backend import on_tpu
 from ..learner.grower import GrowerSpec, TreeArrays, grow_tree
+from ..learner.histogram import row_mesh
 from ..learner.split import SplitParams
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma):
-    """jax.shard_map across jax versions: new jax exposes it with a
-    `check_vma` flag; 0.4.x ships jax.experimental.shard_map with the
-    equivalent `check_rep` (and interim versions expose jax.shard_map
-    still taking check_rep — probe the signature, not the version)."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check_vma})
 
 
 def make_mesh(devices=None, axis_name: str = "data") -> Mesh:
@@ -100,12 +86,14 @@ class DataParallelGrower:
         def fn(bins, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
                feat_mask, params, valid, bundle, rng_key, group_mat, cegb,
                forced, gh_scale):
-            tree, row_leaf = grow_tree(
-                bins, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
-                feat_mask, params, self.spec, valid=valid, bundle=bundle,
-                rng_key=rng_key, group_mat=group_mat, cegb=cegb,
-                forced=forced, gh_scale=gh_scale,
-            )
+            # inside shard_map every kernel already sees one shard
+            with row_mesh(None):
+                tree, row_leaf = grow_tree(
+                    bins, nan_bin, num_bins, mono, is_cat, grad, hess,
+                    mask, feat_mask, params, self.spec, valid=valid,
+                    bundle=bundle, rng_key=rng_key, group_mat=group_mat,
+                    cegb=cegb, forced=forced, gh_scale=gh_scale,
+                )
             # tree state is identical on all shards (computed from psum'd
             # histograms); mark it replicated for the out_spec
             tree = jax.tree.map(lambda a: jax.lax.pmean(a, axis_name) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
@@ -115,7 +103,7 @@ class DataParallelGrower:
                     row, rep, rep, rep, rep, rep, rep)
         out_specs = (jax.tree.map(lambda _: rep, _tree_arrays_structure(spec)), row)
         self._fn = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=in_specs,
@@ -174,10 +162,9 @@ class DataParallelGrower:
 
         n_dev = self.mesh.devices.size
         n_rows = dev["bins"].shape[1]
-        platform = jax.devices()[0].platform
         multiproc = jax.process_count() > 1
         local_dev = n_dev // jax.process_count() if multiproc else n_dev
-        if platform == "tpu" and (n_rows // max(local_dev, 1)) % HIST_BLK != 0:
+        if on_tpu() and (n_rows // max(local_dev, 1)) % HIST_BLK != 0:
             from .. import log
 
             log.warning(

@@ -27,7 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..learner.grower import GrowerSpec, TreeArrays, grow_tree
 from ..learner.split import SplitParams
-from .data_parallel import shard_map_compat
 
 
 class FeatureParallelGrower:
@@ -63,7 +62,7 @@ class FeatureParallelGrower:
         in_specs = (bins_spec, fshard, fshard, fshard, fshard,
                     rep, rep, rep, fshard, rep, rep)
         self._fn = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=in_specs,

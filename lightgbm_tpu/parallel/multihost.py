@@ -75,9 +75,7 @@ def init_distributed(
 
     # NOTE: no jax.process_count()/devices() probe here — touching the
     # backend before jax.distributed.initialize() poisons it
-    from jax._src import distributed as _dist
-
-    if getattr(_dist.global_state, "client", None) is not None:
+    if jax.distributed.is_initialized():
         return jax.process_index()
     mlist = []
     if machine_list_file:

@@ -15,7 +15,7 @@ stack:
   serving degradation paths raise and the HTTP transport maps to
   status codes.
 - ``backoff`` + ``heartbeat`` — the single retry-with-backoff helper
-  (bench.py probe, fleet scrape, cluster join) and per-worker
+  (fleet scrape, gateway attempts, cluster join) and per-worker
   heartbeat files with worker-death detection for run_distributed.
 """
 

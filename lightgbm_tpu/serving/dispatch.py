@@ -41,6 +41,7 @@ from ..obs.metrics import (
 )
 from ..resilience.errors import (
     DeadlineExceeded,
+    InjectedFault,
     QueueOverflow,
     ShutdownError,
 )
@@ -72,8 +73,8 @@ class BucketDispatcher:
         # model tags this entry's /metrics series with {model=...}
         # (fleet tenants set it; docs/OBSERVABILITY.md cardinality note)
         self._stats = latency_stats(name, model=model)
-        # degradation path (docs/RESILIENCE.md): when a device scoring
-        # call faults, a chunk can be rescored by the host tree-walker
+        # degradation path (docs/RESILIENCE.md): when the device_put
+        # fault site fires, a chunk is rescored by the host tree-walker
         # instead of failing the request. The registry installs this as
         # a closure over the source Booster: (chunk (n,F) f32, start,
         # end) -> (summed raw margins (n,K), leaf indices (n,T) with
@@ -118,11 +119,15 @@ class BucketDispatcher:
         dispatcher goes through here, so no request shape escapes the
         ladder (the bounded-compiles contract covers pred_leaf too).
 
-        A device fault mid-chunk (the ``device_put`` fault-injection
-        site models one) degrades THAT chunk to the host tree-walker
-        when ``host_fallback`` is installed: slower, metric-counted,
-        warned once — but the request still answers (parity is
-        regression-tested in tests/test_resilience.py)."""
+        An injected device fault (the ``device_put`` fault site)
+        degrades THAT chunk to the host tree-walker when
+        ``host_fallback`` is installed: slower, metric-counted, warned
+        once — but the request still answers (parity is
+        regression-tested in tests/test_resilience.py). ONLY the
+        injected fault degrades: anything the scorer itself raises — a
+        lowering or Mosaic/XLA compile error above all — reaches the
+        caller, so a scorer that cannot run on the chip fails loudly
+        instead of answering every request from the host."""
         import jax.numpy as jnp
 
         N = X.shape[0]
@@ -139,9 +144,7 @@ class BucketDispatcher:
                 )
             try:
                 fault_point("device_put")
-                score, leaf = self.forest.apply(jnp.asarray(chunk), tw)
-                out = np.asarray(score)[:rows], np.asarray(leaf)[:rows]
-            except Exception:  # noqa: BLE001 — any device-path fault
+            except InjectedFault:
                 if self.host_fallback is None:
                     raise
                 if not self._fallback_warned:
@@ -158,6 +161,9 @@ class BucketDispatcher:
                     np.asarray(s, np.float32),
                     np.asarray(lf)[:rows],
                 )
+            else:
+                score, leaf = self.forest.apply(jnp.asarray(chunk), tw)
+                out = np.asarray(score)[:rows], np.asarray(leaf)[:rows]
             yield out
             pos += top
 

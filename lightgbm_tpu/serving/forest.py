@@ -276,7 +276,11 @@ def forest_apply(tables, X, tree_w, *, has_cat: bool = True,
         # linear semantics (tree.cpp:137-153): const + coeffs . x,
         # rows with NaN in a used feature fall back to leaf_value
         val = jnp.where(anynan, val, const + contrib)
-    score = (val * tree_w[None, :]) @ tables["class_onehot"]  # (N, K)
+    # HIGHEST precision: the default TPU matmul multiplies f32 in bf16,
+    # which would round every leaf value to 8 mantissa bits — the
+    # 1e-5 parity with the host walker needs the exact f32 product
+    score = jnp.dot(val * tree_w[None, :], tables["class_onehot"],
+                    precision=lax.Precision.HIGHEST)  # (N, K)
     return score, leaf
 
 
@@ -444,6 +448,7 @@ def contrib_apply(tables, ctables, X, tree_w, *, has_cat: bool = True):
     unwound-sum permutation-weight DP over every (row, tree, leaf)
     lane at one static path depth."""
     import jax.numpy as jnp
+    from jax import lax
 
     T, L = tables["leaf_value"].shape
     M = tables["pack"].shape[1] // T
@@ -507,7 +512,8 @@ def contrib_apply(tables, ctables, X, tree_w, *, has_cat: bool = True):
             + ctables["feat"])                           # (T, L, P)
     out = jnp.zeros((N, K * (F + 1)), jnp.float32)
     out = out.at[:, cols.reshape(-1)].add(delta.reshape(N, -1))
-    bias = (tw * ctables["expect"]) @ tables["class_onehot"]  # (K,)
+    bias = jnp.dot(tw * ctables["expect"], tables["class_onehot"],
+                   precision=lax.Precision.HIGHEST)  # (K,)
     bcols = (jnp.arange(K, dtype=jnp.int32) + 1) * (F + 1) - 1
     out = out.at[:, bcols].add(jnp.broadcast_to(bias[None], (N, K)))
     return out
@@ -586,7 +592,7 @@ class TensorForest:
     ``mesh=None`` (or a 1-device mesh) uses the shared module-level jit
     — model hot-swaps with identical table shapes reuse the executable.
     With a multi-device mesh the row axis is sharded over
-    ``axis_name`` through the same ``shard_map_compat`` seam training
+    ``axis_name`` through the same ``jax.shard_map`` seam training
     uses (tables replicated); callers must pad rows to a multiple of
     the mesh size (``BucketDispatcher`` aligns its ladder for this).
     """
@@ -627,8 +633,6 @@ class TensorForest:
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from ..parallel.data_parallel import shard_map_compat
-
             rep = NamedSharding(self.mesh, P())
             self.tables = {
                 k: jax.device_put(jnp.asarray(v), rep)
@@ -643,7 +647,7 @@ class TensorForest:
                                     max_depth=max_depth)
 
             tspec = jax.tree.map(lambda _: P(), self.tables)
-            self._sharded = jax.jit(shard_map_compat(
+            self._sharded = jax.jit(jax.shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(tspec, P(axis_name, None), P()),
                 out_specs=(P(axis_name, None), P(axis_name, None)),

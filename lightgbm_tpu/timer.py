@@ -80,15 +80,9 @@ def _sync_devices() -> None:
     computation enqueued per device flushes everything before it."""
     import jax
 
-    try:
-        jax.effects_barrier()
-    except Exception:  # noqa: BLE001 — older jax without effects_barrier
-        pass
+    jax.effects_barrier()
     for d in jax.local_devices():
-        try:
-            (jax.device_put(0, d) + 0).block_until_ready()
-        except Exception:  # noqa: BLE001 — never break the timed path
-            continue
+        (jax.device_put(0, d) + 0).block_until_ready()
 
 
 class Timer:

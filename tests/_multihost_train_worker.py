@@ -8,15 +8,13 @@ import hashlib
 import os
 import sys
 
+# CPU-only by construction: two processes on ONE machine, and a chip
+# belongs to one process at a time
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = " ".join(
     f for f in os.environ.get("XLA_FLAGS", "").split()
     if "xla_force_host_platform_device_count" not in f
 )
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

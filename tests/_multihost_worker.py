@@ -8,6 +8,8 @@ its psums ride the cross-process (Gloo, stand-in for DCN) collectives.
 import os
 import sys
 
+# CPU-only by construction: two processes on ONE machine, and a chip
+# belongs to one process at a time
 os.environ["JAX_PLATFORMS"] = "cpu"
 # pytest's conftest exports an 8-virtual-device XLA_FLAGS; this worker
 # needs exactly ONE local device per process (2-process global mesh)
@@ -17,8 +19,6 @@ os.environ["XLA_FLAGS"] = " ".join(
 )
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

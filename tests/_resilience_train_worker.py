@@ -14,25 +14,14 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 
 def main() -> int:
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
-    # same persistent compile cache as tests/conftest.py — the crash,
-    # resume, and clean runs would otherwise each pay the cold compile
-    from lightgbm_tpu._cache import machine_tag
-
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        f"/root/.cache/jax_comp_cache_{machine_tag()}",
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
+    # the crash, resume and clean runs share the suite's persistent
+    # compile cache: GBDT.__init__ calls _cache.ensure_compile_cache
     from lightgbm_tpu.cli import main as cli_main
 
     return cli_main(sys.argv[1:])

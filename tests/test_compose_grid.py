@@ -1,4 +1,4 @@
-"""Pairwise composability grid (VERDICT r3 #4): every
+"""Pairwise composability grid: every
 (tree_learner x feature-flag) pair must either train cleanly or fail
 with a documented LightGBMError — never crash mid-iteration or train
 silently-wrong trees. The reference composes these freely

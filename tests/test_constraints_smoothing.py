@@ -193,8 +193,8 @@ def test_monotone_methods_violation_scan(method, direction):
 @pytest.mark.parametrize("method", ["basic", "intermediate", "advanced"])
 @pytest.mark.parametrize("direction", [1, -1])
 def test_monotone_rounds_mode_violation_scan(method, direction):
-    """Monotone constraints on the TPU fast path (VERDICT r4 item 3 +
-    ISSUE 14): the round-batched grower enforces basic via inherited
+    """Monotone constraints on the TPU fast path: the round-batched
+    grower enforces basic via inherited
     intervals, intermediate via the per-round ancestry-bounds recompute
     with the same-round opposite-subtree conflict guard, and advanced
     via the per-leaf bin-range overlap refinement of the

@@ -217,7 +217,7 @@ def test_run_passes_rejects_unknown_names():
     with pytest.raises(KeyError, match="nope"):
         run_passes(["nope"])
     assert set(PASSES) == {"lint", "concurrency", "jaxpr", "cost",
-                           "bench_gate", "scale"}
+                           "scale"}
 
 
 # ------------------------------------------------------ real entries

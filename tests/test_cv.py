@@ -1,5 +1,5 @@
 """lgb.cv (reference engine.py:627): fused chunked per-fold training
-with ONE shared traced step across folds (VERDICT r4 item 6)."""
+with ONE shared traced step across folds."""
 
 from __future__ import annotations
 

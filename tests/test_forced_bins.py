@@ -1,7 +1,7 @@
 """forcedbins_filename: user-forced bin boundaries (reference
 src/io/dataset_loader.cpp GetForcedBins + bin.cpp forced-bounds path)
 must actually change bin-edge construction — the key was accepted but
-unwired before this test existed (VERDICT r5 missing #2)."""
+unwired before this test existed."""
 
 import json
 
@@ -109,7 +109,7 @@ def test_forced_bins_file_errors(tmp_path):
 
 
 def test_unwired_params_warn():
-    """The accepted-but-unwired sweep (VERDICT r5 missing #2): params
+    """The accepted-but-unwired sweep: params
     with no effect in this build must WARN when set away from their
     inactive value, and every _UNIMPLEMENTED entry must really be
     unreferenced outside config.py."""

@@ -102,7 +102,7 @@ def test_fused_bagging_and_feature_fraction():
 
 def test_fused_step_memo_across_boosters():
     """cv folds / repeated trains with identical shapes+config reuse one
-    traced+compiled fused step (VERDICT r4 item 6): the second Booster
+    traced+compiled fused step: the second Booster
     must skip trace+compile entirely."""
     import time
 

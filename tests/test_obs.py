@@ -565,8 +565,41 @@ def test_log_fatal_only_verbosity_respected_for_registered_logger():
 
 
 # ------------------------------------------------------------ bench_serve
-def test_bench_serve_writes_artifact(tmp_path, monkeypatch):
+def _load_bench_serve(name: str):
+    spec = importlib.util.spec_from_file_location(
+        name, REPO / "bench_serve.py"
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_bench_serve_refuses_cpu_backend(tmp_path, monkeypatch):
+    """The serving bench measures an accelerator or nothing: on the
+    CPU backend main() exits non-zero naming what it found and writes
+    no artifact."""
     monkeypatch.setenv("BENCH_SERVE_DIR", str(tmp_path))
+    mod = _load_bench_serve("bench_serve_cpu")
+    with pytest.raises(SystemExit) as exc:
+        mod.main()
+    assert exc.value.code not in (0, None)
+    assert "'cpu'" in str(exc.value.code)
+    assert not list(tmp_path.glob("BENCH_SERVE_r*.json"))
+
+
+def test_bench_serve_writes_artifact(tmp_path, monkeypatch):
+    """The bench's phases and artifact, driven on the CPU mesh through
+    a TEST seam (the device check is substituted; the script itself has
+    no CPU mode). The artifact names its device and carries no number
+    from an earlier run; a run manifest is stamped in."""
+    import bench
+
+    monkeypatch.setattr(
+        bench, "require_accelerator",
+        lambda who: {"platform": "test", "device_kind": "seam",
+                     "device_count": 8})
+    monkeypatch.setenv("BENCH_SERVE_DIR", str(tmp_path))
+    monkeypatch.setenv("BENCH_MANIFEST_OUT", str(tmp_path / "m.json"))
     monkeypatch.setenv("BENCH_SERVE_TRAIN_ROWS", "400")
     monkeypatch.setenv("BENCH_SERVE_FEATURES", "4")
     monkeypatch.setenv("BENCH_SERVE_TREES", "5")
@@ -574,11 +607,8 @@ def test_bench_serve_writes_artifact(tmp_path, monkeypatch):
     monkeypatch.setenv("BENCH_SERVE_REQUESTS", "8")
     monkeypatch.setenv("BENCH_SERVE_BATCH", "16")
     monkeypatch.setenv("BENCH_SERVE_THREADS", "2")
-    spec = importlib.util.spec_from_file_location(
-        "bench_serve", REPO / "bench_serve.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    monkeypatch.setenv("BENCH_SERVE_GATEWAY_BACKENDS", "")
+    mod = _load_bench_serve("bench_serve")
     assert mod.main() == 0
     files = list(tmp_path.glob("BENCH_SERVE_r*.json"))
     assert len(files) == 1
@@ -587,50 +617,36 @@ def test_bench_serve_writes_artifact(tmp_path, monkeypatch):
         assert key in data and data[key] >= 0
     assert data["requests"] == 8
     assert data["stats"].get("count", 0) >= 1
-
-
-@pytest.mark.slow
-def test_bench_serve_provenance_and_carry_forward(tmp_path, monkeypatch):
-    """Satellite: bench_serve stamps run_id + run-manifest path into
-    its artifact and carries last_tpu_verified with bench.py's stale
-    semantics (off-chip run -> stale: true, ignored by the gate)."""
-    for k, v in (("BENCH_SERVE_DIR", str(tmp_path)),
-                 ("BENCH_SERVE_TRAIN_ROWS", "400"),
-                 ("BENCH_SERVE_FEATURES", "4"),
-                 ("BENCH_SERVE_TREES", "3"), ("BENCH_SERVE_LEAVES", "7"),
-                 ("BENCH_SERVE_REQUESTS", "4"),
-                 ("BENCH_SERVE_BATCH", "8"),
-                 ("BENCH_SERVE_THREADS", "1")):
-        monkeypatch.setenv(k, v)
-    spec = importlib.util.spec_from_file_location(
-        "bench_serve_prov", REPO / "bench_serve.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.LAST_TPU_VERIFIED = {
-        "qps": 5000.0, "p99_ms": 1.0, "platform": "tpu", "round": 9,
-    }
-    assert mod.main() == 0
-    artifact = next(tmp_path.glob("BENCH_SERVE_r*.json"))
-    data = json.loads(artifact.read_text())
-    assert data["run_id"]
-    mpath = Path(data["run_manifest"])
-    assert mpath.name.startswith("run_manifest_serve_r")
-    manifest = json.loads(mpath.read_text())
+    assert (data["platform"], data["device_kind"],
+            data["device_count"]) == ("test", "seam", 8)
+    assert "last_tpu_verified" not in data
+    assert data["gateway"] is None
+    manifest = json.loads(Path(data["run_manifest"]).read_text())
     assert manifest["extra"]["run_id"] == data["run_id"]
-    assert manifest["extra"]["artifact"] == str(artifact)
-    # this run ran off-chip -> the carried chip numbers are stale
-    assert data["platform"] != "tpu"
-    assert data["last_tpu_verified"]["stale"] is True
-    # ...and therefore contribute NOTHING to the gate's trajectory
-    from lightgbm_tpu.analysis.bench_gate import load_trajectory
+    assert manifest["extra"]["artifact"] == str(files[0])
 
-    assert load_trajectory(tmp_path)["serve"] == []
+
+def test_bench_serve_gateway_phase_refuses_on_accelerator(monkeypatch):
+    """One process per chip: a parent that holds an accelerator must not
+    spawn task=serve children that each need it — the phase answers
+    with the reason instead of a number."""
+    import jax
+
+    mod = _load_bench_serve("bench_serve_gw")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import subprocess
+
+    monkeypatch.setattr(
+        subprocess, "Popen",
+        lambda *a, **k: pytest.fail("spawned a chip-needing child"))
+    out = mod._gateway_phase("unused.txt", "", 4, 1)
+    assert set(out) == {"refused"} and "one process per chip" in \
+        out["refused"]
 
 
 def test_bench_train_manifest_stamp(tmp_path, monkeypatch):
     """bench.py's provenance hook: run manifest written, path + run id
-    folded into the partial state the final JSON reports."""
+    folded into the state the final JSON reports."""
     spec = importlib.util.spec_from_file_location(
         "bench_prov", REPO / "bench.py"
     )
@@ -638,7 +654,9 @@ def test_bench_train_manifest_stamp(tmp_path, monkeypatch):
     spec.loader.exec_module(mod)
     monkeypatch.setenv("BENCH_MANIFEST_OUT",
                        str(tmp_path / "manifest.json"))
-    mod._STATE["run_id"] = "test-run"
+    mod._STATE.update(run_id="test-run", rows=1000, leaves=7,
+                      trees_per_sec=1.0, platform="test",
+                      device_kind="seam", device_count=1)
     mod.write_run_manifest({"objective": "binary", "num_leaves": 7})
     assert mod._STATE["run_manifest"] == str(tmp_path / "manifest.json")
     m = json.loads((tmp_path / "manifest.json").read_text())

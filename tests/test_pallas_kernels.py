@@ -1,4 +1,4 @@
-"""Pallas TPU kernel coverage OFF hardware (VERDICT r3 weak #8).
+"""Pallas TPU kernel coverage OFF hardware.
 
 `LGBM_TPU_PALLAS_INTERPRET=1` makes histogram.py dispatch to the real
 pallas kernels under `pallas_call(interpret=True)` on CPU, so the MXU
@@ -454,3 +454,66 @@ def test_fused_round_chunked_matches_fallback(interp, monkeypatch):
     assert (fused[1] == fb[1]).mean() > 0.999
     np.testing.assert_array_equal(fused[2], fb[2])
     np.testing.assert_array_equal(fused[3], fb[3])
+
+
+def test_row_mesh_wraps_kernels_per_shard(interp):
+    """On a data mesh the per-row kernels the boosting step calls
+    OUTSIDE the grower's shard_map must wrap themselves per shard —
+    Mosaic kernels cannot be partitioned by GSPMD (the four-chip
+    bring-up failure, PR 21). Same numbers as the unwrapped call, every
+    pallas_call inside a shard_map, and the replicated route for row
+    counts that do not split into HIST_BLK-aligned shards."""
+    import jax
+
+    from lightgbm_tpu.learner.histogram import (
+        row_mesh, seg_sum, take_cols)
+    from lightgbm_tpu.parallel.data_parallel import make_mesh
+
+    mesh = make_mesh()
+    n_dev = int(mesh.devices.size)
+    rs = np.random.RandomState(4)
+    L = 15
+    tab = jnp.asarray(rs.randn(3, L).astype(np.float32))
+
+    def both(idx, vals):
+        return take_cols(tab, idx), seg_sum(vals, idx, L)
+
+    for N in (n_dev * HIST_BLK, HIST_BLK):  # sharded / replicated
+        idx = jnp.asarray(rs.randint(-1, L + 1, N).astype(np.int32))
+        vals = jnp.asarray(rs.randint(-4, 5, (2, N)).astype(np.float32))
+        plain = both(idx, vals)
+
+        def on_mesh(idx, vals):
+            with row_mesh(mesh):
+                return both(idx, vals)
+
+        got = jax.jit(on_mesh)(idx, vals)
+        for a, b in zip(got, plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert _pallas_calls(jax.make_jaxpr(both)(idx, vals)) == \
+            {False: 2}
+        assert _pallas_calls(jax.make_jaxpr(on_mesh)(idx, vals)) == \
+            {True: 2}
+
+
+def _pallas_calls(closed) -> dict:
+    """{inside a shard_map?: count} over every pallas_call of a jaxpr."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    counts: dict = {}
+
+    def walk(jaxpr, inside):
+        for e in jaxpr.eqns:
+            name = e.primitive.name
+            if name == "pallas_call":
+                counts[inside] = counts.get(inside, 0) + 1
+                continue
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    if isinstance(sub, ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, Jaxpr):
+                        walk(sub, inside or name == "shard_map")
+
+    walk(closed.jaxpr, False)
+    return counts

@@ -272,8 +272,7 @@ def _extras_problem(n=3000, f=8, seed=11):
 ])
 def test_rounds_per_node_extras_quality(extra):
     """extra_trees / feature_fraction_bynode / CEGB on the rounds fast
-    path (VERDICT r4 item 4 — these configs used to fall back to the
-    ~30x-slower sequential grower). Quality must stay in family with
+    path. Quality must stay in family with
     the exact grower's."""
     import lightgbm_tpu as lgb
 

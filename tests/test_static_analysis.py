@@ -149,7 +149,6 @@ def _wire_fixture_jaxpr(widen: bool):
     from jax.sharding import PartitionSpec as P
 
     from lightgbm_tpu.analysis.jaxpr_audit import _mesh
-    from lightgbm_tpu.parallel.data_parallel import shard_map_compat
 
     mesh = _mesh()
 
@@ -159,8 +158,8 @@ def _wire_fixture_jaxpr(widen: bool):
             wire, "data", scatter_dimension=0, tiled=True
         )
 
-    sm = shard_map_compat(f, mesh=mesh, in_specs=(P(None, "data"),),
-                          out_specs=P("data"), check_vma=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(None, "data"),),
+                       out_specs=P("data"), check_vma=False)
     return jax.make_jaxpr(sm)(
         jax.ShapeDtypeStruct((16, 8), jnp.int32)
     )
@@ -404,13 +403,6 @@ def test_strict_equivalent_in_process():
     results = run_audits()
     bad = [r.format() for r in results if not r.ok]
     assert not bad, "\n".join(bad)
-    # the bench-trajectory gate (Pass 6) runs in tier-1 too: cheap
-    # JSON parsing, and a regressed checked-in BENCH point must fail
-    # the suite just like a lint violation would
-    from lightgbm_tpu.analysis.bench_gate import run_gate
-
-    gate = run_gate()
-    assert gate.ok, gate.format()
     # Pass 7 (scaling contracts) tier-1 hook: the tiny D in {1, 2}
     # ladder on the three law archetypes (1/D, elected + its baseline,
     # bounded) — budget pins still checked EXACT at those rungs. The

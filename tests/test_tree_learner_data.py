@@ -264,8 +264,8 @@ def test_feature_parallel_matches_serial():
 
 def test_data_parallel_quant_reduce_scatter_wire():
     """Quantized data-parallel training rides the int32 reduce-scatter
-    histogram wire with per-rank feature ownership (VERDICT r4 item 9;
-    reference bin.h:63-81 + data_parallel_tree_learner.cpp:286).
+    histogram wire with per-rank feature ownership (reference
+    bin.h:63-81 + data_parallel_tree_learner.cpp:286).
     Lockstep contract: predictions match serial quantized training, and
     the compiled program actually contains an integer reduce-scatter."""
     X, y = _binary_problem(seed=11)

@@ -1,5 +1,5 @@
 """Streamed two_round text loading (dataset_loader.cpp:210 two_round +
-:1399 two-pass extract; VERDICT r4 item 7): the whole-file loader
+:1399 two-pass extract): the whole-file loader
 materializes O(file) host memory, the streamed path O(chunk) + the
 binned matrix."""
 

@@ -11,7 +11,7 @@
 #
 # Budget maintenance (run + review + commit the diff):
 #   tools/analysis.sh --update-budget     # jaxpr_budget.json
-#   tools/analysis.sh --refresh-budgets   # cost_budget.json + bench_budget.json
+#   tools/analysis.sh --refresh-budgets   # cost_budget.json + scale_budget.json
 #                                         #   + scale_budget.json (+ diffs)
 #
 # The python entry point forces jax onto a cpu 8-device mesh itself, so

@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
 # Zero-downtime rolling restart of a task=gateway fleet
 # (docs/RESILIENCE.md "Serving gateway" — the runbook this script
-# automates, end to end, on the CPU backend):
+# automates, end to end). CPU-ONLY: it starts N+1 serving processes on
+# one machine, and a chip belongs to one process at a time:
 #
 #   1. train a tiny model and start N task=serve backends + the
 #      task=gateway front end;
@@ -61,20 +62,13 @@ def free_port():
     return port
 
 
-import os
-import tempfile
-
 # readiness-gated warmup is the load-bearing runbook step: with
 # serve_warmup=true the registry precompiles every bucket BEFORE the
 # HTTP listener binds, so /readyz green implies warm — the gateway
 # never routes live traffic onto a cold restarted process (a cold
 # first score would stall past the client deadline and shed 503).
-# The persistent compile cache makes each restart's re-warm a cache
-# hit instead of a recompile.
-_env = dict(os.environ)
-_env.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(tempfile.gettempdir(), "lgbmtpu_gateway_rolling_cache"))
+# The persistent compile cache (placed by lightgbm_tpu/_cache.py)
+# makes each restart's re-warm a cache hit instead of a recompile.
 
 
 def spawn_backend(port):
@@ -82,7 +76,7 @@ def spawn_backend(port):
         [sys.executable, "-m", "lightgbm_tpu", "task=serve",
          f"input_model={work}/model.txt", f"serve_port={port}",
          "serve_buckets=16,64", "serve_warmup=true", "verbosity=-1"],
-        env=_env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
 
 
 def wait_ready(url, proc, timeout=300):

@@ -10,8 +10,9 @@
 # per-model series; finally an online-loop smoke — task=loop serving
 # v0 over HTTP while /v1/ingest streams microbatches, one gated
 # promotion to v1, and a /metrics scrape asserting the promotion +
-# ingest counters (docs/RESILIENCE.md "Online loop"). Runs on the CPU
-# backend so it is safe anywhere.
+# ingest counters (docs/RESILIENCE.md "Online loop"). CPU-ONLY: it
+# starts one serving process after another while earlier ones may still
+# hold the backend, and a chip belongs to one process at a time.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu

@@ -1,7 +1,7 @@
 """Micro-benchmarks for the TPU histogram kernels and growers.
 
 Run on a live chip; prints one JSON line per measurement. Used to tune
-the slot-packed kernel and record per-phase timings in BENCH_NOTES.md.
+the slot-packed kernel.
 """
 
 import json
