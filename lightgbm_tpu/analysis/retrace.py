@@ -21,16 +21,20 @@ The listener counts for the whole process lifetime once installed (an
 int increment per trace/compile event — events fire per compilation,
 not per dispatch, so the idle cost is nil): guards read deltas, and
 `compile_counters()` exposes the running totals to the run manifest
-(obs/manifest.py). Install happens on the first guard or explicitly
-via `ensure_installed()` (cli.py does this when a manifest or profile
-is requested, so the counts cover the run from the start).
+(obs/manifest.py). It also keeps the SECONDS JAX hands it with each
+event — tracing, lowering, backend compile, persistent-cache
+retrieval — so a set-up time can be split into what it was spent on.
+Install happens on the first guard or explicitly via
+`ensure_installed()` (cli.py does this when a manifest or profile is
+requested, so the counts cover the run from the start).
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Any, Dict, Iterator, List, Optional, Sequence
+import time
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 
 class RetraceError(AssertionError):
@@ -41,15 +45,42 @@ class RetraceError(AssertionError):
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
+# duration events whose seconds are kept, by their compile_counters() key
+_SECONDS_KEYS = {
+    _TRACE_EVENT: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _COMPILE_EVENT: "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
+
 _lock = threading.Lock()
 _installed = False
 _counters: Dict[str, int] = {_TRACE_EVENT: 0, _COMPILE_EVENT: 0}
+_seconds: Dict[str, float] = dict.fromkeys(_SECONDS_KEYS, 0.0)
+# per (thread, event): (start, seconds) of the regions counted so far
+# that a region still open may enclose. JAX fires the trace event for
+# every jitted function traced INSIDE another trace too, inner before
+# outer (a training step's inner regions summed to twice its wall
+# time), so when an enclosing region arrives the regions it holds are
+# taken back out: the total is the union, wall seconds on that thread.
+# One entry per top-level region stays behind — per compilation, not
+# per dispatch.
+_counted: Dict[Tuple[int, str], List[Tuple[float, float]]] = {}
 
 
 def _listener(event: str, duration: float, **kwargs: Any) -> None:
-    if event in _counters:
-        with _lock:
+    if event not in _seconds:
+        return
+    # the event fires as its region ends
+    start = time.perf_counter() - duration
+    with _lock:
+        if event in _counters:
             _counters[event] += 1
+        inner = _counted.setdefault((threading.get_ident(), event), [])
+        while inner and inner[-1][0] >= start:
+            _seconds[event] -= inner.pop()[1]
+        _seconds[event] += duration
+        inner.append((start, duration))
 
 
 def _install() -> None:
@@ -70,15 +101,24 @@ def ensure_installed() -> None:
     _install()
 
 
-def compile_counters() -> Dict[str, int]:
+def compile_counters() -> Dict[str, float]:
     """Process-lifetime (since install) jaxpr-trace and backend-compile
-    event totals — the run manifest's compile section."""
+    event totals, and the seconds of tracing (`trace_s`), lowering to
+    MLIR (`lower_s`), backend compile (`backend_compile_s`) and
+    persistent-cache retrieval (`cache_load_s`) — the run manifest's
+    compile section. JAX fires the backend-compile event around
+    compile-or-load-from-cache, so `backend_compiles` includes the
+    cache hits and `backend_compile_s` includes `cache_load_s`: the
+    difference is what XLA / Mosaic really compiled."""
     with _lock:
-        return {
+        out: Dict[str, float] = {
             "jaxpr_traces": _counters[_TRACE_EVENT],
             "backend_compiles": _counters[_COMPILE_EVENT],
             "listener_installed": int(_installed),
         }
+        for event, key in _SECONDS_KEYS.items():
+            out[key] = _seconds[event]
+        return out
 
 
 def _cache_size(fn: Any) -> Optional[int]:

@@ -44,8 +44,8 @@ from .tree import Tree, traverse_tree_bins
 # _ObsHooks divides it by the dispatch's round count (the booster's
 # _last_dispatch_rounds) to keep per-round record durations. With
 # tpu_chunk_scan=off each dispatch is one round, as historically.
-# obs.tracing records these as trace-event spans and jax.profiler
-# traces carry the same names via jax.named_scope.
+# obs.tracing records these as trace-event spans, and a jax.profiler
+# trace holds each as a host event `lgbm:<name>` (timer.Timer.scope).
 ROUND_PHASES = (
     "round: gradients",
     "round: grow",
@@ -439,11 +439,13 @@ class GBDT:
         # objective/strategy init AFTER ensure_row_block: they cache
         # padded per-row arrays and must see the final row padding
         if self.objective is not None:
-            self.objective.init(train_set)
+            with _gt.scope("boosting.objective_init"):
+                self.objective.init(train_set)
         self.strategy = create_sample_strategy(
             config, train_set.num_data, group=train_set.metadata.group
         )
-        self.dev = train_set.device_arrays()
+        with _gt.scope("boosting.device_inputs"):
+            self.dev = train_set.device_arrays()
         from .binning import BinType
 
         cat_subset = any(
@@ -734,21 +736,23 @@ class GBDT:
         # per-tree wire estimate; refined by the data-parallel grower's
         # voting-aware wire_bytes_per_tree once it exists (below)
         self.voting_wire_bytes_est = None
-        self.train = _ScoreSet(
-            train_set,
-            self._init_score_arr(train_set),
-            "training",
-            [m for m in create_metrics(config)],
-        )
-        meta = train_set.metadata
-        for m in self.train.metrics:
-            m.init(meta.label, meta.weight, meta.group)
+        with _gt.scope("boosting.score_init"):
+            self.train = _ScoreSet(
+                train_set,
+                self._init_score_arr(train_set),
+                "training",
+                [m for m in create_metrics(config)],
+            )
+            meta = train_set.metadata
+            for m in self.train.metrics:
+                m.init(meta.label, meta.weight, meta.group)
+            self._label_dev = (
+                jnp.asarray(train_set.padded(meta.label))
+                if meta.label is not None else None
+            )
         self._boosted_from_average = False
         self._init_scores = [0.0] * self.num_class
         self._feat_rng = np.random.RandomState(config.feature_fraction_seed)
-        self._label_dev = (
-            jnp.asarray(train_set.padded(meta.label)) if meta.label is not None else None
-        )
         if self._parallel_mode == "data":
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -776,7 +780,8 @@ class GBDT:
                 self.voting_wire_bytes_est = self._dp.wire_bytes_per_tree(
                     int(self.dev["bins"].shape[0])
                 )
-            self.dev = self._dp.shard_inputs(self.dev)
+            with _gt.scope("boosting.device_inputs"):
+                self.dev = self._dp.shard_inputs(self.dev)
             # free the unsharded device copies — this booster reads only
             # self.dev for the train set; other boosters re-push fresh
             train_set.invalidate_device_cache()
@@ -818,7 +823,8 @@ class GBDT:
             from .parallel.feature_parallel import FeatureParallelGrower
 
             self._dp = FeatureParallelGrower(self._mesh, self.spec)
-            self.dev = self._dp.shard_inputs(self.dev)
+            with _gt.scope("boosting.device_inputs"):
+                self.dev = self._dp.shard_inputs(self.dev)
             train_set.invalidate_device_cache()
 
     # ------------------------------------------------------------------
@@ -1008,15 +1014,16 @@ class GBDT:
         return jnp.asarray(score)
 
     def add_valid(self, valid_set: BinnedDataset, name: str) -> None:
-        ss = _ScoreSet(
-            valid_set,
-            self._init_score_arr(valid_set),
-            name,
-            [m for m in create_metrics(self.config)],
-        )
-        meta = valid_set.metadata
-        for m in ss.metrics:
-            m.init(meta.label, meta.weight, meta.group)
+        with _gt.scope("boosting.score_init"):
+            ss = _ScoreSet(
+                valid_set,
+                self._init_score_arr(valid_set),
+                name,
+                [m for m in create_metrics(self.config)],
+            )
+            meta = valid_set.metadata
+            for m in ss.metrics:
+                m.init(meta.label, meta.weight, meta.group)
         self.valids.append(ss)
 
     @property
@@ -1044,8 +1051,6 @@ class GBDT:
             return
         import jax
         import jax.numpy as jnp
-
-        from .timer import global_timer as _gt
 
         with _gt.scope("materialize host trees (readback)"):
             fetched = jax.device_get(self._pending)
@@ -1176,7 +1181,8 @@ class GBDT:
                 and not self.has_init_score
             ):
                 for k in range(K):
-                    init = self.objective.boost_from_score(k)
+                    with _gt.scope("objective.boost_from_score"):
+                        init = self.objective.boost_from_score(k)
                     if abs(init) > 1e-15:
                         init_scores[k] = init
                         self.train.score = self.train.score.at[k].add(init)
@@ -1756,6 +1762,10 @@ class GBDT:
 
     def fused_start(self, track_train: bool) -> None:
         """Initialize the device loop state; performs BoostFromAverage."""
+        with _gt.scope("boosting.fused_start"):
+            self._fused_start(track_train)
+
+    def _fused_start(self, track_train: bool) -> None:
         import jax.numpy as jnp
 
         K = self.num_class
@@ -1767,7 +1777,8 @@ class GBDT:
             and not self.has_init_score
         ):
             for k in range(K):
-                init = self.objective.boost_from_score(k)
+                with _gt.scope("objective.boost_from_score"):
+                    init = self.objective.boost_from_score(k)
                 if abs(init) > 1e-15:
                     init_scores[k] = init
                     self.train.score = self.train.score.at[k].add(init)
@@ -1775,7 +1786,8 @@ class GBDT:
                         vs.score = vs.score.at[k].add(init)
                     log.info(f"Start training from score {init:f}")
         self._init_scores = init_scores
-        self._build_fused(track_train)
+        with _gt.scope("boosting.build_step"):
+            self._build_fused(track_train)
         self._fstate = {
             "score": self.train.score,
             "vscores": tuple(vs.score for vs in self.valids),

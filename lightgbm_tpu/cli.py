@@ -673,9 +673,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                         rec.write_chrome(
                             os.path.join(profile_dir, "trace_events.json")
                         )
-                        rec.write_jsonl(
-                            os.path.join(profile_dir, "trace_events.jsonl")
-                        )
                     except OSError as e:
                         log.warning(f"trace export failed: {e}")
             if profile_dir or manifest_path:

@@ -320,8 +320,9 @@ _PARAMS: Dict[str, _P] = {
     # analog of the reference's compile-time USE_TIMETAG) — no restart
     # needed
     "timetag": (False, bool, (), None),
-    # capture a jax.profiler trace + host span trace + run manifest
-    # into this directory (span names align via jax.named_scope)
+    # capture a jax.profiler trace (device ops and the program's own
+    # `lgbm:` spans on one clock) + host span trace + run manifest
+    # into this directory
     "profile_dir": ("", str, (), None),
     # write a run-manifest JSON (config/topology/compiles/wire bytes)
     # to this path after the task finishes

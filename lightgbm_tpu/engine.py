@@ -19,6 +19,7 @@ from .obs.anomaly import AnomalyAbort
 from .resilience import checkpoint as ckpt_mod
 from .resilience import faultinject
 from .resilience.faultinject import fault_point
+from .timer import global_timer as _gt
 
 
 def _resolve_num_boost_round(params: Dict[str, Any], num_boost_round: int) -> Tuple[Dict, int]:
@@ -236,7 +237,30 @@ def train(
     callbacks: Optional[List[Callable]] = None,
     fobj: Optional[Callable] = None,
 ) -> Booster:
-    """Train a model (reference engine.py:109 lgb.train)."""
+    """Train a model (reference engine.py:109 lgb.train).
+
+    The whole call is the `engine.train` span; the spans of its layer
+    boundaries (docs/OBSERVABILITY.md, "Spans of one lgb.train call")
+    open on this thread, so in a profiler trace they nest inside it."""
+    with _gt.scope("engine.train"):
+        return _train(
+            params, train_set, num_boost_round, valid_sets, valid_names,
+            feval, init_model, keep_training_booster, callbacks, fobj,
+        )
+
+
+def _train(
+    params: Dict[str, Any],
+    train_set: Dataset,
+    num_boost_round: int,
+    valid_sets: Optional[List[Dataset]],
+    valid_names: Optional[List[str]],
+    feval: Optional[Callable],
+    init_model: Optional[Union[str, Booster]],
+    keep_training_booster: bool,
+    callbacks: Optional[List[Callable]],
+    fobj: Optional[Callable],
+) -> Booster:
     params, num_boost_round = _resolve_num_boost_round(params, num_boost_round)
     cfg_probe = Config(params)
     if cfg_probe.timetag:
@@ -319,25 +343,26 @@ def train(
             " MB (per-chunk RSS recorded in the run manifest)"
         )
 
-    booster = Booster(params=params, train_set=train_set)
     valid_sets = valid_sets or []
     valid_names = valid_names or []
     valid_contain_train = False
-    for i, vs in enumerate(valid_sets):
-        name = valid_names[i] if i < len(valid_names) else f"valid_{i}"
-        if vs is train_set:
-            valid_contain_train = True
-            booster._train_data_name = name
-            continue
-        booster.add_valid(vs, name)
+    with _gt.scope("engine.booster_init"):
+        booster = Booster(params=params, train_set=train_set)
+        for i, vs in enumerate(valid_sets):
+            name = valid_names[i] if i < len(valid_names) else f"valid_{i}"
+            if vs is train_set:
+                valid_contain_train = True
+                booster._train_data_name = name
+                continue
+            booster.add_valid(vs, name)
 
-    if init_model is not None:
-        ib = (
-            init_model
-            if isinstance(init_model, Booster)
-            else Booster(model_file=init_model)
-        )
-        booster._continue_from(ib)
+        if init_model is not None:
+            ib = (
+                init_model
+                if isinstance(init_model, Booster)
+                else Booster(model_file=init_model)
+            )
+            booster._continue_from(ib)
 
     cb_before = [cb for cb in callbacks if getattr(cb, "before_iteration", False)]
     cb_after = [cb for cb in callbacks if not getattr(cb, "before_iteration", False)]
@@ -473,7 +498,6 @@ def train(
             done = 0
             stop = False
             from .obs.metrics import record_eval_values, record_training_round
-            from .timer import global_timer as _gt
 
             while done < num_boost_round and not stop:
                 n = min(chunk, num_boost_round - done)
@@ -490,26 +514,29 @@ def train(
                     obs_hooks.start_chunk(
                         len(records), time.perf_counter() - t_chunk
                     )
-                for j, evals in enumerate(records):
-                    i = done + j
-                    fault_point("round", resume_offset + i)
-                    evaluation_result_list = evals
-                    record_eval_values(evals)
-                    if obs_hooks is not None:
-                        obs_hooks.fused_round(i, j, evals)
-                    _snapshot(i, evals)
-                    try:
-                        for cb in cb_after:
-                            cb(CallbackEnv(booster, params,
-                                           resume_offset + i, 0,
-                                           total_rounds, evals))
-                    except EarlyStopException as e:
-                        booster.best_iteration = e.best_iteration + 1
-                        evaluation_result_list = e.best_score
-                        # truncate counts TOTAL iterations: keep loaded trees
-                        gbdt.fused_truncate(gbdt._init_iters + i + 1)
-                        stop = True
-                        break
+                # one span per CHUNK: the rounds' records replayed
+                with _gt.scope("engine.callbacks"):
+                    for j, evals in enumerate(records):
+                        i = done + j
+                        fault_point("round", resume_offset + i)
+                        evaluation_result_list = evals
+                        record_eval_values(evals)
+                        if obs_hooks is not None:
+                            obs_hooks.fused_round(i, j, evals)
+                        _snapshot(i, evals)
+                        try:
+                            for cb in cb_after:
+                                cb(CallbackEnv(booster, params,
+                                               resume_offset + i, 0,
+                                               total_rounds, evals))
+                        except EarlyStopException as e:
+                            booster.best_iteration = e.best_iteration + 1
+                            evaluation_result_list = e.best_score
+                            # truncate counts TOTAL iterations: keep
+                            # loaded trees
+                            gbdt.fused_truncate(gbdt._init_iters + i + 1)
+                            stop = True
+                            break
                 done += max(len(records), 1)
                 if gbdt._stopped:
                     # the sync path runs cb_after once for the stop iteration
@@ -614,28 +641,31 @@ def train(
         if obs_hooks is not None:
             obs_hooks.close()
 
-    # flush the async training pipeline (fast-path pending device trees)
-    booster._gbdt._materialize()
-    # surface the run's sentinel verdict on the booster: the online
-    # promotion gate (online/gate.py) reads trips from the refit result
-    # directly instead of the module-global recorder summary
-    if obs_hooks is not None and obs_hooks.sentinel is not None:
-        booster.anomaly_summary = obs_hooks.sentinel.summary()
-    # the stop condition is only detected every _check_every iterations on
-    # the fast path; _materialize may have truncated blindly-trained
-    # iterations — clamp iteration-derived state to the surviving models
-    n_iters = booster._gbdt.num_trees() // booster._gbdt.num_class
-    if booster.best_iteration > n_iters:
-        booster.best_iteration = n_iters
-    if n_iters < booster._gbdt._init_iters + i + 1:
-        # truncation rolled back the blindly-trained iterations whose
-        # scores produced the last eval — don't record stale values
-        evaluation_result_list = []
+    with _gt.scope("engine.finish"):
+        # flush the async training pipeline (fast-path pending device
+        # trees)
+        booster._gbdt._materialize()
+        # surface the run's sentinel verdict on the booster: the online
+        # promotion gate (online/gate.py) reads trips from the refit
+        # result directly instead of the module-global recorder summary
+        if obs_hooks is not None and obs_hooks.sentinel is not None:
+            booster.anomaly_summary = obs_hooks.sentinel.summary()
+        # the stop condition is only detected every _check_every
+        # iterations on the fast path; _materialize may have truncated
+        # blindly-trained iterations — clamp iteration-derived state to
+        # the surviving models
+        n_iters = booster._gbdt.num_trees() // booster._gbdt.num_class
+        if booster.best_iteration > n_iters:
+            booster.best_iteration = n_iters
+        if n_iters < booster._gbdt._init_iters + i + 1:
+            # truncation rolled back the blindly-trained iterations whose
+            # scores produced the last eval — don't record stale values
+            evaluation_result_list = []
 
-    # record best score
-    for item in evaluation_result_list or []:
-        booster.best_score.setdefault(item[0], collections.OrderedDict())
-        booster.best_score[item[0]][item[1]] = item[2]
+        # record best score
+        for item in evaluation_result_list or []:
+            booster.best_score.setdefault(item[0], collections.OrderedDict())
+            booster.best_score[item[0]][item[1]] = item[2]
     return booster
 
 

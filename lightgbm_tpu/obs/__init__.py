@@ -7,10 +7,10 @@ instrumented entries):
 - ``metrics`` — a thread-safe **metrics registry** (counters / gauges /
   histograms with labels) with Prometheus text exposition, served from
   the serving HTTP transport's ``/metrics`` route;
-- ``tracing`` — **span tracing** layered on ``timer.Timer`` +
-  ``jax.named_scope``, exportable as Chrome trace-event JSON
-  (Perfetto) and a JSONL event log, name-aligned with ``jax.profiler``
-  traces captured via the ``profile_dir`` CLI param;
+- ``tracing`` — **span tracing** layered on ``timer.Timer``: every
+  scope is a ``lgbm:<name>`` host event in a ``jax.profiler`` trace
+  (the ``profile_dir`` CLI param), and a ``TraceRecorder`` exports the
+  same spans as Chrome trace-event JSON (Perfetto) without a profiler;
 - ``manifest`` — per-run **manifest JSON**: config, device topology,
   compile counts (retrace guard), phase timings, metrics snapshot, and
   runtime collective wire bytes vs the static ``cost_budget.json``

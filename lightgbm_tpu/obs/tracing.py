@@ -1,16 +1,16 @@
 """Span tracing: Chrome trace-event export layered on the phase timer.
 
-``timer.Timer.scope`` already wraps every instrumented host region in
-``jax.named_scope``, so device profiles collected with ``jax.profiler``
-carry the same names. This module adds the HOST half: while a
+``timer.Timer.scope`` opens every instrumented host region as a
+``jax.profiler.TraceAnnotation`` named ``lgbm:<name>``: under a
+profiler session (the ``profile_dir`` CLI param) the spans are host
+events of the profiler's own trace, on the device ops' clock — that
+``.xplane.pb`` is the timeline to open when host and device have to be
+laid over each other. This module is the profiler-free half: while a
 ``TraceRecorder`` is active, every scope also records a complete-event
-span (phase ``X``), and ad-hoc regions can use :func:`span` directly.
-The result exports two ways:
-
-- ``write_chrome(path)`` — Chrome trace-event JSON (open in Perfetto /
-  chrome://tracing, or drop next to a ``jax.profiler`` trace captured
-  over the same run via the ``profile_dir`` CLI param);
-- ``write_jsonl(path)`` — one event per line for ad-hoc analysis.
+span (phase ``X``) on the recorder's own clock (``t0`` = its creation),
+and ad-hoc regions can use :func:`span` directly. ``write_chrome(path)``
+exports Chrome trace-event JSON (open in Perfetto / chrome://tracing):
+host spans only, for runs where no profiler session is wanted.
 
 Recording is host-side only (the recorder is a Python list behind a
 lock); nothing here runs inside jit, so the audited jaxprs stay
@@ -70,17 +70,6 @@ class TraceRecorder:
         with self._lock:
             self._events.append(ev)
 
-    def add_counter(self, name: str, values: Dict[str, float]) -> None:
-        ev = {
-            "name": name,
-            "ph": "C",
-            "ts": round((time.perf_counter() - self.t0) * 1e6, 3),
-            "pid": os.getpid(),
-            "args": {k: float(v) for k, v in values.items()},
-        }
-        with self._lock:
-            self._events.append(ev)
-
     # ------------------------------------------------------------------
     def events(self) -> List[Dict[str, Any]]:
         with self._lock:
@@ -102,12 +91,6 @@ class TraceRecorder:
     def write_chrome(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
-
-    def write_jsonl(self, path: str) -> None:
-        events = self.events()
-        with open(path, "w") as f:
-            for ev in events:
-                f.write(json.dumps(ev) + "\n")
 
 
 _lock = threading.Lock()
@@ -142,8 +125,8 @@ def stop_tracing() -> Optional[TraceRecorder]:
 
 
 @contextmanager
-def tracing(chrome_path: Optional[str] = None,
-            jsonl_path: Optional[str] = None) -> Iterator[TraceRecorder]:
+def tracing(chrome_path: Optional[str] = None
+            ) -> Iterator[TraceRecorder]:
     """Record spans for the duration of the block; optionally export on
     exit. Owns start/stop, so it must not wrap a region that already
     has an active recorder (start_tracing would alias it)."""
@@ -154,8 +137,6 @@ def tracing(chrome_path: Optional[str] = None,
         stop_tracing()
         if chrome_path:
             rec.write_chrome(chrome_path)
-        if jsonl_path:
-            rec.write_jsonl(jsonl_path)
 
 
 @contextmanager

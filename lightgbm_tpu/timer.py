@@ -7,9 +7,15 @@ TPU adaptation: phases are HOST-side regions (dispatch, collect,
 binning, eval). Device work inside jit is asynchronous, so a scope that
 must include device completion passes `block=True` to synchronize
 before stopping the clock (used by bench/profilers, off in production
-paths). Scopes also enter `jax.profiler.TraceAnnotation`-compatible
-`jax.named_scope` so traces collected with jax.profiler line up with
-the same names.
+paths).
+
+Every scope is also a `jax.profiler.TraceAnnotation` named
+`lgbm:<name>`: while a profiler session is active (`jax.profiler.
+start_trace`, the `profile_dir` param, the benchmark's `--trace 1`) it
+is a host event in the profiler's own trace, on the device ops' clock,
+nested under the scopes that enclose it on its thread. The session is
+the switch; with none active an annotation costs well under a
+microsecond and records nothing.
 
 Enable summary-at-exit with env LIGHTGBM_TPU_TIMETAG=1 (the analog of
 the reference's compile-time USE_TIMETAG), with the `timetag` config /
@@ -19,8 +25,8 @@ restarting the process.
 
 While an obs.tracing recorder is active, every scope additionally
 records a Chrome trace-event span (the recorder installs itself here
-through `set_trace_sink`), so the phase table, the trace timeline, and
-jax.profiler annotations all carry the same names.
+through `set_trace_sink`), so the phase table, the recorder's timeline
+and the profiler's host events all carry the same names.
 """
 
 from __future__ import annotations
@@ -31,6 +37,10 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Callable, Dict, Iterator, List, Optional
+
+# prefix of this program's host events in a profiler trace ("bench:" is
+# the benchmark harness's)
+TRACE_PREFIX = "lgbm:"
 
 # active span sinks: obs.tracing installs `(name, start_s, dur_s) ->
 # None` here while recording, and obs.recorder adds its per-round
@@ -109,30 +119,40 @@ class Timer:
     def scope(self, name: str, block: bool = False) -> Iterator[None]:
         """Time a region; with block=True waits for completion of all
         dispatched device work (every local device) before stopping
-        the clock, so the region includes its dispatched work."""
-        sinks = _trace_sinks
-        if not self.enabled and not sinks:
-            yield
-            return
+        the clock, so the region includes its dispatched work.
+
+        The region is always a `TraceAnnotation` (module docstring);
+        the stopwatch and the sinks run only when the timer is enabled
+        or a sink is installed. `jax.named_scope` only prefixes the
+        HLO metadata of whatever is TRACED inside the region (op names
+        in a compiled module); it writes no event anywhere."""
         import jax
 
-        t0 = time.perf_counter()
-        with jax.named_scope(name.replace(" ", "_")):
-            yield
-        if block:
-            _sync_devices()
-        dt = time.perf_counter() - t0
-        if self.enabled:
-            self._acc[name] = self._acc.get(name, 0.0) + dt
-            self._cnt[name] = self._cnt.get(name, 0) + 1
-        for sink in sinks:
-            sink(name, t0, dt)
+        with jax.profiler.TraceAnnotation(TRACE_PREFIX + name):
+            sinks = _trace_sinks
+            if not self.enabled and not sinks:
+                yield
+                return
+            t0 = time.perf_counter()
+            with jax.named_scope(name.replace(" ", "_")):
+                yield
+            if block:
+                _sync_devices()
+            dt = time.perf_counter() - t0
+            if self.enabled:
+                self._acc[name] = self._acc.get(name, 0.0) + dt
+                self._cnt[name] = self._cnt.get(name, 0) + 1
+            for sink in sinks:
+                sink(name, t0, dt)
 
     def add(self, name: str, seconds: float,
             start: Optional[float] = None) -> None:
         """Record an externally-timed region: accumulates like scope()
         and reports to the active trace sink (`start` is the region's
-        time.perf_counter() start, for span placement)."""
+        time.perf_counter() start, for span placement). The region is
+        over when this is called, so it cannot be a `TraceAnnotation`:
+        it does not appear in a profiler trace (one caller, the eager
+        loop's score update)."""
         if self.enabled:
             self._acc[name] = self._acc.get(name, 0.0) + seconds
             self._cnt[name] = self._cnt.get(name, 0) + 1

@@ -8,6 +8,7 @@ import importlib.util
 import json
 import re
 import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -181,9 +182,7 @@ def test_trace_export_fused_round_spans(rng, tmp_path):
     X = rng.randn(400, 4)
     y = (X[:, 0] > 0).astype(np.float32)
     path = tmp_path / "trace.json"
-    jsonl = tmp_path / "trace.jsonl"
-    with tracing.tracing(chrome_path=str(path),
-                         jsonl_path=str(jsonl)) as rec:
+    with tracing.tracing(chrome_path=str(path)) as rec:
         _train({"objective": "binary", "num_leaves": 7}, X, y, rounds=4)
     data = json.loads(path.read_text())
     assert "traceEvents" in data
@@ -194,10 +193,6 @@ def test_trace_export_fused_round_spans(rng, tmp_path):
         assert "pid" in e and "tid" in e and e["name"]
     fused = [e for e in spans if e["name"] == boosting.FUSED_ROUND_PHASE]
     assert len(fused) == 1  # 4 rounds = one chunk dispatch
-    # the JSONL log carries the same events one-per-line
-    lines = [json.loads(l) for l in jsonl.read_text().splitlines()]
-    assert sum(1 for e in lines
-               if e.get("name") == boosting.FUSED_ROUND_PHASE) == 1
     assert rec.events()  # recorder still readable after export
     # per-round dispatch keeps the one-span-per-round stream
     path2 = tmp_path / "trace_off.json"
@@ -262,14 +257,13 @@ def _validate_chrome_trace(data):
 def test_trace_export_schema_valid(rng, tmp_path):
     """The full Chrome export passes trace-event schema validation
     (required ph/ts/pid/tid/name fields, paired B/E or complete X),
-    including instant + counter + metadata events."""
+    including instant + metadata events."""
     X = rng.randn(300, 4)
     y = (X[:, 0] > 0).astype(np.float32)
     path = tmp_path / "trace.json"
     with tracing.tracing(chrome_path=str(path)) as rec:
         _train({"objective": "binary", "num_leaves": 7}, X, y, rounds=2)
         rec.add_instant("checkpoint", {"k": 1})
-        rec.add_counter("queue", {"depth": 3.0})
     _validate_chrome_trace(json.loads(path.read_text()))
 
 
@@ -280,6 +274,158 @@ def test_trace_validation_catches_unpaired_begin():
     ]}
     with pytest.raises(AssertionError, match="unclosed B"):
         _validate_chrome_trace(bad)
+
+
+# ------------------------------------- program spans in a profiler trace
+@pytest.fixture(scope="module")
+def profiled_job(tmp_path_factory):
+    """The same tiny 8-round fused job twice: bare (no profiler, no
+    timetag, no sink), then under a jax.profiler session. Returns the
+    phase-timer summary before and after the bare run, both model
+    texts, and the `lgbm:` host events of the profiled run, per thread
+    line."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from lightgbm_tpu.timer import TRACE_PREFIX, global_timer
+
+    rs = np.random.RandomState(11)
+    X = rs.randn(600, 5)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    vs = lgb.Dataset(X[:200], label=y[:200], reference=ds,
+                     free_raw_data=False)
+    ds.construct()
+    vs.construct()
+
+    def job():
+        return lgb.train(
+            {"objective": "binary", "num_leaves": 7, "metric": "auc",
+             "verbosity": -1}, ds, num_boost_round=8, valid_sets=[vs],
+            valid_names=["valid"]).model_to_string()
+
+    was_enabled = global_timer.enabled
+    global_timer.disable()
+    try:
+        summary_before = global_timer.summary()
+        bare = job()
+        summary_after = global_timer.summary()
+        out = tmp_path_factory.mktemp("xplane")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # spans only, not every Python call
+        jax.profiler.start_trace(str(out), profiler_options=opts)
+        try:
+            profiled = job()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        global_timer.enabled = was_enabled
+    (pb,) = out.glob("plugins/profile/*/*.xplane.pb")
+    lines = []
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(
+                ((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                 for e in line.events if e.name.startswith(TRACE_PREFIX)),
+                key=lambda e: (e[1], -e[2]))
+            if evs:
+                lines.append(evs)
+    return {"summaries": (summary_before, summary_after), "bare": bare,
+            "profiled": profiled, "lines": lines}
+
+
+def test_program_spans_nest_in_the_profiler_trace(profiled_job):
+    """ACCEPTANCE: under a profiler session one lgb.train call leaves
+    its layer-boundary spans as `lgbm:` host events on ONE thread, in
+    order, nested the way the call nests."""
+    (events,) = profiled_job["lines"]  # one thread holds them all
+    by_name = {}
+    for name, s, e in events:
+        by_name.setdefault(name, []).append((s, e))
+    (train,) = by_name["lgbm:engine.train"]
+    assert all(train[0] <= s and e <= train[1] for _, s, e in events)
+    order = ["lgbm:engine.booster_init", "lgbm:boosting.fused_start",
+             "lgbm:fused dispatch", "lgbm:fused collect (readback)"]
+    spans = []
+    for n in order:
+        (one,) = by_name[n]
+        spans.append(one)
+    for (_, e0), (s1, _) in zip(spans, spans[1:]):
+        assert e0 <= s1, "layer-boundary spans out of order or overlapping"
+    (fs,) = by_name["lgbm:boosting.fused_start"]
+    for child in ("lgbm:objective.boost_from_score",
+                  "lgbm:boosting.build_step"):
+        (c,) = by_name[child]
+        assert fs[0] <= c[0] and c[1] <= fs[1], child
+    (bi,) = by_name["lgbm:engine.booster_init"]
+    for child in ("lgbm:boosting.objective_init",
+                  "lgbm:boosting.device_inputs"):
+        (c,) = by_name[child]
+        assert bi[0] <= c[0] and c[1] <= bi[1], child
+    # the train set and the valid set each get their score arrays
+    assert len(by_name["lgbm:boosting.score_init"]) == 2
+    assert all(bi[0] <= s and e <= bi[1]
+               for s, e in by_name["lgbm:boosting.score_init"])
+    (fd,) = by_name["lgbm:fused dispatch"]
+    steps = by_name["lgbm:" + boosting.FUSED_ROUND_PHASE]
+    assert len(steps) == 2  # 8 rounds = two rung-4 chunk dispatches
+    assert all(fd[0] <= s and e <= fd[1] for s, e in steps)
+    for n in ("lgbm:engine.callbacks", "lgbm:engine.finish",
+              "lgbm:materialize host trees (readback)"):
+        assert n in by_name, n
+
+
+def test_program_spans_are_per_call_not_per_round(profiled_job):
+    """An 8-round fused job opens at most 40 spans: none sits inside a
+    per-round, per-leaf or per-row loop of the fused path."""
+    n = sum(len(evs) for evs in profiled_job["lines"])
+    assert 10 <= n <= 40, n
+
+
+def test_spans_without_a_profiler_record_and_change_nothing(profiled_job):
+    """No profiler, no timetag, no sink: the scopes accumulate nothing,
+    and the model is byte-identical to the profiled run's."""
+    before, after = profiled_job["summaries"]
+    # (empty, unless an earlier test of this process left entries)
+    assert after == before
+    assert profiled_job["bare"] == profiled_job["profiled"]
+
+
+def test_compile_counters_keep_seconds_by_stage():
+    """compile_counters() splits compile time into trace / lower /
+    backend-compile seconds (a nested jit's trace is not counted
+    twice); the two event counts count as before."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.analysis import retrace
+
+    retrace.ensure_installed()
+
+    @jax.jit
+    def inner(x):
+        return jnp.tanh(x) * 3.0
+
+    def fresh(x):
+        return inner(x).sum() + inner(x + 1).sum()
+
+    x = jnp.asarray(np.arange(7, dtype=np.float32))
+    before = retrace.compile_counters()
+    t0 = time.perf_counter()
+    jax.jit(fresh)(x).block_until_ready()
+    wall = time.perf_counter() - t0
+    after = retrace.compile_counters()
+    assert after["jaxpr_traces"] >= before["jaxpr_traces"] + 2  # + inner
+    assert after["backend_compiles"] == before["backend_compiles"] + 1
+    grew = {k: after[k] - before[k]
+            for k in ("trace_s", "lower_s", "backend_compile_s")}
+    assert all(v > 0 for v in grew.values()), grew
+    # wall seconds, so the three stages fit inside the call
+    assert sum(grew.values()) <= wall
+    assert after["cache_load_s"] >= before["cache_load_s"]
+    assert after["cache_load_s"] <= after["backend_compile_s"]
 
 
 # -------------------------------------------------------------- aggregate
