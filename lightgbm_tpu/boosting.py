@@ -746,10 +746,8 @@ class GBDT:
             meta = train_set.metadata
             for m in self.train.metrics:
                 m.init(meta.label, meta.weight, meta.group)
-            self._label_dev = (
-                jnp.asarray(train_set.padded(meta.label))
-                if meta.label is not None else None
-            )
+            # the data set's own device copy, shared with the objective
+            self._label_dev = train_set.device_label()
         self._boosted_from_average = False
         self._init_scores = [0.0] * self.num_class
         self._feat_rng = np.random.RandomState(config.feature_fraction_seed)
@@ -796,10 +794,12 @@ class GBDT:
                         np.asarray(self._label_dev), self._mesh, axis=0
                     )
                 # objective per-row device arrays follow the same global
-                # row sharding (each rank contributed its shard). The
-                # HOST statistics (_bfs_label & friends) must cache
-                # BEFORE the swap: afterwards np.asarray on the global
-                # arrays would raise (non-addressable shards)
+                # row sharding (each rank contributed its shard), as
+                # copies: the data set's own arrays stay as they are. The
+                # HOST statistics (_bfs_label & friends) gather BEFORE
+                # the swap, on every rank alike: a label derived on the
+                # device (reg_sqrt) is read back for them, and np.asarray
+                # on the global arrays would raise (non-addressable shards)
                 o = self.objective
                 if o is not None:
                     o._bfs_label()
@@ -1003,14 +1003,17 @@ class GBDT:
         import jax.numpy as jnp
 
         npad = ds.num_rows_padded()
-        score = np.zeros((self.num_class, npad), dtype=np.float32)
         init = ds.metadata.init_score
-        if init is not None:
-            init = np.asarray(init, dtype=np.float32)
-            if init.size == ds.num_data * self.num_class:
-                score[:, : ds.num_data] = init.reshape(self.num_class, ds.num_data)
-            else:
-                score[:, : ds.num_data] = init[None, :]
+        if init is None:
+            # made on the device, and one per Booster: the fused step
+            # donates its score, so it is never a data set's to share
+            return jnp.zeros((self.num_class, npad), jnp.float32)
+        score = np.zeros((self.num_class, npad), dtype=np.float32)
+        init = np.asarray(init, dtype=np.float32)
+        if init.size == ds.num_data * self.num_class:
+            score[:, : ds.num_data] = init.reshape(self.num_class, ds.num_data)
+        else:
+            score[:, : ds.num_data] = init[None, :]
         return jnp.asarray(score)
 
     def add_valid(self, valid_set: BinnedDataset, name: str) -> None:
@@ -1494,12 +1497,8 @@ class GBDT:
             # mesh); don't re-push an unsharded copy through the cache
             dev = self.dev if ss is self.train else ss.dataset.device_arrays()
             meta = ss.dataset.metadata
-            label = jnp.asarray(ss.dataset.padded(meta.label))
-            weight = (
-                jnp.asarray(ss.dataset.padded(meta.weight))
-                if meta.weight is not None
-                else None
-            )
+            label = ss.dataset.device_label()
+            weight = ss.dataset.device_weight()
             eval_specs.append((ss.name, tuple(names), tuple(hb), meta.group))
             eval_arrs.append(
                 {"label": label, "weight": weight, "valid": dev["valid"]}
@@ -2371,8 +2370,8 @@ class GBDT:
         # leaf assignment of every (row, model tree) on the new data
         leaf_pred = self.predict_leaf_index(X)  # (N, num_models)
 
-        # a minimal dataset shim so a fresh objective can init on the new
-        # data (no padding needed: gradients run in plain numpy here)
+        # a featureless data set so a fresh objective can init on the new
+        # data (row_block 1 = no padding: gradients run in plain numpy here)
         from .dataset import Metadata
         from .objectives import create_objective
 
@@ -2381,19 +2380,14 @@ class GBDT:
             weight=None if weight is None else np.asarray(weight, np.float32),
             group=None if group is None else np.asarray(group, np.int32),
         )
-
-        class _Shim:
-            metadata = md
-            num_data = N
-
-            @staticmethod
-            def padded(arr, fill: float = 0.0, dtype=np.float32):
-                return np.asarray(arr, dtype)
-
         obj = create_objective(c)
         if obj is None:
             log.fatal("Cannot refit without an objective function")
-        obj.init(_Shim())
+        obj.init(BinnedDataset(
+            bins=np.empty((0, N), np.uint8), mappers=[],
+            used_features=np.empty(0, np.int64), num_data=N, metadata=md,
+            feature_names=[], max_num_bin=1, row_block=1,
+        ))
 
         score = np.zeros((K, N), np.float64)
         for it in range(len(self.models) // K):

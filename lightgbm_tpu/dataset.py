@@ -108,6 +108,13 @@ class BinnedDataset:
     bundle_layout: Optional[Any] = None
     bundle_expand: Optional[np.ndarray] = None  # (F, max_num_bin) int32
     _device: Optional[Dict[str, Any]] = field(default=None, repr=False)
+    # label-sized state that is a function of this data set alone and
+    # is shared by every Booster built on it (device_label/_weight,
+    # label_stat): kind -> (host array, padded rows, device array), and
+    # the host statistics of the (label, weight) pair in _stats_of
+    _rows_dev: Dict[str, Any] = field(default_factory=dict, repr=False)
+    _stats: Dict[Any, Any] = field(default_factory=dict, repr=False)
+    _stats_of: Tuple[Any, Any] = field(default=(None, None), repr=False)
 
     # ---------------- construction ----------------
     @staticmethod
@@ -664,10 +671,12 @@ class BinnedDataset:
             self.invalidate_device_cache()
 
     def invalidate_device_cache(self) -> None:
-        """Drop cached device arrays (next device_arrays() re-pushes).
-        Used when padding changes or when a mesh booster keeps its own
-        sharded copies and the unsharded ones would waste HBM."""
+        """Drop cached device arrays (next device_arrays() /
+        device_label() re-pushes). Used when padding changes or when a
+        mesh booster keeps its own sharded copies and the unsharded
+        ones would waste HBM."""
         self._device = None
+        self._rows_dev = {}
 
     # ---------------- device arrays ----------------
     def device_arrays(self) -> Dict[str, Any]:
@@ -714,6 +723,60 @@ class BinnedDataset:
             "bundle": self._bundle_info(),
         }
         return self._device
+
+    def device_label(self):
+        """Padded label on the device, pushed once per data set and
+        shared by every Booster (objective.label, GBDT._label_dev, the
+        fused step's eval arrays). Never donated, never sharded in
+        place. None without labels."""
+        return self._device_rows("label")
+
+    def device_weight(self):
+        """Padded weight on the device (see device_label); None when
+        unweighted."""
+        return self._device_rows("weight")
+
+    def _device_rows(self, kind: str):
+        from .obs.metrics import record_label_cache
+
+        host = getattr(self.metadata, kind)
+        if host is None:
+            return None
+        npad = self.num_rows_padded()
+        ent = self._rows_dev.get(kind)
+        # keyed on the host array's IDENTITY (the entry keeps it alive,
+        # so the id cannot be reused) and on the row padding: whoever
+        # replaces metadata.label / .weight (Dataset.set_label, set_weight,
+        # set_field, the loaders) needs no hook, the next lookup misses
+        if ent is not None and ent[0] is host and ent[1] == npad:
+            record_label_cache(kind, hit=True)
+            return ent[2]
+        record_label_cache(kind, hit=False)
+        import jax.numpy as jnp
+
+        # padded() is a fresh host array: on a CPU backend the device
+        # array may alias it, never the caller's own label array
+        dev = jnp.asarray(self.padded(host))
+        self._rows_dev[kind] = (host, npad, dev)
+        return dev
+
+    def label_stat(self, key, compute):
+        """Host statistic of this data set's label / weight (a
+        check_label verdict, class counts, an init score), computed once
+        per (label array, weight array, key) and shared by every
+        Booster. Host values only: they outlive
+        invalidate_device_cache()."""
+        from .obs.metrics import record_label_cache
+
+        src = (self.metadata.label, self.metadata.weight)
+        if self._stats_of[0] is not src[0] or self._stats_of[1] is not src[1]:
+            self._stats, self._stats_of = {}, src
+        if key in self._stats:
+            record_label_cache("stats", hit=True)
+            return self._stats[key]
+        record_label_cache("stats", hit=False)
+        value = self._stats[key] = compute()
+        return value
 
     def _bundle_info(self):
         """Device BundleInfo for the growers, or None without EFB."""

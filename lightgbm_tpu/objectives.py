@@ -47,6 +47,10 @@ class ObjectiveFunction:
     # get_gradients is pure jax (traceable into the fused device loop);
     # host-loop objectives (lambdarank) override to False
     is_device_gradients = True
+    # config fields _init_score reads besides the label and the weight:
+    # with the class and the class id, the key under which the data set
+    # keeps the init score
+    init_score_params: Tuple[str, ...] = ()
 
     def __init__(self, config: Config):
         self.config = config
@@ -54,18 +58,28 @@ class ObjectiveFunction:
         self.weight: Optional[jax.Array] = None
 
     def init(self, dataset: BinnedDataset) -> None:
+        """Bind to a data set. label / weight ARE the data set's device
+        copies (dataset.device_label): one push per Dataset, shared by
+        every Booster, so an objective never writes into them — what it
+        derives from the label is its own array. Host statistics read
+        the host arrays and are kept per Dataset (dataset.label_stat)."""
         meta = dataset.metadata
         if meta.label is None:
             log.fatal(f"objective {self.name} requires labels")
-        self.check_label(meta.label)
-        self.label = jnp.asarray(dataset.padded(meta.label))
-        self.weight = (
-            jnp.asarray(dataset.padded(meta.weight))
-            if meta.weight is not None
-            else None
+
+        def checked() -> bool:
+            self.check_label(meta.label)  # fatal on a bad label
+            return True
+
+        dataset.label_stat(
+            ("check_label", type(self).__name__, self.num_class), checked
         )
+        self.label = dataset.device_label()
+        self.weight = dataset.device_weight()
+        self._dataset = dataset
         self._meta = meta
         self._num_data = dataset.num_data
+        self._g_label = self._g_weight = None
 
     def check_label(self, label: np.ndarray) -> None:
         pass
@@ -74,6 +88,17 @@ class ObjectiveFunction:
         raise NotImplementedError
 
     def boost_from_score(self, class_id: int) -> float:
+        """Init score of one class (BoostFromAverage): a constant of the
+        data set, computed by _init_score once per Dataset, objective
+        and init_score_params values, then shared by every Booster."""
+        key = ("init_score", type(self).__name__, class_id) + tuple(
+            getattr(self.config, p) for p in self.init_score_params
+        )
+        return self._dataset.label_stat(
+            key, lambda: self._init_score(class_id)
+        )
+
+    def _init_score(self, class_id: int) -> float:
         return 0.0
 
     def convert_output(self, score: np.ndarray) -> np.ndarray:
@@ -85,42 +110,36 @@ class ObjectiveFunction:
             return g * self.weight, h * self.weight
         return g, h
 
+    def _host_label(self) -> np.ndarray:
+        """This process's real rows of the label as the device holds
+        them (float32), read from the host array they were pushed from:
+        nothing comes back from the device."""
+        return np.asarray(self._meta.label, dtype=np.float32)
+
     def _bfs_label(self):
         """Host label for init-score statistics — GLOBAL across the
         process cluster: under multi-host training every rank must
         derive the SAME boost_from_average value (the reference's
         BoostFromAverage is computed after the network allreduce,
         gbdt.cpp); gathered lazily and cached."""
-        if getattr(self, "_g_label", None) is None:
+        if self._g_label is None:
             from .parallel.multihost import gather_host_rows
 
-            self._g_label = gather_host_rows(
-                np.asarray(self.label)[: self._num_data]
-            )
+            self._g_label = gather_host_rows(self._host_label())
         return self._g_label
 
     def _np_weight(self):
-        """Host weights truncated to real rows (None when unweighted),
+        """Host weights of the real rows (None when unweighted),
         globally gathered like _bfs_label."""
-        if self.weight is None:
+        if self._meta.weight is None:
             return None
-        if getattr(self, "_g_weight", None) is None:
+        if self._g_weight is None:
             from .parallel.multihost import gather_host_rows
 
             self._g_weight = gather_host_rows(
-                np.asarray(self.weight)[: self._num_data]
+                np.asarray(self._meta.weight, dtype=np.float32)
             )
         return self._g_weight
-
-    def _bfs_label_weight(self):
-        """Objective-derived per-row weights (e.g. MAPE), gathered."""
-        if getattr(self, "_g_label_weight", None) is None:
-            from .parallel.multihost import gather_host_rows
-
-            self._g_label_weight = gather_host_rows(
-                np.asarray(self._label_weight)[: self._num_data]
-            )
-        return self._g_label_weight
 
 
 # ---------------------------------------------------------------- regression
@@ -128,17 +147,25 @@ class RegressionL2(ObjectiveFunction):
     """reference regression_objective.hpp RegressionL2loss."""
 
     name = "regression"
+    init_score_params = ("reg_sqrt",)
 
     def init(self, dataset: BinnedDataset) -> None:
         super().init(dataset)
         if self.config.reg_sqrt:
-            lab = np.asarray(self.label)
-            self.label = jnp.sign(jnp.asarray(lab)) * jnp.sqrt(jnp.abs(jnp.asarray(lab)))
+            # a new array, the objective's own: the data set's stays
+            self.label = jnp.sign(self.label) * jnp.sqrt(jnp.abs(self.label))
+
+    def _host_label(self) -> np.ndarray:
+        if self.config.reg_sqrt:
+            # derived on the device, so its statistic reads it back
+            # (once per Dataset: the scalar is what the data set keeps)
+            return np.asarray(self.label)[: self._num_data]
+        return super()._host_label()
 
     def get_gradients(self, score):
         return self._w(score - self.label, jnp.ones_like(score))
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         w = self._np_weight()
         return float(np.average(lab, weights=w))
@@ -156,7 +183,7 @@ class RegressionL1(RegressionL2):
     def get_gradients(self, score):
         return self._w(jnp.sign(score - self.label), jnp.ones_like(score))
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         w = self._np_weight()
         if w is None:
@@ -189,7 +216,7 @@ class Fair(RegressionL2):
         c = jnp.float32(self.config.fair_c)
         return self._w(c * d / (jnp.abs(d) + c), c * c / (jnp.abs(d) + c) ** 2)
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         return 0.0
 
 
@@ -204,7 +231,7 @@ class Poisson(RegressionL2):
         mds = jnp.float32(self.config.poisson_max_delta_step)
         return self._w(jnp.exp(score) - self.label, jnp.exp(score + mds))
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         return float(np.log(max(np.average(lab, weights=self._np_weight()), 1e-20)))
 
@@ -215,13 +242,14 @@ class Poisson(RegressionL2):
 class Quantile(RegressionL2):
     name = "quantile"
     is_renew_tree_output = True
+    init_score_params = ("reg_sqrt", "alpha")
 
     def get_gradients(self, score):
         a = jnp.float32(self.config.alpha)
         g = jnp.where(score > self.label, 1.0 - a, -a)
         return self._w(g, jnp.ones_like(score))
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         w = self._np_weight()
         if w is None:
@@ -238,17 +266,37 @@ class MAPE(RegressionL2):
 
     def init(self, dataset):
         super().init(dataset)
-        lab = np.asarray(self.label)
-        lw = 1.0 / np.maximum(1.0, np.abs(lab))
-        if self.weight is not None:
-            lw = lw * np.asarray(self.weight)
-        self._label_weight = jnp.asarray(lw.astype(np.float32))
+        self._g_label_weight = None
+        # padding rows as before: label 0 -> 1.0, times a padded weight 0
+        self._label_weight = jnp.asarray(dataset.padded(
+            self._host_label_weight(),
+            fill=1.0 if self._meta.weight is None else 0.0,
+        ))
+
+    def _host_label_weight(self) -> np.ndarray:
+        """1 / max(1, |label|) (x weight) of this process's real rows,
+        float32 like the device copy."""
+        lw = 1.0 / np.maximum(1.0, np.abs(self._host_label()))
+        if self._meta.weight is not None:
+            lw = lw * np.asarray(self._meta.weight, dtype=np.float32)
+        return lw.astype(np.float32)
+
+    def _bfs_label_weight(self):
+        """The label-derived weights, globally gathered like
+        _bfs_label."""
+        if self._g_label_weight is None:
+            from .parallel.multihost import gather_host_rows
+
+            self._g_label_weight = gather_host_rows(
+                self._host_label_weight()
+            )
+        return self._g_label_weight
 
     def get_gradients(self, score):
         g = jnp.sign(score - self.label) * self._label_weight
         return g, self._label_weight
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         w = self._bfs_label_weight()
         return _weighted_percentile(lab, w, 0.5)
@@ -284,17 +332,22 @@ class Binary(ObjectiveFunction):
     scaling, is_unbalance / scale_pos_weight label weighting."""
 
     name = "binary"
+    init_score_params = ("is_unbalance", "scale_pos_weight", "sigmoid")
 
     def check_label(self, label):
         u = np.unique(label)
         if not np.all(np.isin(u, [0, 1])):
             log.fatal("[binary]: labels must be 0 or 1")
 
+    def _count_labels(self) -> Tuple[float, float]:
+        lab = self._bfs_label()
+        return float(np.sum(lab == 1)), float(np.sum(lab == 0))
+
     def init(self, dataset):
         super().init(dataset)
-        lab = self._bfs_label()
-        cnt_pos = float(np.sum(lab == 1))
-        cnt_neg = float(np.sum(lab == 0))
+        cnt_pos, cnt_neg = dataset.label_stat(
+            ("binary_counts",), self._count_labels
+        )
         if self.config.is_unbalance and cnt_pos > 0 and cnt_neg > 0:
             if cnt_pos > cnt_neg:
                 self._pos_w, self._neg_w = 1.0, cnt_pos / cnt_neg
@@ -314,15 +367,21 @@ class Binary(ObjectiveFunction):
         h = p * (1.0 - p) * sig * sig * lw
         return self._w(g, h)
 
-    def boost_from_score(self, class_id: int) -> float:
-        lab = self._bfs_label()
-        w = (
-            self._np_weight()
-            if self.weight is not None
-            else np.ones_like(lab)
-        )
-        lw = np.where(lab > 0, self._pos_w, self._neg_w) * w
-        pavg = float(np.sum(lab * lw) / max(np.sum(lw), 1e-20))
+    def _init_score(self, class_id: int) -> float:
+        w = self._np_weight()
+        if w is None and self._pos_w == 1.0 and self._neg_w == 1.0:
+            # every term of both float64 sums below is 0 or 1: they ARE
+            # the counts, to the last bit, at any row count under 2^53
+            suml, sumw = self._cnt_pos, self._cnt_pos + self._cnt_neg
+        else:
+            # sums of other constants round on the way: not reproducible
+            # from the counts, so the array formula it is, once
+            lab = self._bfs_label()
+            if w is None:
+                w = np.ones_like(lab)
+            lw = np.where(lab > 0, self._pos_w, self._neg_w) * w
+            suml, sumw = np.sum(lab * lw), np.sum(lw)
+        pavg = float(suml / max(sumw, 1e-20))
         pavg = min(max(pavg, 1e-15), 1.0 - 1e-15)
         return float(np.log(pavg / (1.0 - pavg)) / self.config.sigmoid)
 
@@ -364,6 +423,7 @@ class MulticlassOVA(ObjectiveFunction):
     """One-vs-all: K independent sigmoid binaries (multiclass_objective.hpp)."""
 
     name = "multiclassova"
+    init_score_params = ("sigmoid",)
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -380,7 +440,7 @@ class MulticlassOVA(ObjectiveFunction):
             h = h * self.weight[None, :]
         return g, h
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         p = float(np.mean(lab == class_id))
         p = min(max(p, 1e-15), 1.0 - 1e-15)
@@ -404,7 +464,7 @@ class CrossEntropy(ObjectiveFunction):
         p = jax.nn.sigmoid(score)
         return self._w(p - self.label, p * (1.0 - p))
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         pavg = float(np.average(lab, weights=self._np_weight()))
         pavg = min(max(pavg, 1e-15), 1.0 - 1e-15)
@@ -428,8 +488,12 @@ class CrossEntropyLambda(ObjectiveFunction):
 
     def init(self, dataset):
         super().init(dataset)
-        if self.weight is not None:
-            wmin = float(np.asarray(self.weight)[: self._num_data].min())
+        if self._meta.weight is not None:
+            wmin = dataset.label_stat(
+                ("min_weight",),
+                lambda: float(np.min(
+                    np.asarray(self._meta.weight, dtype=np.float32))),
+            )
             if wmin <= 0:
                 log.fatal("[cross_entropy_lambda]: at least one weight is non-positive")
 
@@ -455,7 +519,7 @@ class CrossEntropyLambda(ObjectiveFunction):
         h = a * (1.0 + y * b)
         return g, h
 
-    def boost_from_score(self, class_id: int) -> float:
+    def _init_score(self, class_id: int) -> float:
         lab = self._bfs_label()
         havg = float(np.average(lab, weights=self._np_weight()))
         return float(np.log(max(np.expm1(havg), 1e-15)))
@@ -493,7 +557,7 @@ class LambdaRank(ObjectiveFunction):
         )
 
         label = np.asarray(self._meta.label)
-        npad = len(np.asarray(self.label))
+        npad = int(self.label.shape[0])
         self._layout = build_query_layout(self._meta.group, npad)
         gains = list(self.config.label_gain)
         if not gains:
@@ -625,7 +689,7 @@ class RankXENDCG(ObjectiveFunction):
             log.fatal("rank_xendcg requires query group information")
         from .learner.ranking import build_query_layout
 
-        npad = len(np.asarray(self.label))
+        npad = int(self.label.shape[0])
         layout = build_query_layout(self._meta.group, npad)
         qdoc = jnp.asarray(layout.qdoc)
         qvalid = jnp.asarray(layout.qvalid)

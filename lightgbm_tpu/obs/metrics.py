@@ -613,6 +613,20 @@ def record_collective_wire(entry: str, nbytes: int) -> None:
               labels=("entry",)).inc(nbytes, entry=entry)
 
 
+def record_label_cache(kind: str, hit: bool) -> None:
+    """One lookup of a data set's label-sized residency
+    (dataset.BinnedDataset.device_label / device_weight / label_stat):
+    kind is label | weight | stats."""
+    r = _default
+    if not r.enabled:
+        return
+    r.counter("lgbmtpu_dataset_label_cache_total",
+              "lookups of the per-Dataset label / weight device copies "
+              "and label statistics, by kind and result",
+              labels=("kind", "result")
+              ).inc(1, kind=kind, result="hit" if hit else "miss")
+
+
 # gateway bridges (serving/gateway.py). Label/naming conventions in
 # docs/OBSERVABILITY.md "Gateway metrics": outcome is the GATEWAY
 # verdict (ok/failed/shed/deadline/unavailable/drain/fanout_partial),
