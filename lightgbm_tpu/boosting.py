@@ -887,7 +887,7 @@ class GBDT:
         )
 
     def _grow_int_packed(self, gk, hk, mask, feat_mask, valid, it, k,
-                         bins=None, tables=None):
+                         bins=None, tables=None, with_stats=False):
         """Internal hist_dtype=int16/int8 policy (ISSUE 12): the default
         API path discretizes g/h to self._hist_levels integer levels,
         accumulates 3 narrow channels through the rounds grower's
@@ -896,9 +896,9 @@ class GBDT:
         semantics stay within stochastic-rounding noise of bf16x2."""
         gq, hq, scale = self._quantize(gk, hk, it, k,
                                        num_bins=self._hist_levels)
-        arrays, row_leaf = self._grow(
+        arrays, row_leaf, *stats = self._grow(
             gq, hq, mask, feat_mask, valid, it, k, gh_scale=scale,
-            bins=bins, tables=tables,
+            bins=bins, tables=tables, with_stats=with_stats,
         )
         if self._true_renew_ok:
             from .learner.quantize import renew_leaf_with_true_gradients
@@ -909,35 +909,37 @@ class GBDT:
                     self.params, self.spec.num_leaves,
                 )
             )
-        return arrays, row_leaf
+        return (arrays, row_leaf, *stats)
 
     def _grow_maybe_quantized(self, gk, hk, mask, feat_mask, valid, it, k,
-                              bins=None, tables=None):
+                              bins=None, tables=None, with_stats=False):
         """One tree: quantize gradients first when use_quantized_grad
         (all paths — fast, fused, sync/DART, RF — share this so none can
         silently skip quantization), optionally renewing leaf outputs
-        with the true gradients afterward."""
+        with the true gradients afterward. with_stats=True appends the
+        grower's stats (grower.grow_tree) to the returned pair."""
         c = self.config
         if not c.use_quantized_grad:
             if self._int_packed and self.spec.quant:
                 return self._grow_int_packed(
                     gk, hk, mask, feat_mask, valid, it, k,
-                    bins=bins, tables=tables,
+                    bins=bins, tables=tables, with_stats=with_stats,
                 )
             return self._grow(gk, hk, mask, feat_mask, valid, it, k,
-                              bins=bins, tables=tables)
+                              bins=bins, tables=tables,
+                              with_stats=with_stats)
         gq, hq, scale = self._quantize(gk, hk, it, k)
         if self.spec.quant:
             # rounds grower consumes the integer levels directly: exact
             # int histogram sums in 3 channels/slot (48 slots/MXU pass)
-            arrays, row_leaf = self._grow(
+            arrays, row_leaf, *stats = self._grow(
                 gq, hq, mask, feat_mask, valid, it, k, gh_scale=scale,
-                bins=bins, tables=tables,
+                bins=bins, tables=tables, with_stats=with_stats,
             )
         else:
-            arrays, row_leaf = self._grow(
+            arrays, row_leaf, *stats = self._grow(
                 gq * scale[0], hq * scale[1], mask, feat_mask, valid, it, k,
-                bins=bins, tables=tables,
+                bins=bins, tables=tables, with_stats=with_stats,
             )
         if c.quant_train_renew_leaf and self._quant_renew_ok:
             from .learner.quantize import renew_leaf_with_true_gradients
@@ -948,7 +950,7 @@ class GBDT:
                     self.params, self.spec.num_leaves,
                 )
             )
-        return arrays, row_leaf
+        return (arrays, row_leaf, *stats)
 
     def _apply_renewal(self, arrays, row_leaf, score_k, mask, renew_alpha,
                        renew_w, label=None):
@@ -967,7 +969,7 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def _grow(self, gk, hk, mask, feat_mask, valid, it=0, k=0, gh_scale=None,
-              bins=None, tables=None):
+              bins=None, tables=None, with_stats=False):
         """Grow one tree on the training set — serial, or sharded over the
         data mesh when tree_learner=data/voting (lockstep trees on every
         shard, reference data_parallel_tree_learner.cpp). Traceable: used
@@ -987,18 +989,21 @@ class GBDT:
                 self._node_key, it * self.num_class + k
             )
         if self._dp is not None:
-            return self._dp(
+            # the mesh growers return the pair alone: their ladder is
+            # read from the trace (docs/OBSERVABILITY.md)
+            out = self._dp(
                 d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
                 gk, hk, mask, feat_mask, self.params, valid,
                 d.get("bundle"), rng_key, self._group_mat, self._cegb_info,
                 self._forced, gh_scale,
             )
+            return (*out, None) if with_stats else out
         return grow_tree(
             d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
             gk, hk, mask, feat_mask, self.params, self.spec, valid=valid,
             bundle=d.get("bundle"), rng_key=rng_key,
             group_mat=self._group_mat, cegb=self._cegb_info,
-            forced=self._forced, gh_scale=gh_scale,
+            forced=self._forced, gh_scale=gh_scale, with_stats=with_stats,
         )
 
     # ------------------------------------------------------------------
@@ -1479,6 +1484,7 @@ class GBDT:
         from .device_metrics import (DeviceEvalSet, rank_eval_arrays,
                                      supported_names)
         from .learner.histogram import row_mesh
+        from .learner.rounds import ladder_widths
 
         data_mesh = self._mesh if self._parallel_mode == "data" else None
 
@@ -1532,6 +1538,14 @@ class GBDT:
             getattr(c, "record_file", "")
             or getattr(c, "anomaly_policy", "off") != "off"
         )
+        # the step ends its eval row with the rounds grower's round
+        # counts, one per ladder width and their total (a mesh grower
+        # returns none: _grow)
+        ladder_ws = (
+            ladder_widths(self.spec)
+            if self.spec.rounds_slots > 0 and self._dp is None else ()
+        )
+        self._f_ladder_widths = ladder_ws
         # memo eligibility must be known BEFORE tracing. Query groups do
         # not bar it: the ranking objectives and metrics read their
         # layout, grids and per-query statistics from `data` (shapes in
@@ -1610,6 +1624,7 @@ class GBDT:
             valid_mask = data["valid"]
             trees = []
             grew = []  # per-class split indicators (pre-mask)
+            ladder_rounds = []  # per class-tree (W+1,) round counts
             for k in range(K):
                 gk, hk = grad[k], hess[k]
                 mask, gk, hk = strategy.sample(
@@ -1622,10 +1637,12 @@ class GBDT:
                     feat_mask = jax.random.permutation(fkey, F) < n_feat
                 else:
                     feat_mask = jnp.ones(F, dtype=bool)
-                arrays, row_leaf = self._grow_maybe_quantized(
+                arrays, row_leaf, *ladder = self._grow_maybe_quantized(
                     gk, hk, mask, feat_mask, valid_mask, it, k,
                     bins=data["bins"], tables=data["tables"],
+                    with_stats=bool(ladder_ws),
                 )
+                ladder_rounds += [st["rounds"] for st in ladder]
                 grew.append(arrays.num_nodes > 0)
                 # `actf` folds the activity mask in: post-stop / masked-
                 # tail rounds store zeroed leaf values (ok=0), so every
@@ -1683,6 +1700,13 @@ class GBDT:
                     jnp.sqrt(jnp.sum(hess * hess)),
                 ])
                 eval_row = jnp.concatenate([eval_row, gh_row])
+            # the rounds grower's per-width round counts (rounds.py
+            # ladder) end the row, summed over the class trees, for
+            # lgbmtpu_grower_rounds_total: they ride this readback too
+            if ladder_rounds:
+                eval_row = jnp.concatenate(
+                    [eval_row, sum(ladder_rounds).astype(jnp.float32)]
+                )
             # the reference's stop condition (no class-tree could split,
             # gbdt.cpp:429-452) carried as a sticky device mask: once an
             # ACTIVE round grows K stumps, every later round in this and
@@ -1917,6 +1941,13 @@ class GBDT:
         mat = (
             np.stack(rows) if rows else np.zeros((0, 0), np.float32)
         )
+        widths = self._f_ladder_widths
+        if widths and mat.shape[0]:
+            from .obs.metrics import record_grower_rounds
+
+            n = len(widths) + 1  # the row's tail: per width, then total
+            record_grower_rounds(widths, mat[:, -n:-1].sum(axis=0))
+            mat = mat[:, :-n]
         self._materialize()
         n_iter_after = len(self._models) // self.num_class
         produced = n_iter_after - n_iter_before

@@ -367,8 +367,11 @@ def grow_tree(
     cegb: Optional[CegbInfo] = None,
     forced: Optional[Any] = None,  # ForcedSplits plan
     gh_scale: Optional[jax.Array] = None,  # (2,) quantized-level scales
-) -> Tuple[TreeArrays, jax.Array]:
-    """Grow one tree; returns (tree arrays, per-row leaf assignment).
+    with_stats: bool = False,  # also return the grower's stats, or None
+):
+    """Grow one tree; returns (tree arrays, per-row leaf assignment),
+    and with_stats=True a third item: the rounds grower's
+    {"widths", "rounds"} ladder counts, None from the other growers.
 
     Dispatches on spec.rounds_slots / spec.partition: "rounds"
     (natural-order round-batched, rounds.py — the TPU fast path),
@@ -382,26 +385,28 @@ def grow_tree(
             bins_fm, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
             feat_mask, params, spec, valid, bundle, gh_scale,
             rng_key=rng_key, group_mat=group_mat, cegb=cegb,
-            forced=forced,
+            forced=forced, with_stats=with_stats,
         )
     if spec.partition == "permuted":
         from .permuted import grow_tree_permuted
 
-        return grow_tree_permuted(
+        out = grow_tree_permuted(
             bins_fm, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
             feat_mask, params, spec, valid, bundle, rng_key, group_mat, cegb,
             forced
         )
+        return (*out, None) if with_stats else out
     if (spec.extra_trees or spec.ff_bynode or spec.cegb or spec.n_groups
             or spec.n_forced):
         raise ValueError(
             "extra_trees / feature_fraction_bynode / cegb / interaction "
             "constraints ride the permuted grower only"
         )
-    return _grow_tree_flat(
+    out = _grow_tree_flat(
         bins_fm, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
         feat_mask, params, spec, valid, bundle
     )
+    return (*out, None) if with_stats else out
 
 
 @partial(jax.jit, static_argnames=("spec",))

@@ -82,12 +82,27 @@ from .split import (
 )
 
 
+# Kernel widths below a program's slot count. A pass costs
+# max(VPU side, slots x per-slot MXU time); on a v5e the two cross
+# near 21 slots bf16 / 27 int8 (PERF.md section 5), so 16 is the last
+# rung the floor pays for. Each rung is one more copy of round_step to
+# trace, lower and compile.
+LADDER_RUNGS = (8, 16, 32)
+
+
+def ladder_widths(spec: GrowerSpec) -> Tuple[int, ...]:
+    """The round kernel's widths for this program, ascending; the last
+    is the slot count itself."""
+    slots = min(spec.rounds_slots, max(spec.num_leaves - 1, 1))  # top_k: k <= L
+    return tuple(w for w in LADDER_RUNGS if w < slots) + (slots,)
+
+
 class _NState(NamedTuple):
     i: jax.Array  # splits performed so far
     r: jax.Array  # (W+1,) int32 — rounds executed, by ladder width
     # (r[w] = rounds run at widths[w]; r[-1] = total). Scalar counters,
-    # free at runtime; surfaced by grow_tree_rounds(..., with_stats=True)
-    # for profiling the ladder on real gain landscapes.
+    # free at runtime; surfaced by grow_tree_rounds(..., with_stats=True),
+    # which the fused step reads for lgbmtpu_grower_rounds_total{width}.
     pleaf: jax.Array  # (N,) int32 row -> leaf; invalid rows carry L
     hist: jax.Array  # (L, 3, G, Bc) histogram pool
     leaf_g: jax.Array
@@ -173,7 +188,8 @@ def grow_tree_rounds(
     B = spec.num_bins
     G, N = bins_fm.shape  # G = device columns (bundles when spec.efb)
     F = num_bins.shape[0]
-    S = min(spec.rounds_slots, max(L - 1, 1))  # top_k needs k <= L
+    widths = ladder_widths(spec)  # the S-ladder of body() below
+    S = widths[-1]
     ax = spec.axis_name
     Bc = spec.col_bins if (spec.efb and spec.col_bins) else B
     # voting-parallel on the rounds path (ISSUE 14): the per-round
@@ -416,10 +432,10 @@ def grow_tree_rounds(
     # costs M = S x channels rows REGARDLESS of how many slots are
     # live — a full-width S=48 pass for a 1-candidate round wastes
     # ~4 ms of MXU time. The while body therefore switches between
-    # narrow/mid/full kernel widths by live candidate count. Selection
-    # is unchanged (top-k of a wider k picks the same set), so the
-    # grown tree is bit-identical to the single-width formulation.
-    widths = tuple(w for w in (8, 32) if w < S) + (S,)
+    # kernel widths (LADDER_RUNGS below S, then S) by live candidate
+    # count. Selection is unchanged (top-k of a wider k picks the same
+    # set), so the grown tree is bit-identical to the single-width
+    # formulation.
 
     # ---- budget-aware tail (small data): round batching deviates from
     # best-first greedy once the leaf budget binds — children created

@@ -613,6 +613,20 @@ def record_collective_wire(entry: str, nbytes: int) -> None:
               labels=("entry",)).inc(nbytes, entry=entry)
 
 
+def record_grower_rounds(widths, rounds) -> None:
+    """Rounds the rounds grower ran at each width of its slot ladder
+    (learner/rounds.py ladder_widths), as counted on the device and
+    fetched with a fused chunk's eval rows."""
+    r = _default
+    if not r.enabled:
+        return
+    c = r.counter("lgbmtpu_grower_rounds_total",
+                  "tree-growth rounds executed, by the round kernel's "
+                  "slot width", labels=("width",))
+    for w, n in zip(widths, rounds):
+        c.inc(float(n), width=str(w))
+
+
 def record_label_cache(kind: str, hit: bool) -> None:
     """One lookup of a data set's label-sized residency
     (dataset.BinnedDataset.device_label / device_weight / label_stat):

@@ -62,26 +62,32 @@ def test_slot_chunks_at_137_columns_are_two_equal_calls():
     assert 24 <= s_max < 32
     assert [sc for _, sc in _slot_chunks(32, s_max)] == [16, 16]
     assert [sc for _, sc in _slot_chunks(48, s_max)] == [24, 24]
+    # the 16-slot rung is one call at 137 columns too
+    assert _slot_chunks(16, s_max) == [(0, 16)]
     # 28 columns: every rung of the ladder is one call, as before
     s28 = _round_s_max(28, BINS, True, False)
-    assert [_slot_chunks(s, s28) for s in (8, 32, 48)] == [
-        [(0, 8)], [(0, 32)], [(0, 48)]]
+    assert [_slot_chunks(s, s28) for s in (8, 16, 32, 48)] == [
+        [(0, 8)], [(0, 16)], [(0, 32)], [(0, 48)]]
 
 
-@pytest.mark.parametrize("slots,int8", [(8, False), (16, False),
-                                        (24, False), (24, True)])
-def test_round_kernel_compiles_at_137_columns(one_chip, no_compile_cache,
-                                              slots, int8):
+@pytest.mark.parametrize("features,slots,int8", [
+    (FEATURES, 8, False), (FEATURES, 16, False), (FEATURES, 24, False),
+    (FEATURES, 24, True),
+    # the Higgs cells' width: the ladder's 16-slot rung, both MXU types
+    (28, 16, False), (28, 16, True),
+])
+def test_round_kernel_compiles(one_chip, no_compile_cache,
+                                              features, slots, int8):
     from lightgbm_tpu.learner.pallas_hist import hist_round_tpu
 
     fn = jax.jit(lambda b, g, p, pr, oh: hist_round_tpu(
         b, g, p, pr, oh, slots, BINS, 3, int8=int8, oh_shift=0))
     compiled = fn.lower(
-        _arg(one_chip, (FEATURES, ROWS), jnp.int32),
+        _arg(one_chip, (features, ROWS), jnp.int32),
         _arg(one_chip, (8, ROWS), jnp.float32),
         _arg(one_chip, (ROWS,), jnp.int32),
         _arg(one_chip, (slots, 16), jnp.int32),
-        _arg(one_chip, (slots, FEATURES), jnp.float32)).compile()
+        _arg(one_chip, (slots, features), jnp.float32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "hist_round_tpu" in text
 

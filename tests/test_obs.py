@@ -600,6 +600,47 @@ def test_data_parallel_runtime_wire_counter(rng):
     assert after > before
 
 
+@pytest.mark.parametrize("extra", [{}, {"tpu_chunk_scan": "off"},
+                                   {"record_file": "rec.jsonl"}],
+                         ids=["chunk_scan", "per_round", "recorded"])
+def test_grower_rounds_counter_rides_the_eval_readback(rng, tmp_path, extra):
+    """lgbmtpu_grower_rounds_total{width}: the rounds grower's per-width
+    round counts end the fused step's eval row, so they arrive with the
+    readback fused_collect already makes; the caller's evals and the
+    recorder's gh norms read the row as before."""
+    from lightgbm_tpu.learner.rounds import ladder_widths
+
+    c = default_registry().counter("lgbmtpu_grower_rounds_total",
+                                   labels=("width",))
+    X = rng.randn(3000, 5)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    extra = dict(extra)
+    if "record_file" in extra:
+        extra["record_file"] = str(tmp_path / extra["record_file"])
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    before = {w: c.value(width=str(w)) for w in (8, 16)}
+    ev = {}
+    bst = lgb.train(
+        {"verbosity": -1, "objective": "binary", "num_leaves": 63,
+         "metric": "auc", "min_data_in_leaf": 5,
+         "tpu_growth_mode": "rounds", **extra},
+        ds, num_boost_round=5, valid_sets=[ds], valid_names=["tr"],
+        callbacks=[lgb.record_evaluation(ev)])
+    g = bst._gbdt
+    widths = ladder_widths(g.spec)
+    assert widths[:2] == (8, 16) and g._f_ladder_widths == widths
+    delta = {w: c.value(width=str(w)) - before[w] for w in (8, 16)}
+    # five trees: each doubles 1, 2, 4, 8 candidates at 8 slots, then
+    # has at least one round of 9-16
+    assert delta[8] >= 20 and delta[16] >= 5
+    assert all(float(v).is_integer() for v in delta.values())
+    assert list(ev["tr"]) == ["auc"] and len(ev["tr"]["auc"]) == 5
+    assert all(0.5 < a <= 1.0 for a in ev["tr"]["auc"])
+    if "record_file" in extra:
+        assert len(g._last_gh_rows) > 0
+        assert all(gn > 0 and hn > 0 for gn, hn in g._last_gh_rows)
+
+
 # ------------------------------------------------------------ re-audit
 def test_instrumentation_added_no_host_callbacks():
     """All audited jaxpr entries stay callback-free: the observability

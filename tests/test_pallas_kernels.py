@@ -157,58 +157,114 @@ def test_int8_oh_shift_policy():
     assert int8_oh_shift(16 * 10 ** 6, 127) == 7
 
 
-def _grow_case(spec_kw, quant=False, columns=6):
+def _grow_case(spec_kw, quant=False, columns=6, rows=HIST_BLK,
+               smooth=False, with_stats=False):
     """Grow one tree on a synthetic set; returns (leaf_values, row_leaf,
-    node_feature, node_bin)."""
+    node_feature, node_bin), and with_stats=True the whole TreeArrays
+    and the grower's stats after them. smooth=True makes the gradients
+    a smooth function of the columns plus noise, not noise alone: the
+    splits then halve their leaves, so every early leaf can split
+    again."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.dataset import BinnedDataset
     from lightgbm_tpu.learner import GrowerSpec, grow_tree, make_split_params
 
     rs = np.random.RandomState(11)
-    X = rs.randn(HIST_BLK, columns).astype(np.float32)
+    X = rs.randn(rows, columns).astype(np.float32)
     cfg = Config({"max_bin": 63, "min_data_in_leaf": 5})
     ds = BinnedDataset.from_numpy(X, cfg)
     d = ds.device_arrays()
     N = ds.num_rows_padded()
     F = ds.num_used_features
     if quant:
-        grad = jnp.asarray(
-            rs.randint(-2, 3, N).astype(np.float32)) * d["valid"]
-        hess = jnp.asarray(
-            rs.randint(1, 4, N).astype(np.float32)) * d["valid"]
+        grad = rs.randint(-2, 3, N).astype(np.float32)
+        hess = rs.randint(1, 4, N).astype(np.float32)
         gh_scale = jnp.asarray(np.float32([0.125, 0.25]))
     else:
-        grad = jnp.asarray(rs.randn(N).astype(np.float32)) * d["valid"]
-        hess = jnp.ones(N, jnp.float32) * 0.25 * d["valid"]
+        grad = rs.randn(N).astype(np.float32)
+        hess = np.full(N, 0.25, np.float32)
         gh_scale = None
+    if smooth:
+        z = np.zeros(N, np.float32)
+        z[:rows] = np.tanh(X @ rs.randn(columns).astype(np.float32) / 2)
+        grad = (np.clip(np.rint(2 * z + 0.4 * grad), -2, 2) if quant
+                else z + 0.05 * grad)
+    grad = jnp.asarray(grad) * d["valid"]
+    hess = jnp.asarray(hess) * d["valid"]
     spec_kw = dict(spec_kw)  # callers reuse their dict across runs
     n_leaves = spec_kw.pop("num_leaves", 15)
     params = make_split_params(Config({"num_leaves": n_leaves, "max_bin": 63,
                                        "min_data_in_leaf": 5}))
     spec = GrowerSpec(num_leaves=n_leaves, num_bins=ds.max_num_bin,
                       max_depth=-1, **spec_kw)
-    tree, rl = grow_tree(
+    tree, rl, *stats = grow_tree(
         d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
         grad, hess, d["valid"], jnp.ones(F, bool), params, spec,
-        valid=d["valid"], gh_scale=gh_scale,
+        valid=d["valid"], gh_scale=gh_scale, with_stats=with_stats,
     )
-    return (np.asarray(tree.leaf_value), np.asarray(rl),
-            np.asarray(tree.node_feature), np.asarray(tree.node_bin))
+    out = (np.asarray(tree.leaf_value), np.asarray(rl),
+           np.asarray(tree.node_feature), np.asarray(tree.node_bin))
+    return out + (tree, *stats) if with_stats else out
 
 
-def test_fused_round_ladder_matches_fallback(interp):
-    """Multi-width S-ladder (widths 8/32/48 at rounds_slots=48): the
-    lax.switch dispatch across kernel widths must reproduce the XLA
-    path's tree exactly."""
-    import os
+def _ladder_rounds(widths, leaves, rows):
+    """Rounds per ladder width of a tree whose every leaf can split:
+    candidates double until the slot count or the leaf budget binds;
+    at a small row count a round takes at most half the budget left
+    (rounds.py tail_exact)."""
+    counts, splits, live = [0] * len(widths), 0, 1
+    while splits < leaves - 1:
+        budget = leaves - 1 - splits
+        n = min(budget, live)
+        if rows <= 32 * 8192:
+            n = min(n, max((budget + 1) // 2, 1))
+        counts[sum(n > w for w in widths[:-1])] += 1
+        n = min(n, widths[-1])
+        splits, live = splits + n, live + n
+    return counts
 
+
+@pytest.mark.parametrize("layout,leaves,rows", [
+    ("bf16x2", 63, HIST_BLK), ("int16", 63, HIST_BLK),
+    ("int8", 63, HIST_BLK), ("int16", 255, 4 * HIST_BLK),
+])
+def test_fused_round_ladder_matches_fallback(interp, monkeypatch, layout,
+                                             leaves, rows):
+    """The slot ladder (rounds.LADDER_RUNGS below the slot count, then
+    it: 8/16/32/48 for the 3-channel layouts, 8/16/25 for bf16x2): a
+    grow passes through the 16-candidate round, runs as many rounds at
+    each width as its leaf budget implies, equals the single-width
+    tree bit for bit, and reproduces the XLA path's tree."""
     import jax
 
-    kw = dict(rounds_slots=48, has_cat=False, num_leaves=63)
-    fused = _grow_case(kw)
-    os.environ["LGBM_TPU_PALLAS_INTERPRET"] = "0"
+    from lightgbm_tpu.learner import rounds as rounds_mod
+
+    quant = layout != "bf16x2"
+    slots = 48 if quant else 25
+    kw = dict(rounds_slots=slots, has_cat=False, num_leaves=leaves,
+              quant=quant, quant_int8=layout == "int8",
+              quant_levels=4 if quant else 0)
+    fused = _grow_case(kw, quant=quant, rows=rows, smooth=True,
+                       with_stats=True)
+    widths = tuple(int(w) for w in fused[5]["widths"])
+    assert widths == ((8, 16, 32, 48) if quant else (8, 16, 25))
+    counts = [int(n) for n in fused[5]["rounds"]]
+    assert counts[:-1] == _ladder_rounds(widths, leaves, rows)
+    assert counts[-1] == sum(counts[:-1]) and counts[1] > 0
+    assert int(fused[4].num_nodes) == leaves - 1
+
+    with monkeypatch.context() as m:
+        m.setattr(rounds_mod, "LADDER_RUNGS", ())
+        jax.clear_caches()  # the ladder is read when the grower is traced
+        single = _grow_case(kw, quant=quant, rows=rows, smooth=True,
+                            with_stats=True)
+    assert tuple(int(w) for w in single[5]["widths"]) == (slots,)
+    for a, b in zip(jax.tree.leaves(fused[4]), jax.tree.leaves(single[4])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(fused[1], single[1])
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "0")
     jax.clear_caches()
-    fb = _grow_case(kw)
+    fb = _grow_case(kw, quant=quant, rows=rows, smooth=True)
     np.testing.assert_allclose(fused[0], fb[0], atol=5e-4)
     np.testing.assert_array_equal(fused[2], fb[2])
     np.testing.assert_array_equal(fused[3], fb[3])
