@@ -26,8 +26,6 @@ BENCH_WARMUP, BENCH_MAX_BIN, BENCH_GROWTH_MODE, BENCH_MANIFEST_OUT
 Voting segment (needs more than one chip):
 BENCH_SKIP_VOTING, BENCH_VOTING_TREES, BENCH_VOTING_EXACT_TREES,
 BENCH_VOTING_LEAVES, BENCH_VOTING_TOPK.
-Chunk-scan segment (tpu_chunk_scan=auto vs off, same run):
-BENCH_SKIP_CHUNK_SCAN, BENCH_CHUNK_TREES.
 Ingest segment (out-of-core data plane, docs/DATA_PLANE.md):
 BENCH_SKIP_INGEST, BENCH_INGEST_ROWS, BENCH_INGEST_TREES,
 BENCH_INGEST_BUDGET_MB, BENCH_INGEST_CHUNK_ROWS.
@@ -70,10 +68,6 @@ def _final_json():
               "voting_trees_per_sec", "voting_exact_trees_per_sec",
               "voting_speedup_vs_exact", "voting_auc_valid",
               "voting_leaves", "voting_devices",
-              "chunk_scan_trees_per_sec", "chunk_scan_off_trees_per_sec",
-              "chunk_scan_speedup", "chunk_scan_dispatches",
-              "chunk_scan_off_dispatches", "chunk_scan_host_ms_per_tree",
-              "chunk_scan_off_host_ms_per_tree",
               "ingest_rows", "ingest_features", "ingest_chunks",
               "ingest_ram_budget_mb", "ingest_spool_rows_per_sec",
               "ingest_bin_rows_per_sec", "ingest_fit_trees_per_sec",
@@ -209,7 +203,7 @@ def main() -> None:
     # served by the persistent cache). Both numbers are reported;
     # `value` is steady-state when >= 2 boundaries exist.
     def timed_train(run_params, n_trees, tag=""):
-        """One timed training run; returns (steady, total_tps, auc, bst).
+        """One timed training run; returns (steady, total_tps, auc).
 
         Steady-state = trees between the first and last chunk-boundary
         callback burst over the wall time between them (excludes the
@@ -258,9 +252,9 @@ def main() -> None:
         from sklearn.metrics import roc_auc_score
 
         auc = round(float(roc_auc_score(yv, bst2.predict(Xv))), 5)
-        return steady, total_tps, auc, bst2
+        return steady, total_tps, auc
 
-    steady, total_tps, auc, _ = timed_train(params, trees)
+    steady, total_tps, auc = timed_train(params, trees)
     record(
         trees_per_sec=round(steady if steady else total_tps, 4),
         total_trees_per_sec=round(total_tps, 4),
@@ -278,7 +272,7 @@ def main() -> None:
         qtrees = int(os.environ.get("BENCH_QUANT_TREES", trees))
         qparams = dict(params, use_quantized_grad=True,
                        num_grad_quant_bins=4, quant_train_renew_leaf=True)
-        qsteady, qtotal, qauc, _ = timed_train(
+        qsteady, qtotal, qauc = timed_train(
             qparams, qtrees, tag="quant ")
         record(
             quantized_trees_per_sec=round(qsteady or qtotal, 4),
@@ -286,39 +280,6 @@ def main() -> None:
         )
         if qauc is not None:
             record(quantized_auc_valid=qauc)
-
-    # chunk-scan segment: the SAME training with rounds dispatched as
-    # C-round lax.scan chunks (tpu_chunk_scan=auto, the default) vs one
-    # executable launch per round (=off) — a same-run measurement of
-    # what evicting the host from the inner loop buys. Alongside
-    # trees/sec it reports the dispatch count (the probe the tests
-    # assert on: chunks, not rounds) and host ms spent inside
-    # fused_dispatch per tree; the device-side step math is identical
-    # on both sides by construction (bit-parity tested).
-    if not os.environ.get("BENCH_SKIP_CHUNK_SCAN"):
-        ctrees = int(os.environ.get("BENCH_CHUNK_TREES", min(trees, 30)))
-
-        def _host_ms_per_tree(b, n):
-            return round(1000.0 * b._gbdt._dispatch_host_s / max(n, 1), 3)
-
-        csteady, ctotal, _, cbst = timed_train(
-            dict(params, tpu_chunk_scan="auto"), ctrees, tag="chunk ")
-        osteady, ototal, _, obst = timed_train(
-            dict(params, tpu_chunk_scan="off"), ctrees,
-            tag="chunk-off ")
-        ctps, otps = csteady or ctotal, osteady or ototal
-        record(
-            chunk_scan_trees_per_sec=round(ctps, 4),
-            chunk_scan_off_trees_per_sec=round(otps, 4),
-            chunk_scan_speedup=(
-                round(ctps / otps, 3) if otps else None),
-            chunk_scan_dispatches=cbst._gbdt.fused_dispatch_count,
-            chunk_scan_off_dispatches=obst._gbdt.fused_dispatch_count,
-            chunk_scan_host_ms_per_tree=_host_ms_per_tree(
-                cbst, ctrees),
-            chunk_scan_off_host_ms_per_tree=_host_ms_per_tree(
-                obst, ctrees),
-        )
 
     # ingest segment: the out-of-core data plane (docs/DATA_PLANE.md) —
     # spool the bench matrix to a disk chunk store, stream the two-pass
@@ -398,13 +359,13 @@ def main() -> None:
                            num_leaves=vleaves, tpu_growth_mode="rounds")
             record(voting_leaves=vleaves,
                          voting_devices=jax.device_count())
-            vsteady, vtotal, vauc, _ = timed_train(
+            vsteady, vtotal, vauc = timed_train(
                 vparams, vtrees, tag="voting ")
             vtps = vsteady or vtotal
             record(voting_trees_per_sec=round(vtps, 4))
             if vauc is not None:
                 record(voting_auc_valid=vauc)
-            esteady, etotal, _, _ = timed_train(
+            esteady, etotal, _ = timed_train(
                 dict(vparams, tpu_growth_mode="exact"), etrees,
                 tag="voting-exact ")
             etps = esteady or etotal

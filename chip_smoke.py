@@ -49,8 +49,8 @@ CHECK_BLOCKS = 64
 SCORE_TOL = 1e-5
 AUC_FLOOR = 0.8  # "well above chance" after a handful of rounds
 MULTICHIP_AUC_BAND = 2e-3  # the band tests/test_tree_learner_data.py pins
-# everything else is the default: tpu_growth_mode, tpu_hist_dtype and
-# tpu_chunk_scan stay `auto` and resolve on the chip
+# everything else is the default: tpu_growth_mode and tpu_hist_dtype
+# stay `auto` and resolve on the chip
 PARAMS = {"objective": "binary", "metric": "auc", "num_leaves": LEAVES,
           "max_bin": MAX_BIN, "verbosity": -1}
 
@@ -261,7 +261,6 @@ def train_phase(name, lgb, ds, vs, extra: dict, want_dtype: str,
           f"N={N} S={g.spec.rounds_slots} G={G} B={g.spec.num_bins}")
     check(not g._force_sync, f"{name}: forced onto the sync loop: "
                              f"{g._force_sync_reason}")
-    check(g.config.tpu_chunk_scan == "auto", name)
     check(g.fused_dispatch_count == 2 and len(g._f_program.chunks) == 1,
           f"{name}: expected one scan executable dispatched twice, got "
           f"{g.fused_dispatch_count} dispatches of "
@@ -285,7 +284,7 @@ def train_phase(name, lgb, ds, vs, extra: dict, want_dtype: str,
         "train_auc": evals["train"]["auc"][-1],
         "line": (
             f"{name}: growth=rounds(S={g.spec.rounds_slots}) "
-            f"hist_dtype={g.hist_dtype} chunk_scan=auto"
+            f"hist_dtype={g.hist_dtype} chunk scans "
             f"(rung {sorted(g._f_program.chunks)} x"
             f"{g.fused_dispatch_count}) {rounds} rounds: "
             f"first run {first_s:.1f}s (trace+compile+rounds), repeat "

@@ -10,8 +10,7 @@ the CPU backend and checks the compiled executable's
 
 - **flops** and **bytes accessed** — a fusion break or an
   accidentally-materialized intermediate shows up here long before a
-  chip benchmark can (BENCH_r05 ran on CPU fallback; the auditor runs
-  anywhere);
+  chip benchmark can (the auditor runs anywhere);
 - **peak temp / output allocation** — the HBM-blowup guard: a new
   buffer the size of the bin matrix fails the budget instead of OOMing
   a chip three PRs later;

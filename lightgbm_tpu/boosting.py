@@ -39,11 +39,9 @@ from .tree import Tree, traverse_tree_bins
 # canonical per-round host phase names (docs/OBSERVABILITY.md): the
 # eager loops (fast/sync) emit the three phases each iteration; the
 # fused loop — whose phases live inside one jit — emits one span per
-# DISPATCH. Under chunk scanning (tpu_chunk_scan=auto, the default) a
-# dispatch is a C-round lax.scan, so the span covers the whole chunk;
-# _ObsHooks divides it by the dispatch's round count (the booster's
-# _last_dispatch_rounds) to keep per-round record durations. With
-# tpu_chunk_scan=off each dispatch is one round, as historically.
+# DISPATCH. A dispatch is a C-round lax.scan, so the span covers the
+# whole chunk; _ObsHooks divides it by the dispatch's round count (the
+# booster's _last_dispatch_rounds) to keep per-round record durations.
 # obs.tracing records these as trace-event spans, and a jax.profiler
 # trace holds each as a host event `lgbm:<name>` (timer.Timer.scope).
 ROUND_PHASES = (
@@ -165,18 +163,14 @@ def _pick_chunk(rounds_left: int, ladder: Sequence[int]) -> int:
 
 
 class _FusedProgram:
-    """Traced programs for one fused-step memo key: the raw step body,
-    its per-round jit, and lazily-built C-round lax.scan chunk jits
-    (one per ladder rung actually dispatched). Cached in
-    _FUSED_STEP_CACHE, so the memo key effectively grows the chunk
-    length through ``chunks`` — cv folds and repeated trains share the
-    scan executables exactly like they share the per-round step."""
+    """Traced programs for one fused-step memo key: the raw step body
+    and lazily-built C-round lax.scan chunk jits (one per ladder rung
+    actually dispatched). Cached in _FUSED_STEP_CACHE, so the memo key
+    effectively grows the chunk length through ``chunks`` — cv folds
+    and repeated trains share the scan executables."""
 
     def __init__(self, step_fn, donate):
-        import jax
-
         self.step_fn = step_fn
-        self.step = jax.jit(step_fn, donate_argnums=donate)
         self._donate = donate
         self.chunks: Dict[int, Any] = {}
 
@@ -330,14 +324,11 @@ class GBDT:
         self._pending: List[Any] = []  # TreeArrays (per-round) / _PendingChunk
         self._pending_meta: List[Tuple[int, float, float]] = []  # (k, bias, shrinkage)
         # dispatch-count probe: executable launches issued by
-        # fused_dispatch (one per chunk under chunk scanning, one per
-        # round with tpu_chunk_scan=off) + the host seconds spent
-        # issuing them — read by tests and bench.py's chunk_scan
-        # segment. _last_dispatch_rounds holds the round count of each
-        # dispatch in the most recent chunk so _ObsHooks can expand
-        # per-dispatch spans into per-round durations.
+        # fused_dispatch (one per chunk). _last_dispatch_rounds holds
+        # the round count of each dispatch in the most recent chunk so
+        # _ObsHooks can expand per-dispatch spans into per-round
+        # durations.
         self.fused_dispatch_count = 0
-        self._dispatch_host_s = 0.0
         self._last_dispatch_rounds: List[int] = []
         self._stopped = False
         # aligned to max(config.DEFAULT_CHUNK_LADDER) so a full driver
@@ -669,15 +660,6 @@ class GBDT:
             cat_subset=cat_subset,
             efb=train_set.bundle_layout is not None,
             col_bins=train_set.col_bins,
-            # the PERMUTED batched mode still excludes per-node extras,
-            # monotone intermediate, voting and forced splits
-            # (permuted.py raises); the natural-order rounds grower is
-            # the path that supports them
-            rounds=(config.tpu_growth_rounds and not use_rounds
-                    and rounds_ok and not mono_mode
-                    and not use_voting and not n_forced
-                    and not (use_extra or use_bynode or use_cegb
-                             or n_groups)),
             # slot defaults are chip-tuned END TO END:
             # quant ch3 S=48 beat both 42 (0.258 vs 0.302 ms/split) and
             # 64 (10.06 vs 9.83 trees/s); non-quant S=32 measured
@@ -1778,7 +1760,6 @@ class GBDT:
             if cached is not None:
                 _FUSED_STEP_CACHE.move_to_end(key)  # LRU touch
                 self._f_program = cached
-                self._f_step = cached.step
                 return
         # donate the loop state on accelerators (scores are the big
         # per-iteration buffers); NOT on CPU — XLA:CPU donation has
@@ -1787,7 +1768,6 @@ class GBDT:
         # and CPU runs are tests/CI where the extra score copy is noise
         donate = () if platform() == "cpu" else (0,)
         self._f_program = _FusedProgram(step, donate)
-        self._f_step = self._f_program.step
         if key is not None:
             _FUSED_STEP_CACHE[key] = self._f_program
             while len(_FUSED_STEP_CACHE) > _FUSED_STEP_CACHE_MAX:
@@ -1829,88 +1809,65 @@ class GBDT:
             "init": jnp.asarray(np.asarray(init_scores, np.float32)),
             "stopped": jnp.asarray(False),
         }
-        # entries are (device rows, n_active): a per-round (E,) row with
-        # n_active=None, or a chunk's (C, E) stack whose first n_active
-        # rows are live — fused_collect slices on the host
-        self._f_evals: List[Tuple[Any, Optional[int]]] = []
+        # entries are (device rows, n_active): a chunk's (C, E) stack
+        # whose first n_active rows are live — fused_collect slices on
+        # the host
+        self._f_evals: List[Tuple[Any, int]] = []
         self._last_dispatch_rounds = []
 
     def fused_dispatch(self, n: int) -> None:
         """Dispatch n fused iterations without any host synchronization.
 
-        Default (``tpu_chunk_scan=auto``): n is greedily decomposed over
-        the ``config.DEFAULT_CHUNK_LADDER`` rungs, largest-first, and
-        each rung launches ONE jitted ``lax.scan`` of the per-round step
-        — one executable launch and one host pytree unpack per CHUNK
-        instead of per round, the all-device inner loop of ROADMAP item
-        2. A remainder shorter than the smallest rung still dispatches
+        n is greedily decomposed over the ``config.DEFAULT_CHUNK_LADDER``
+        rungs, largest-first, and each rung launches ONE jitted
+        ``lax.scan`` of the per-round step — one executable launch and
+        one host pytree unpack per CHUNK instead of per round, the
+        all-device inner loop of ROADMAP item 2. A remainder shorter
+        than the smallest rung still dispatches
         that rung: rounds at or past ``it_end`` are algebraic no-ops on
         device (zeroed leaf values, frozen scores/``it``) and their
         stacked outputs are sliced off at materialize, so truncation is
-        exact and no chunk size ever retraces. ``tpu_chunk_scan=off``
-        keeps the historical one-dispatch-per-round loop as the
-        bit-parity baseline.
+        exact and no chunk size ever retraces.
 
         The ``FUSED_ROUND_PHASE`` span covers one DISPATCH (a whole
-        chunk by default) and only its async host cost — device time
-        lands in "fused collect"; per-dispatch round counts land in
+        chunk) and only its async host cost — device time lands in
+        "fused collect"; per-dispatch round counts land in
         ``_last_dispatch_rounds`` so the flight recorder can apportion
         the span across rounds.
         """
-        import time as _time
-
         import jax.numpy as jnp
+
+        # read at call time: tests swap the ladder for (1,)
+        from .config import DEFAULT_CHUNK_LADDER
 
         if n <= 0:
             return
         K = self.num_class
-        t0 = _time.perf_counter()
         self._last_dispatch_rounds = []
         self._f_data["it_end"] = jnp.int32(self.iter_ + n)
-        if getattr(self.config, "tpu_chunk_scan", "auto") == "off":
-            for _ in range(n):
-                with _gt.scope(FUSED_ROUND_PHASE):
-                    self._fstate, trees, eval_row = self._f_step(
-                        self._fstate, self._f_data
-                    )
-                self.fused_dispatch_count += 1
-                self._last_dispatch_rounds.append(1)
-                for k, arrays in enumerate(trees):
-                    self.device_trees.append((arrays, None))
-                    self._pending.append(arrays)
+        left = n
+        while left > 0:
+            length = _pick_chunk(left, DEFAULT_CHUNK_LADDER)
+            n_act = min(length, left)
+            chunk_fn = self._f_program.chunk(length)
+            with _gt.scope(FUSED_ROUND_PHASE):
+                self._fstate, trees, eval_mat = chunk_fn(
+                    self._fstate, self._f_data
+                )
+            self.fused_dispatch_count += 1
+            self._last_dispatch_rounds.append(n_act)
+            self._pending.append(_PendingChunk(trees, length, n_act))
+            for _r in range(n_act):
+                for k in range(K):
+                    self.device_trees.append(_PENDING_SLOT)
                     self._pending_meta.append(
-                        (k, self._init_scores[k] if self.iter_ == 0 else 0.0,
+                        (k,
+                         self._init_scores[k] if self.iter_ == 0 else 0.0,
                          self.shrinkage_rate)
                     )
-                self._f_evals.append((eval_row, None))
                 self.iter_ += 1
-        else:
-            from .config import DEFAULT_CHUNK_LADDER
-
-            left = n
-            while left > 0:
-                length = _pick_chunk(left, DEFAULT_CHUNK_LADDER)
-                n_act = min(length, left)
-                chunk_fn = self._f_program.chunk(length)
-                with _gt.scope(FUSED_ROUND_PHASE):
-                    self._fstate, trees, eval_mat = chunk_fn(
-                        self._fstate, self._f_data
-                    )
-                self.fused_dispatch_count += 1
-                self._last_dispatch_rounds.append(n_act)
-                self._pending.append(_PendingChunk(trees, length, n_act))
-                for _r in range(n_act):
-                    for k in range(K):
-                        self.device_trees.append(_PENDING_SLOT)
-                        self._pending_meta.append(
-                            (k,
-                             self._init_scores[k] if self.iter_ == 0 else 0.0,
-                             self.shrinkage_rate)
-                        )
-                    self.iter_ += 1
-                self._f_evals.append((eval_mat, n_act))
-                left -= n_act
-        self._dispatch_host_s += _time.perf_counter() - t0
+            self._f_evals.append((eval_mat, n_act))
+            left -= n_act
         self._record_collective_wire(n * K)
         # keep canonical score handles current (no sync; handle reassign)
         self.train.score = self._fstate["score"]
@@ -1928,16 +1885,12 @@ class GBDT:
         self._f_evals = []
         rows: List[np.ndarray] = []
         if evals:
-            # ONE batched readback over per-round (E,) rows and chunked
-            # (C, E) stacks alike; chunk stacks are host-sliced to their
-            # live rounds (the masked tail never produced real evals)
+            # ONE batched readback over the chunks' (C, E) stacks, each
+            # host-sliced to its live rounds (the masked tail never
+            # produced real evals)
             fetched = jax.device_get([e for e, _na in evals])
             for got, (_e, n_act) in zip(fetched, evals):
-                got = np.asarray(got)
-                if got.ndim == 1:
-                    rows.append(got)
-                else:
-                    rows.extend(got[:n_act])
+                rows.extend(np.asarray(got)[:n_act])
         mat = (
             np.stack(rows) if rows else np.zeros((0, 0), np.float32)
         )

@@ -20,8 +20,10 @@ from . import log
 
 # name -> (default, type, aliases, check)
 # type is one of: bool, int, float, str, "list_int", "list_float", "list_str"
-# check is a predicate on the coerced value (None = no check).
-_P = Tuple[Any, Any, Tuple[str, ...], Optional[Callable[[Any], bool]]]
+# check is a predicate on the coerced value, or the tuple of accepted
+# values (None = no check).
+_P = Tuple[Any, Any, Tuple[str, ...],
+           Union[None, Callable[[Any], bool], Tuple[str, ...]]]
 
 _pos = lambda v: v > 0
 _nonneg = lambda v: v >= 0
@@ -147,7 +149,7 @@ _PARAMS: Dict[str, _P] = {
     # device push, bounding host memory by ram_budget_mb instead of
     # dataset size
     "data_source": ("memory", str, (),
-                    lambda v: v in ("memory", "chunked")),
+                    ("memory", "chunked")),
     # host RAM budget (MB) for the data plane: chunk sizing, prefetch
     # depth, and the single over-budget warning path (0 = 1024, the
     # legacy two_round >1GB text-size threshold)
@@ -213,11 +215,6 @@ _PARAMS: Dict[str, _P] = {
     "num_gpu": (1, int, (), _pos),
     # ---- TPU-specific extensions (not in reference) ----
     "tpu_row_block": (0, int, (), _nonneg),  # 0 = auto; rows per histogram matmul block
-    # round-batched growth: split every positive-gain leaf per device
-    # step (multi-leaf histograms + one sort per round). Faster on TPU,
-    # but once num_leaves binds the tree differs from exact leaf-wise
-    # greedy (best-first); off by default for reference parity.
-    "tpu_growth_rounds": (False, bool, (), None),
     # growth strategy: "exact" = sequential best-first (reference-exact
     # trees); "rounds" = natural-order round-batched growth (rounds.py:
     # top-k positive-gain leaves split per device step, slot-packed MXU
@@ -229,7 +226,7 @@ _PARAMS: Dict[str, _P] = {
     # runs keep reference-exact trees. Voting-parallel, forced splits,
     # per-node extras and all monotone methods ride rounds.
     "tpu_growth_mode": ("auto", str, (),
-                        lambda v: v in ("auto", "rounds", "exact")),
+                        ("auto", "rounds", "exact")),
     # max leaves split per round in rounds mode; 0 = auto (25 = 5 gh
     # channels x 25 slots filling the MXU's 128-row matmul axis; 42
     # under use_quantized_grad's 3 integer channels)
@@ -240,21 +237,10 @@ _PARAMS: Dict[str, _P] = {
     # integer levels and accumulate 3 narrow channels (scales recovered
     # before gain/leaf math, true-gradient leaf renewal keeps the
     # public semantics); "auto" = int16 on the rounds growth path,
-    # bf16x2 otherwise. "float32" is accepted as a legacy synonym for
-    # bf16x2. Under use_quantized_grad the quantized-API levels govern
-    # and this param is ignored.
+    # bf16x2 otherwise. Under use_quantized_grad the quantized-API
+    # levels govern and this param is ignored.
     "tpu_hist_dtype": ("auto", str, ("hist_dtype",),
-                       lambda v: v in ("auto", "float32", "bf16x2",
-                                       "int16", "int8")),
-    # fused-loop round chunking: "auto" (default) = dispatch boosting
-    # rounds as C-round lax.scan chunks over the DEFAULT_CHUNK_LADDER
-    # (one executable launch per chunk — the all-device inner loop);
-    # "off" = the historical one-jit-dispatch-per-round loop, kept as
-    # the bit-parity baseline for tests and the bench.py `chunk_scan`
-    # segment. Both paths share one traced step body, so models and
-    # eval records are bit-identical either way.
-    "tpu_chunk_scan": ("auto", str, (),
-                       lambda v: v in ("auto", "off")),
+                       ("auto", "bf16x2", "int16", "int8")),
     # USE_DEBUG split validation (serial_tree_learner.h:174 CheckSplit):
     # recompute leaf counts/hessian sums from the partition each
     # iteration and fatal on drift; forces the sync loop
@@ -338,7 +324,7 @@ _PARAMS: Dict[str, _P] = {
     # restore the last snapshot_freq checkpoint and retrain (optionally
     # with a shrunken learning_rate) instead of aborting
     "anomaly_policy": ("off", str, (),
-                       lambda v: v in ("off", "warn", "abort", "rollback")),
+                       ("off", "warn", "abort", "rollback")),
     # ---- resilience (lightgbm_tpu/resilience, docs/RESILIENCE.md) ----
     # crash-consistent checkpoint/resume: snapshot_freq>0 additionally
     # maintains ONE rolling checkpoint (model text + round index + eval
@@ -346,7 +332,7 @@ _PARAMS: Dict[str, _P] = {
     # restarts train() from it when present; resume_from= names an
     # explicit checkpoint file (missing -> error). The resumed model
     # bit-matches the uninterrupted run.
-    "resume": ("off", str, (), lambda v: v in ("off", "auto")),
+    "resume": ("off", str, (), ("off", "auto")),
     "resume_from": ("", str, (), None),
     # rolling checkpoint path; empty = <output_model>.ckpt
     "checkpoint_file": ("", str, (), None),
@@ -516,8 +502,14 @@ class Config:
                 cv = _coerce(name, typ, v)
             except (ValueError, TypeError) as e:
                 log.fatal(f"Parameter {name}: {e}")
-            if check is not None and cv is not None and not check(cv):
-                log.fatal(f"Parameter {name}={cv} violates its constraint")
+            if check is not None and cv is not None:
+                if isinstance(check, tuple):
+                    if cv not in check:
+                        log.fatal(f"Parameter {name}={cv} must be one of: "
+                                  f"{', '.join(check)}")
+                elif not check(cv):
+                    log.fatal(
+                        f"Parameter {name}={cv} violates its constraint")
             self._values[name] = cv
             self._raw[name] = v
         self._post_process()

@@ -100,19 +100,15 @@ class _ObsHooks:
         ``round: fused step`` spans out; chunk-level scopes
         (dispatch/collect/materialize) ride the chunk's first record.
 
-        One span covers one DISPATCH — a whole C-round lax.scan under
-        chunk scanning — so the booster's ``_last_dispatch_rounds``
-        apportions each span evenly across its rounds: records keep a
-        per-round duration either way."""
+        One span covers one DISPATCH — a whole C-round lax.scan — so
+        the booster's ``_last_dispatch_rounds`` apportions each span
+        evenly across its rounds: records keep a per-round duration."""
         from .boosting import FUSED_ROUND_PHASE
 
         drained = self.recorder.drain_phases()
         spans = drained.pop(FUSED_ROUND_PHASE, [])
-        per_dispatch = getattr(self._gbdt, "_last_dispatch_rounds", None)
-        if not per_dispatch:
-            per_dispatch = [1] * len(spans)
         durs: List[float] = []
-        for dur, n_rounds in zip(spans, per_dispatch):
+        for dur, n_rounds in zip(spans, self._gbdt._last_dispatch_rounds):
             durs.extend([dur / max(n_rounds, 1)] * n_rounds)
         self._step_durs = durs
         self._chunk_phases = {

@@ -67,8 +67,8 @@ class GrowerSpec(NamedTuple):
     # data_partition.hpp); False = masked full scans (simpler, for debug)
     gather_hist: bool = True
     # "permuted": physically leaf-grouped rows, O(segment) per split
-    # (permuted.py — the production path); "flat": per-row leaf-id vector,
-    # O(N) per split (kept as the reference/debug implementation)
+    # (permuted.py — the sequential reference-exact oracle); "flat":
+    # per-row leaf-id vector, O(N) per split (tree_learner=feature)
     partition: str = "permuted"
     # EFB (dataset.cpp:111 FindGroups): the bin matrix columns are
     # BUNDLES; histograms expand back to per-feature layout before split
@@ -77,17 +77,6 @@ class GrowerSpec(NamedTuple):
     # (>= num_bins); 0 means same as num_bins.
     efb: bool = False
     col_bins: int = 0
-    # round-batched growth (permuted partition only, opt-in via
-    # tpu_growth_rounds): split EVERY positive-gain leaf per step while
-    # the budget allows — one stable sort partitions all leaves, one
-    # multi-slot histogram pass covers all smaller children (the
-    # reference CUDA kernel's all-leaves batching,
-    # cuda_histogram_constructor.cu). NOT identical to sequential
-    # leaf-wise greedy once the leaf budget binds: greedy may spend the
-    # remaining budget on descendants of high-gain splits instead of
-    # sibling leaves (best-first vs breadth-batched). Default off; the
-    # sequential path is the reference-exact semantics.
-    rounds: bool = False
     # feature parallel (tree_learner=feature, parallel_tree_learner.h:26):
     # the FLAT grower with the FEATURE axis sharded over this mesh axis —
     # every shard holds all rows (the reference's all-ranks-hold-all-data
@@ -153,8 +142,8 @@ class GrowerSpec(NamedTuple):
     # dataset has at least one categorical feature: rounds-mode partition
     # updates need the per-row category-set test only then; all-numerical
     # datasets (the common benchmark shape) skip that machinery
-    # statically — the (L*B,) mask gather it replaces costs ~10 ms/round
-    # at 1M rows (tools/tpu_gather_probe.py)
+    # statically — the (L*B,) mask gather it replaces is an element
+    # gather per row, ~10 ms/round at 1M rows on the chip
     has_cat: bool = True
 
 
@@ -375,9 +364,9 @@ def grow_tree(
 
     Dispatches on spec.rounds_slots / spec.partition: "rounds"
     (natural-order round-batched, rounds.py — the TPU fast path),
-    "permuted" (leaf-grouped rows, O(segment) per split — the
-    reference-exact production path) or "flat" (per-row leaf ids,
-    O(N) per split — reference/debug)."""
+    "permuted" (leaf-grouped rows, one split per step: the
+    reference-exact parity oracle, tpu_growth_mode=exact) or "flat"
+    (per-row leaf ids, O(N) per split: tree_learner=feature)."""
     if spec.rounds_slots > 0:
         from .rounds import grow_tree_rounds
 

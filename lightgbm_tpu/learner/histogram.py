@@ -90,15 +90,6 @@ def _pallas_ok(kernel: str, n_rows: int, fits: bool = True,
     return False
 
 
-def _use_int4_oh() -> bool:
-    """Opt-in for the experimental nibble-SWAR (int4) one-hot on the
-    int8 histogram path (pallas_hist._swar_onehot4 has the evaluation
-    verdict — kept behind LGBM_TPU_INT4_OH=1)."""
-    import os
-
-    return os.environ.get("LGBM_TPU_INT4_OH", "") == "1"
-
-
 def build_gh8(grad: jax.Array, hess: jax.Array, count: jax.Array) -> jax.Array:
     """(N,) grad/hess/count (already masked) -> (8, N) bf16x2-split channels."""
     g_hi = grad.astype(jnp.bfloat16).astype(jnp.float32)
@@ -154,45 +145,6 @@ def histogram(bins_fm: jax.Array, gh8: jax.Array, num_bins: int) -> jax.Array:
             hist_tpu(bins_fm, gh8, num_bins, interpret=_interpret_pallas())
         )
     return _hist_fallback(bins_fm, gh8, num_bins)
-
-
-def hist_slots(
-    bins_fm: jax.Array,
-    gh8: jax.Array,
-    begins: jax.Array,
-    counts: jax.Array,
-    num_bins: int,
-    num_slots: int,
-    dense_visits: bool = False,
-) -> jax.Array:
-    """Per-slot histograms over contiguous row segments -> (S, 3, F, B).
-
-    One data pass for ALL slots (the multi-leaf batched construction of
-    the reference CUDA histogram kernel, cuda_histogram_constructor.cu —
-    there one block per leaf, here one visit plan over sorted segments).
-    Slots with counts == 0 return zeros. `dense_visits` doubles the
-    visit budget for sharded runs where local segments can exceed N/2.
-    """
-    F, N = bins_fm.shape
-    if _pallas_ok("hist_slots_tpu", N):
-        from .pallas_hist import hist_slots_tpu
-
-        out = hist_slots_tpu(
-            bins_fm, gh8, begins, counts, num_bins, num_slots,
-            dense_visits=dense_visits, interpret=_interpret_pallas(),
-        )  # (S+1, CH, F*B)
-        out3 = jnp.stack(
-            [out[:, 0] + out[:, 1], out[:, 2] + out[:, 3], out[:, 4]], axis=1
-        ).reshape(num_slots + 1, 3, F, num_bins)[:num_slots]
-        return jnp.where((counts > 0)[:, None, None, None], out3, 0.0)
-
-    iota = jnp.arange(N, dtype=jnp.int32)
-
-    def one(b, c):
-        m = ((iota >= b) & (iota < b + c)).astype(jnp.float32)
-        return _hist_fallback(bins_fm, gh8 * m[None, :], num_bins)
-
-    return jax.vmap(one)(begins, counts)
 
 
 def _hist_nat_fallback(bins_fm: jax.Array, gh8: jax.Array, slot: jax.Array,
@@ -285,8 +237,6 @@ def hist_nat_slots(
                   f"{budget} B VMEM budget"):
         from .pallas_hist import hist_nat_tpu
 
-        int4 = bool(use_i8 and _use_int4_oh())
-
         def call(bins_fm, gh8, slot):
             parts = []
             for c0, sc in _slot_chunks(num_slots, s_max):
@@ -298,7 +248,7 @@ def hist_nat_slots(
                 out = hist_nat_tpu(
                     bins_fm, gh8, local, sc, num_bins,
                     interpret=_interpret_pallas(), nat_ch=nat_ch,
-                    int8=use_i8, oh_shift=oh_shift, int4=int4,
+                    int8=use_i8, oh_shift=oh_shift,
                 )  # (sc*nat_ch, F*B)
                 o = out.reshape(sc, nat_ch, F, num_bins)
                 if quant:

@@ -122,28 +122,18 @@ def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
     _accum_features(bins_ref, out_ref, contribution, F=F, B=B)
 
 
-def _oh_iota_shape(B: int, blk: int, int8: bool,
-                   int4: bool = False) -> tuple:
+def _oh_iota_shape(B: int, blk: int, int8: bool) -> tuple:
     """Shape of the persistent one-hot iota scratch (one VMEM buffer
     per kernel invocation, written at grid step 0 and reused by every
     later step): the compare path persists the (B, blk) row iota, the
-    byte-SWAR path the packed (ceil(B/4), blk) byte iota, the
-    nibble-SWAR (int4) path a (2*ceil(B/8), blk) stack of the packed
-    nibble iota and the per-row hi-block index."""
-    if int8 and int4:
-        return (2 * (-(-B // 8)), blk)
+    byte-SWAR path the packed (ceil(B/4), blk) byte iota."""
     if int8:
         return (-(-B // 4), blk)
     return (B, blk)
 
 
-def _oh_iota_init(shape: tuple, int8: bool, int4: bool = False):
+def _oh_iota_init(shape: tuple, int8: bool):
     """Value for the persistent iota scratch (see _oh_iota_shape)."""
-    if int8 and int4:
-        half = shape[0] // 2
-        bg = lax.broadcasted_iota(jnp.int32, (half, shape[1]), 0)
-        iota_nib = (bg & 1) * _SWAR4_M8 + 0x76543210
-        return jnp.concatenate([iota_nib, bg >> 1], axis=0)
     bg = lax.broadcasted_iota(jnp.int32, shape, 0)
     if int8:
         return bg * (4 * _SWAR_REP) + 0x03020100
@@ -152,8 +142,7 @@ def _oh_iota_init(shape: tuple, int8: bool, int4: bool = False):
 
 def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
                 *, F: int, B: int, blk: int, S: int, nat_ch: int,
-                int8: bool = False, oh_shift: int = 0,
-                int4: bool = False):
+                int8: bool = False, oh_shift: int = 0):
     """Slot-packed natural-order histogram: rows carry a slot id; the
     weight matrix W packs (slot x channel) onto the MXU's M axis —
     W[(s, c), r] = gh[c, r] * (slot[r] == s) — so one (S*nat_ch, blk) @
@@ -178,7 +167,7 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
-        iota_ref[...] = _oh_iota_init(iota_ref.shape, int8, int4)
+        iota_ref[...] = _oh_iota_init(iota_ref.shape, int8)
 
     iota = iota_ref[...]  # VMEM-persistent one-hot iota (step-invariant)
     slot = slot_ref[0, :]  # (blk,) int32
@@ -194,12 +183,8 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
         ).astype(jnp.int8)
         # SWAR one-hot (see _swar_onehot): 1.65x the compare+cast rate
         # on the VPU-bound end; sums come out scaled by the byte value
-        # (nibble value on the experimental int4 variant)
         def contribution(bins_row):
-            if int4:
-                oh = _swar_onehot4(bins_row, B, blk, iota2=iota)
-            else:
-                oh = _swar_onehot(bins_row, B, blk, oh_shift, iota_p=iota)
+            oh = _swar_onehot(bins_row, B, blk, oh_shift, iota_p=iota)
             return lax.dot_general(
                 W, oh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32,
@@ -221,10 +206,6 @@ def _swar_divisor(oh_shift: int) -> float:
     return -128.0 if oh_shift == 0 else float(128 >> oh_shift)
 
 
-# nibble-SWAR (int4) one-hot marker: 0x8 per nibble, always positive
-# after the even/odd plane split (see _swar_onehot4)
-_SWAR4_DIVISOR = 8.0
-
 # every kernel states its scoped-VMEM limit instead of inheriting the
 # compiler's default (which moves between toolchains): the slot caps in
 # histogram._round_caps are compile limits established AT this value
@@ -239,7 +220,7 @@ _ARBITRARY = pltpu.CompilerParams(dimension_semantics=("arbitrary",),
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "blk", "interpret", "nat_ch",
-                     "int8", "oh_shift", "int4"),
+                     "int8", "oh_shift"),
 )
 def hist_nat_tpu(
     bins_fm: jax.Array,  # (F, N) int32, natural row order
@@ -252,13 +233,9 @@ def hist_nat_tpu(
     nat_ch: int = NAT_CH,
     int8: bool = False,
     oh_shift: int = 0,
-    int4: bool = False,
 ) -> jax.Array:
     """(S*nat_ch, F*B) f32 packed per-slot channel histograms (exact
-    integer sums computed in s32 when int8). `int4` (int8 path only,
-    LGBM_TPU_INT4_OH=1) swaps the byte-SWAR one-hot for the nibble
-    variant: 8 bins per i32 lane, marker 8 — see _swar_onehot4 for the
-    evaluation verdict."""
+    integer sums computed in s32 when int8)."""
     F, N = bins_fm.shape
     assert N % blk == 0, (N, blk)
     assert gh8.shape == (CH, N), gh8.shape
@@ -268,7 +245,7 @@ def hist_nat_tpu(
     block = hist_out_block(S * nat_ch, F, B)
     out = pl.pallas_call(
         functools.partial(_nat_kernel, F=F, B=B, blk=blk, S=S, nat_ch=nat_ch,
-                          int8=int8, oh_shift=oh_shift, int4=int4),
+                          int8=int8, oh_shift=oh_shift),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((F, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
@@ -282,7 +259,7 @@ def hist_nat_tpu(
             block, jnp.int32 if int8 else jnp.float32
         ),
         scratch_shapes=[
-            pltpu.VMEM(_oh_iota_shape(B, blk, int8, int4), jnp.int32),
+            pltpu.VMEM(_oh_iota_shape(B, blk, int8), jnp.int32),
         ],
         compiler_params=_ARBITRARY,
         interpret=interpret,
@@ -290,8 +267,7 @@ def hist_nat_tpu(
     out = hist_out_flat(out, F, B)
     if not int8:
         return out
-    div = _SWAR4_DIVISOR if int4 else _swar_divisor(oh_shift)
-    return out.astype(jnp.float32) * (1.0 / div)
+    return out.astype(jnp.float32) * (1.0 / _swar_divisor(oh_shift))
 
 
 _SWAR_REP = 0x01010101
@@ -340,60 +316,6 @@ def _swar_onehot(bins_row, B: int, blk: int, oh_shift: int, iota_p=None):
         z = (z >> oh_shift) & (_SWAR_REP * (0x80 >> oh_shift))
     oh = pltpu.bitcast(z, jnp.int8)
     return oh if 4 * B4 == B else oh[:B, :]
-
-
-_SWAR4_REP = 0x11111111
-_SWAR4_M7 = 0x77777777
-_SWAR4_M8 = -2004318072  # 0x88888888 as i32
-
-
-def _swar_onehot4(bins_row, B: int, blk: int, iota2=None):
-    """(1, blk) i32 bin values -> (B, blk) s8 one-hot via NIBBLE (int4)
-    SWAR packing: EIGHT bins per i32 lane (ISSUE 12 evaluation).
-
-    Packed row j covers bins 8j..8j+7, which always share one 16-bin
-    block (hi nibble j >> 1), so equality splits into a nibble zero
-    test on the LOW nibble against the packed nibble iota (row j even:
-    0x76543210, odd: 0xFEDCBA98) AND a whole-lane hi-block match:
-
-        t = ((bins & 15) * 0x11111111) ^ iota_nib
-        z = ~(((t & 0x77777777) + 0x77777777) | t) & 0x88888888
-        z = where(bins >> 4 == j >> 1, z, 0)
-
-    (the same carry-free masked test as the byte variant — (t & 7) + 7
-    cannot carry across nibbles). Marker 0x8 per matching nibble.
-
-    EVALUATION VERDICT (kept opt-in, LGBM_TPU_INT4_OH=1): this
-    toolchain's pltpu.bitcast cannot widen i32 -> 8 x i4 (it rejects
-    the 4-bit element reinterpret), so the unpack degrades to an
-    even/odd nibble-plane split — two masked shifts, two i32 -> s8
-    byte bitcasts and a sublane interleave. The halved one-hot VMEM
-    footprint survives only up to that unpack; the extra VPU work eats
-    most of the packing win, and the MXU dot still runs s8. The
-    nibble TEST itself (3 ops for 8 bins vs 3 ops for 4) is the part
-    worth keeping if a true i4 reinterpret lands.
-
-    `iota2` passes the (2*ceil(B/8), blk) VMEM scratch stack
-    [iota_nib; row_hi] (_oh_iota_init). Marker is always 8 (the s32
-    headroom of the byte path's oh_shift=4), divisor _SWAR4_DIVISOR."""
-    B8 = -(-B // 8)
-    if iota2 is None:
-        bg = lax.broadcasted_iota(jnp.int32, (B8, blk), 0)
-        iota_nib = (bg & 1) * _SWAR4_M8 + 0x76543210
-        row_hi = bg >> 1
-    else:
-        iota_nib = iota2[:B8, :]
-        row_hi = iota2[B8:, :]
-    lo = (bins_row & 15) * _SWAR4_REP
-    t = lo ^ iota_nib
-    z = ~(((t & _SWAR4_M7) + _SWAR4_M7) | t) & _SWAR4_M8
-    z = jnp.where((bins_row >> 4) == row_hi, z, 0)
-    # nibble-plane split: even bins live in low nibbles, odd in high;
-    # each plane is a byte-plane the toolchain CAN bitcast to s8
-    ze = pltpu.bitcast(z & 0x0F0F0F0F, jnp.int8)  # (4*B8, blk) bins 2r
-    zo = pltpu.bitcast((z >> 4) & 0x0F0F0F0F, jnp.int8)  # bins 2r+1
-    oh = jnp.stack([ze, zo], axis=1).reshape(8 * B8, blk)
-    return oh if 8 * B8 == B else oh[:B, :]
 
 
 def _round_kernel(
@@ -487,7 +409,7 @@ def _round_kernel(
         # over disjoint memberships) gets a single-feature one-hot and
         # one (S, B) @ (B, blk) contraction against the per-slot masks
         # — the (L*B,) flat gather this replaces costs ~10 ms at 1M
-        # rows (tools/tpu_gather_probe.py).
+        # rows (an element gather per row; the chip has no vector gather).
         is_cat_s = params_ref[:, 10:11] != 0  # (S, 1)
         fb_own = jnp.sum(jnp.where(memb, fb, 0.0), axis=0,
                          keepdims=True)  # (1, blk) f32 integer-valued
@@ -614,7 +536,7 @@ def _take_kernel(idx_ref, tab_ref, out_ref, *, L: int, k: int, blk: int):
     A (N,) vector gather from a small table costs ~1 ms per 1M rows on
     TPU (no vector-gather hardware); this does the same lookup as
     (k, L) @ (L, blk) one-hot matmuls per tile, ~0.1 ms for the whole
-    array (tools/tpu_gather_probe.py). HIGHEST precision: table VALUES
+    array. HIGHEST precision: table VALUES
     are arbitrary f32 (leaf outputs) and the default TPU matmul would
     round them to bf16; with a 0/1 one-hot operand the HIGHEST-precision
     product is exact."""
@@ -714,115 +636,6 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, F: int, B: int, blk: int):
     g = gh_ref[...].astype(jnp.bfloat16)  # (CH, blk)
     _accum_hist_nt(bins_ref, g, out_ref, F=F, B=B, blk=blk,
                    dt=jnp.bfloat16, acc_t=jnp.float32)
-
-
-def _hist_slots_kernel(
-    vblock_ref, vslot_ref, vlo_ref, vhi_ref,  # scalar prefetch
-    bins_ref, gh_ref, out_ref, acc_ref, *, F: int, B: int, blk: int
-):
-    """One visit = (row block, slot, in-block row range). Visits arrive
-    sorted by slot; acc accumulates a slot's histogram across its visits
-    and flushes to the slot's output block on the slot's last visit."""
-    v = pl.program_id(0)
-    slot = vslot_ref[v]
-    prev_slot = vslot_ref[jnp.maximum(v - 1, 0)]
-
-    @pl.when((v == 0) | (slot != prev_slot))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    lo = vlo_ref[v]
-    hi = vhi_ref[v]
-    iota_r = lax.broadcasted_iota(jnp.int32, (CH, blk), 1)
-    g = jnp.where((iota_r >= lo) & (iota_r < hi), gh_ref[...], 0.0).astype(
-        jnp.bfloat16
-    )
-    _accum_hist_nt(bins_ref, g, acc_ref, F=F, B=B, blk=blk,
-                   dt=jnp.bfloat16, acc_t=jnp.float32)
-
-    # vslot has a trailing sentinel, so v+1 is always readable
-    @pl.when(vslot_ref[v + 1] != slot)
-    def _flush():
-        out_ref[...] = acc_ref[...][None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_bins", "num_slots", "blk", "dense_visits",
-                     "interpret"),
-)
-def hist_slots_tpu(
-    bins_fm: jax.Array,  # (F, N) int32, rows POSITION-grouped by slot
-    gh8: jax.Array,  # (CH, N) f32
-    begins: jax.Array,  # (num_slots,) int32 — slot segment starts
-    counts: jax.Array,  # (num_slots,) int32 — slot segment lengths
-    num_bins: int,
-    num_slots: int,
-    blk: int = HIST_BLK,
-    dense_visits: bool = False,
-    interpret: bool = False,
-) -> jax.Array:
-    """Per-slot histograms in ONE data pass: (num_slots+1, CH, F*B).
-
-    Each slot is a contiguous row segment [begin, begin+count); segments
-    must be disjoint but need not cover all rows (total visited blocks
-    is bounded by nb//2 + 2*num_slots — callers use this for the
-    smaller-children of one round, whose total is <= N/2). The +1 slot
-    is a trash row absorbing padding visits; slot s of the output is
-    garbage when counts[s] == 0 AND no visit wrote it — callers must
-    mask by counts > 0.
-    """
-    F, N = bins_fm.shape
-    assert N % blk == 0, (N, blk)
-    B = num_bins
-    nb = N // blk
-    S = num_slots
-    # visit budget: sum(counts) <= N/2 (smaller children) + 2 boundary
-    # blocks per slot; sharded runs can exceed N/2 locally -> dense
-    V = (nb if dense_visits else nb // 2) + 2 * S + 2
-
-    cnt1 = jnp.maximum(counts, 1)  # empty slots still get one zero visit
-    blk0 = begins // blk
-    blk1 = (begins + cnt1 - 1) // blk
-    nblk = jnp.clip(blk1 - blk0 + 1, 1, nb)
-    offs = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(nblk)])
-    iota_v = jnp.arange(V, dtype=jnp.int32)
-    s_of_v = (
-        jnp.searchsorted(offs, iota_v, side="right").astype(jnp.int32) - 1
-    )
-    pad = s_of_v >= S
-    s_clip = jnp.clip(s_of_v, 0, S - 1)
-    vblock = jnp.clip(
-        blk0[s_clip] + iota_v - offs[s_clip], 0, nb - 1
-    ).astype(jnp.int32)
-    bstart = vblock * blk
-    vlo = jnp.clip(begins[s_clip] - bstart, 0, blk)
-    vhi = jnp.clip(begins[s_clip] + counts[s_clip] - bstart, 0, blk)
-    vslot = jnp.where(pad, S, s_of_v).astype(jnp.int32)
-    vlo = jnp.where(pad, 0, vlo).astype(jnp.int32)
-    vhi = jnp.where(pad, 0, vhi).astype(jnp.int32)
-    vslot_s = jnp.concatenate([vslot, jnp.full(1, S + 1, jnp.int32)])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(V,),
-        in_specs=[
-            pl.BlockSpec((F, blk), lambda v, vb, vs, lo, hi: (0, vb[v])),
-            pl.BlockSpec((CH, blk), lambda v, vb, vs, lo, hi: (0, vb[v])),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, CH, F * B), lambda v, vb, vs, lo, hi: (vs[v], 0, 0)
-        ),
-        scratch_shapes=[pltpu.VMEM((CH, F * B), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_hist_slots_kernel, F=F, B=B, blk=blk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S + 1, CH, F * B), jnp.float32),
-        compiler_params=_ARBITRARY,
-        interpret=interpret,
-    )(vblock, vslot_s, vlo, vhi, bins_fm, gh8)
-    return out
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "blk", "interpret"))

@@ -1,6 +1,9 @@
-"""Leaf-wise tree growth over a physically permuted bin matrix.
+"""Sequential leaf-wise tree growth over a physically permuted bin
+matrix: the reference-exact parity oracle (tpu_growth_mode=exact).
 
-This is the TPU formulation of the reference's index-list partition
+One split per step, in the reference's best-first order; the measured
+program is the rounds grower (rounds.py), and the tests hold it to
+this one. This is the TPU formulation of the reference's index-list partition
 (src/treelearner/data_partition.hpp: rows stored grouped by leaf as one
 permuted array + per-leaf (begin, count)): the bin matrix, channel
 matrix, and a row-origin vector are kept PHYSICALLY reordered so every
@@ -37,7 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .bundle import BundleInfo, decode_feature_bins, expand_hist
-from .histogram import HIST_BLK, build_gh8, hist_slots, histogram, root_sums
+from .histogram import HIST_BLK, build_gh8, histogram, root_sums
 from .split import (
     BIG,
     NEG_INF,
@@ -129,41 +132,12 @@ class _PState(NamedTuple):
     anc_left: jax.Array
 
 
-class _RState(NamedTuple):
-    """Round-phase state: _PState plus an explicit row -> leaf vector."""
-
-    p: _PState
-    pleaf: jax.Array  # (N,) int32; padding rows carry L (sorts last)
-
-
 def _go_left(fbins, rec, fnan):
     return jnp.where(
         rec.is_cat,
         rec.cat_mask[fbins],
         (fbins <= rec.bin) | (rec.default_left & (fbins == fnan) & (fnan >= 0)),
     )
-
-
-def _excl_prefix(x: jax.Array, blk: int = 512) -> jax.Array:
-    """(N,) f32 -> (N+1,) exclusive prefix sums.
-
-    Two-level: strict-upper-triangular matmul for in-block prefixes
-    (MXU, f32-exact for counts < 2^24) + a tiny cumsum over block
-    totals — a plain 1M-element jnp.cumsum measured ~47 ms on TPU,
-    this is ~1 GFLOP of matmul instead.
-    """
-    n = x.shape[0]
-    nb2 = n // blk
-    if nb2 * blk != n:  # fall back for odd sizes (CPU tests)
-        cs = jnp.cumsum(x)
-        return jnp.concatenate([jnp.zeros(1, x.dtype), cs])
-    xb = x.reshape(nb2, blk)
-    upper = jnp.triu(jnp.ones((blk, blk), jnp.float32), 1)
-    intra = jnp.dot(xb, upper, preferred_element_type=jnp.float32)
-    tot = jnp.sum(xb, axis=1)
-    boff = jnp.concatenate([jnp.zeros(1, jnp.float32), jnp.cumsum(tot)])
-    p = (intra + boff[:-1, None]).reshape(n)
-    return jnp.concatenate([p, boff[-1:]])
 
 
 @partial(jax.jit, static_argnames=("spec",))
@@ -209,15 +183,12 @@ def grow_tree_permuted(
             "use tpu_growth_mode=rounds for the combination"
         )
     per_node = spec.extra_trees or spec.ff_bynode or spec.cegb or spec.n_groups
-    if spec.rounds and (per_node or spec.n_forced):
-        raise ValueError("tpu_growth_rounds excludes per-node extras")
-    if spec.mono_mode and (per_node or spec.voting_k or spec.n_forced
-                           or spec.rounds):
+    if spec.mono_mode and (per_node or spec.voting_k or spec.n_forced):
         # the intermediate re-search pass uses the plain feature mask
         # and assumes globally-valid histograms
         raise ValueError(
             "monotone intermediate/advanced excludes per-node extras / "
-            "voting / forced splits / rounds"
+            "voting / forced splits"
         )
 
     # shared per-node machinery (grower.make_node_candidates): the
@@ -286,245 +257,6 @@ def grow_tree_permuted(
     n_valid = jnp.sum(valid_f > 0).astype(jnp.int32)  # local (shard) count
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
-    S = L // 2 + 1  # max splits per round (budget guard caps at L/2)
-
-    def _round_body(rs: _RState) -> _RState:
-        """Split EVERY positive-gain leaf at once (multi-leaf batch)."""
-        s = rs.p
-        t = s.tree
-        i = s.i
-        mask = s.best.gain > 0.0  # (L,)
-        n_split = jnp.sum(mask).astype(jnp.int32)
-        rank = jnp.cumsum(mask.astype(jnp.int32)) - mask  # exclusive
-        rank = jnp.minimum(rank, S - 1)
-        node_id = i + rank  # node slot per split leaf
-        new_id = i + 1 + rank  # right-child leaf id per split leaf
-        drop_node = jnp.where(mask, node_id, L - 1)  # L-1 -> mode=drop
-        drop_new = jnp.where(mask, new_id, L)
-
-        rec = s.best  # per-leaf records, fields (L,)
-
-        # ---- outputs / monotone intervals, vectorized over leaves ----
-        pmin, pmax = s.leaf_min, s.leaf_max
-        lo, ro = split_leaf_outputs(rec, params, num_bins, spec.cat_subset,
-                                    t.leaf_value, pmin, pmax)
-        lmin, lmax, rmin, rmax = monotone_child_intervals(
-            rec, mono, lo, ro, pmin, pmax
-        )
-        depth_new = t.leaf_depth + 1
-
-        # ---- tree bookkeeping (Tree::Split, batched) ----
-        p = s.leaf_parent
-        pc = jnp.maximum(p, 0)
-        p_is_left = t.node_left[pc] == ~iota_L
-        fix = mask & (p >= 0)
-        node_left = t.node_left.at[
-            jnp.where(fix & p_is_left, pc, L - 1)
-        ].set(node_id, mode="drop")
-        node_right = t.node_right.at[
-            jnp.where(fix & ~p_is_left, pc, L - 1)
-        ].set(node_id, mode="drop")
-        node_left = node_left.at[drop_node].set(~iota_L, mode="drop")
-        node_right = node_right.at[drop_node].set(~drop_new, mode="drop")
-
-        tree_new = TreeArrays(
-            num_nodes=i + n_split,
-            node_feature=t.node_feature.at[drop_node].set(rec.feature, mode="drop"),
-            node_bin=t.node_bin.at[drop_node].set(rec.bin, mode="drop"),
-            node_gain=t.node_gain.at[drop_node].set(rec.gain, mode="drop"),
-            node_default_left=t.node_default_left.at[drop_node].set(
-                rec.default_left, mode="drop"
-            ),
-            node_cat=t.node_cat.at[drop_node].set(rec.is_cat, mode="drop"),
-            node_cat_mask=t.node_cat_mask.at[drop_node].set(
-                rec.cat_mask, mode="drop"
-            ),
-            node_left=node_left,
-            node_right=node_right,
-            node_value=t.node_value.at[drop_node].set(t.leaf_value, mode="drop"),
-            node_weight=t.node_weight.at[drop_node].set(s.leaf_h, mode="drop"),
-            node_count=t.node_count.at[drop_node].set(s.leaf_c, mode="drop"),
-            leaf_value=jnp.where(mask, lo, t.leaf_value)
-            .at[drop_new].set(ro, mode="drop"),
-            leaf_weight=jnp.where(mask, rec.left_h, t.leaf_weight)
-            .at[drop_new].set(rec.right_h, mode="drop"),
-            leaf_count=jnp.where(mask, rec.left_c, t.leaf_count)
-            .at[drop_new].set(rec.right_c, mode="drop"),
-            leaf_depth=jnp.where(mask, depth_new, t.leaf_depth)
-            .at[drop_new].set(depth_new, mode="drop"),
-        )
-
-        # ---- per-row split decision for ALL leaves at once ----
-        pl_c = jnp.minimum(rs.pleaf, L - 1)  # padding rows -> dead lanes
-        f_row = rec.feature[pl_c]
-        col_row = bundle.bundle_of[f_row] if spec.efb else f_row
-        # masked select of each row's split column (no 2D gather)
-        sel = col_row[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
-        fbins = jnp.sum(jnp.where(sel, s.pbins, 0), axis=0)
-        if spec.efb:
-            fbins = decode_feature_bins(fbins, f_row, bundle)  # vector f
-        fnan_row = nan_bin[f_row]
-        cat_hit = rec.cat_mask.reshape(-1)[pl_c * B + jnp.minimum(fbins, B - 1)]
-        go_left = jnp.where(
-            rec.is_cat[pl_c],
-            cat_hit,
-            (fbins <= rec.bin[pl_c])
-            | (rec.default_left[pl_c] & (fbins == fnan_row) & (fnan_row >= 0)),
-        )
-        in_split = mask[pl_c] & (rs.pleaf < L)
-        pleaf_new = jnp.where(
-            in_split & ~go_left, new_id[pl_c], rs.pleaf
-        ).astype(jnp.int32)
-
-        # ---- stable multi-leaf partition WITHOUT a sort (XLA TPU sort
-        # is seconds at 1M rows): per-row destination = segment start +
-        # stable rank within the destination child, via two-level
-        # prefix sums; then one scatter to invert the permutation and
-        # one gather to apply it to all channels.
-        gl_in = in_split & go_left
-        gr_in = in_split & ~go_left
-        P_l = _excl_prefix(gl_in.astype(jnp.float32))  # (N+1,)
-        P_r = _excl_prefix(gr_in.astype(jnp.float32))
-        beg = s.seg_begin
-        endp = jnp.minimum(beg + s.seg_count, N)
-        n_l = (P_l[endp] - P_l[jnp.minimum(beg, N)]).astype(jnp.int32)
-        n_l = jnp.where(mask, n_l, 0)
-
-        pos = jnp.arange(N, dtype=jnp.int32)
-        b_row = beg[pl_c]
-        Pl_b = P_l[jnp.minimum(b_row, N)]
-        Pr_b = P_r[jnp.minimum(b_row, N)]
-        dst_l = b_row + (P_l[:-1] - Pl_b).astype(jnp.int32)
-        dst_r = b_row + n_l[pl_c] + (P_r[:-1] - Pr_b).astype(jnp.int32)
-        dst = jnp.where(gl_in, dst_l, jnp.where(gr_in, dst_r, pos))
-        inv = jnp.zeros(N, jnp.int32).at[dst].set(pos)
-        pbins = jnp.take(s.pbins, inv, axis=1)
-        pgh = jnp.take(s.pgh, inv, axis=1)
-        pperm = s.pperm[inv]
-        pleaf_s = pleaf_new[inv]
-        n_r = jnp.where(mask, s.seg_count - n_l, 0)
-        if ax is not None:
-            gn_l = lax.psum(n_l, ax)
-            gn_r = lax.psum(n_r, ax)
-        else:
-            gn_l, gn_r = n_l, n_r
-        left_smaller = gn_l <= gn_r  # (L,)
-
-        seg_begin = s.seg_begin.at[drop_new].set(
-            s.seg_begin + n_l, mode="drop"
-        )
-        seg_count = jnp.where(mask, n_l, s.seg_count).at[drop_new].set(
-            n_r, mode="drop"
-        )
-
-        # ---- multi-slot histograms for all smaller children ----
-        sm_begin_leaf = jnp.where(left_smaller, s.seg_begin, s.seg_begin + n_l)
-        sm_cnt_leaf = jnp.where(left_smaller, n_l, n_r)
-        slot_of = jnp.where(mask, rank, S)
-        slot_begin = jnp.zeros(S, jnp.int32).at[slot_of].set(
-            sm_begin_leaf, mode="drop"
-        )
-        slot_cnt = jnp.zeros(S, jnp.int32).at[slot_of].set(
-            sm_cnt_leaf, mode="drop"
-        )
-        slot_hists = hist_slots(
-            pbins, pgh, slot_begin, slot_cnt, Bc, S,
-            dense_visits=ax is not None,
-        )  # (S, 3, G, Bc)
-        if ax is not None:
-            slot_hists = lax.psum(slot_hists, ax)
-
-        # ---- per-leaf child hists: smaller from slots, larger by
-        # subtraction; write both into the pool
-        small_leaf = slot_hists[jnp.minimum(rank, S - 1)]  # (L, 3, G, Bc)
-        large_leaf = s.hist - small_leaf
-        left_h_ = jnp.where(
-            left_smaller[:, None, None, None], small_leaf, large_leaf
-        )
-        right_h_ = jnp.where(
-            left_smaller[:, None, None, None], large_leaf, small_leaf
-        )
-        hist = jnp.where(mask[:, None, None, None], left_h_, s.hist)
-        hist = hist.at[drop_new].set(right_h_, mode="drop")
-
-        # ---- best splits for all 2*n_split children, batched ----
-        def child_best(h, g_, h__, c_, po, cmn, cmx):
-            return best_split(
-                exp_hist(h, g_, h__, c_), g_, h__, c_, num_bins, nan_bin,
-                mono, is_cat, params, feat_mask,
-                cat_subset=spec.cat_subset, parent_output=po,
-                cmin=cmn, cmax=cmx,
-            )
-
-        vbest = jax.vmap(child_best)
-        ch_hist = jnp.concatenate([left_h_, right_h_])  # (2L, 3, G, Bc)
-        ch_g = jnp.concatenate([rec.left_g, rec.right_g])
-        ch_h = jnp.concatenate([rec.left_h, rec.right_h])
-        ch_c = jnp.concatenate([rec.left_c, rec.right_c])
-        ch_po = jnp.concatenate([lo, ro])
-        ch_mn = jnp.concatenate([lmin, rmin])
-        ch_mx = jnp.concatenate([lmax, rmax])
-        ch_rec = vbest(ch_hist, ch_g, ch_h, ch_c, ch_po, ch_mn, ch_mx)
-        depth_ok = (spec.max_depth <= 0) | (depth_new < spec.max_depth)
-        ch_gain = jnp.where(
-            jnp.concatenate([depth_ok, depth_ok]), ch_rec.gain, NEG_INF
-        )
-        ch_leaf = jnp.concatenate([jnp.where(mask, iota_L, L), drop_new])
-
-        def scat(dst, val):
-            return dst.at[ch_leaf].set(val, mode="drop")
-
-        best2 = SplitRecord(
-            gain=scat(s.best.gain, ch_gain),
-            feature=scat(s.best.feature, ch_rec.feature),
-            bin=scat(s.best.bin, ch_rec.bin),
-            default_left=scat(s.best.default_left, ch_rec.default_left),
-            is_cat=scat(s.best.is_cat, ch_rec.is_cat),
-            cat_mask=scat(s.best.cat_mask, ch_rec.cat_mask),
-            left_g=scat(s.best.left_g, ch_rec.left_g),
-            left_h=scat(s.best.left_h, ch_rec.left_h),
-            left_c=scat(s.best.left_c, ch_rec.left_c),
-            right_g=scat(s.best.right_g, ch_rec.right_g),
-            right_h=scat(s.best.right_h, ch_rec.right_h),
-            right_c=scat(s.best.right_c, ch_rec.right_c),
-        )
-
-        p_new = _PState(
-            i=i + n_split,
-            pbins=pbins,
-            pgh=pgh,
-            pperm=pperm,
-            seg_begin=seg_begin,
-            seg_count=seg_count,
-            hist=hist,
-            leaf_g=jnp.where(mask, rec.left_g, s.leaf_g)
-            .at[drop_new].set(rec.right_g, mode="drop"),
-            leaf_h=jnp.where(mask, rec.left_h, s.leaf_h)
-            .at[drop_new].set(rec.right_h, mode="drop"),
-            leaf_c=jnp.where(mask, rec.left_c, s.leaf_c)
-            .at[drop_new].set(rec.right_c, mode="drop"),
-            leaf_parent=jnp.where(mask, node_id, s.leaf_parent)
-            .at[drop_new].set(node_id, mode="drop"),
-            leaf_min=jnp.where(mask, lmin, s.leaf_min)
-            .at[drop_new].set(rmin, mode="drop"),
-            leaf_max=jnp.where(mask, lmax, s.leaf_max)
-            .at[drop_new].set(rmax, mode="drop"),
-            best=best2,
-            tree=tree_new,
-            hist_valid=s.hist_valid,
-            extra=s.extra,
-            anc_in=s.anc_in,
-            anc_left=s.anc_left,
-        )
-        return _RState(p=p_new, pleaf=pleaf_s)
-
-    def _round_cond(rs: _RState) -> jax.Array:
-        mask = rs.p.best.gain > 0.0
-        n_split = jnp.sum(mask)
-        # budget guard: after splitting every positive-gain leaf the
-        # leaf count stays within num_leaves — identical to sequential
-        # greedy (which would also split exactly these leaves)
-        return (n_split > 0) & (rs.p.i + 1 + n_split <= L)
 
     state = _PState(
         i=jnp.int32(0),
@@ -547,14 +279,6 @@ def grow_tree_permuted(
         anc_in=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
         anc_left=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
     )
-
-    if spec.rounds and L > 2:
-        rstate = _RState(
-            p=state,
-            pleaf=jnp.where(valid_f > 0, 0, L).astype(jnp.int32),
-        )
-        rstate = lax.while_loop(_round_cond, _round_body, rstate)
-        state = rstate.p
 
     def _forced_valid(s: _PState):
         """Is step s.i a forced split with both children non-empty?"""

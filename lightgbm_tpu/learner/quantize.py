@@ -70,7 +70,7 @@ def resolve_hist_dtype(
         if use_rounds and num_grad_quant_bins <= 256:
             return "int16", 0, None
         return "bf16x2", 0, None
-    req = "bf16x2" if requested == "float32" else requested
+    req = requested
     if req == "auto":
         req = "int16" if (use_rounds and on_tpu) else "bf16x2"
     if req in HIST_DTYPE_LEVELS and not use_rounds:

@@ -176,7 +176,7 @@ def grow_tree_rounds(
 
     Trace-safety contract: this function is the workhorse inside the
     fused step, which since round 18 is the BODY of a `lax.scan` chunk
-    (boosting.fused_dispatch, tpu_chunk_scan). Everything here must
+    (boosting.fused_dispatch). Everything here must
     therefore stay traceable with abstract operands — no host branching
     on data values (python `if` only on static spec/params fields), no
     `.item()`/`float()` coercions, shapes independent of the round
@@ -634,8 +634,8 @@ def grow_tree_rounds(
         # selected leaves' parameters. A (N,) jnp.take from an (L,)
         # table costs ~1 ms each on TPU (no vector-gather hardware) and
         # the old (L*B,) category-mask flat gather ~10 ms; the one-hot
-        # matmul is ~20 us for all of them together
-        # (tools/tpu_gather_probe.py). The contraction runs in f32:
+        # matmul is ~20 us for all of them together, because the
+        # lookup rides the MXU. The contraction runs in f32:
         # packed values include feature/column ids and bin thresholds,
         # which exceed bf16's exact-integer range (256) on wide or
         # deep-binned datasets; f32 is exact to 2^24 and the (N,S)@(S,9)

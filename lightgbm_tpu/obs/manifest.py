@@ -25,8 +25,7 @@ SCHEMA = "lightgbm-tpu/run-manifest/v1"
 _CORE_KEYS = (
     "task", "objective", "boosting", "num_iterations", "num_leaves",
     "learning_rate", "max_bin", "tree_learner", "num_class",
-    "use_quantized_grad", "tpu_growth_mode", "tpu_growth_rounds",
-    "tpu_hist_dtype",
+    "use_quantized_grad", "tpu_growth_mode", "tpu_hist_dtype",
 )
 
 

@@ -1,8 +1,8 @@
 """Resilient serving gateway: cross-process scale-out front end.
 
 One ``task=serve`` process is a single point of failure AND a single
-point of slowness: BENCH_SERVE_r02 shows a churned tenant paying
-~579 ms while residents answer in 2-5 ms, and any backend wedge or
+point of slowness: a churned tenant paid ~579 ms while residents
+answered in 2-5 ms (a CPU run of bench_serve.py, PR 13), and any backend wedge or
 restart is client-visible. This module is the host-side HTTP front end
 that spreads traffic over N backend processes sharing ONE registry
 directory as the hot-swap source of truth, and ties client latency to

@@ -1,25 +1,21 @@
 """Chunk-scan fused boosting (ISSUE 18): `fused_dispatch` runs rounds
 as C-round `lax.scan` chunks — one executable launch per chunk — and
-must be BIT-identical to the per-round-dispatch loop
-(`tpu_chunk_scan=off`) on the same seed: model text, eval records,
-early-stop truncation, and the no-splittable-leaf stop. The chunk
-ladder bounds distinct scan executables at len(DEFAULT_CHUNK_LADDER)
-for any round count (retrace-guard contract)."""
-
-import re
+chunking and the masked tail must change NO bit: the same training
+under a chunk ladder of (1,) (every round its own dispatch, every
+round live, no masked tail; same code path) gives the same model text,
+eval records, early-stop truncation, and no-splittable-leaf stop. The
+chunk ladder bounds distinct scan executables at
+len(DEFAULT_CHUNK_LADDER) for any round count (retrace-guard contract).
+The independent reference (the eager sync loop) is tests/test_fused_loop.py."""
 
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
 import lightgbm_tpu.callback as cbm
+import lightgbm_tpu.config as cfg
 from lightgbm_tpu.boosting import _FUSED_STEP_CACHE, _pick_chunk
 from lightgbm_tpu.config import DEFAULT_CHUNK_LADDER
-
-
-def _norm(model_str: str) -> str:
-    # the echoed parameter block necessarily differs between the paths
-    return re.sub(r"\[tpu_chunk_scan: \w+\]\n", "", model_str)
 
 
 def _expected_dispatches(n: int) -> int:
@@ -30,7 +26,9 @@ def _expected_dispatches(n: int) -> int:
     return d
 
 
-def _train(params, X, y, rounds, mode, Xv=None, yv=None):
+def _train(params, X, y, rounds, ladder, Xv=None, yv=None):
+    """Train under chunk ladder `ladder` (fused_dispatch reads
+    config.DEFAULT_CHUNK_LADDER at call time)."""
     ds = lgb.Dataset(X, label=y, free_raw_data=False)
     valid_sets = valid_names = None
     if Xv is not None:
@@ -38,18 +36,22 @@ def _train(params, X, y, rounds, mode, Xv=None, yv=None):
                                   free_raw_data=False)]
         valid_names = ["va"]
     res = {}
-    bst = lgb.train(dict(params, tpu_chunk_scan=mode), ds,
-                    num_boost_round=rounds, valid_sets=valid_sets,
-                    valid_names=valid_names,
-                    callbacks=[cbm.record_evaluation(res)])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cfg, "DEFAULT_CHUNK_LADDER", ladder)
+        bst = lgb.train(dict(params), ds,
+                        num_boost_round=rounds, valid_sets=valid_sets,
+                        valid_names=valid_names,
+                        callbacks=[cbm.record_evaluation(res)])
     return bst, res
 
 
 def _assert_bit_identical(params, X, y, rounds, Xv=None, yv=None):
-    bc, rc = _train(params, X, y, rounds, "auto", Xv, yv)
-    bp, rp = _train(params, X, y, rounds, "off", Xv, yv)
-    assert _norm(bc.model_to_string()) == _norm(bp.model_to_string())
+    bc, rc = _train(params, X, y, rounds, DEFAULT_CHUNK_LADDER, Xv, yv)
+    bp, rp = _train(params, X, y, rounds, (1,), Xv, yv)
+    assert bc.model_to_string() == bp.model_to_string()
     assert rc == rp  # eval records, exact float equality
+    assert bp._gbdt.fused_dispatch_count == bp.num_trees() \
+        // bp._gbdt.num_class == rounds
     return bc, bp
 
 
@@ -62,8 +64,8 @@ def test_chunk_vs_per_round_regression_bit_identical():
          "verbosity": -1},
         X[:600], y[:600], 8, X[600:], y[600:],
     )
-    # dispatch-count probe: one _f_step-equivalent launch per CHUNK on
-    # the scan path, one per round on the baseline
+    # dispatch-count probe: one launch per CHUNK under the default
+    # ladder, one per round under ladder (1,)
     assert bc._gbdt.fused_dispatch_count == _expected_dispatches(8)
     assert bc._gbdt.fused_dispatch_count < 8
     assert bp._gbdt.fused_dispatch_count == 8
@@ -82,6 +84,27 @@ def test_chunk_vs_per_round_binary_sampled_bit_identical():
          "feature_fraction": 0.7, "verbosity": -1},
         X[:700], y[:700], 7, X[700:], y[700:],
     )
+
+
+@pytest.mark.parametrize("n", [1, 5])
+def test_remainder_shorter_than_smallest_rung(n):
+    """n rounds with n % 4 != 0 end in a dispatch of the smallest rung
+    whose tail is masked on the device: the tail's trees and eval rows
+    are sliced off on the host and no bit of what is kept differs from
+    the run that never had a tail."""
+    rs = np.random.RandomState(21)
+    X = rs.randn(700, 6)
+    y = ((X @ rs.randn(6) + 0.3 * rs.randn(700)) > 0).astype(float)
+    bc, bp = _assert_bit_identical(
+        {"objective": "binary", "num_leaves": 7, "metric": "auc",
+         "bagging_fraction": 0.7, "bagging_freq": 1, "verbosity": -1},
+        X[:500], y[:500], n, X[500:], y[500:],
+    )
+    g = bc._gbdt
+    assert g.fused_dispatch_count == _expected_dispatches(n) == -(-n // 4)
+    # the memoized program is shared with the ladder-(1,) run
+    assert set(g._f_program.chunks) - {1} == {min(DEFAULT_CHUNK_LADDER)}
+    assert bc.num_trees() == len(g.device_trees) == g.iter_ == n
 
 
 def test_chunk_vs_per_round_multiclass_bit_identical():
@@ -106,14 +129,14 @@ def test_early_stop_mid_chunk_truncates_bit_exactly():
     y = (X[:, 0] + 0.5 * rs.randn(900) > 0).astype(float)
     params = {"objective": "binary", "num_leaves": 7, "metric": "auc",
               "verbosity": -1, "early_stopping_round": 3}
-    bc, rc = _train(params, X[:600], y[:600], 40, "auto",
+    bc, rc = _train(params, X[:600], y[:600], 40, DEFAULT_CHUNK_LADDER,
                     X[600:], y[600:])
-    bp, rp = _train(params, X[:600], y[:600], 40, "off",
+    bp, rp = _train(params, X[:600], y[:600], 40, (1,),
                     X[600:], y[600:])
     assert bc.best_iteration == bp.best_iteration >= 1
     assert bc.num_trees() == bp.num_trees() == bc.best_iteration + 3
     assert bc.num_trees() < 40  # actually stopped mid-chunk
-    assert _norm(bc.model_to_string()) == _norm(bp.model_to_string())
+    assert bc.model_to_string() == bp.model_to_string()
     assert rc == rp
 
 
@@ -126,10 +149,11 @@ def test_no_splittable_leaf_stop_matches():
     y = X[:, 0] + 0.1 * rs.randn(200)
     params = {"objective": "regression", "num_leaves": 7,
               "verbosity": -1, "min_data_in_leaf": 120}
-    bc, _ = _train(params, X, y, 8, "auto")
-    bp, _ = _train(params, X, y, 8, "off")
+    bc, _ = _train(params, X, y, 8, DEFAULT_CHUNK_LADDER)
+    bp, _ = _train(params, X, y, 8, (1,))
     assert bc.num_trees() == bp.num_trees() == 1  # the kept bias tree
-    assert _norm(bc.model_to_string()) == _norm(bp.model_to_string())
+    assert bp._gbdt.fused_dispatch_count == 8
+    assert bc.model_to_string() == bp.model_to_string()
 
 
 @pytest.mark.slow  # 100/13/64-round trainings warm the whole ladder

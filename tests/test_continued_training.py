@@ -62,6 +62,37 @@ def test_init_model_from_file(tmp_path):
     assert l2 <= l1 + 1e-6
 
 
+def test_model_text_with_removed_options_loads_and_continues(tmp_path):
+    """A model text saved before tpu_chunk_scan / tpu_growth_rounds went
+    carries them in its parameter block: it loads, predicts the same,
+    and continues training to the same model as the text without them."""
+    X, y = _problem(seed=11)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    first = lgb.train(dict(PARAMS), ds, num_boost_round=4)
+    text = first.model_to_string()
+    old = text.replace(
+        "parameters:\n",
+        "parameters:\n[tpu_chunk_scan: auto]\n[tpu_growth_rounds: 0]\n")
+    assert old != text and "[tpu_chunk_scan: auto]" in old
+    path = tmp_path / "old.txt"
+    path.write_text(old)
+
+    for loaded in (lgb.Booster(model_str=old),
+                   lgb.Booster(model_file=str(path))):
+        assert loaded.num_trees() == 4
+        np.testing.assert_array_equal(loaded.predict(X[:300]),
+                                      first.predict(X[:300]))
+
+    def continued(init):
+        ds2 = lgb.Dataset(X, label=y, free_raw_data=False)
+        return lgb.train(dict(PARAMS), ds2, num_boost_round=3,
+                         init_model=init)
+
+    from_old, from_new = continued(str(path)), continued(first)
+    assert from_old.num_trees() == 7
+    assert from_old.model_to_string() == from_new.model_to_string()
+
+
 def test_continued_training_with_valid_and_early_stop():
     X, y = _problem(seed=5)
     ds = lgb.Dataset(X, label=y, free_raw_data=False)

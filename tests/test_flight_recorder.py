@@ -96,28 +96,31 @@ def test_fused_loop_streams_full_records(rng, tmp_path):
 
 def test_fused_chunk_records_match_per_round_dispatch(rng, tmp_path):
     """Chunk-scan equivalence (ISSUE 18): with rounds dispatched as one
-    lax.scan per chunk, the recorder must stream the SAME story as the
-    per-round-dispatch loop — round indices, eval values, and gh norms
-    bit-equal, and the apportioned FUSED_ROUND_PHASE span present in
-    every record on both paths."""
+    lax.scan per chunk, the recorder must stream the SAME story as one
+    dispatch per round (chunk ladder (1,)) — round indices, eval values,
+    and gh norms bit-equal, and the apportioned FUSED_ROUND_PHASE span
+    present in every record on both sides."""
+    import lightgbm_tpu.config as cfg
+
     X = rng.randn(400, 4)
     y = (X[:, 0] > 0).astype(np.float32)
     Xv = rng.randn(150, 4)
     yv = (Xv[:, 0] > 0).astype(np.float32)
 
-    def run(mode):
+    def run(ladder):
         ds = lgb.Dataset(X, label=y, free_raw_data=False)
         vs = lgb.Dataset(Xv, label=yv, reference=ds,
                          free_raw_data=False)
-        path = tmp_path / f"fr_{mode}.jsonl"
-        lgb.train({"objective": "binary", "num_leaves": 7,
-                   "verbosity": -1, "record_file": str(path),
-                   "tpu_chunk_scan": mode},
-                  ds, num_boost_round=6, valid_sets=[vs],
-                  valid_names=["v"])
+        path = tmp_path / f"fr_{len(ladder)}.jsonl"
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(cfg, "DEFAULT_CHUNK_LADDER", ladder)
+            lgb.train({"objective": "binary", "num_leaves": 7,
+                       "verbosity": -1, "record_file": str(path)},
+                      ds, num_boost_round=6, valid_sets=[vs],
+                      valid_names=["v"])
         return read_stream(str(path))
 
-    chunked, eager = run("auto"), run("off")
+    chunked, eager = run(cfg.DEFAULT_CHUNK_LADDER), run((1,))
     assert [r["round"] for r in chunked] == [r["round"] for r in eager] \
         == list(range(6))
     assert [r["evals"] for r in chunked] == [r["evals"] for r in eager]

@@ -38,14 +38,27 @@ def test_resolve_hist_dtype_default_path():
                               on_tpu=False)[0] == "bf16x2"
     assert resolve_hist_dtype("int16", False, 16, True,
                               on_tpu=False)[0] == "int16"
-    # float32 is the legacy synonym for the f32 hi/lo split
-    assert resolve_hist_dtype("float32", False, 16, True)[0] == "bf16x2"
     # explicit narrow layouts carry their level counts
     assert resolve_hist_dtype("int16", False, 16, True) == ("int16", 256,
                                                             None)
     assert resolve_hist_dtype("int8", False, 16, True) == ("int8", 127,
                                                            None)
     assert HIST_DTYPE_LEVELS == {"int16": 256, "int8": 127}
+
+
+def test_hist_dtype_float32_rejected_with_accepted_values():
+    """The legacy synonym went: the validator refuses it and names what
+    it accepts, before any Booster exists."""
+    from lightgbm_tpu.basic import LightGBMError
+    from lightgbm_tpu.config import Config
+
+    with pytest.raises(LightGBMError) as ei:
+        Config({"tpu_hist_dtype": "float32"})
+    msg = str(ei.value)
+    assert "tpu_hist_dtype=float32" in msg
+    assert "auto, bf16x2, int16, int8" in msg
+    for ok in ("auto", "bf16x2", "int16", "int8"):
+        assert Config({"hist_dtype": ok}).tpu_hist_dtype == ok
 
 
 def test_resolve_hist_dtype_off_rounds_falls_back_with_warning():

@@ -174,11 +174,11 @@ def test_latency_stats_reset_and_shared_ring():
 
 
 # ----------------------------------------------------------------- tracing
-def test_trace_export_fused_round_spans(rng, tmp_path):
+def test_trace_export_fused_round_spans(rng, tmp_path, monkeypatch):
     """Chrome trace-event JSON loads and carries one fused-round span
     per DISPATCH: a 4-round training is one chunk-scan launch (one
-    span covering all 4 rounds); with tpu_chunk_scan=off it
-    degenerates to the historical one-span-per-round stream."""
+    span covering all 4 rounds); under a chunk ladder of (1,) it is
+    one span per round."""
     X = rng.randn(400, 4)
     y = (X[:, 0] > 0).astype(np.float32)
     path = tmp_path / "trace.json"
@@ -195,10 +195,12 @@ def test_trace_export_fused_round_spans(rng, tmp_path):
     assert len(fused) == 1  # 4 rounds = one chunk dispatch
     assert rec.events()  # recorder still readable after export
     # per-round dispatch keeps the one-span-per-round stream
-    path2 = tmp_path / "trace_off.json"
+    import lightgbm_tpu.config as cfg
+
+    monkeypatch.setattr(cfg, "DEFAULT_CHUNK_LADDER", (1,))
+    path2 = tmp_path / "trace_ladder_1.json"
     with tracing.tracing(chrome_path=str(path2)):
-        _train({"objective": "binary", "num_leaves": 7,
-                "tpu_chunk_scan": "off"}, X, y, rounds=4)
+        _train({"objective": "binary", "num_leaves": 7}, X, y, rounds=4)
     data2 = json.loads(path2.read_text())
     fused2 = [e for e in data2["traceEvents"]
               if e.get("ph") == "X"
@@ -600,10 +602,11 @@ def test_data_parallel_runtime_wire_counter(rng):
     assert after > before
 
 
-@pytest.mark.parametrize("extra", [{}, {"tpu_chunk_scan": "off"},
+@pytest.mark.parametrize("extra", [{}, {"ladder": (1,)},
                                    {"record_file": "rec.jsonl"}],
-                         ids=["chunk_scan", "per_round", "recorded"])
-def test_grower_rounds_counter_rides_the_eval_readback(rng, tmp_path, extra):
+                         ids=["chunk_scan", "ladder_1", "recorded"])
+def test_grower_rounds_counter_rides_the_eval_readback(rng, tmp_path, extra,
+                                                       monkeypatch):
     """lgbmtpu_grower_rounds_total{width}: the rounds grower's per-width
     round counts end the fused step's eval row, so they arrive with the
     readback fused_collect already makes; the caller's evals and the
@@ -615,6 +618,10 @@ def test_grower_rounds_counter_rides_the_eval_readback(rng, tmp_path, extra):
     X = rng.randn(3000, 5)
     y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
     extra = dict(extra)
+    if "ladder" in extra:  # one dispatch per round: five (1, E) stacks
+        import lightgbm_tpu.config as cfg
+
+        monkeypatch.setattr(cfg, "DEFAULT_CHUNK_LADDER", extra.pop("ladder"))
     if "record_file" in extra:
         extra["record_file"] = str(tmp_path / extra["record_file"])
     ds = lgb.Dataset(X, label=y, free_raw_data=False)

@@ -2,8 +2,8 @@
 
 `LGBM_TPU_PALLAS_INTERPRET=1` makes histogram.py dispatch to the real
 pallas kernels under `pallas_call(interpret=True)` on CPU, so the MXU
-one-hot formulation, the visit-plan slot kernel, and the slot-packed
-natural-order kernel are all exercised by CI and compared against the
+one-hot formulation and the slot-packed natural-order kernel are
+exercised by CI and compared against the
 XLA einsum fallback — kernel drift fails the suite instead of waiting
 for a live chip (the reference analog: running CUDA learner logic
 through the CPU build's tests, test_consistency.py)."""
@@ -57,25 +57,6 @@ def test_hist_tpu_interpret_matches_fallback(interp, data):
     ref = _hist_fallback(bins, gh8, B)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-3, rtol=1e-4)
-
-
-def test_hist_slots_tpu_interpret_matches_fallback(interp, data):
-    N, F, B, bins, gh8 = data
-    from lightgbm_tpu.learner.histogram import hist_slots
-
-    S = 4
-    begins = jnp.asarray(np.int32([0, 700, HIST_BLK, 0]))
-    counts = jnp.asarray(np.int32([700, 300, 1024, 0]))
-    out = np.asarray(hist_slots(bins, gh8, begins, counts, B, S))
-    for s in range(S):
-        b, c = int(begins[s]), int(counts[s])
-        if c == 0:
-            np.testing.assert_allclose(out[s], 0.0)
-            continue
-        iota = np.arange(N)
-        m = jnp.asarray(((iota >= b) & (iota < b + c)).astype(np.float32))
-        ref = np.asarray(_hist_fallback(bins, gh8 * m[None, :], B))
-        np.testing.assert_allclose(out[s], ref, atol=2e-3, rtol=1e-4)
 
 
 def test_hist_nat_tpu_interpret_matches_fallback(interp, data):
@@ -430,58 +411,60 @@ def test_nat_grower_with_interpreted_kernel(interp):
     assert (rl_interp == rl_fb).mean() > 0.999
 
 
-# ------------------------------------------- int4 SWAR one-hot (ISSUE 12)
-@pytest.mark.parametrize("B4", [16, 24, 32])
-def test_hist_nat_int4_interpret_exact(interp, monkeypatch, data, B4):
-    """Nibble-SWAR one-hot (8 bins per i32 lane, LGBM_TPU_INT4_OH=1):
-    integer sums must equal the f32 fallback bit-for-bit, including bin
-    counts that are not multiples of 8 (the packed-row padding)."""
+# ------------------------------------------- int8 SWAR one-hot (ISSUE 12)
+@pytest.mark.parametrize("B", [18, 63, 255])
+def test_hist_nat_int8_swar_interpret_exact(interp, data, B):
+    """Byte-SWAR one-hot (4 bins per i32 lane): integer sums must equal
+    the f32 fallback bit-for-bit at bin counts that are not multiples
+    of 4 (the packed-row padding), the cells' own 255 among them, for
+    every marker shift the growers pass."""
     N, F, _, _, _ = data
     from lightgbm_tpu.learner.histogram import (
         build_gh8_quant,
         hist_nat_slots,
     )
 
-    monkeypatch.setenv("LGBM_TPU_INT4_OH", "1")
     rs = np.random.RandomState(12)
-    bins = jnp.asarray(rs.randint(0, B4, (F, N)).astype(np.int32))
+    bins = jnp.asarray(rs.randint(0, B, (F, N)).astype(np.int32))
     gq = jnp.asarray(rs.randint(-8, 9, N).astype(np.float32))
     hq = jnp.asarray(rs.randint(0, 17, N).astype(np.float32))
     gh8q = build_gh8_quant(gq, hq, jnp.ones(N, jnp.float32))
     S = 6
     slot = jnp.asarray(rs.randint(0, S + 1, N).astype(np.int32))
-    out = hist_nat_slots(bins, gh8q, slot, S, B4, quant=True, int8=True,
-                         oh_shift=0)
-    ref = _hist_nat_fallback(bins, gh8q, slot, S, B4, quant=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    ref = np.asarray(_hist_nat_fallback(bins, gh8q, slot, S, B, quant=True))
+    for oh_shift in (0, 4, 7):
+        out = hist_nat_slots(bins, gh8q, slot, S, B, quant=True, int8=True,
+                             oh_shift=oh_shift)
+        np.testing.assert_array_equal(np.asarray(out), ref)
 
 
-def test_swar4_onehot_unpack_ordering():
-    """The nibble-plane unpack (even/odd split + byte bitcasts + stack
-    interleave) must place packed row j's nibble m at bin 8*j + m — a
-    swapped interleave would score every odd bin into its even
-    neighbor. pltpu.bitcast only evaluates inside a kernel, so the
-    helper runs under an interpreted pallas_call."""
+def test_swar_onehot_unpack_ordering():
+    """The byte unpack (i32 -> 4 x s8 bitcast onto sublanes) must place
+    packed row j's byte m at bin 4*j + m — a swapped order would score
+    every bin into a neighbor — and slice the padding rows off when the
+    bin count is not a multiple of 4. pltpu.bitcast only evaluates
+    inside a kernel, so the helper runs under an interpreted
+    pallas_call."""
     import jax
     from jax.experimental import pallas as pl
 
-    from lightgbm_tpu.learner.pallas_hist import _swar_onehot4
+    from lightgbm_tpu.learner.pallas_hist import _swar_onehot
 
-    B, blk = 16, 256
+    B, blk = 18, 256
     rs = np.random.RandomState(13)
     bins_row = jnp.asarray(rs.randint(0, B, (1, blk)).astype(np.int32))
+    hit = (np.arange(B)[:, None]
+           == np.asarray(bins_row)[0][None, :]).astype(np.int8)
+    for oh_shift, marker in ((0, -128), (4, 8), (7, 1)):
+        def kernel(bins_ref, out_ref, oh_shift=oh_shift):
+            out_ref[...] = _swar_onehot(bins_ref[...], B, blk, oh_shift)
 
-    def kernel(bins_ref, out_ref):
-        out_ref[...] = _swar_onehot4(bins_ref[...], B, blk)
-
-    oh = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((B, blk), jnp.int8),
-        interpret=True,
-    )(bins_row)
-    expect = (np.arange(B)[:, None]
-              == np.asarray(bins_row)[0][None, :]).astype(np.int8) * 8
-    np.testing.assert_array_equal(np.asarray(oh), expect)
+        oh = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((B, blk), jnp.int8),
+            interpret=True,
+        )(bins_row)
+        np.testing.assert_array_equal(np.asarray(oh), hit * marker)
 
 
 # -------------------------------------- chunked fused round (ISSUE 12)

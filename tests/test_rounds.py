@@ -1,5 +1,5 @@
-"""Round-batched growth mode (tpu_growth_rounds) and the multi-slot
-histogram used by it (reference CUDA all-leaves batching,
+"""Round-batched growth (tpu_growth_mode=rounds, rounds.py) and the
+slot-packed histogram used by it (reference CUDA all-leaves batching,
 cuda_histogram_constructor.cu)."""
 
 import numpy as np
@@ -37,16 +37,14 @@ def small_ds():
 
 def test_rounds_matches_greedy_unbound_budget(small_ds):
     """With a non-binding leaf budget, round-batched growth IS greedy:
-    both split exactly the positive-gain leaves. Covers the legacy
-    permuted rounds prelude AND the natural-order rounds grower
-    (rounds.py)."""
+    both split exactly the positive-gain leaves (the sequential
+    oracle against the natural-order rounds grower, rounds.py)."""
     cfg = Config({"num_leaves": 512, "max_bin": 63, "min_data_in_leaf": 40,
                   "min_gain_to_split": 0.5})
     params = make_split_params(cfg)
     vals = {}
     variants = {
         "seq": dict(),
-        "permuted_rounds": dict(rounds=True),
         "nat_rounds": dict(rounds_slots=25),
         "nat_rounds_small_k": dict(rounds_slots=4),
     }
@@ -126,69 +124,6 @@ def test_hist_nat_slots_matches_bruteforce():
                 ref = np.bincount(bn[f][m], weights=gh3[c][m], minlength=B)[:B]
                 np.testing.assert_allclose(out[s, c, f], ref, atol=2e-4,
                                            rtol=1e-4)
-
-
-def test_rounds_tree_consistency(small_ds):
-    """Bound budget: tree differs from greedy but must be internally
-    consistent (partition counts == leaf counts, positive gains, full
-    budget used)."""
-    cfg = Config({"num_leaves": 31, "max_bin": 63, "min_data_in_leaf": 5})
-    params = make_split_params(cfg)
-    spec = GrowerSpec(num_leaves=31, num_bins=small_ds.max_num_bin,
-                      max_depth=-1, rounds=True)
-    tree, row_leaf = _grow(small_ds, params, spec)
-    nn = int(tree.num_nodes)
-    assert nn == 30
-    rl = np.asarray(row_leaf)[: small_ds.num_data]
-    lc = np.bincount(rl, minlength=31).astype(float)
-    np.testing.assert_allclose(lc, np.asarray(tree.leaf_count))
-    assert (np.asarray(tree.node_gain)[:nn] > 0).all()
-
-
-def test_rounds_via_train_api():
-    rs = np.random.RandomState(5)
-    X = rs.randn(3000, 6)
-    y = (X[:, 0] + X[:, 1] ** 2 + 0.3 * rs.randn(3000) > 1).astype(float)
-    preds = {}
-    for rounds in (False, True):
-        params = dict(objective="binary", num_leaves=15, min_data_in_leaf=5,
-                      verbosity=-1, tpu_growth_rounds=rounds)
-        ds = lgb.Dataset(X, label=y, free_raw_data=False)
-        bst = lgb.train(params, ds, num_boost_round=5)
-        preds[rounds] = bst.predict(X)
-    # different growth order, but both must learn the signal
-    from sklearn.metrics import roc_auc_score
-
-    assert roc_auc_score(y, preds[True]) > 0.85
-    assert roc_auc_score(y, preds[False]) > 0.85
-
-
-def test_hist_slots_matches_masked():
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.learner.histogram import build_gh8, hist_slots, histogram
-
-    rs = np.random.RandomState(0)
-    N, F, B, S = 4096, 4, 31, 5
-    bins = jnp.asarray(rs.randint(0, B, (F, N)).astype(np.int32))
-    gh8 = build_gh8(
-        jnp.asarray(rs.randn(N).astype(np.float32)),
-        jnp.asarray((rs.rand(N) + 0.5).astype(np.float32)),
-        jnp.ones(N, jnp.float32),
-    )
-    begins = jnp.asarray(np.int32([0, 500, 1500, 2000, 0]))
-    counts = jnp.asarray(np.int32([500, 1000, 300, 2000, 0]))
-    out = hist_slots(bins, gh8, begins, counts, B, S)
-    assert out.shape == (S, 3, F, B)
-    for s in range(S):
-        b, c = int(begins[s]), int(counts[s])
-        if c == 0:
-            np.testing.assert_allclose(np.asarray(out[s]), 0.0)
-            continue
-        ref = histogram(bins[:, b : b + c], gh8[:, b : b + c], B)
-        np.testing.assert_allclose(
-            np.asarray(out[s]), np.asarray(ref), atol=1e-4, rtol=1e-4
-        )
 
 
 def test_rounds_forced_splits_match_exact(tmp_path):

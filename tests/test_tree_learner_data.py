@@ -224,9 +224,9 @@ def test_voting_rounds_jaxpr_wire():
 
 
 def test_rounds_and_efb_on_mesh():
-    """Round-batched growth and EFB under shard_map: the rounds-body
-    psums (global child counts, slot histograms) and the dense_visits
-    slot budget only execute on a mesh — cover them here."""
+    """The rounds grower and EFB under shard_map: the round's psums
+    (global child counts, slot histograms over bundle columns) only
+    execute on a mesh — cover them here."""
     # sparse blocks so EFB actually bundles
     rs = np.random.RandomState(13)
     n = 4096
@@ -237,11 +237,12 @@ def test_rounds_and_efb_on_mesh():
     Xd = rs.randn(n, 3)
     X = np.hstack([Xd, Xs])
     y = ((X[:, 0] + Xs.sum(1) + 0.3 * rs.randn(n)) > 0.7).astype(np.float64)
-    serial = _train({**BASE, "tpu_growth_rounds": True}, X, y, rounds=8)
+    serial = _train({**BASE, "tpu_growth_mode": "rounds"}, X, y, rounds=8)
     mesh = _train(
-        {**BASE, "tree_learner": "data", "tpu_growth_rounds": True}, X, y,
+        {**BASE, "tree_learner": "data", "tpu_growth_mode": "rounds"}, X, y,
         rounds=8,
     )
+    assert mesh._gbdt.spec.rounds_slots > 0 and mesh._gbdt.spec.efb
     np.testing.assert_allclose(
         mesh.predict(X), serial.predict(X), rtol=1e-4, atol=1e-5
     )
