@@ -129,7 +129,7 @@ def kernel_selfcheck(bins, num_bins: int) -> list:
 
     from lightgbm_tpu.learner.histogram import (
         build_gh8, build_gh8_quant, can_hist_round, hist_nat_slots,
-        hist_round, int8_oh_shift, seg_sum, take_cols,
+        hist_round, int8_oh_shift, route_round, seg_sum, take_cols,
     )
 
     H = _histogram_module()
@@ -189,6 +189,11 @@ def kernel_selfcheck(bins, num_bins: int) -> list:
                                         int8=int8, oh_shift=shift))
         check(np.array_equal(np.asarray(pl_new), np.asarray(pl_ref)),
               f"hist_round_tpu {name}: row->leaf differs from XLA")
+        # the routing-only pass of a tree's last round: one call at
+        # the program's full slot count, no histogram
+        pl_route = route_round(bins, pleaf, params, coh, S, B)
+        check(np.array_equal(np.asarray(pl_route), np.asarray(pl_ref)),
+              f"route_round_tpu S={S}: row->leaf differs from XLA")
         check(np.abs(np.asarray(ref)).sum() > 0, f"{name}: empty reference")
         if quant:  # integer sums: exact or wrong
             check(np.array_equal(out, np.asarray(ref)),
@@ -200,7 +205,7 @@ def kernel_selfcheck(bins, num_bins: int) -> list:
                                        atol=2e-3, rtol=1e-4)
             np.testing.assert_allclose(nat, nat_ref, atol=2e-3, rtol=1e-4)
         lines.append(
-            f"hist_round_tpu + hist_nat_tpu {name}: S={S} "
+            f"hist_round_tpu + route_round_tpu + hist_nat_tpu {name}: S={S} "
             f"(one-chunk cap {s_max}) G={G} B={B} N={N} ok "
             f"({'exact' if quant else 'atol 2e-3'}; first call {dt:.1f}s)"
         )

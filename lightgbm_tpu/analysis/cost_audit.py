@@ -229,7 +229,12 @@ def audit_cost(summary: CostSummary, budget: Optional[Dict[str, Any]],
 # fallback collapses bf16x2 to 3 channels before contracting, leaving
 # only a sliver of difference there (the rounds_serial_packed entry
 # still budget-ratchets on its own).
-_DROP_PAIRS: Dict[str, str] = {"hist_round_fused": "hist_round_fused_bf16"}
+_DROP_PAIRS: Dict[str, str] = {
+    "hist_round_fused": "hist_round_fused_bf16",
+    # the routing-only variant of the same kernel must stay a fraction
+    # of the fused pass: it exists to drop the histogram's bytes
+    "hist_round_fused_route": "hist_round_fused",
+}
 
 # same contract shape on the WIRE account (ISSUE 14 satellite): the
 # voting-parallel entry's collective payload (votes + elected-columns

@@ -358,24 +358,30 @@ def _trace_rounds_serial_packed():
     )
 
 
-def _trace_hist_round(quant: bool = True):
+def _trace_hist_round(quant: bool = True, route_only: bool = False):
     """The fused partition+histogram pallas kernel (_round_kernel) —
     traced abstractly; pallas_call jaxpr construction is platform-free
     even though compilation needs a TPU. quant=True is the 3-channel
     int-packed layout, quant=False the 5-channel bf16x2 hi/lo split —
-    cost_audit pins the bytes-accessed DROP between the pair."""
+    cost_audit pins the bytes-accessed DROP between the pair.
+    route_only=True is the same kernel body stopped after the partition
+    decision (histogram.route_round: the round that spends the last of
+    the leaf budget), which reads no gradients and writes no
+    histogram."""
     import jax
     import jax.numpy as jnp
 
-    from ..learner.histogram import HIST_BLK, hist_round
+    from ..learner.histogram import HIST_BLK, hist_round, route_round
 
     S, G, B, N = 8, 8, 64, HIST_BLK * 2
     mk = lambda s, d: jax.ShapeDtypeStruct(s, d)  # noqa: E731
-    return jax.make_jaxpr(
-        lambda b, g, p, prm, coh: hist_round(
-            b, g, p, prm, coh, S, B, quant=quant
-        )
-    )(
+    if route_only:
+        def call(b, g, p, prm, coh):
+            return route_round(b, p, prm, coh, S, B)
+    else:
+        def call(b, g, p, prm, coh):
+            return hist_round(b, g, p, prm, coh, S, B, quant=quant)
+    return jax.make_jaxpr(call)(
         mk((G, N), jnp.int32), mk((8, N), jnp.float32), mk((N,), jnp.int32),
         mk((S, 16), jnp.int32), mk((S, G), jnp.float32),
     )
@@ -764,6 +770,20 @@ ENTRIES: Dict[str, _Entry] = {
         ],
         "fused round kernel, 5-channel bf16x2 hi/lo layout — the "
         "baseline the int-packed pair must undercut",
+        pallas_interpret=True,
+    ),
+    "hist_round_fused_route": _Entry(
+        lambda: _trace_hist_round(route_only=True),
+        lambda budget: [
+            has_prim("pallas_call", "_round_kernel, route_only"),
+            no_host_callbacks(),
+            no_f64(),
+            within_budget(budget),
+        ],
+        "the fused round kernel's routing half alone (histogram."
+        "route_round): the new row->leaf vector of the round that spends "
+        "the last of the leaf budget — no gradient input, no histogram "
+        "block, a fraction of the fused pass's bytes and flops",
         pallas_interpret=True,
     ),
     "serving_forest": _Entry(

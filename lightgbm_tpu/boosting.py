@@ -1521,8 +1521,8 @@ class GBDT:
             or getattr(c, "anomaly_policy", "off") != "off"
         )
         # the step ends its eval row with the rounds grower's round
-        # counts, one per ladder width and their total (a mesh grower
-        # returns none: _grow)
+        # counts: one per ladder width, the routing-only rounds, and
+        # their total (a mesh grower returns none: _grow)
         ladder_ws = (
             ladder_widths(self.spec)
             if self.spec.rounds_slots > 0 and self._dp is None else ()
@@ -1896,10 +1896,13 @@ class GBDT:
         )
         widths = self._f_ladder_widths
         if widths and mat.shape[0]:
+            from .learner.rounds import ROUTE_LABEL
             from .obs.metrics import record_grower_rounds
 
-            n = len(widths) + 1  # the row's tail: per width, then total
-            record_grower_rounds(widths, mat[:, -n:-1].sum(axis=0))
+            # the row's tail: per width, routing-only, then total
+            n = len(widths) + 2
+            record_grower_rounds(widths + (ROUTE_LABEL,),
+                                 mat[:, -n:-1].sum(axis=0))
             mat = mat[:, :-n]
         self._materialize()
         n_iter_after = len(self._models) // self.num_class

@@ -461,6 +461,31 @@ def hist_round(
     return o3, pl_new
 
 
+def route_round(
+    bins_fm: jax.Array,  # (F, N) int32
+    pleaf: jax.Array,  # (N,) int32 row -> leaf
+    params: jax.Array,  # (S, 16) int32 per-slot split params
+    col_onehot: jax.Array,  # (S, F) f32
+    num_slots: int,
+    num_bins: int,
+    efb: bool = False,
+    cat_mask=None,
+) -> jax.Array:
+    """hist_round's second output without the first: the (N,) new
+    row->leaf of a round whose children nobody will search (the round
+    that spends the last of the leaf budget, rounds.py). Same gate as
+    hist_round (can_hist_round). ONE call at any slot count: the pass
+    has no grid-constant histogram block, so the VMEM schedule that
+    chunks hist_round's slot axis (_round_s_max) has nothing to bound
+    here; what it holds per slot is a few (1, HIST_BLK) vectors."""
+    from .pallas_hist import route_round_tpu
+
+    return route_round_tpu(
+        bins_fm, pleaf, params, col_onehot, num_slots, num_bins, efb=efb,
+        cat_mask=cat_mask, interpret=_interpret_pallas(),
+    )
+
+
 # the take/seg_sum kernels materialize an (L, HIST_BLK) f32 one-hot
 # tile in VMEM per grid step; num_leaves may legally reach 131072
 # (config.h num_leaves check), at which point the tile alone (131072 x

@@ -319,14 +319,17 @@ def _swar_onehot(bins_row, B: int, blk: int, oh_shift: int, iota_p=None):
 
 
 def _round_kernel(
-    params_ref, coh_ref, cat_ref, bins_ref, gh_ref, pleaf_ref,  # inputs
-    out_ref, pl_out_ref,  # outputs
-    *scratch,  # persistent one-hot iota buffers (mode-dependent)
+    params_ref, coh_ref, cat_ref, bins_ref,  # inputs every round has
+    *refs,  # gh, pleaf | hist out, pleaf out | iota scratch; with
+    # route_only: pleaf | pleaf out | iota scratch
     F: int, B: int, blk: int, S: int, nat_ch: int, int8: bool,
-    oh_shift: int, efb: bool, has_cat: bool,
+    oh_shift: int, efb: bool, has_cat: bool, route_only: bool = False,
 ):
     """Fused round step: partition decision + slot-packed histograms
-    in ONE data pass.
+    in ONE data pass. With `route_only` (the round that spends the last
+    of a tree's leaf budget: no child of it can ever split) the pass
+    stops after the partition decision: no gradient input, no histogram
+    output block, no one-hot, no MXU contraction.
 
     Compile-time contracts (no host callbacks, no f64, jaxpr size
     budget) are enforced by the `hist_round_fused` entry of
@@ -356,22 +359,23 @@ def _round_kernel(
     gh channels are zero and whose new id is L: harmless by
     construction, same argument as the XLA path in rounds.py)."""
     i = pl.program_id(0)
-    # scratch layout (_round_scratch_shapes): int8 -> one byte-SWAR
-    # iota (shared by the bins one-hots and the cat one-hot); bf16
-    # with cat -> compare iota + byte-SWAR iota; bf16 without -> just
-    # the compare iota. All written once at step 0, VMEM-resident after.
-    if int8:
-        iota_swar_ref, = scratch
-        iota_cmp_ref = None
-    elif has_cat:
-        iota_cmp_ref, iota_swar_ref = scratch
+    if route_only:
+        pleaf_ref, pl_out_ref, *scratch = refs
+        gh_ref = out_ref = None
     else:
-        iota_cmp_ref, = scratch
-        iota_swar_ref = None
+        gh_ref, pleaf_ref, out_ref, pl_out_ref, *scratch = refs
+    # scratch layout (_round_iotas): the compare iota of the bf16
+    # bins one-hots, then the byte-SWAR iota of the int8 bins one-hots
+    # and of the cat one-hot, each only where the mode builds such a
+    # one-hot. All written once at step 0, VMEM-resident after.
+    want_cmp, want_swar = _round_iotas(int8, has_cat, route_only)
+    iota_cmp_ref = scratch.pop(0) if want_cmp else None
+    iota_swar_ref = scratch.pop(0) if want_swar else None
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        if out_ref is not None:
+            out_ref[...] = jnp.zeros_like(out_ref)
         if iota_cmp_ref is not None:
             iota_cmp_ref[...] = _oh_iota_init(iota_cmp_ref.shape, False)
         if iota_swar_ref is not None:
@@ -379,12 +383,10 @@ def _round_kernel(
 
     iota_swar = None if iota_swar_ref is None else iota_swar_ref[...]
     pleaf = pleaf_ref[...]  # (1, blk) i32
-    gh = gh_ref[...]  # (CH, blk) f32
     sel = params_ref[:, 0:1]  # (S, 1) i32
     thr = params_ref[:, 2:3].astype(jnp.float32)
     dl = params_ref[:, 3:4] != 0
     nanb = params_ref[:, 4:5].astype(jnp.float32)
-    small = params_ref[:, 5:6] != 0
     new_id = params_ref[:, 6:7]
 
     memb = pleaf == sel  # (S, blk)
@@ -428,7 +430,11 @@ def _round_kernel(
     # masked deltas over the slot axis applies at most one update
     delta = jnp.where(memb & ~gl, new_id - pleaf, 0)
     pl_out_ref[...] = pleaf + jnp.sum(delta, axis=0, keepdims=True)
+    if route_only:
+        return
 
+    gh = gh_ref[...]  # (CH, blk) f32
+    small = params_ref[:, 5:6] != 0
     side = memb & (gl == small)  # rows feeding slot s's histogram
     if int8:
         side_i = side.astype(jnp.int32)
@@ -451,6 +457,75 @@ def _round_kernel(
         _accum_hist_nt(bins_ref, W, out_ref, F=F, B=B, blk=blk,
                        dt=jnp.bfloat16, acc_t=jnp.float32,
                        iota_bT=iota_cmp_ref[...])
+
+
+def _round_iotas(int8: bool, has_cat: bool, route_only: bool) -> tuple:
+    """(compare iota?, byte-SWAR iota?) of a round kernel's scratch:
+    the bf16 histogram one-hots compare against the first, the int8
+    ones and the categorical own-bin one-hot unpack the second."""
+    return (not route_only and not int8,
+            has_cat or (int8 and not route_only))
+
+
+def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
+                num_slots: int, num_bins: int, nat_ch: int, int8: bool,
+                oh_shift: int, efb: bool, blk: int, interpret: bool):
+    """The one pallas_call behind hist_round_tpu and route_round_tpu:
+    `gh8 is None` asks for the routing pass alone (_round_kernel's
+    route_only), which has neither the gradient input nor the
+    grid-constant histogram block."""
+    F, N = bins_fm.shape
+    assert N % blk == 0, (N, blk)
+    S = num_slots
+    route_only = gh8 is None
+    has_cat = cat_mask is not None
+    if cat_mask is None:
+        cat_mask = jnp.zeros((S, num_bins), jnp.int8)
+    # persistent one-hot iota scratch (see _round_kernel): part of the
+    # kernel's explicit VMEM block schedule, accounted against the
+    # scoped budget by histogram._round_caps callers
+    scratch = [
+        pltpu.VMEM(_oh_iota_shape(num_bins, blk, swar), jnp.int32)
+        for swar, want in zip((False, True),
+                              _round_iotas(int8, has_cat, route_only))
+        if want
+    ]
+
+    def const(shape):
+        return pl.BlockSpec(shape, lambda i: (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
+    def rows(height):
+        return pl.BlockSpec((height, blk), lambda i: (0, i),
+                            memory_space=pltpu.VMEM)
+
+    ins = [(const((S, 16)), params), (const((S, F)), col_onehot),
+           (const((S, num_bins)), cat_mask), (rows(F), bins_fm),
+           (rows(1), pleaf.reshape(1, N))]
+    outs = [(rows(1), jax.ShapeDtypeStruct((1, N), jnp.int32))]
+    if not route_only:
+        block = hist_out_block(S * nat_ch, F, num_bins)
+        ins.insert(4, (rows(CH), gh8))
+        outs.insert(0, (const(block), jax.ShapeDtypeStruct(
+            block, jnp.int32 if int8 else jnp.float32)))
+    *out, pl_new = pl.pallas_call(
+        functools.partial(
+            _round_kernel, F=F, B=num_bins, blk=blk, S=S, nat_ch=nat_ch,
+            int8=int8, oh_shift=oh_shift, efb=efb, has_cat=has_cat,
+            route_only=route_only,
+        ),
+        grid=(N // blk,),
+        in_specs=[spec for spec, _ in ins],
+        out_specs=[spec for spec, _ in outs],
+        out_shape=[shape for _, shape in outs],
+        scratch_shapes=scratch,
+        # the routing pass accumulates nothing across grid steps, but
+        # its iota scratch is still written at step 0 only
+        compiler_params=_ARBITRARY,
+        interpret=interpret,
+    )(*(a for _, a in ins))
+    return (*(hist_out_flat(o, F, num_bins) for o in out),
+            pl_new.reshape(N))
 
 
 @functools.partial(
@@ -478,56 +553,38 @@ def hist_round_tpu(
 
     int8 histogram sums come back scaled by -(128 >> oh_shift) (SWAR
     one-hot bytes); callers divide once on the (S*ch, F*B) output."""
-    F, N = bins_fm.shape
-    assert N % blk == 0, (N, blk)
-    S = num_slots
-    nb = N // blk
-    has_cat = cat_mask is not None
-    if cat_mask is None:
-        cat_mask = jnp.zeros((S, num_bins), jnp.int8)
-    # persistent one-hot iota scratch (see _round_kernel): part of the
-    # kernel's explicit VMEM block schedule, accounted against the
-    # scoped budget by histogram._round_caps callers
-    if int8:
-        scratch = [pltpu.VMEM(_oh_iota_shape(num_bins, blk, True),
-                              jnp.int32)]
-    else:
-        scratch = [pltpu.VMEM(_oh_iota_shape(num_bins, blk, False),
-                              jnp.int32)]
-        if has_cat:
-            scratch.append(pltpu.VMEM(_oh_iota_shape(num_bins, blk, True),
-                                      jnp.int32))
-    block = hist_out_block(S * nat_ch, F, num_bins)
-    out, pl_new = pl.pallas_call(
-        functools.partial(
-            _round_kernel, F=F, B=num_bins, blk=blk, S=S, nat_ch=nat_ch,
-            int8=int8, oh_shift=oh_shift, efb=efb, has_cat=has_cat,
-        ),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((S, 16), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((S, F), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((S, num_bins), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((F, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((CH, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(block, lambda i: (0,) * len(block),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(block,
-                                 jnp.int32 if int8 else jnp.float32),
-            jax.ShapeDtypeStruct((1, N), jnp.int32),
-        ],
-        scratch_shapes=scratch,
-        compiler_params=_ARBITRARY,
-        interpret=interpret,
-    )(params, col_onehot, cat_mask, bins_fm, gh8, pleaf.reshape(1, N))
-    return hist_out_flat(out, F, num_bins), pl_new.reshape(N)
+    return _round_call(
+        bins_fm, gh8, pleaf, params, col_onehot, cat_mask,
+        num_slots=num_slots, num_bins=num_bins, nat_ch=nat_ch, int8=int8,
+        oh_shift=oh_shift, efb=efb, blk=blk, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_slots", "num_bins", "efb", "blk", "interpret"),
+)
+def route_round_tpu(
+    bins_fm: jax.Array,  # (F, N) int32, natural row order
+    pleaf: jax.Array,  # (N,) int32 row -> leaf
+    params: jax.Array,  # (S, 16) int32 per-slot split params
+    col_onehot: jax.Array,  # (S, F) f32 one-hot of the split column
+    num_slots: int,
+    num_bins: int,
+    efb: bool = False,
+    cat_mask=None,  # (S, B) s8 per-slot category sets, or None
+    blk: int = HIST_BLK,
+    interpret: bool = False,
+) -> jax.Array:
+    """hist_round_tpu's second output alone: the (N,) new row->leaf of
+    a round whose children will never be searched. Its own name on
+    purpose: a device trace names a Pallas call by its jitted wrapper,
+    and the histogram kernels' readers take every call named
+    hist_round_tpu / hist_nat_tpu for a histogram pass."""
+    pl_new, = _round_call(
+        bins_fm, None, pleaf, params, col_onehot, cat_mask,
+        num_slots=num_slots, num_bins=num_bins, nat_ch=0, int8=False,
+        oh_shift=0, efb=efb, blk=blk, interpret=interpret)
+    return pl_new
 
 
 def _take_kernel(idx_ref, tab_ref, out_ref, *, L: int, k: int, blk: int):

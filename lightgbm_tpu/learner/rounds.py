@@ -13,7 +13,9 @@ hardware). This grower never moves a row:
   every split gets its histogram from ONE slot-packed MXU pass
   (histogram.hist_nat_slots — the multi-leaf batching of the reference
   CUDA kernel, cuda_histogram_constructor.cu:20), the larger sibling
-  by parent subtraction (serial_tree_learner.cpp:411);
+  by parent subtraction (serial_tree_learner.cpp:411); the round that
+  spends the last of the leaf budget builds none (its children can
+  never split) and only routes its rows (spends_budget below);
 - per-tree device work is ~#rounds histogram passes plus O(N)
   elementwise updates — no gathers, no sorts, no prefix sums.
 
@@ -58,6 +60,7 @@ from .histogram import (
     histogram,
     int8_oh_shift,
     root_sums,
+    route_round,
     rs_exact_ok,
     rs_wire_dtype,
 )
@@ -88,6 +91,9 @@ from .split import (
 # rung the floor pays for. Each rung is one more copy of round_step to
 # trace, lower and compile.
 LADDER_RUNGS = (8, 16, 32)
+# device rows up to which a round takes at most half the remaining
+# leaf budget (the budget-aware tail in grow_tree_rounds)
+TAIL_EXACT_ROWS = 32 * 8192
 
 
 def ladder_widths(spec: GrowerSpec) -> Tuple[int, ...]:
@@ -97,12 +103,30 @@ def ladder_widths(spec: GrowerSpec) -> Tuple[int, ...]:
     return tuple(w for w in LADDER_RUNGS if w < slots) + (slots,)
 
 
+# the label of routing-only rounds among the per-width round counts
+ROUTE_LABEL = "route"
+
+
+def spends_budget(n_cand: jax.Array, budget: jax.Array, slots: int
+                  ) -> jax.Array:
+    """Will a round of `n_cand` candidates end the tree by spending all
+    of its remaining leaf `budget`? Known before the round's data pass:
+    the round splits min(n_cand, slots) leaves (every candidate has a
+    positive gain or is a valid forced split, and off the monotone
+    conflict guard none is deferred), so it spends the budget exactly
+    when both reach it. Its children can never split, and the round
+    needs neither their histograms nor their best splits."""
+    return (n_cand >= budget) & (budget <= slots)
+
+
 class _NState(NamedTuple):
     i: jax.Array  # splits performed so far
-    r: jax.Array  # (W+1,) int32 — rounds executed, by ladder width
-    # (r[w] = rounds run at widths[w]; r[-1] = total). Scalar counters,
-    # free at runtime; surfaced by grow_tree_rounds(..., with_stats=True),
-    # which the fused step reads for lgbmtpu_grower_rounds_total{width}.
+    r: jax.Array  # (W+2,) int32 — rounds executed: r[w] = histogram
+    # rounds run at widths[w]; r[W] = routing-only rounds (the round
+    # that spends the last of the leaf budget); r[-1] = total. Scalar
+    # counters, free at runtime; surfaced by grow_tree_rounds(...,
+    # with_stats=True), which the fused step reads for
+    # lgbmtpu_grower_rounds_total{width}.
     pleaf: jax.Array  # (N,) int32 row -> leaf; invalid rows carry L
     hist: jax.Array  # (L, 3, G, Bc) histogram pool
     leaf_g: jax.Array
@@ -165,7 +189,8 @@ def grow_tree_rounds(
     with_stats: bool = False,  # also return per-width round counters
 ):
     """Grow one tree; returns (tree arrays, natural-order row->leaf),
-    plus a {"widths", "rounds"} stats dict when with_stats=True.
+    plus a {"widths", "rounds"} stats dict when with_stats=True
+    (rounds: per width, then routing-only, then total; _NState.r).
 
     With spec.quant, grad/hess are INTEGER quantization levels and
     gh_scale carries the per-iteration dequantization scales: histogram
@@ -448,7 +473,12 @@ def grow_tree_rounds(
     # boundary effect is statistically negligible while ~5 extra rounds
     # would cost ~15% throughput. Measured on examples/binary (7k rows,
     # 63 leaves): closes most of the rounds-vs-exact AUC gap.
-    tail_exact = N <= 32 * 8192  # 262144 device rows
+    tail_exact = N <= TAIL_EXACT_ROWS
+    # under the monotone conflict guard a round may split fewer leaves
+    # than its candidates (round_step defers conflicting ones), so its
+    # being the last is not known before its data pass: those programs
+    # keep a histogram in every round
+    route_last = not spec.mono_mode
 
     def body(s: _NState) -> _NState:
         budget0 = (L - 1) - s.i
@@ -467,13 +497,39 @@ def grow_tree_rounds(
         bidx = jnp.sum(
             n_cand > jnp.asarray(widths[:-1], jnp.int32)
         ).astype(jnp.int32)
-        s = s._replace(r=s.r.at[bidx].add(1).at[-1].add(1))
-        return lax.switch(
-            bidx, [partial(round_step, Sk=w, n_max=n_cand) for w in widths],
-            s
+
+        def ladder(st: _NState) -> _NState:
+            return lax.switch(
+                bidx,
+                [partial(round_step, Sk=w, n_max=n_cand) for w in widths],
+                st,
+            )
+
+        if not route_last:
+            return ladder(s._replace(r=s.r.at[bidx].add(1).at[-1].add(1)))
+        # ---- the round that spends the last of the leaf budget routes
+        # rows only: after it cond() ends the loop on `i`, and nothing
+        # reads the children's histograms or their best splits (the
+        # reference builds none after its last Split either,
+        # serial_tree_learner.cpp Train). One branch at the full slot
+        # count serves every tree size: top-k of a wider k picks the
+        # same set, and without a histogram block the width costs the
+        # pass next to nothing. A cond AROUND the ladder's switch, not
+        # a fifth branch of it: as a fifth branch the compiler rounded
+        # the 137-column program's split gains differently in their
+        # sixth digit (PERF.md section 6, PR 29); around it, every
+        # model text is the parent's byte for byte.
+        last = spends_budget(n_cand, budget0, S)
+        ridx = jnp.where(last, len(widths), bidx).astype(jnp.int32)
+        return lax.cond(
+            last,
+            partial(round_step, Sk=S, n_max=n_cand, route_only=True),
+            ladder,
+            s._replace(r=s.r.at[ridx].add(1).at[-1].add(1)),
         )
 
-    def round_step(s: _NState, Sk: int, n_max=None) -> _NState:
+    def round_step(s: _NState, Sk: int, n_max=None,
+                   route_only: bool = False) -> _NState:
         t = s.tree
         i = s.i
         S = Sk  # kernel width for this round (see the ladder above)
@@ -763,12 +819,18 @@ def grow_tree_rounds(
                     cm_s = jnp.pad(cm_s, ((0, 0), (0, Bc - B)))
             else:
                 cm_s = None
-            slot_hists, pleaf_new = hist_round(
-                bins_fm, gh8, s.pleaf, params16, coh, S, Bc,
-                quant=spec.quant, int8=use_int8, oh_shift=oh_shift,
-                efb=spec.efb, cat_mask=cm_s,
-            )
-            slot_hists, elected = reduce_slots(slot_hists)
+            if route_only:
+                pleaf_new = route_round(
+                    bins_fm, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
+                    cat_mask=cm_s,
+                )
+            else:
+                slot_hists, pleaf_new = hist_round(
+                    bins_fm, gh8, s.pleaf, params16, coh, S, Bc,
+                    quant=spec.quant, int8=use_int8, oh_shift=oh_shift,
+                    efb=spec.efb, cat_mask=cm_s,
+                )
+                slot_hists, elected = reduce_slots(slot_hists)
         else:
             pack_cols = [
                 col_s.astype(jnp.float32),  # 0: device bin column
@@ -834,16 +896,35 @@ def grow_tree_rounds(
                 in_split & ~go_left, new_id_row, s.pleaf
             ).astype(jnp.int32)
 
-            # ---- smaller-child histograms: one slot-packed pass ----
-            go_small = go_left == small_row
-            hslot = jnp.where(
-                in_split & go_small, rank_row, S
-            ).astype(jnp.int32)
-            slot_hists = hist_nat_slots(
-                bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
-                int8=use_int8, oh_shift=oh_shift,
-            )  # (S, 3, G, Bc)
-            slot_hists, elected = reduce_slots(slot_hists)
+            if not route_only:
+                # ---- smaller-child histograms: one slot-packed pass ----
+                go_small = go_left == small_row
+                hslot = jnp.where(
+                    in_split & go_small, rank_row, S
+                ).astype(jnp.int32)
+                slot_hists = hist_nat_slots(
+                    bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
+                    int8=use_int8, oh_shift=oh_shift,
+                )  # (S, 3, G, Bc)
+                slot_hists, elected = reduce_slots(slot_hists)
+
+        leaf_g2 = jnp.where(sel, rec.left_g, s.leaf_g) \
+            .at[drop_new].set(rec.right_g, mode="drop")
+        leaf_h2 = jnp.where(sel, rec.left_h, s.leaf_h) \
+            .at[drop_new].set(rec.right_h, mode="drop")
+        leaf_c2 = jnp.where(sel, rec.left_c, s.leaf_c) \
+            .at[drop_new].set(rec.right_c, mode="drop")
+        leaf_parent2 = jnp.where(sel, node_id, s.leaf_parent) \
+            .at[drop_new].set(node_id, mode="drop")
+        if route_only:
+            # the tree and the rows' leaves are all a last round leaves
+            # behind; the pool, the best splits and the constraint
+            # carries pass through, read by nobody
+            return s._replace(
+                i=i + n_split, pleaf=pleaf_new, leaf_g=leaf_g2,
+                leaf_h=leaf_h2, leaf_c=leaf_c2, leaf_parent=leaf_parent2,
+                tree=tree_new,
+            )
 
         # ---- per-slot child hists: smaller from the pass, larger by
         # subtraction; scatter both into the pool. Work stays O(S), not
@@ -887,13 +968,6 @@ def grow_tree_rounds(
                 cat_subset=spec.cat_subset, parent_output=po,
                 cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
             )
-
-        leaf_g2 = jnp.where(sel, rec.left_g, s.leaf_g) \
-            .at[drop_new].set(rec.right_g, mode="drop")
-        leaf_h2 = jnp.where(sel, rec.left_h, s.leaf_h) \
-            .at[drop_new].set(rec.right_h, mode="drop")
-        leaf_c2 = jnp.where(sel, rec.left_c, s.leaf_c) \
-            .at[drop_new].set(rec.right_c, mode="drop")
 
         anc_in2, anc_left2 = s.anc_in, s.anc_left
         flo2, fhi2 = s.leaf_flo, s.leaf_fhi
@@ -1126,8 +1200,7 @@ def grow_tree_rounds(
             leaf_g=leaf_g2,
             leaf_h=leaf_h2,
             leaf_c=leaf_c2,
-            leaf_parent=jnp.where(sel, node_id, s.leaf_parent)
-            .at[drop_new].set(node_id, mode="drop"),
+            leaf_parent=leaf_parent2,
             leaf_min=nmin,
             leaf_max=nmax,
             anc_in=anc_in2,
@@ -1163,7 +1236,7 @@ def grow_tree_rounds(
 
     state = _NState(
         i=jnp.int32(0),
-        r=jnp.zeros(len(widths) + 1, jnp.int32),
+        r=jnp.zeros(len(widths) + 2, jnp.int32),
         pleaf=jnp.where(valid_f > 0, 0, L).astype(jnp.int32),
         hist=hist,
         leaf_g=jnp.zeros(L, jnp.float32).at[0].set(root[0]),

@@ -615,14 +615,17 @@ def record_collective_wire(entry: str, nbytes: int) -> None:
 
 def record_grower_rounds(widths, rounds) -> None:
     """Rounds the rounds grower ran at each width of its slot ladder
-    (learner/rounds.py ladder_widths), as counted on the device and
-    fetched with a fused chunk's eval rows."""
+    (learner/rounds.py ladder_widths) and, as width="route", the rounds
+    that only routed rows (the last round of a tree that ends on its
+    leaf budget), as counted on the device and fetched with a fused
+    chunk's eval rows."""
     r = _default
     if not r.enabled:
         return
     c = r.counter("lgbmtpu_grower_rounds_total",
                   "tree-growth rounds executed, by the round kernel's "
-                  "slot width", labels=("width",))
+                  "slot width (route: no histogram pass)",
+                  labels=("width",))
     for w, n in zip(widths, rounds):
         c.inc(float(n), width=str(w))
 

@@ -92,6 +92,39 @@ def test_round_kernel_compiles(one_chip, no_compile_cache,
     assert "tpu_custom_call" in text and "hist_round_tpu" in text
 
 
+@pytest.mark.parametrize("features,cat", [
+    (28, False), (FEATURES, False), (28, True), (FEATURES, True)],
+    ids=["28", "137", "28-cat", "137-cat"])
+def test_route_kernel_compiles_in_one_call(one_chip, no_compile_cache,
+                                           features, cat):
+    """The routing-only pass of the round that spends the leaf budget,
+    at the cells' full 48 slots, under VMEM_LIMIT_BYTES: ONE call also
+    at 137 columns, where the histogram pass it replaces is two
+    (_slot_chunks bounds the histogram block, which this pass lacks),
+    and under a name the histogram kernels' trace readers
+    (benchmark/rooflines/hist_round.KERNEL_PATTERN) do not match."""
+    import re
+
+    from lightgbm_tpu.learner.histogram import (
+        _round_s_max, _slot_chunks, route_round)
+
+    slots = 48
+    chunks = _slot_chunks(slots, _round_s_max(features, BINS, True, False))
+    assert len(chunks) == (2 if features == FEATURES else 1)
+    args = [_arg(one_chip, (features, ROWS), jnp.int32),
+            _arg(one_chip, (ROWS,), jnp.int32),
+            _arg(one_chip, (slots, 16), jnp.int32),
+            _arg(one_chip, (slots, features), jnp.float32)]
+    if cat:
+        args.append(_arg(one_chip, (slots, BINS), jnp.int8))
+    fn = jax.jit(lambda b, p, pr, oh, cm=None: route_round(
+        b, p, pr, oh, slots, BINS, cat_mask=cm))
+    text = fn.lower(*args).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%route_round_tpu" in text
+    assert not re.search(r"%(hist_round_tpu|hist_nat_tpu)\b", text)
+
+
 def test_root_kernel_compiles_at_137_columns(one_chip, no_compile_cache):
     from lightgbm_tpu.learner.pallas_hist import hist_nat_tpu
 

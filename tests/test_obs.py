@@ -648,6 +648,47 @@ def test_grower_rounds_counter_rides_the_eval_readback(rng, tmp_path, extra,
         assert all(gn > 0 and hn > 0 for gn, hn in g._last_gh_rows)
 
 
+@pytest.mark.parametrize("extra,ends_on_budget", [
+    ({}, True), ({"min_gain_to_split": 40.0}, False),
+    ({"monotone_constraints": [1, 0, 0, 0, 0],
+      "monotone_constraints_method": "intermediate"}, None),
+], ids=["leaf_budget", "stops_on_gain", "conflict_guard"])
+def test_grower_rounds_counter_routing_only_rounds(rng, extra,
+                                                   ends_on_budget):
+    """lgbmtpu_grower_rounds_total{width="route"}: the rounds that only
+    routed rows, one per tree that ends on its leaf budget, on the same
+    readback as the per-width counts. A tree that runs out of positive
+    gain first has none, and neither has one grown under the monotone
+    conflict guard (its last round is not known before its data pass);
+    the per-width counts then hold every round."""
+    from lightgbm_tpu.learner.rounds import ROUTE_LABEL, ladder_widths
+
+    c = default_registry().counter("lgbmtpu_grower_rounds_total",
+                                   labels=("width",))
+    X = rng.randn(3000, 5)
+    y = X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.1 * rng.randn(3000)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    params = {"verbosity": -1, "objective": "regression", "num_leaves": 31,
+              "metric": "l2", "min_data_in_leaf": 5,
+              "tpu_growth_mode": "rounds", **extra}
+    labels = ["8", "16", "25", ROUTE_LABEL]
+    before = {w: c.value(width=w) for w in labels}
+    bst = lgb.train(params, ds, num_boost_round=4, valid_sets=[ds],
+                    valid_names=["tr"])
+    delta = {w: c.value(width=w) - before[w] for w in labels}
+    assert [str(w) for w in ladder_widths(bst._gbdt.spec)] == labels[:-1]
+    leaves = [t.num_leaves for t in bst._gbdt.models]
+    if ends_on_budget:
+        assert leaves == [31] * 4 and delta[ROUTE_LABEL] == 4
+    elif ends_on_budget is None:
+        assert bst._gbdt.spec.mono_mode == 1 and leaves == [31] * 4
+        assert delta[ROUTE_LABEL] == 0
+    else:
+        assert 1 < max(leaves) < 31 and delta[ROUTE_LABEL] == 0
+    assert delta["8"] >= 4 and sum(delta.values()) >= 4 * 3
+    assert all(float(v).is_integer() for v in delta.values())
+
+
 # ------------------------------------------------------------ re-audit
 def test_instrumentation_added_no_host_callbacks():
     """All audited jaxpr entries stay callback-free: the observability

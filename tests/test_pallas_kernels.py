@@ -189,17 +189,23 @@ def _grow_case(spec_kw, quant=False, columns=6, rows=HIST_BLK,
 
 
 def _ladder_rounds(widths, leaves, rows):
-    """Rounds per ladder width of a tree whose every leaf can split:
-    candidates double until the slot count or the leaf budget binds;
-    at a small row count a round takes at most half the budget left
-    (rounds.py tail_exact)."""
-    counts, splits, live = [0] * len(widths), 0, 1
+    """Rounds per ladder width, then the routing-only rounds, of a tree
+    whose every leaf can split: candidates double until the slot count
+    or the leaf budget binds; at a small row count a round takes at
+    most half the budget left (rounds.py tail_exact); the round that
+    spends the last of the budget builds no histogram."""
+    from lightgbm_tpu.learner.rounds import TAIL_EXACT_ROWS
+
+    counts, splits, live = [0] * (len(widths) + 1), 0, 1
     while splits < leaves - 1:
         budget = leaves - 1 - splits
         n = min(budget, live)
-        if rows <= 32 * 8192:
+        if rows <= TAIL_EXACT_ROWS:
             n = min(n, max((budget + 1) // 2, 1))
-        counts[sum(n > w for w in widths[:-1])] += 1
+        if budget <= min(n, widths[-1]):
+            counts[-1] += 1
+        else:
+            counts[sum(n > w for w in widths[:-1])] += 1
         n = min(n, widths[-1])
         splits, live = splits + n, live + n
     return counts
@@ -232,6 +238,7 @@ def test_fused_round_ladder_matches_fallback(interp, monkeypatch, layout,
     counts = [int(n) for n in fused[5]["rounds"]]
     assert counts[:-1] == _ladder_rounds(widths, leaves, rows)
     assert counts[-1] == sum(counts[:-1]) and counts[1] > 0
+    assert counts[-2] == 1  # the last round routed rows only
     assert int(fused[4].num_nodes) == leaves - 1
 
     with monkeypatch.context() as m:
