@@ -83,7 +83,12 @@ def _is_sequence_input(data: Any) -> bool:
     )
 
 
-def _to_2d_numpy(data: Any) -> Tuple[np.ndarray, Optional[List[str]]]:
+def _to_2d_numpy(data: Any, keep_float32: bool = False
+                 ) -> Tuple[np.ndarray, Optional[List[str]]]:
+    """(2-D float64 matrix, column names or None). `keep_float32`
+    leaves a float32 array as it is, for a caller that converts what it
+    reads column by column (Dataset.construct's binning): the whole
+    matrix as float64 is twice its bytes, written once and read once."""
     feature_name = None
     try:  # pandas support without importing pandas eagerly
         import pandas as pd  # type: ignore
@@ -123,6 +128,8 @@ def _to_2d_numpy(data: Any) -> Tuple[np.ndarray, Optional[List[str]]]:
     arr = np.asarray(data)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
+    if keep_float32 and arr.dtype == np.float32:
+        return arr, feature_name
     return arr.astype(np.float64, copy=False), feature_name
 
 
@@ -484,20 +491,23 @@ class Dataset:
             if self.free_raw_data:
                 self.data = None
             return self
-        arr, pandas_names = _to_2d_numpy(self.data)
+        cfg = Config(self.params)
+        keep_raw = bool(cfg.linear_tree)
+        # binning converts each column as it reads it; only the raw
+        # values that linear trees keep have to be float64 as a whole
+        arr, pandas_names = _to_2d_numpy(self.data,
+                                         keep_float32=not keep_raw)
         if isinstance(self.feature_name, list):
             names = [str(n) for n in self.feature_name]
         elif pandas_names is not None:
             names = pandas_names
         else:
             names = [f"Column_{i}" for i in range(arr.shape[1])]
-        cfg = Config(self.params)
         ref_binned = None
         if self.reference is not None:
             self.reference.construct()
             ref_binned = self.reference._binned
         cat = self._resolve_categorical(names)
-        keep_raw = bool(cfg.linear_tree)
         with _gt.scope("dataset construct (binning)"):
             self._binned = BinnedDataset.from_numpy(
                 arr,

@@ -1466,7 +1466,7 @@ class GBDT:
         from .device_metrics import (DeviceEvalSet, rank_eval_arrays,
                                      supported_names)
         from .learner.histogram import row_mesh
-        from .learner.rounds import ladder_widths
+        from .learner.rounds import hist_schedule, ladder_widths
 
         data_mesh = self._mesh if self._parallel_mode == "data" else None
 
@@ -1528,6 +1528,12 @@ class GBDT:
             if self.spec.rounds_slots > 0 and self._dp is None else ()
         )
         self._f_ladder_widths = ladder_ws
+        if ladder_ws:
+            from .obs.metrics import record_hist_schedule
+
+            n_cols, n_rows = self.dev["bins"].shape
+            record_hist_schedule(
+                hist_schedule(self.spec, n_rows, n_cols), n_cols)
         # memo eligibility must be known BEFORE tracing. Query groups do
         # not bar it: the ranking objectives and metrics read their
         # layout, grids and per-query statistics from `data` (shapes in

@@ -80,6 +80,7 @@ def find_groups(
 
     groups: List[List[int]] = []
     group_mask: List[np.ndarray] = []
+    group_cnt: List[int] = []  # rows set in group_mask
     group_bins: List[int] = []
     group_conflict: List[int] = []
     group_has_cat: List[bool] = []
@@ -109,10 +110,16 @@ def find_groups(
                 if rest < 0:
                     continue
                 searched += 1
+                # two sets of a and b of N rows share at least a + b - N:
+                # where that alone is past the budget (any two dense
+                # columns) the O(N) intersection need not be counted
+                if group_cnt[gid] + int(nd_cnt[f]) - N > rest:
+                    continue
                 cnt = int(np.sum(group_mask[gid] & nd_masks[f]))
                 if cnt <= rest and cnt <= nd_cnt[f] // 2:
                     groups[gid].append(f)
                     group_mask[gid] |= nd_masks[f]
+                    group_cnt[gid] += int(nd_cnt[f]) - cnt
                     group_bins[gid] += width
                     group_conflict[gid] += cnt
                     placed = True
@@ -120,6 +127,7 @@ def find_groups(
         if not placed:
             groups.append([f])
             group_mask.append(nd_masks[f].copy())
+            group_cnt.append(int(nd_cnt[f]))
             # a solo feature keeps its full bin range (incl. mfb)
             group_bins.append(1 + width)
             group_conflict.append(0)

@@ -34,6 +34,35 @@ from .learner.histogram import HIST_BLK
 DEFAULT_ROW_BLOCK = HIST_BLK  # pallas histogram row block
 
 
+# columns of one slab (_column_slab), and the threads that build bin
+# mappers from slabs (numpy's sort / unique and the native FindBin
+# release the GIL)
+_SLAB_COLS = 32
+_BIN_THREADS = 8
+
+
+def _column_slabs(cols: Sequence[int]) -> List[Tuple[int, np.ndarray]]:
+    """[(position in `cols` of a slab's first column, the slab's column
+    ids)]: `cols` in runs of _SLAB_COLS."""
+    cols = np.asarray(cols, dtype=np.int64)
+    return [(i0, cols[i0:i0 + _SLAB_COLS])
+            for i0 in range(0, len(cols), _SLAB_COLS)]
+
+
+def _column_slab(data: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """Columns `idx` of a row-major (N, F) matrix as a (len(idx), N)
+    float64 C-contiguous slab: one blocked transpose in place of a
+    strided read per column (a 2,000-column float32 row is 8 kB, so a
+    single column's values lie a cache line apart each). float32 ->
+    float64 is exact, so the values a mapper sees do not depend on the
+    path."""
+    if len(idx) and idx[-1] - idx[0] == len(idx) - 1:
+        part = data[:, idx[0]:idx[-1] + 1]  # a run: a view
+    else:
+        part = data[:, idx]
+    return np.ascontiguousarray(part.T, dtype=np.float64)
+
+
 def _choose_bin_dtype(max_num_bin: int) -> Any:
     if max_num_bin <= 256:
         return np.uint8
@@ -175,22 +204,22 @@ class BinnedDataset:
             forced_map = load_forced_bins(
                 config.forcedbins_filename, num_features
             )
-            mappers = []
-            for f in range(num_features):
-                mb = (
-                    max_bin_by_feature[f]
-                    if f < len(max_bin_by_feature)
-                    else config.max_bin
-                )
-                col = sample[:, f]
-                mappers.append(
+
+            def slab_mappers(slab):
+                f0, idx = slab
+                cols = _column_slab(sample, idx)
+                return [
                     BinMapper.from_sample(
                         col,
                         total_sample_cnt=len(sample),
                         # the reference passes config max_bin straight to
                         # FindBin (dataset_loader.cpp:652) — num_bin ends
                         # <= max_bin, NOT max_bin+1
-                        max_bin=mb,
+                        max_bin=(
+                            max_bin_by_feature[f]
+                            if f < len(max_bin_by_feature)
+                            else config.max_bin
+                        ),
                         min_data_in_bin=config.min_data_in_bin,
                         use_missing=config.use_missing,
                         zero_as_missing=config.zero_as_missing,
@@ -198,7 +227,17 @@ class BinnedDataset:
                         max_cat_threshold=config.max_cat_threshold,
                         forced_bounds=forced_map.get(f),
                     )
-                )
+                    for f, col in enumerate(cols, start=f0)
+                ]
+
+            # a feature's mapper depends on its own column alone, so the
+            # slabs go to threads
+            from concurrent.futures import ThreadPoolExecutor
+
+            with ThreadPoolExecutor(_BIN_THREADS) as pool:
+                mappers = [m for ms in pool.map(
+                    slab_mappers, _column_slabs(range(num_features)))
+                    for m in ms]
             used = np.array(
                 [f for f in range(num_features) if not mappers[f].is_trivial],
                 dtype=np.int64,
@@ -216,8 +255,9 @@ class BinnedDataset:
         # bin the full matrix, feature-major
         dtype = _choose_bin_dtype(max_num_bin)
         bins = np.empty((len(used), num_data), dtype=dtype)
-        for i, f in enumerate(used):
-            bins[i] = mappers[f].values_to_bins(data[:, f]).astype(dtype)
+        for i0, idx in _column_slabs(used):
+            for i, col in enumerate(_column_slab(data, idx), start=i0):
+                bins[i] = mappers[used[i]].values_to_bins(col).astype(dtype)
 
         # EFB bundling (dataset.cpp:111 FindGroups / :250
         # FastFeatureBundling): merge near-exclusive sparse features into

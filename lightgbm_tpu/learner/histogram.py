@@ -28,7 +28,7 @@ like the reference's GPU path (gpu_hist_t, docs/GPU-Performance.rst).
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -63,13 +63,15 @@ _gate_warned: set = set()
 
 
 def _pallas_ok(kernel: str, n_rows: int, fits: bool = True,
-               why: str = "") -> bool:
+               why: str = "",
+               instead: str = "the XLA formulation") -> bool:
     """The one gate in front of every Pallas kernel: the backend runs
     Pallas (a TPU, or the interpreter under test), the row count is
     HIST_BLK-aligned, and the caller's VMEM condition `fits` holds.
     Off-TPU a miss is how the XLA formulations get chosen. ON a TPU a
-    miss means a chip run is about to take the slow formulation, so it
-    warns — once per (kernel, reason) — instead of passing silently."""
+    miss means a chip run is about to take the slow formulation
+    (`instead` names it), so it warns — once per (kernel, reason) —
+    instead of passing silently."""
     if not _use_pallas():
         return False
     if n_rows % HIST_BLK != 0 or n_rows < HIST_BLK:
@@ -84,8 +86,8 @@ def _pallas_ok(kernel: str, n_rows: int, fits: bool = True,
         from .. import log
 
         log.warning(
-            f"pallas {kernel} not used: {reason}; running the XLA "
-            "formulation instead (much slower on TPU)"
+            f"pallas {kernel} not used: {reason}; running {instead} "
+            "instead (much slower on TPU)"
         )
     return False
 
@@ -205,6 +207,9 @@ def hist_nat_slots(
     quant: bool = False,  # gh8 built by build_gh8_quant (3 channels)
     int8: bool = False,  # quant levels within +/-127: s8 MXU, s32 sums
     oh_shift: int = 0,  # SWAR one-hot scale (int8_oh_shift policy)
+    plan: Optional["HistPlan"] = None,  # the caller's program-wide
+    # plan (rounds.py: one feature block for all of a tree's passes);
+    # else this call's own
 ) -> jax.Array:
     """Per-slot histograms keyed by a row->slot vector -> (S, 3, F, B).
 
@@ -219,27 +224,21 @@ def hist_nat_slots(
     its per-leaf row indices."""
     F, N = bins_fm.shape
     nat_ch = 3 if quant else NAT_CH
-    # VMEM guard: chunk the slot axis so the kernel's grid-constant
-    # output block stays within its share of the stated scoped limit
-    # (_round_caps). The byte formula guards wide feature sets; the
-    # per-channel-count cap guards the slot axis.
-    per_slot = _slot_block_bytes(nat_ch, F, num_bins)
-    s_cap, budget = _round_caps(nat_ch)
     use_i8 = bool(int8 and quant)
-    # the persistent one-hot iota scratch is part of the kernel's VMEM
-    # block schedule — charge it against the scoped budget
-    budget = max(budget - _oh_scratch_bytes(num_bins, use_i8), 0)
-    s_max = max(1, min(budget // max(per_slot, 1), s_cap))
+    # VMEM guard (hist_plan): the slot axis in chunks and, past one
+    # bins tile, the feature axis in blocks, so that a call's resident
+    # output block stays within its share of the stated scoped limit
+    if plan is None:
+        plan = hist_plan(num_slots, F, num_bins, quant, use_i8)
     n_local, ax, mesh = _row_layout(N)
-    if _pallas_ok("hist_nat_tpu", n_local, per_slot <= budget,
-                  f"one slot's output block ({per_slot} B at "
-                  f"{F} columns x {num_bins} bins) exceeds the "
-                  f"{budget} B VMEM budget"):
+    if _pallas_ok("hist_nat_tpu", n_local, plan.s_max > 0,
+                  f"one slot's output block at {F} columns x {num_bins} "
+                  "bins exceeds the VMEM budget"):
         from .pallas_hist import hist_nat_tpu
 
         def call(bins_fm, gh8, slot):
             parts = []
-            for c0, sc in _slot_chunks(num_slots, s_max):
+            for c0, sc in _slot_chunks(num_slots, plan.s_max):
                 if c0 == 0 and sc == num_slots:
                     local = slot
                 else:
@@ -249,6 +248,7 @@ def hist_nat_slots(
                     bins_fm, gh8, local, sc, num_bins,
                     interpret=_interpret_pallas(), nat_ch=nat_ch,
                     int8=use_i8, oh_shift=oh_shift,
+                    feat_block=plan.feat_block,
                 )  # (sc*nat_ch, F*B)
                 o = out.reshape(sc, nat_ch, F, num_bins)
                 if quant:
@@ -393,20 +393,87 @@ def _round_s_max(num_feat: int, num_bins: int, quant: bool,
     return max(1, min(budget // max(per_slot, 1), s_cap))
 
 
+# columns of the (columns, HIST_BLK) int32 bins tile a kernel call may
+# hold per grid step: double-buffered, an eighth of the scoped limit
+# (512 columns, 8 MiB). The cells' tables (28, 137 columns) are a
+# fraction of it; a 2,000-column tile would be 2 x 16.4 MB before any
+# output block.
+_TILE_COLS = VMEM_LIMIT_BYTES // 8 // (2 * HIST_BLK * 4)
+
+
+class HistPlan(NamedTuple):
+    """How the slot-packed histogram kernels cover `num_slots` slots of
+    a (num_feat, N) table (hist_plan)."""
+
+    s_max: int  # slots one kernel call holds (0: not even one fits)
+    feat_block: int  # columns of one feature block (num_feat: the whole
+    # table is one bins tile and one resident output block)
+    num_feat: int
+
+    @property
+    def blocks(self) -> int:
+        return -(-self.num_feat // self.feat_block)
+
+
+def hist_plan(num_slots: int, num_feat: int, num_bins: int, quant: bool,
+              int8: bool = False) -> HistPlan:
+    """Static VMEM plan of a histogram pass, from the shapes alone.
+
+    While the whole table fits one call's schedule (one slot's output
+    block inside the budget, the slot axis in at most _ROUND_MAX_CHUNKS
+    chunks, the bins tile inside _TILE_COLS) the plan is the whole
+    table in chunks of _round_s_max slots: every program of 28 or 137
+    columns. Past that the FEATURE axis is blocked instead, so that a
+    pass still reads the bin matrix once (each slot chunk would
+    re-stream it): blocks of whole FEATURE_UNROLL groups, equal, the
+    widest whose min(num_slots, slot cap)-slot output block fits the
+    same budget. 2,000 columns x 64 bins, 3 channels, 48 slots: one
+    group of one slot is 3 x 32 x 64 x 4 = 24,576 B, the budget
+    12,897,484 B, so 10 groups fit 48 slots, the 63 groups go in 7
+    blocks of 9 (288 columns, 10.6 MB); at 255 bins 2 groups, 32 blocks
+    of 64 columns."""
+    from .pallas_hist import FEATURE_UNROLL
+
+    nat_ch = 3 if quant else NAT_CH
+    s_whole = _round_s_max(num_feat, num_bins, quant, int8)
+    whole = HistPlan(s_whole, num_feat, num_feat)
+    if (s_whole > 0 and num_slots <= _ROUND_MAX_CHUNKS * s_whole
+            and num_feat <= _TILE_COLS):
+        return whole
+    s_cap, budget = _round_caps(nat_ch)
+    budget = max(budget - _oh_scratch_bytes(num_bins, int8), 0)
+    group = nat_ch * FEATURE_UNROLL * num_bins * 4  # one slot's, one group
+    slots = min(num_slots, s_cap, budget // group)
+    if slots < 1:
+        return whole
+    groups = -(-num_feat // FEATURE_UNROLL)
+    fit = min(budget // (slots * group), _TILE_COLS // FEATURE_UNROLL)
+    blocks = -(-groups // max(fit, 1))
+    if blocks == 1:  # nothing to block: the whole table, chunked
+        return whole
+    return HistPlan(slots, -(-groups // blocks) * FEATURE_UNROLL, num_feat)
+
+
 def can_hist_round(n_rows: int, num_slots: int, num_feat: int,
                    num_bins: int, quant: bool,
                    int8: bool = False) -> bool:
-    """Static gate for the fused round kernel (pallas path only). The
-    slot axis may be CHUNKED (hist_round composes the disjoint
-    per-chunk partition updates), so the gate requires one chunk to
-    fit the scoped-VMEM schedule and caps the re-stream fan-out at
-    _ROUND_MAX_CHUNKS."""
+    """Static gate for the fused round kernel (pallas path only),
+    which holds the WHOLE table's bins tile. The slot axis may be
+    CHUNKED (hist_round composes the disjoint per-chunk partition
+    updates), so the gate requires one chunk to fit the scoped-VMEM
+    schedule and caps the re-stream fan-out at _ROUND_MAX_CHUNKS. A
+    table that hist_plan blocks by features never asks here: its round
+    is the routing pass over the split columns and a blocked
+    slot-keyed pass (rounds.py)."""
     s_max = _round_s_max(num_feat, num_bins, quant, int8)
     return _pallas_ok(
         "hist_round_tpu", n_rows,
-        s_max > 0 and num_slots <= _ROUND_MAX_CHUNKS * s_max,
+        s_max > 0 and num_slots <= _ROUND_MAX_CHUNKS * s_max
+        and num_feat <= _TILE_COLS,
         f"{num_slots} slots at {num_feat} columns x {num_bins} bins need "
-        f"more than {_ROUND_MAX_CHUNKS} chunks of {s_max} slots",
+        f"more than {_ROUND_MAX_CHUNKS} chunks of {s_max} slots, or a "
+        f"bins tile past {_TILE_COLS} columns",
+        instead="the XLA partition and hist_nat_tpu passes",
     )
 
 
@@ -470,19 +537,27 @@ def route_round(
     num_bins: int,
     efb: bool = False,
     cat_mask=None,
-) -> jax.Array:
+    with_slot: bool = False,
+):
     """hist_round's second output without the first: the (N,) new
     row->leaf of a round whose children nobody will search (the round
     that spends the last of the leaf budget, rounds.py). Same gate as
     hist_round (can_hist_round). ONE call at any slot count: the pass
     has no grid-constant histogram block, so the VMEM schedule that
     chunks hist_round's slot axis (_round_s_max) has nothing to bound
-    here; what it holds per slot is a few (1, HIST_BLK) vectors."""
+    here; what it holds per slot is a few (1, HIST_BLK) vectors.
+
+    A round at width (hist_plan blocks the features) routes through
+    here in EVERY round, over a table of its split columns alone
+    (bins_fm[columns], col_onehot the identity), and `with_slot` also
+    returns each row's histogram slot (num_slots: none) for the blocked
+    hist_nat_slots pass that follows."""
     from .pallas_hist import route_round_tpu
 
     return route_round_tpu(
         bins_fm, pleaf, params, col_onehot, num_slots, num_bins, efb=efb,
         cat_mask=cat_mask, interpret=_interpret_pallas(),
+        with_slot=with_slot,
     )
 
 

@@ -56,11 +56,23 @@ def feature_groups(F: int) -> tuple:
 
 
 def hist_out_block(rows: int, F: int, B: int) -> tuple:
-    """Shape of a histogram kernel's grid-constant output block:
-    (rows, F*B) while the feature loop is unrolled whole, else
-    (groups, rows, per_group*B), indexed by the loop's group."""
+    """Shape of a histogram kernel's resident output block (the whole
+    table's, or one feature block's): (rows, F*B) while the feature
+    loop is unrolled whole, else (groups, rows, per_group*B), indexed
+    by the loop's group."""
     G, Fg = feature_groups(F)
     return (rows, F * B) if G == 1 else (G, rows, Fg * B)
+
+
+def feature_blocks(F: int, feat_block: int) -> int:
+    """Feature blocks of a kernel call: 1 while the whole table is one
+    (F, HIST_BLK) bins tile (`feat_block` 0 or >= F), else the grid's
+    leading dimension (histogram.hist_plan sizes the block: whole
+    FEATURE_UNROLL groups, so a block's columns are its loop groups)."""
+    if not feat_block or feat_block >= F:
+        return 1
+    assert feat_block % FEATURE_UNROLL == 0, feat_block
+    return -(-F // feat_block)
 
 
 def hist_out_flat(out: jax.Array, F: int, B: int) -> jax.Array:
@@ -71,23 +83,28 @@ def hist_out_flat(out: jax.Array, F: int, B: int) -> jax.Array:
     return out.transpose(1, 0, 2).reshape(rows, G * width)[:, :F * B]
 
 
-def _accum_features(bins_ref, out_ref, contribution, *, F: int, B: int):
+def _accum_features(bins_ref, out_ref, contribution, *, F: int, B: int,
+                    last=None):
     """out[.., f*B:(f+1)*B] += contribution(bins row f) for every
-    feature f: the kernels' one loop over the columns, unrolled whole
-    into a 2-D block (hist_out_block; the single-leaf kernels always),
-    else by groups into a 3-D one."""
+    feature f of the bins tile: the kernels' one loop over the columns,
+    unrolled whole into a 2-D block (hist_out_block; the single-leaf
+    kernels always), else by groups into a 3-D one. `last` is the
+    tile's last real row where that is not F - 1 (the ragged last
+    feature block of a blocked call, _block_last)."""
     G, Fg = feature_groups(F)
     if len(out_ref.shape) == 2:
         for f in range(F):
             out_ref[:, f * B : (f + 1) * B] += contribution(
                 bins_ref[f : f + 1, :])
         return
+    if last is None:
+        last = F - 1
 
     def group(j, carry):
         for f in range(Fg):
             # past the last column the group re-reads it into columns
             # that hist_out_flat drops
-            row = jnp.minimum(j * Fg + f, F - 1)
+            row = jnp.minimum(j * Fg + f, last)
             out_ref[j, :, f * B : (f + 1) * B] += contribution(
                 bins_ref[pl.ds(row, 1), :])
         return carry
@@ -95,8 +112,15 @@ def _accum_features(bins_ref, out_ref, contribution, *, F: int, B: int):
     lax.fori_loop(0, G, group, 0)
 
 
+def _block_last(F: int, feat_block: int):
+    """Last real row of this grid step's bins tile in a blocked call
+    (grid = (feature blocks, row blocks)): the last block's tile runs
+    past the table's F columns, and what lies there is not data."""
+    return jnp.minimum(F - pl.program_id(0) * feat_block, feat_block) - 1
+
+
 def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
-                   iota_bT=None):
+                   iota_bT=None, last=None):
     """Shared accumulate loop: one NT matmul per feature, the one-hot
     built TRANSPOSED (B, blk) directly from the bins tile's native
     (F, blk) layout — the former per-block (blk, F) int32 transpose
@@ -119,7 +143,7 @@ def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
             preferred_element_type=acc_t,
         )
 
-    _accum_features(bins_ref, out_ref, contribution, F=F, B=B)
+    _accum_features(bins_ref, out_ref, contribution, F=F, B=B, last=last)
 
 
 def _oh_iota_shape(B: int, blk: int, int8: bool) -> tuple:
@@ -142,7 +166,8 @@ def _oh_iota_init(shape: tuple, int8: bool):
 
 def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
                 *, F: int, B: int, blk: int, S: int, nat_ch: int,
-                int8: bool = False, oh_shift: int = 0):
+                int8: bool = False, oh_shift: int = 0,
+                feat_block: int = 0):
     """Slot-packed natural-order histogram: rows carry a slot id; the
     weight matrix W packs (slot x channel) onto the MXU's M axis —
     W[(s, c), r] = gh[c, r] * (slot[r] == s) — so one (S*nat_ch, blk) @
@@ -161,8 +186,22 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     v5e and the block sums are exact integers (the TPU analog of the
     reference's int16/int32 histogram buffers, bin.h:63-81). Worst-case
     block sum 127 * blk << 2^31; cross-block accumulation rides the s32
-    output block."""
-    i = pl.program_id(0)
+    output block.
+
+    With `feat_block` (a table too wide for one bins tile,
+    histogram.hist_plan) the grid is (feature blocks, row blocks): the
+    bins tile and the output block are ONE feature block's, resident
+    while the rows sweep and written back once per block, so the VMEM
+    need is a function of the block and not of F; gh and the slot
+    vector are re-read per block (36 B a row against 4 B x block
+    columns of bins) and W is rebuilt from them."""
+    last = None
+    if feat_block:
+        i = pl.program_id(1)
+        last = _block_last(F, feat_block)
+        F = feat_block  # the tile's columns from here on
+    else:
+        i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
@@ -190,14 +229,16 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
                 preferred_element_type=jnp.int32,
             )
 
-        _accum_features(bins_ref, out_ref, contribution, F=F, B=B)
+        _accum_features(bins_ref, out_ref, contribution, F=F, B=B,
+                        last=last)
         return
     sl = (slot[None, :] == iota_s).astype(jnp.bfloat16)  # (S, blk)
     g5 = gh[:nat_ch, :].astype(jnp.bfloat16)  # (nat_ch, blk)
     W = (sl[:, None, :] * g5[None, :, :]).reshape(S * nat_ch, blk)
 
     _accum_hist_nt(bins_ref, W, out_ref, F=F, B=B, blk=blk,
-                   dt=jnp.bfloat16, acc_t=jnp.float32, iota_bT=iota)
+                   dt=jnp.bfloat16, acc_t=jnp.float32, iota_bT=iota,
+                   last=last)
 
 
 def _swar_divisor(oh_shift: int) -> float:
@@ -215,12 +256,18 @@ _VMEM = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 # of letting it infer (the chip-resident schedule contract, ISSUE 12)
 _ARBITRARY = pltpu.CompilerParams(dimension_semantics=("arbitrary",),
                                   vmem_limit_bytes=VMEM_LIMIT_BYTES)
+# a blocked call's grid (feature blocks, row blocks): a feature block's
+# output accumulates over its row steps, and the one iota scratch is
+# rewritten at each block's first
+_ARBITRARY_2D = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "blk", "interpret", "nat_ch",
-                     "int8", "oh_shift"),
+                     "int8", "oh_shift", "feat_block"),
 )
 def hist_nat_tpu(
     bins_fm: jax.Array,  # (F, N) int32, natural row order
@@ -233,35 +280,60 @@ def hist_nat_tpu(
     nat_ch: int = NAT_CH,
     int8: bool = False,
     oh_shift: int = 0,
+    feat_block: int = 0,  # columns per feature block; 0: the whole table
 ) -> jax.Array:
     """(S*nat_ch, F*B) f32 packed per-slot channel histograms (exact
-    integer sums computed in s32 when int8)."""
+    integer sums computed in s32 when int8). One pass over the bin
+    matrix at any F: past one bins tile (`feat_block`, see _nat_kernel)
+    the feature axis is the grid's leading dimension."""
     F, N = bins_fm.shape
     assert N % blk == 0, (N, blk)
     assert gh8.shape == (CH, N), gh8.shape
     B = num_bins
     S = num_slots
     nb = N // blk
-    block = hist_out_block(S * nat_ch, F, B)
+    nfb = feature_blocks(F, feat_block)
+    if nfb == 1:
+        feat_block = 0
+        block = out_shape = hist_out_block(S * nat_ch, F, B)
+        grid = (nb,)
+
+        def rows(height):
+            return pl.BlockSpec((height, blk), lambda i: (0, i),
+                                memory_space=pltpu.VMEM)
+
+        bins_spec = rows(F)
+        out_spec = pl.BlockSpec(
+            block, lambda i: (0,) * len(block), memory_space=pltpu.VMEM)
+    else:
+        # always by groups, also where a block is one group
+        groups = feat_block // FEATURE_UNROLL
+        block = (groups, S * nat_ch, FEATURE_UNROLL * B)
+        out_shape = (nfb * groups,) + block[1:]
+        grid = (nfb, nb)
+
+        def rows(height):
+            return pl.BlockSpec((height, blk), lambda j, i: (0, i),
+                                memory_space=pltpu.VMEM)
+
+        bins_spec = pl.BlockSpec((feat_block, blk), lambda j, i: (j, i),
+                                 memory_space=pltpu.VMEM)
+        out_spec = pl.BlockSpec(block, lambda j, i: (j, 0, 0),
+                                memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_nat_kernel, F=F, B=B, blk=blk, S=S, nat_ch=nat_ch,
-                          int8=int8, oh_shift=oh_shift),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((F, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((CH, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            block, lambda i: (0,) * len(block), memory_space=pltpu.VMEM
-        ),
+                          int8=int8, oh_shift=oh_shift,
+                          feat_block=feat_block),
+        grid=grid,
+        in_specs=[bins_spec, rows(CH), rows(1)],
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(
-            block, jnp.int32 if int8 else jnp.float32
+            out_shape, jnp.int32 if int8 else jnp.float32
         ),
         scratch_shapes=[
             pltpu.VMEM(_oh_iota_shape(B, blk, int8), jnp.int32),
         ],
-        compiler_params=_ARBITRARY,
+        compiler_params=_ARBITRARY if nfb == 1 else _ARBITRARY_2D,
         interpret=interpret,
     )(bins_fm, gh8, slot.reshape(1, N))
     out = hist_out_flat(out, F, B)
@@ -324,12 +396,17 @@ def _round_kernel(
     # route_only: pleaf | pleaf out | iota scratch
     F: int, B: int, blk: int, S: int, nat_ch: int, int8: bool,
     oh_shift: int, efb: bool, has_cat: bool, route_only: bool = False,
+    with_slot: bool = False,
 ):
     """Fused round step: partition decision + slot-packed histograms
     in ONE data pass. With `route_only` (the round that spends the last
     of a tree's leaf budget: no child of it can ever split) the pass
     stops after the partition decision: no gradient input, no histogram
-    output block, no one-hot, no MXU contraction.
+    output block, no one-hot, no MXU contraction. `with_slot` (routing
+    only) adds each row's histogram slot as a second blocked output:
+    what a round at width hands to the blocked slot-keyed pass
+    (hist_nat_tpu) that follows it over the whole table, this pass
+    having seen the round's split columns alone.
 
     Compile-time contracts (no host callbacks, no f64, jaxpr size
     budget) are enforced by the `hist_round_fused` entry of
@@ -359,7 +436,11 @@ def _round_kernel(
     gh channels are zero and whose new id is L: harmless by
     construction, same argument as the XLA path in rounds.py)."""
     i = pl.program_id(0)
-    if route_only:
+    slot_out_ref = None
+    if route_only and with_slot:
+        pleaf_ref, pl_out_ref, slot_out_ref, *scratch = refs
+        gh_ref = out_ref = None
+    elif route_only:
         pleaf_ref, pl_out_ref, *scratch = refs
         gh_ref = out_ref = None
     else:
@@ -430,12 +511,20 @@ def _round_kernel(
     # masked deltas over the slot axis applies at most one update
     delta = jnp.where(memb & ~gl, new_id - pleaf, 0)
     pl_out_ref[...] = pleaf + jnp.sum(delta, axis=0, keepdims=True)
-    if route_only:
+    if route_only and not with_slot:
         return
 
-    gh = gh_ref[...]  # (CH, blk) f32
+    if not route_only:
+        gh = gh_ref[...]  # (CH, blk) f32
     small = params_ref[:, 5:6] != 0
     side = memb & (gl == small)  # rows feeding slot s's histogram
+    if route_only:
+        # at most one slot claims a row; a row none claims gets S, the
+        # slot-keyed kernels' trash id
+        iota_s = lax.broadcasted_iota(jnp.int32, (S, blk), 0)
+        slot_out_ref[...] = S + jnp.sum(
+            jnp.where(side, iota_s - S, 0), axis=0, keepdims=True)
+        return
     if int8:
         side_i = side.astype(jnp.int32)
         g32 = gh[:nat_ch, :].astype(jnp.int32)
@@ -469,11 +558,13 @@ def _round_iotas(int8: bool, has_cat: bool, route_only: bool) -> tuple:
 
 def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
                 num_slots: int, num_bins: int, nat_ch: int, int8: bool,
-                oh_shift: int, efb: bool, blk: int, interpret: bool):
+                oh_shift: int, efb: bool, blk: int, interpret: bool,
+                with_slot: bool = False):
     """The one pallas_call behind hist_round_tpu and route_round_tpu:
     `gh8 is None` asks for the routing pass alone (_round_kernel's
     route_only), which has neither the gradient input nor the
-    grid-constant histogram block."""
+    grid-constant histogram block; `with_slot` for its rows' histogram
+    slots beside their new leaves."""
     F, N = bins_fm.shape
     assert N % blk == 0, (N, blk)
     S = num_slots
@@ -503,16 +594,19 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
            (const((S, num_bins)), cat_mask), (rows(F), bins_fm),
            (rows(1), pleaf.reshape(1, N))]
     outs = [(rows(1), jax.ShapeDtypeStruct((1, N), jnp.int32))]
+    if with_slot:
+        assert route_only
+        outs.append((rows(1), jax.ShapeDtypeStruct((1, N), jnp.int32)))
     if not route_only:
         block = hist_out_block(S * nat_ch, F, num_bins)
         ins.insert(4, (rows(CH), gh8))
         outs.insert(0, (const(block), jax.ShapeDtypeStruct(
             block, jnp.int32 if int8 else jnp.float32)))
-    *out, pl_new = pl.pallas_call(
+    res = pl.pallas_call(
         functools.partial(
             _round_kernel, F=F, B=num_bins, blk=blk, S=S, nat_ch=nat_ch,
             int8=int8, oh_shift=oh_shift, efb=efb, has_cat=has_cat,
-            route_only=route_only,
+            route_only=route_only, with_slot=with_slot,
         ),
         grid=(N // blk,),
         in_specs=[spec for spec, _ in ins],
@@ -524,6 +618,9 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
         compiler_params=_ARBITRARY,
         interpret=interpret,
     )(*(a for _, a in ins))
+    if with_slot:
+        return tuple(r.reshape(N) for r in res)
+    *out, pl_new = res
     return (*(hist_out_flat(o, F, num_bins) for o in out),
             pl_new.reshape(N))
 
@@ -561,7 +658,8 @@ def hist_round_tpu(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_slots", "num_bins", "efb", "blk", "interpret"),
+    static_argnames=("num_slots", "num_bins", "efb", "blk", "interpret",
+                     "with_slot"),
 )
 def route_round_tpu(
     bins_fm: jax.Array,  # (F, N) int32, natural row order
@@ -574,17 +672,21 @@ def route_round_tpu(
     cat_mask=None,  # (S, B) s8 per-slot category sets, or None
     blk: int = HIST_BLK,
     interpret: bool = False,
-) -> jax.Array:
+    with_slot: bool = False,
+):
     """hist_round_tpu's second output alone: the (N,) new row->leaf of
-    a round whose children will never be searched. Its own name on
+    a round whose children will never be searched; with `with_slot`
+    the pair (new row->leaf, (N,) histogram slot of each row, num_slots
+    where it feeds none) of a round at width. Its own name on
     purpose: a device trace names a Pallas call by its jitted wrapper,
     and the histogram kernels' readers take every call named
     hist_round_tpu / hist_nat_tpu for a histogram pass."""
-    pl_new, = _round_call(
+    res = _round_call(
         bins_fm, None, pleaf, params, col_onehot, cat_mask,
         num_slots=num_slots, num_bins=num_bins, nat_ch=0, int8=False,
-        oh_shift=0, efb=efb, blk=blk, interpret=interpret)
-    return pl_new
+        oh_shift=0, efb=efb, blk=blk, interpret=interpret,
+        with_slot=with_slot)
+    return res if with_slot else res[0]
 
 
 def _take_kernel(idx_ref, tab_ref, out_ref, *, L: int, k: int, blk: int):

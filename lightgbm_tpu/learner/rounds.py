@@ -51,11 +51,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from .bundle import BundleInfo, decode_feature_bins, expand_hist
+from ..timer import global_timer
 from .histogram import (
+    HistPlan,
+    _pallas_ok,
+    _slot_chunks,
     build_gh8,
     build_gh8_quant,
     can_hist_round,
     hist_nat_slots,
+    hist_plan,
     hist_round,
     histogram,
     int8_oh_shift,
@@ -105,6 +110,68 @@ def ladder_widths(spec: GrowerSpec) -> Tuple[int, ...]:
 
 # the label of routing-only rounds among the per-width round counts
 ROUTE_LABEL = "route"
+# the label of a tree's first pass among the per-width call counts
+ROOT_LABEL = "root"
+
+
+class HistSchedule(NamedTuple):
+    """How a program's histogram passes run, decided once from its
+    shapes (hist_schedule)."""
+
+    plan: HistPlan  # at the program's full slot count
+    use_int8: bool
+    oh_shift: int  # SWAR one-hot scale of the int8 kernels
+    # one of three rounds: `fused` (hist_round_tpu holds the whole
+    # table's tile: partition and histograms in one kernel), `routed`
+    # (at width: route_round_tpu over the round's split columns, then
+    # hist_nat_tpu by feature blocks), else the XLA partition followed
+    # by hist_nat_slots (no Pallas backend, odd row counts)
+    fused: bool
+    routed: bool
+    # ((width label, kernel calls that stream the rows in one pass at
+    # that width), ...): ROOT_LABEL, then ladder_widths; empty where no
+    # kernel runs
+    calls: Tuple[Tuple[str, int], ...]
+
+
+def hist_schedule(spec: GrowerSpec, n_rows: int, n_cols: int
+                  ) -> HistSchedule:
+    """Feature blocks and slot chunks of every histogram pass of the
+    rounds grower's program over a (n_cols, n_rows) device bin matrix:
+    static, from the spec and the shapes (histogram.hist_plan holds the
+    VMEM arithmetic). One feature block size serves all of a tree's
+    passes, sized at the full slot count."""
+    with global_timer.scope("learner.hist_plan"):
+        widths = ladder_widths(spec)
+        S = widths[-1]
+        Bc = spec.col_bins if (spec.efb and spec.col_bins) else spec.num_bins
+        # SWAR one-hot scale for the int8 kernels; int8 itself is gated
+        # on the policy finding ANY safe shift
+        oh_shift = (int8_oh_shift(n_rows, spec.quant_levels)
+                    if spec.quant_int8 else 0)
+        use_int8 = bool(spec.quant_int8 and oh_shift is not None)
+        plan = hist_plan(S, n_cols, Bc, spec.quant, use_int8)
+        if plan.blocks > 1:
+            fused = False
+            routed = _pallas_ok("hist_nat_tpu", n_rows, plan.s_max > 0,
+                                f"one slot of a 32-column group at {Bc} "
+                                "bins exceeds the VMEM budget")
+        else:
+            fused = can_hist_round(n_rows, S, n_cols, Bc, spec.quant,
+                                   int8=use_int8)
+            routed = False
+        calls: Tuple[Tuple[str, int], ...] = ()
+        if fused or routed or _pallas_ok("hist_nat_tpu", n_rows):
+            def n_calls(w: int) -> int:
+                # off both kernel rounds hist_nat_slots plans per call
+                p = plan if fused or routed else hist_plan(
+                    w, n_cols, Bc, spec.quant, use_int8)
+                return len(_slot_chunks(w, max(p.s_max, 1)))
+
+            calls = ((ROOT_LABEL, 1),) + tuple(
+                (str(w), n_calls(w)) for w in widths)
+        return HistSchedule(plan, use_int8, oh_shift or 0, fused, routed,
+                            calls)
 
 
 def spends_budget(n_cand: jax.Array, budget: jax.Array, slots: int
@@ -246,19 +313,21 @@ def grow_tree_rounds(
         raise ValueError("extra_trees / ff_bynode need rng_key")
     NG = max(1, spec.n_groups)
 
-    # SWAR one-hot scale for the int8 kernels (histogram.int8_oh_shift);
-    # int8 itself is gated on the policy finding ANY safe shift
-    oh_shift = int8_oh_shift(N, spec.quant_levels) if spec.quant_int8 else 0
-    use_int8 = bool(spec.quant_int8 and oh_shift is not None)
-    oh_shift = oh_shift or 0
     # fused partition+histogram kernel: one pass
     # computes the slot-packed child histograms AND the new row->leaf
     # vector; the separate (G, N) split-column select, membership
     # matmul and partition update disappear. Categorical splits ride
     # the kernel too: the row's own split-column bin gets a
     # single-feature SWAR one-hot contracted against the per-slot
-    # category masks.
-    use_fused = can_hist_round(N, S, G, Bc, spec.quant, int8=use_int8)
+    # category masks. At width (a table past one bins tile) the same
+    # kernel routes over the round's <= S split columns alone and a
+    # blocked slot-keyed pass builds the histograms (use_routed).
+    sched = hist_schedule(spec, N, G)
+    use_int8, oh_shift = sched.use_int8, sched.oh_shift
+    use_fused, use_routed = sched.fused, sched.routed
+    # the root pass and a routed round's passes share the program's
+    # feature block; every other hist_nat_slots call plans for itself
+    nat_plan = sched.plan if use_routed else None
     # ---- reduce-scatter histogram wire: the full
     # psum ships every rank the whole f32 histogram; the reference
     # ships INTEGER histograms through ReduceScatter with per-rank
@@ -380,7 +449,7 @@ def grow_tree_rounds(
         root = root * scale3
         hist0 = hist_nat_slots(
             bins_fm, gh8, jnp.zeros(N, jnp.int32), 1, Bc, quant=True,
-            int8=use_int8, oh_shift=oh_shift,
+            int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
         )[0]
         if use_rs:
             hist0 = rs_hist(hist0)  # (3, Gn, Bc) owned block, int wire
@@ -391,7 +460,12 @@ def grow_tree_rounds(
         scale3 = None
         gh8 = build_gh8(grad * mask, hess * mask, mask)  # (8, N)
         root = root_sums(gh8, ax)
-        hist0 = histogram(bins_fm, gh8, Bc)
+        if use_routed:
+            # the single-leaf kernel holds the whole table's tile
+            hist0 = hist_nat_slots(bins_fm, gh8, jnp.zeros(N, jnp.int32),
+                                   1, Bc, plan=nat_plan)[0]
+        else:
+            hist0 = histogram(bins_fm, gh8, Bc)
         if ax is not None:
             hist0 = lax.psum(hist0, ax)
     root_out = leaf_output(root[0], root[1], params)
@@ -790,7 +864,7 @@ def grow_tree_rounds(
                 sh = sh * scale3[:, None, None]
             return sh, el
 
-        if use_fused:
+        if use_fused or use_routed:
             zs = jnp.zeros(S, jnp.int32)
             if spec.efb:
                 efb_cols = [bundle.off_lo[feat_s], bundle.mfb[feat_s],
@@ -810,9 +884,22 @@ def grow_tree_rounds(
                 ] + [zs] * 5,
                 axis=1,
             ).astype(jnp.int32)  # (S, 16)
-            coh = (
-                col_s[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
-            ).astype(jnp.float32)  # (S, G)
+            if use_routed:
+                # the routing pass sees the round's split columns as a
+                # table of their own: slot s's column is its row s. One
+                # row slice per slot, not bins_fm[col_s]: XLA lowers
+                # that gather to element work over the whole matrix
+                # (7 ms a round at 2,000 x 400k, my chip run, PR 30)
+                table = jnp.concatenate([
+                    lax.dynamic_slice_in_dim(bins_fm, col_s[k], 1, axis=0)
+                    for k in range(S)])  # (S, N)
+                coh = jnp.eye(S, dtype=jnp.float32)
+            else:
+                table = bins_fm
+                coh = (
+                    col_s[:, None]
+                    == jnp.arange(G, dtype=jnp.int32)[None, :]
+                ).astype(jnp.float32)  # (S, G)
             if spec.has_cat:
                 cm_s = rec.cat_mask[sl_i].astype(jnp.int8)  # (S, B)
                 if Bc > B:  # kernel bin space is the bundle width
@@ -821,9 +908,19 @@ def grow_tree_rounds(
                 cm_s = None
             if route_only:
                 pleaf_new = route_round(
-                    bins_fm, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
+                    table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
                     cat_mask=cm_s,
                 )
+            elif use_routed:
+                pleaf_new, hslot = route_round(
+                    table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
+                    cat_mask=cm_s, with_slot=True,
+                )
+                slot_hists = hist_nat_slots(
+                    bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
+                    int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
+                )  # (S, 3, G, Bc)
+                slot_hists, elected = reduce_slots(slot_hists)
             else:
                 slot_hists, pleaf_new = hist_round(
                     bins_fm, gh8, s.pleaf, params16, coh, S, Bc,

@@ -630,6 +630,48 @@ def record_grower_rounds(widths, rounds) -> None:
         c.inc(float(n), width=str(w))
 
 
+def record_hist_schedule(sched, n_cols: int) -> None:
+    """Gauges of a rounds-grower program's histogram schedule
+    (learner/rounds.py hist_schedule), set where the fused step is
+    built: per Pallas kernel the feature blocks of one call (0: the
+    program does not call it) and the bins tile's columns as the
+    kernel multiplies them (whole loop groups); per pass width
+    (root, then the slot ladder) the kernel calls that stream the
+    rows. Nothing where no kernel runs (an XLA-formulation backend)."""
+    r = _default
+    if not r.enabled or not sched.calls:
+        return
+    from ..learner.pallas_hist import feature_groups
+
+    groups, per_group = feature_groups(n_cols)
+    whole = (1, groups * per_group)
+    slots = int(sched.calls[-1][0])  # the ladder's last width
+    per_kernel = {
+        "hist_nat_tpu": ((sched.plan.blocks, sched.plan.feat_block)
+                         if sched.routed else whole),
+        "hist_round_tpu": whole if sched.fused else (0, 0),
+        # a routed round's routing pass sees its <= slots split columns
+        "route_round_tpu": ((1, slots) if sched.routed
+                            else whole if sched.fused else (0, 0)),
+    }
+    blocks = r.gauge("lgbmtpu_hist_feature_blocks",
+                     "feature blocks (the leading grid dimension) of one "
+                     "call of a histogram or routing kernel; 0: not in "
+                     "the program", labels=("kernel",))
+    cols = r.gauge("lgbmtpu_hist_block_columns",
+                   "columns of one feature block's bins tile as the "
+                   "kernel's loop groups cover them", labels=("kernel",))
+    for kernel, (b, c) in per_kernel.items():
+        blocks.set(b, kernel=kernel)
+        cols.set(c, kernel=kernel)
+    calls = r.gauge("lgbmtpu_hist_calls_per_pass",
+                    "histogram kernel calls that stream the rows in one "
+                    "pass, by pass width (root, then the slot ladder)",
+                    labels=("width",))
+    for width, n in sched.calls:
+        calls.set(n, width=width)
+
+
 def record_label_cache(kind: str, hit: bool) -> None:
     """One lookup of a data set's label-sized residency
     (dataset.BinnedDataset.device_label / device_weight / label_stat):

@@ -136,6 +136,65 @@ def test_root_kernel_compiles_at_137_columns(one_chip, no_compile_cache):
     assert "hist_nat_tpu" in compiled.as_text()
 
 
+# ------------------------------------------------ the wide cell (PR 30)
+# epsilon-wide.train: 400,000 rows (196 row blocks) x 2,000 columns x 63
+# bins. No call holds the table's (2000, 2048) bins tile: the histogram
+# kernel runs by 6 feature blocks of 352 columns (11 loop groups), the
+# routing pass over the round's 48 split columns.
+WIDE_ROWS, WIDE_FEATURES, WIDE_BINS = 196 * 2048, 2000, 63
+
+
+@pytest.mark.parametrize("slots,int8", [
+    (8, False), (48, False), (8, True), (48, True)])
+def test_blocked_kernel_compiles_at_2000_columns(one_chip, no_compile_cache,
+                                                 slots, int8):
+    """The 48-slot bf16 call is the long one (about 45 s here; 1.7 s on
+    the int8 operands): its resident block is 48 x 3 x 352 x 63 x 4 B =
+    12.8 MB of the 64 MiB scoped limit."""
+    from lightgbm_tpu.learner.histogram import HistPlan, hist_plan
+    from lightgbm_tpu.learner.pallas_hist import hist_nat_tpu
+
+    plan = hist_plan(48, WIDE_FEATURES, WIDE_BINS, True, int8)
+    assert plan == HistPlan(48, 352, WIDE_FEATURES) and plan.blocks == 6
+    fn = jax.jit(lambda b, g, s: hist_nat_tpu(
+        b, g, s, slots, WIDE_BINS, nat_ch=3, int8=int8,
+        feat_block=plan.feat_block))
+    text = fn.lower(
+        _arg(one_chip, (WIDE_FEATURES, WIDE_ROWS), jnp.int32),
+        _arg(one_chip, (8, WIDE_ROWS), jnp.float32),
+        _arg(one_chip, (WIDE_ROWS,), jnp.int32)).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%hist_nat_tpu" in text
+    # (blocks x groups, slots x channels, 32 columns x bins)
+    kind = "s32" if int8 else "f32"
+    assert f"{kind}[66,{slots * 3},2016]" in text
+
+
+@pytest.mark.parametrize("cat", [False, True], ids=["plain", "cat"])
+def test_routing_pass_compiles_over_the_split_columns(
+        one_chip, no_compile_cache, cat):
+    """A round at width: route_round_tpu over a (48, rows) table of the
+    round's split columns, returning the rows' new leaves AND their
+    histogram slots, in one call."""
+    import re
+
+    from lightgbm_tpu.learner.histogram import route_round
+
+    slots = 48
+    args = [_arg(one_chip, (slots, WIDE_ROWS), jnp.int32),
+            _arg(one_chip, (WIDE_ROWS,), jnp.int32),
+            _arg(one_chip, (slots, 16), jnp.int32),
+            _arg(one_chip, (slots, slots), jnp.float32)]
+    if cat:
+        args.append(_arg(one_chip, (slots, WIDE_BINS), jnp.int8))
+    fn = jax.jit(lambda b, p, pr, oh, cm=None: route_round(
+        b, p, pr, oh, slots, WIDE_BINS, cat_mask=cm, with_slot=True))
+    text = fn.lower(*args).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%route_round_tpu" in text
+    assert not re.search(r"%(hist_round_tpu|hist_nat_tpu)\b", text)
+
+
 def test_rank_marks_keep_their_names_in_the_compiled_program(
         one_chip, no_compile_cache, monkeypatch):
     """The device trace finds the LambdaRank gradient by the names of
