@@ -11,7 +11,9 @@ generator, 1,000,000 x 28, num_leaves=255, max_bin=255, binary/auc, a
 1. kernel self-check: every Pallas entry the two programs below use,
    compiled by Mosaic at the full-width shapes, against the XLA
    formulation beside it in learner/histogram.py (integer paths must
-   match exactly);
+   match exactly); then the same entries at 63 bins (max_bin=63, the
+   reference's accelerator setting), where the kernels' feature loop
+   takes two columns per one-hot tile;
 2. lgb.train, default parameters (on a TPU: rounds grower, int16
    3-channel histograms, chunk-scan dispatch), 2 x the smallest chunk
    rung, then the same again to show the rounds that follow set-up
@@ -46,6 +48,9 @@ ROWS, FEATS, LEAVES, MAX_BIN = 1_000_000, 28, 255, 255
 # kernels' compile limits depend on width (columns x bins x slots), the
 # row count only sets the grid length
 CHECK_BLOCKS = 64
+# the bin count of the second kernel self-check: the kernels pair columns
+# at 33..64 bins (pallas_hist.columns_per_matmul)
+PAIR_BINS = 63
 SCORE_TOL = 1e-5
 AUC_FLOOR = 0.8  # "well above chance" after a handful of rounds
 MULTICHIP_AUC_BAND = 2e-3  # the band tests/test_tree_learner_data.py pins
@@ -404,6 +409,7 @@ def main(argv=None) -> int:
     bins = binned.device_arrays()["bins"][:, :CHECK_BLOCKS * HIST_BLK]
     t0 = time.perf_counter()
     kernel_lines = kernel_selfcheck(bins, binned.max_num_bin)
+    kernel_lines += kernel_selfcheck(bins % PAIR_BINS, PAIR_BINS)
     kernels_s = time.perf_counter() - t0
     for ln in kernel_lines:
         say("kernel self-check: " + ln)

@@ -350,8 +350,10 @@ def _oh_scratch_bytes(num_bins: int, int8: bool) -> int:
     """VMEM bytes of the kernels' persistent one-hot iota scratch
     (pallas_hist._oh_iota_shape): part of the explicit block schedule,
     so the slot-budget math must charge for it."""
-    rows = -(-num_bins // 4) if int8 else num_bins
-    return rows * HIST_BLK * 4
+    from .pallas_hist import _oh_iota_shape
+
+    rows, blk = _oh_iota_shape(num_bins, HIST_BLK, int8)
+    return rows * blk * 4
 
 
 # the fused round kernel may chunk its slot axis (each chunk re-streams
@@ -375,11 +377,12 @@ def _slot_chunks(num_slots: int, s_max: int) -> list:
 def _slot_block_bytes(nat_ch: int, num_feat: int, num_bins: int) -> int:
     """Bytes one slot takes of a histogram kernel's output block: its
     channels x the block's columns (whole feature groups,
-    pallas_hist.hist_out_block) x the bins, 4 B each."""
-    from .pallas_hist import feature_groups
+    pallas_hist.hist_out_block) x a column's lanes (its bins, or the
+    pair's stride), 4 B each."""
+    from .pallas_hist import column_stride, feature_groups
 
-    groups, per_group = feature_groups(num_feat)
-    return nat_ch * groups * per_group * num_bins * 4
+    groups, per_group = feature_groups(num_feat, num_bins)
+    return nat_ch * groups * per_group * column_stride(num_bins) * 4
 
 
 def _round_s_max(num_feat: int, num_bins: int, quant: bool,
@@ -427,12 +430,14 @@ def hist_plan(num_slots: int, num_feat: int, num_bins: int, quant: bool,
     pass still reads the bin matrix once (each slot chunk would
     re-stream it): blocks of whole FEATURE_UNROLL groups, equal, the
     widest whose min(num_slots, slot cap)-slot output block fits the
-    same budget. 2,000 columns x 64 bins, 3 channels, 48 slots: one
-    group of one slot is 3 x 32 x 64 x 4 = 24,576 B, the budget
-    12,897,484 B, so 10 groups fit 48 slots, the 63 groups go in 7
-    blocks of 9 (288 columns, 10.6 MB); at 255 bins 2 groups, 32 blocks
-    of 64 columns."""
-    from .pallas_hist import FEATURE_UNROLL
+    same budget. 2,000 columns x 33..64 bins (a column's stride in the
+    block is 64 lanes, pallas_hist.column_stride), 3 channels, 48 slots:
+    one group of one slot is 3 x 32 x 64 x 4 = 24,576 B, the budget
+    12,373,196 B (a fifth of the limit less the 128-row iota scratch of
+    a pair's one-hot tile), so 10 groups fit 48 slots, the 63 groups go
+    in 7 blocks of 9 (288 columns, 10.6 MB); at 255 bins 2 groups, 32
+    blocks of 64 columns."""
+    from .pallas_hist import FEATURE_UNROLL, column_stride
 
     nat_ch = 3 if quant else NAT_CH
     s_whole = _round_s_max(num_feat, num_bins, quant, int8)
@@ -442,7 +447,8 @@ def hist_plan(num_slots: int, num_feat: int, num_bins: int, quant: bool,
         return whole
     s_cap, budget = _round_caps(nat_ch)
     budget = max(budget - _oh_scratch_bytes(num_bins, int8), 0)
-    group = nat_ch * FEATURE_UNROLL * num_bins * 4  # one slot's, one group
+    # one slot's block of one group
+    group = nat_ch * FEATURE_UNROLL * column_stride(num_bins) * 4
     slots = min(num_slots, s_cap, budget // group)
     if slots < 1:
         return whole

@@ -48,20 +48,50 @@ from .histogram import CH, HIST_BLK, NAT_CH, VMEM_LIMIT_BYTES
 FEATURE_UNROLL = 32
 
 
-def feature_groups(F: int) -> tuple:
-    """(groups, features per group) of the kernels' feature loop; the
-    last group may run past F (its extra columns are cut off outside)."""
+# lanes of a column in the output block where two columns share one
+# one-hot tile (columns_per_matmul): half an MXU tile's 128 output lanes
+PAIR_STRIDE = 64
+
+
+def columns_per_matmul(B: int) -> int:
+    """Columns whose one-hots one matmul of the feature loop contracts:
+    two where each fills at most half of the MXU's 128 output lanes and
+    more than a quarter (32 < B <= 64: `max_bin=63`, what the reference
+    recommends on an accelerator), else one. Read from the bin count
+    alone, where the kernel is built."""
+    return 2 if PAIR_STRIDE // 2 < B <= PAIR_STRIDE else 1
+
+
+def column_stride(B: int) -> int:
+    """Lanes from one column to the next in a kernel's output block: B,
+    or PAIR_STRIDE under the pair, whose matmul lands on a whole
+    128-lane slab (the lanes past B of a column stay zero and
+    hist_out_flat drops them)."""
+    return PAIR_STRIDE if columns_per_matmul(B) == 2 else B
+
+
+def feature_groups(F: int, B: int) -> tuple:
+    """(groups, features per group) of the kernels' feature loop at B
+    bins; a group is whole matmuls (columns_per_matmul), and the last
+    group may run past F (its extra columns are cut off outside)."""
     G = -(-F // FEATURE_UNROLL)
-    return G, -(-F // G)
+    per = columns_per_matmul(B)
+    return G, -(-F // (G * per)) * per
 
 
-def hist_out_block(rows: int, F: int, B: int) -> tuple:
+def hist_out_block(rows: int, F: int, B: int, whole: bool = False) -> tuple:
     """Shape of a histogram kernel's resident output block (the whole
-    table's, or one feature block's): (rows, F*B) while the feature
-    loop is unrolled whole, else (groups, rows, per_group*B), indexed
-    by the loop's group."""
-    G, Fg = feature_groups(F)
-    return (rows, F * B) if G == 1 else (G, rows, Fg * B)
+    table's, or one feature block's): (rows, columns * stride) while the
+    feature loop is unrolled whole (`whole`: at any F, the single-leaf
+    kernel), else (groups, rows, per_group * stride), indexed by the
+    loop's group. The stride is column_stride's and the columns whole
+    matmuls: (rows, F*B) off the pair."""
+    G, Fg = feature_groups(F, B)
+    if whole:
+        per = columns_per_matmul(B)
+        G, Fg = 1, -(-F // per) * per
+    width = Fg * column_stride(B)
+    return (rows, width) if G == 1 else (G, rows, width)
 
 
 def feature_blocks(F: int, feat_block: int) -> int:
@@ -76,37 +106,51 @@ def feature_blocks(F: int, feat_block: int) -> int:
 
 
 def hist_out_flat(out: jax.Array, F: int, B: int) -> jax.Array:
-    """A kernel's output block as (rows, F*B)."""
-    if out.ndim == 2:
-        return out
-    G, rows, width = out.shape
-    return out.transpose(1, 0, 2).reshape(rows, G * width)[:, :F * B]
+    """A kernel's output block as (rows, F*B): without the columns past
+    F of the last group or matmul and, at a stride past B, without each
+    column's pad lanes."""
+    st = column_stride(B)
+    if out.ndim == 3:
+        G, rows, width = out.shape
+        out = out.transpose(1, 0, 2).reshape(rows, G * width)
+    rows, width = out.shape
+    if st != B:
+        out = out.reshape(rows, width // st, st)[:, :F, :B]
+        return out.reshape(rows, F * B)
+    return out if width == F * B else out[:, :F * B]
 
 
 def _accum_features(bins_ref, out_ref, contribution, *, F: int, B: int,
                     last=None):
-    """out[.., f*B:(f+1)*B] += contribution(bins row f) for every
-    feature f of the bins tile: the kernels' one loop over the columns,
-    unrolled whole into a 2-D block (hist_out_block; the single-leaf
-    kernels always), else by groups into a 3-D one. `last` is the
-    tile's last real row where that is not F - 1 (the ragged last
-    feature block of a blocked call, _block_last)."""
-    G, Fg = feature_groups(F)
+    """out[.., m*w:(m+1)*w] += contribution(bins rows of matmul m) for
+    every matmul of the bins tile's columns (one column and w = B
+    lanes, or a pair and 128: columns_per_matmul): the kernels' one loop
+    over the columns, unrolled whole into a 2-D block (hist_out_block;
+    the single-leaf kernels always), else by groups into a 3-D one.
+    `last` is the tile's last real row where that is not F - 1 (the
+    ragged last feature block of a blocked call, _block_last)."""
+    per = columns_per_matmul(B)
+    w = per * column_stride(B)
+    G, Fg = feature_groups(F, B)
     if len(out_ref.shape) == 2:
-        for f in range(F):
-            out_ref[:, f * B : (f + 1) * B] += contribution(
-                bins_ref[f : f + 1, :])
+        for m in range(-(-F // per)):
+            # an odd F pairs its last column with itself, into lanes
+            # that hist_out_flat drops
+            cols = [min(f, F - 1) for f in range(m * per, (m + 1) * per)]
+            out_ref[:, m * w : (m + 1) * w] += contribution(
+                *(bins_ref[f : f + 1, :] for f in cols))
         return
     if last is None:
         last = F - 1
 
     def group(j, carry):
-        for f in range(Fg):
+        for m in range(Fg // per):
             # past the last column the group re-reads it into columns
             # that hist_out_flat drops
-            row = jnp.minimum(j * Fg + f, last)
-            out_ref[j, :, f * B : (f + 1) * B] += contribution(
-                bins_ref[pl.ds(row, 1), :])
+            rows = [jnp.minimum(j * Fg + f, last)
+                    for f in range(m * per, (m + 1) * per)]
+            out_ref[j, :, m * w : (m + 1) * w] += contribution(
+                *(bins_ref[pl.ds(row, 1), :] for row in rows))
         return carry
 
     lax.fori_loop(0, G, group, 0)
@@ -121,23 +165,37 @@ def _block_last(F: int, feat_block: int):
 
 def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
                    iota_bT=None, last=None):
-    """Shared accumulate loop: one NT matmul per feature, the one-hot
-    built TRANSPOSED (B, blk) directly from the bins tile's native
-    (F, blk) layout — the former per-block (blk, F) int32 transpose
-    cost ~2 ms/pass at 1M rows and serialized against the int8 MXU
-    stream. Grouping features into wider matmuls was tried and measured
-    SLOWER (lane-axis concat of one-hots cost more than the larger
-    matmul saved: 4.75 -> 3.71 trees/s end to end; 3D->2D reshapes onto
-    the lane axis don't lower in Mosaic at all).
+    """Shared accumulate loop: one NT matmul per column, or per pair of
+    columns (columns_per_matmul), the one-hot built TRANSPOSED (rows,
+    blk) directly from the bins tile's native (F, blk) layout — the
+    former per-block (blk, F) int32 transpose cost ~2 ms/pass at 1M rows
+    and serialized against the int8 MXU stream.
 
-    `iota_bT` passes the (B, blk) row-iota from a VMEM scratch buffer
-    written once at grid step 0 (see _oh_iota_init) so the constant is
-    block-resident instead of re-materialized every step x feature."""
+    The pair: at 33..64 bins a column's product (M, B) fills at most
+    half of an MXU tile's 128 output lanes and costs a whole tile, so
+    two columns share one (128, blk) one-hot tile, the second on
+    SUBLANES 64..127 (_tile_key: one select, one compare), and one
+    matmul fills all 128 lanes of a lane-aligned slab of the output
+    block. An earlier grouping (PR 6) concatenated 255-bin one-hots on
+    the LANE axis, where every column already fills 255 of 256 lanes:
+    it had a relayout to pay and no empty lanes to win, and was slower.
+    Here a concat of two (64, blk) one-hots on the sublane axis was
+    measured too: 3.9% slower per tree than the select (PERF.md section
+    6, PR 31). What the pair gives: PERF_LEDGER.jsonl, PR 31,
+    epsilon-wide.train.
+
+    `iota_bT` passes the (rows, blk) row-iota from a VMEM scratch
+    buffer written once at grid step 0 (see _oh_iota_init) so the
+    constant is block-resident instead of re-materialized every step x
+    feature."""
     if iota_bT is None:
-        iota_bT = lax.broadcasted_iota(jnp.int32, (B, blk), 0)
+        iota_bT = lax.broadcasted_iota(
+            jnp.int32, _oh_iota_shape(B, blk, False), 0)
+    # once, not per matmul
+    lower = _lower_rows(iota_bT.shape, columns_per_matmul(B))
 
-    def contribution(bins_row):
-        ohT = (bins_row == iota_bT).astype(dt)  # (B, blk)
+    def contribution(*bins_rows):
+        ohT = (_tile_key(bins_rows, lower) == iota_bT).astype(dt)
         return lax.dot_general(
             lhs, ohT, (((1,), (1,)), ((), ())),
             preferred_element_type=acc_t,
@@ -146,14 +204,38 @@ def _accum_hist_nt(bins_ref, lhs, out_ref, *, F, B, blk, dt, acc_t,
     _accum_features(bins_ref, out_ref, contribution, F=F, B=B, last=last)
 
 
+def _lower_rows(shape: tuple, columns: int):
+    """Mask of the rows of a pair's one-hot tile (or of its packed byte
+    iota), of `shape`, that are the first column's: the lower half.
+    None where the tile is one column's."""
+    if columns == 1:
+        return None
+    return lax.broadcasted_iota(jnp.int32, shape, 0) < shape[0] // 2
+
+
+def _tile_key(bins_rows, lower, rep: int = 1):
+    """What each row of one matmul's one-hot tile compares its iota
+    with, times `rep`: the column's (1, blk) bins, or under the pair
+    the first column's over the `lower` rows (_lower_rows) and the
+    second's + PAIR_STRIDE over the upper (bins < B <= PAIR_STRIDE: the
+    pad rows of either half match nothing)."""
+    keys = [row if k == 0 else row + k * PAIR_STRIDE
+            for k, row in enumerate(bins_rows)]
+    if rep != 1:
+        keys = [key * rep for key in keys]
+    return keys[0] if len(keys) == 1 else jnp.where(lower, *keys)
+
+
 def _oh_iota_shape(B: int, blk: int, int8: bool) -> tuple:
     """Shape of the persistent one-hot iota scratch (one VMEM buffer
     per kernel invocation, written at grid step 0 and reused by every
-    later step): the compare path persists the (B, blk) row iota, the
-    byte-SWAR path the packed (ceil(B/4), blk) byte iota."""
+    later step): the compare path persists the (rows, blk) row iota of
+    one matmul's one-hot tile (B rows, or the pair's 128), the
+    byte-SWAR path the packed (ceil(rows/4), blk) byte iota."""
+    rows = columns_per_matmul(B) * column_stride(B)
     if int8:
-        return (-(-B // 4), blk)
-    return (B, blk)
+        return (-(-rows // 4), blk)
+    return (rows, blk)
 
 
 def _oh_iota_init(shape: tuple, int8: bool):
@@ -222,8 +304,8 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
         ).astype(jnp.int8)
         # SWAR one-hot (see _swar_onehot): 1.65x the compare+cast rate
         # on the VPU-bound end; sums come out scaled by the byte value
-        def contribution(bins_row):
-            oh = _swar_onehot(bins_row, B, blk, oh_shift, iota_p=iota)
+        def contribution(*bins_rows):
+            oh = _swar_onehot(bins_rows, B, blk, oh_shift, iota_p=iota)
             return lax.dot_general(
                 W, oh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32,
@@ -308,7 +390,7 @@ def hist_nat_tpu(
     else:
         # always by groups, also where a block is one group
         groups = feat_block // FEATURE_UNROLL
-        block = (groups, S * nat_ch, FEATURE_UNROLL * B)
+        block = (groups, S * nat_ch, FEATURE_UNROLL * column_stride(B))
         out_shape = (nfb * groups,) + block[1:]
         grid = (nfb, nb)
 
@@ -347,8 +429,11 @@ _SWAR_M7 = 0x7F7F7F7F
 _SWAR_M8 = -2139062144  # 0x80808080 as i32
 
 
-def _swar_onehot(bins_row, B: int, blk: int, oh_shift: int, iota_p=None):
-    """(1, blk) i32 bin values -> (B, blk) s8 one-hot, 4 bins per i32.
+def _swar_onehot(bins_rows: tuple, B: int, blk: int, oh_shift: int,
+                 iota_p=None):
+    """(1, blk) i32 bin values -> (B, blk) s8 one-hot, 4 bins per i32;
+    two such rows (the pair, columns_per_matmul) -> their (128, blk)
+    tile, the second column's one-hot on rows 64..127.
 
     The straight `bins == iota` compare + s8 cast costs ~4.4 ms per
     1M x 28 x 256 pass — the VPU floor of every histogram pass (i32
@@ -375,19 +460,23 @@ def _swar_onehot(bins_row, B: int, blk: int, oh_shift: int, iota_p=None):
 
     `iota_p` passes the packed byte iota from a VMEM scratch written at
     grid step 0 (_oh_iota_init) instead of re-materializing the
-    constant every step x feature."""
-    B4 = -(-B // 4)  # pad to a byte multiple; extra rows sliced off
+    constant every step x feature; a scratch sized for a pair serves a
+    single row by its first rows."""
+    rows = B if len(bins_rows) == 1 else len(bins_rows) * PAIR_STRIDE
+    B4 = -(-rows // 4)  # pad to a byte multiple; extra rows sliced off
     if iota_p is None:
-        bg = lax.broadcasted_iota(jnp.int32, (B4, blk), 0)
-        iota_p = bg * (4 * _SWAR_REP) + 0x03020100
-    t = (bins_row * _SWAR_REP) ^ iota_p
+        iota_p = _oh_iota_init((B4, blk), True)
+    elif iota_p.shape[0] != B4:
+        iota_p = iota_p[:B4]
+    lower = _lower_rows(iota_p.shape, len(bins_rows))
+    t = _tile_key(bins_rows, lower, _SWAR_REP) ^ iota_p
     z = ~(((t & _SWAR_M7) + _SWAR_M7) | t) & _SWAR_M8
     if oh_shift:
         # arithmetic >> smears the top byte's sign bit; the mask keeps
         # only the intended per-byte marker bit
         z = (z >> oh_shift) & (_SWAR_REP * (0x80 >> oh_shift))
     oh = pltpu.bitcast(z, jnp.int8)
-    return oh if 4 * B4 == B else oh[:B, :]
+    return oh if 4 * B4 == rows else oh[:rows, :]
 
 
 def _round_kernel(
@@ -496,7 +585,7 @@ def _round_kernel(
         is_cat_s = params_ref[:, 10:11] != 0  # (S, 1)
         fb_own = jnp.sum(jnp.where(memb, fb, 0.0), axis=0,
                          keepdims=True)  # (1, blk) f32 integer-valued
-        ohfb = _swar_onehot(fb_own.astype(jnp.int32), B, blk, 7,
+        ohfb = _swar_onehot((fb_own.astype(jnp.int32),), B, blk, 7,
                             iota_p=iota_swar)  # 0/1 s8
         hits = lax.dot_general(
             cat_ref[...], ohfb, (((1,), (0,)), ((), ())),
@@ -531,8 +620,9 @@ def _round_kernel(
         W = (side_i[:, None, :] * g32[None, :, :]).reshape(
             S * nat_ch, blk).astype(jnp.int8)
 
-        def contribution(bins_row):
-            oh = _swar_onehot(bins_row, B, blk, oh_shift, iota_p=iota_swar)
+        def contribution(*bins_rows):
+            oh = _swar_onehot(bins_rows, B, blk, oh_shift,
+                              iota_p=iota_swar)
             return lax.dot_general(
                 W, oh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32,
@@ -811,6 +901,7 @@ def hist_tpu(
     assert gh8.shape == (CH, N), gh8.shape
     B = num_bins
     nb = N // blk
+    block = hist_out_block(CH, F, B, whole=True)
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, F=F, B=B, blk=blk),
@@ -819,9 +910,10 @@ def hist_tpu(
             pl.BlockSpec((F, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
             pl.BlockSpec((CH, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((CH, F * B), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((CH, F * B), jnp.float32),
+        out_specs=pl.BlockSpec(block, lambda i: (0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(block, jnp.float32),
         compiler_params=_ARBITRARY,
         interpret=interpret,
     )(bins_fm, gh8)
-    return out.reshape(CH, F, B)
+    return hist_out_flat(out, F, B).reshape(CH, F, B)

@@ -119,6 +119,7 @@ class HistSchedule(NamedTuple):
     shapes (hist_schedule)."""
 
     plan: HistPlan  # at the program's full slot count
+    num_bins: int  # of a device column: what the kernels' one-hots span
     use_int8: bool
     oh_shift: int  # SWAR one-hot scale of the int8 kernels
     # one of three rounds: `fused` (hist_round_tpu holds the whole
@@ -170,8 +171,8 @@ def hist_schedule(spec: GrowerSpec, n_rows: int, n_cols: int
 
             calls = ((ROOT_LABEL, 1),) + tuple(
                 (str(w), n_calls(w)) for w in widths)
-        return HistSchedule(plan, use_int8, oh_shift or 0, fused, routed,
-                            calls)
+        return HistSchedule(plan, Bc, use_int8, oh_shift or 0, fused,
+                            routed, calls)
 
 
 def spends_budget(n_cand: jax.Array, budget: jax.Array, slots: int

@@ -635,24 +635,30 @@ def record_hist_schedule(sched, n_cols: int) -> None:
     (learner/rounds.py hist_schedule), set where the fused step is
     built: per Pallas kernel the feature blocks of one call (0: the
     program does not call it) and the bins tile's columns as the
-    kernel multiplies them (whole loop groups); per pass width
-    (root, then the slot ladder) the kernel calls that stream the
-    rows. Nothing where no kernel runs (an XLA-formulation backend)."""
+    kernel multiplies them (whole loop groups) and the columns one
+    matmul of its feature loop contracts (2: the pair at 33..64 bins);
+    per pass width (root, then the slot ladder) the kernel calls that
+    stream the rows. Nothing where no kernel runs (an XLA-formulation
+    backend)."""
     r = _default
     if not r.enabled or not sched.calls:
         return
-    from ..learner.pallas_hist import feature_groups
+    from ..learner.pallas_hist import columns_per_matmul, feature_groups
 
-    groups, per_group = feature_groups(n_cols)
-    whole = (1, groups * per_group)
+    groups, per_group = feature_groups(n_cols, sched.num_bins)
+    per = columns_per_matmul(sched.num_bins)
+    whole = (1, groups * per_group, per)
+    absent = (0, 0, 0)
     slots = int(sched.calls[-1][0])  # the ladder's last width
     per_kernel = {
-        "hist_nat_tpu": ((sched.plan.blocks, sched.plan.feat_block)
+        "hist_nat_tpu": ((sched.plan.blocks, sched.plan.feat_block, per)
                          if sched.routed else whole),
-        "hist_round_tpu": whole if sched.fused else (0, 0),
-        # a routed round's routing pass sees its <= slots split columns
-        "route_round_tpu": ((1, slots) if sched.routed
-                            else whole if sched.fused else (0, 0)),
+        "hist_round_tpu": whole if sched.fused else absent,
+        # a routed round's routing pass sees its <= slots split columns;
+        # no routing pass builds a histogram one-hot
+        "route_round_tpu": ((1, slots, 0) if sched.routed
+                            else whole[:2] + (0,) if sched.fused
+                            else absent),
     }
     blocks = r.gauge("lgbmtpu_hist_feature_blocks",
                      "feature blocks (the leading grid dimension) of one "
@@ -661,9 +667,14 @@ def record_hist_schedule(sched, n_cols: int) -> None:
     cols = r.gauge("lgbmtpu_hist_block_columns",
                    "columns of one feature block's bins tile as the "
                    "kernel's loop groups cover them", labels=("kernel",))
-    for kernel, (b, c) in per_kernel.items():
+    per_matmul = r.gauge("lgbmtpu_hist_columns_per_matmul",
+                         "columns whose one-hots share one MXU tile in a "
+                         "histogram kernel's feature loop (2 at 33..64 "
+                         "bins); 0: no histograms", labels=("kernel",))
+    for kernel, (b, c, m) in per_kernel.items():
         blocks.set(b, kernel=kernel)
         cols.set(c, kernel=kernel)
+        per_matmul.set(m, kernel=kernel)
     calls = r.gauge("lgbmtpu_hist_calls_per_pass",
                     "histogram kernel calls that stream the rows in one "
                     "pass, by pass width (root, then the slot ladder)",

@@ -70,18 +70,21 @@ def test_slot_chunks_at_137_columns_are_two_equal_calls():
         [(0, 8)], [(0, 16)], [(0, 32)], [(0, 48)]]
 
 
-@pytest.mark.parametrize("features,slots,int8", [
-    (FEATURES, 8, False), (FEATURES, 16, False), (FEATURES, 24, False),
-    (FEATURES, 24, True),
+@pytest.mark.parametrize("features,slots,int8,bins", [
+    (FEATURES, 8, False, BINS), (FEATURES, 16, False, BINS),
+    (FEATURES, 24, False, BINS), (FEATURES, 24, True, BINS),
     # the Higgs cells' width: the ladder's 16-slot rung, both MXU types
-    (28, 16, False), (28, 16, True),
+    (28, 16, False, BINS), (28, 16, True, BINS),
+    # max_bin=63 under one bins tile: the fused round with two columns
+    # per one-hot tile (PR 31), unrolled whole and by loop groups
+    (28, 48, False, 63), (28, 48, True, 63), (FEATURES, 48, False, 63),
 ])
 def test_round_kernel_compiles(one_chip, no_compile_cache,
-                                              features, slots, int8):
+                               features, slots, int8, bins):
     from lightgbm_tpu.learner.pallas_hist import hist_round_tpu
 
     fn = jax.jit(lambda b, g, p, pr, oh: hist_round_tpu(
-        b, g, p, pr, oh, slots, BINS, 3, int8=int8, oh_shift=0))
+        b, g, p, pr, oh, slots, bins, 3, int8=int8, oh_shift=0))
     compiled = fn.lower(
         _arg(one_chip, (features, ROWS), jnp.int32),
         _arg(one_chip, (8, ROWS), jnp.float32),
@@ -139,8 +142,10 @@ def test_root_kernel_compiles_at_137_columns(one_chip, no_compile_cache):
 # ------------------------------------------------ the wide cell (PR 30)
 # epsilon-wide.train: 400,000 rows (196 row blocks) x 2,000 columns x 63
 # bins. No call holds the table's (2000, 2048) bins tile: the histogram
-# kernel runs by 6 feature blocks of 352 columns (11 loop groups), the
-# routing pass over the round's 48 split columns.
+# kernel runs by 7 feature blocks of 288 columns (9 loop groups of 16
+# pairs: two 63-bin columns per one-hot tile, PR 31; the int8 operands'
+# smaller scratch leaves room for 6 blocks of 352), the routing pass
+# over the round's 48 split columns.
 WIDE_ROWS, WIDE_FEATURES, WIDE_BINS = 196 * 2048, 2000, 63
 
 
@@ -148,14 +153,17 @@ WIDE_ROWS, WIDE_FEATURES, WIDE_BINS = 196 * 2048, 2000, 63
     (8, False), (48, False), (8, True), (48, True)])
 def test_blocked_kernel_compiles_at_2000_columns(one_chip, no_compile_cache,
                                                  slots, int8):
-    """The 48-slot bf16 call is the long one (about 45 s here; 1.7 s on
-    the int8 operands): its resident block is 48 x 3 x 352 x 63 x 4 B =
-    12.8 MB of the 64 MiB scoped limit."""
+    """The 48-slot bf16 call is the long one (5.5 s here; 43-50 s
+    before PR 31, when every accumulate landed at a multiple of 63
+    lanes and not on a whole 128-lane slab): its resident block is 48 x
+    3 x 288 x 64 x 4 B = 10.6 MB of the 64 MiB scoped limit."""
     from lightgbm_tpu.learner.histogram import HistPlan, hist_plan
     from lightgbm_tpu.learner.pallas_hist import hist_nat_tpu
 
     plan = hist_plan(48, WIDE_FEATURES, WIDE_BINS, True, int8)
-    assert plan == HistPlan(48, 352, WIDE_FEATURES) and plan.blocks == 6
+    block = 352 if int8 else 288
+    assert plan == HistPlan(48, block, WIDE_FEATURES)
+    assert plan.blocks == -(-WIDE_FEATURES // block)
     fn = jax.jit(lambda b, g, s: hist_nat_tpu(
         b, g, s, slots, WIDE_BINS, nat_ch=3, int8=int8,
         feat_block=plan.feat_block))
@@ -165,9 +173,11 @@ def test_blocked_kernel_compiles_at_2000_columns(one_chip, no_compile_cache,
         _arg(one_chip, (WIDE_ROWS,), jnp.int32)).compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert "%hist_nat_tpu" in text
-    # (blocks x groups, slots x channels, 32 columns x bins)
+    # (blocks x groups, slots x channels, 32 columns x 64 lanes)
     kind = "s32" if int8 else "f32"
-    assert f"{kind}[66,{slots * 3},2016]" in text
+    groups = plan.blocks * block // 32
+    assert groups == (66 if int8 else 63)
+    assert f"{kind}[{groups},{slots * 3},2048]" in text
 
 
 @pytest.mark.parametrize("cat", [False, True], ids=["plain", "cat"])

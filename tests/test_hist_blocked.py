@@ -88,16 +88,138 @@ def test_blocked_hist_nat_equals_the_xla_formulation(interp, layout, bins):
             np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-3)
 
 
+def _int_valued(build, rs, N):
+    """Gradient channels whose sums are exact in every layout (small
+    integers: exact in bf16, so the bf16x2 split's low halves are 0)."""
+    g = jnp.asarray(rs.randint(-100, 100, N).astype(np.float32))
+    h = jnp.asarray(rs.randint(0, 100, N).astype(np.float32))
+    return build(g, h, jnp.ones(N, jnp.float32))
+
+
+def _pallas_out_shapes(fn, *args):
+    """Output shapes of the pallas_call equations `fn` traces to."""
+    shapes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                shapes.extend(v.aval.shape for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
+@pytest.mark.parametrize("bins", [33, 63, 64])
+@pytest.mark.parametrize("layout", ["int16", "int8", "bf16x2"])
+def test_paired_kernels_equal_the_xla_formulation(interp, layout, bins):
+    """33..64 bins: two columns per one-hot tile, 64 lanes a column in
+    the output block. 75 columns (odd): whole (three loop groups of 26,
+    the last pairing column 74 with itself), by blocks of 64 (the
+    second ragged: 11 columns) and of 32 (one-group blocks); 7 columns:
+    the loop unrolled whole into a 2-D block, the single-leaf kernel
+    too. Integer-valued channels, so every layout is bit for bit."""
+    from lightgbm_tpu.learner.histogram import _hist_fallback, histogram
+    from lightgbm_tpu.learner.pallas_hist import hist_nat_tpu
+
+    rs = np.random.RandomState(bins)
+    N, S = 2 * HIST_BLK, 6
+    quant = layout != "bf16x2"
+    kw = dict(quant=quant, int8=layout == "int8")
+    slot = jnp.asarray(rs.randint(0, S + 1, N).astype(np.int32))
+    gh8 = _int_valued(build_gh8 if layout == "bf16x2" else build_gh8_quant,
+                      rs, N)
+    for F, plans in ((75, (None, HistPlan(4, 64, 75), HistPlan(6, 32, 75))),
+                     (7, (None,))):
+        table = jnp.asarray(rs.randint(0, bins, (F, N)).astype(np.int32))
+        want = np.asarray(_hist_nat_fallback(table, gh8, slot, S, bins,
+                                             quant=quant))
+        for plan in plans:
+            got = hist_nat_slots(table, gh8, slot, S, bins, plan=plan, **kw)
+            np.testing.assert_array_equal(np.asarray(got), want)
+    one = _int_valued(build_gh8, rs, N)  # the single-leaf kernel's
+    np.testing.assert_array_equal(
+        np.asarray(histogram(table, one, bins)),
+        np.asarray(_hist_fallback(table, one, bins)))
+    # the blocks themselves: 128 lanes a pair, whole groups of 32
+    nat = lambda F, fb: _pallas_out_shapes(  # noqa: E731
+        lambda b, g, s: hist_nat_tpu(b, g, s, S, bins, nat_ch=3,
+                                     interpret=True, feat_block=fb),
+        jnp.zeros((F, N), jnp.int32), gh8, slot)
+    assert nat(75, 0) == [(3, 18, 26 * 64)]
+    assert nat(75, 32) == [(3, 18, 32 * 64)]
+    assert nat(7, 0) == [(18, 8 * 64)]
+
+
+@pytest.mark.parametrize("bins", [15, 32, 65, 255])
+def test_other_bin_counts_keep_one_column_per_matmul(bins):
+    """<= 32 and > 64 bins: a column's stride in the output block is
+    its bins and the blocks have the shapes they had."""
+    from lightgbm_tpu.learner import pallas_hist as ph
+
+    assert ph.columns_per_matmul(bins) == 1
+    assert ph.column_stride(bins) == bins
+    assert ph.feature_groups(33, bins) == (2, 17)
+    assert ph.hist_out_block(24, 28, bins) == (24, 28 * bins)
+    assert ph.hist_out_block(24, 137, bins) == (5, 24, 28 * bins)
+    assert ph.hist_out_block(8, 137, bins, whole=True) == (8, 137 * bins)
+    assert ph._oh_iota_shape(bins, HIST_BLK, False) == (bins, HIST_BLK)
+    assert ph._oh_iota_shape(bins, HIST_BLK, True) == (-(-bins // 4),
+                                                       HIST_BLK)
+    N = HIST_BLK
+    shapes = _pallas_out_shapes(
+        lambda b, g, s: ph.hist_nat_tpu(b, g, s, 4, bins, nat_ch=3,
+                                        interpret=True, feat_block=32),
+        jnp.zeros((70, N), jnp.int32), jnp.zeros((8, N), jnp.float32),
+        jnp.zeros(N, jnp.int32))
+    assert shapes == [(3, 12, 32 * bins)]
+
+
+@pytest.mark.parametrize("bins", [33, 63, 64])
+def test_the_pair_rule_and_hist_out_flat_of_a_stride_64_block(bins):
+    from lightgbm_tpu.learner import pallas_hist as ph
+
+    assert ph.columns_per_matmul(bins) == 2 and ph.column_stride(bins) == 64
+    # groups of whole pairs; an odd table's last pair runs past it
+    assert ph.feature_groups(33, bins) == (2, 18)
+    assert ph.feature_groups(137, bins) == (5, 28)
+    assert ph.hist_out_block(24, 7, bins) == (24, 8 * 64)
+    assert ph.hist_out_block(24, 137, bins) == (5, 24, 28 * 64)
+    assert ph.hist_out_block(8, 137, bins, whole=True) == (8, 138 * 64)
+    assert ph._oh_iota_shape(bins, HIST_BLK, False) == (128, HIST_BLK)
+    assert ph._oh_iota_shape(bins, HIST_BLK, True) == (32, HIST_BLK)
+    # a (2 groups, 3 rows, 4 columns x 64 lanes) block of a 7-column
+    # table: cell (row, column, bin) holds its own index, pad lanes and
+    # the eighth column -1
+    G, rows, Fg, F = 2, 3, 4, 7
+    block = -np.ones((G, rows, Fg, 64), np.float32)
+    want = np.arange(rows * F * bins, dtype=np.float32).reshape(rows, F, bins)
+    for f in range(F):
+        block[f // Fg, :, f % Fg, :bins] = want[:, f]
+    got = ph.hist_out_flat(jnp.asarray(block.reshape(G, rows, Fg * 64)),
+                           F, bins)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  want.reshape(rows, F * bins))
+    got = ph.hist_out_flat(
+        jnp.asarray(block.transpose(1, 0, 2, 3).reshape(rows, G * Fg * 64)),
+        F, bins)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  want.reshape(rows, F * bins))
+
+
 # --------------------------------------------- (b) the round at width
+@pytest.mark.parametrize("B", [32, 63])
 @pytest.mark.parametrize("variant", ["plain", "efb", "cat"])
 @pytest.mark.parametrize("layout", ["bf16x2", "int16", "int8"])
-def test_routed_round_is_the_fused_round(interp, layout, variant):
+def test_routed_round_is_the_fused_round(interp, layout, variant, B):
     """Routing over the round's split columns alone (a table of S rows,
     the identity for column one-hot), then the blocked pass keyed by the
     slots it returns, against the fused kernel holding all 100 columns:
     the same row->leaf vector and the same histograms, also where the
-    split columns (EFB-encoded, categorical) lie past the first block."""
-    S, B, F = 8, 32, 100
+    split columns (EFB-encoded, categorical) lie past the first block,
+    one column per matmul (32 bins) and two (63)."""
+    S, F = 8, 100
     table, gh8, pleaf, params, coh, cat_mask = _round_inputs(
         layout, F, variant, S, B)
     quant = layout != "bf16x2"
@@ -205,26 +327,37 @@ def test_models_at_width_equal_the_single_block_formulation(
 def test_hist_plan_hand_numbers():
     """The slot-budget functions at the benchmark's shapes. Budget: a
     fifth of the 64 MiB scoped limit, 13,421,772 B, less the one-hot
-    iota scratch (bins x 2048 x 4 B on the compare path)."""
-    budget64 = 13_421_772 - 64 * 2048 * 4
-    assert budget64 == 12_897_484
+    iota scratch (the rows of one matmul's one-hot tile x 2048 x 4 B on
+    the compare path: a column's bins, or 128 rows at 33..64 bins, where
+    two columns share the tile)."""
+    budget = 13_421_772 - 128 * 2048 * 4
+    assert budget == 12_373_196
     # 2,000 x 64, 3 channels. Whole table: one slot's block is 63
-    # groups x 32 columns x 64 bins x 3 x 4 B = 1,548,288 B -> 8 slots a
-    # call, 4 chunks = 32 < 48, and the bins tile is 16.4 MB: blocked.
-    assert _round_s_max(2000, 64, True, False) == budget64 // 1_548_288 == 8
+    # groups x 32 columns x 64 lanes x 3 x 4 B = 1,548,288 B -> 7 slots
+    # a call, 4 chunks = 28 < 48, and the bins tile is 16.4 MB: blocked.
+    assert _round_s_max(2000, 64, True, False) == budget // 1_548_288 == 7
     # One 32-column group of one slot: 3 x 32 x 64 x 4 = 24,576 B; 48
     # slots x 10 groups = 11,796,480 B fit, 11 do not: 63 groups go in
     # 7 equal blocks of 9 groups = 288 columns (10.6 MB resident)
-    assert budget64 // (48 * 24_576) == 10
+    assert budget // (48 * 24_576) == 10
     assert hist_plan(48, 2000, 64, True) == HistPlan(48, 288, 2000)
     assert hist_plan(48, 2000, 64, True).blocks == 7
-    # 63 bins (what max_bin=63 gives): 24,192 B a group and slot, 11
-    # groups fit, 6 blocks of 11 = 352 columns; the int8 path's iota
-    # scratch is a quarter, the plan the same
-    assert hist_plan(48, 2000, 63, True) == HistPlan(48, 352, 2000)
+    # 63 bins (what max_bin=63 gives) and 33: the same plan, because a
+    # column takes 64 lanes of the block at any of 33..64 bins; 7 x 288
+    # = 2,016 columns multiplied
+    for bins in (33, 63):
+        assert _round_s_max(2000, bins, True, False) == 7
+        assert hist_plan(48, 2000, bins, True) == HistPlan(48, 288, 2000)
+    # the int8 path's iota scratch is a quarter (32 packed rows,
+    # 262,144 B): 13,159,628 // (48 x 24,576) = 11 groups fit, 6 blocks
+    # of 11 = 352 columns
+    assert (13_421_772 - 32 * 2048 * 4) // (48 * 24_576) == 11
     assert hist_plan(48, 2000, 63, True, True) == HistPlan(48, 352, 2000)
+    # 32 bins, one column per matmul: 12,288 B a group and slot, 22
+    # groups would fit, the bins tile holds 16: 4 blocks of 512 columns
+    assert hist_plan(48, 2000, 32, True) == HistPlan(48, 512, 2000)
     # the bf16x2 split (5 channels): 32 slots a call at most, so 48
-    # slots are 24 + 24 over blocks sized for 32: 12,897,484 // (32 x
+    # slots are 24 + 24 over blocks sized for 32: 12,373,196 // (32 x
     # 40,960) = 9 groups
     p = hist_plan(48, 2000, 64, False)
     assert p == HistPlan(32, 288, 2000)
@@ -260,11 +393,11 @@ def _spec(**kw):
 def test_hist_schedule_of_the_cells(interp):
     """What rounds.hist_schedule resolves the benchmark's four shapes
     to: one feature block and the fused kernel at 28 and 137 columns,
-    the routed round by six blocks at 2,000 x 63, one kernel call a
+    the routed round by seven blocks at 2,000 x 63, one kernel call a
     pass everywhere but the rank cell's 32- and 48-slot passes."""
     wide = rounds_mod.hist_schedule(_spec(), 196 * HIST_BLK, 2000)
-    assert wide.routed and not wide.fused
-    assert wide.plan == HistPlan(48, 352, 2000) and wide.plan.blocks == 6
+    assert wide.routed and not wide.fused and wide.num_bins == 63
+    assert wide.plan == HistPlan(48, 288, 2000) and wide.plan.blocks == 7
     assert wide.calls == (("root", 1), ("8", 1), ("16", 1), ("32", 1),
                           ("48", 1))
     higgs = rounds_mod.hist_schedule(_spec(num_bins=255), 512 * HIST_BLK, 28)
@@ -284,7 +417,9 @@ def test_no_kernel_no_calls(monkeypatch):
 
 
 # ------------------------------------- (f) gauges, span, warning text
-def test_gauges_and_span_of_a_wide_program(interp, narrow_tile):
+@pytest.mark.parametrize("max_bin,per_matmul", [(15, 1), (63, 2)])
+def test_gauges_and_span_of_a_wide_program(interp, narrow_tile, max_bin,
+                                           per_matmul):
     from lightgbm_tpu import timer
 
     seen = []
@@ -295,7 +430,8 @@ def test_gauges_and_span_of_a_wide_program(interp, narrow_tile):
         X = rs.randn(HIST_BLK, 70).astype(np.float32)
         ds = lgb.Dataset(X, label=X[:, 3] + X[:, 66], free_raw_data=False)
         lgb.train({"objective": "regression", "verbosity": -1,
-                   "num_leaves": 15, "max_bin": 15, "min_data_in_leaf": 5,
+                   "num_leaves": 15, "max_bin": max_bin,
+                   "min_data_in_leaf": 5,
                    "tpu_growth_mode": "rounds", "tpu_hist_dtype": "int16"},
                   ds, num_boost_round=2)
     finally:
@@ -312,6 +448,12 @@ def test_gauges_and_span_of_a_wide_program(interp, narrow_tile):
     calls = snap["lgbmtpu_hist_calls_per_pass"]
     assert calls['{width="root"}'] == calls['{width="8"}'] \
         == calls['{width="14"}'] == 1
+    # two columns per one-hot tile at 33..64 bins; the routing pass
+    # builds no histogram
+    per = snap["lgbmtpu_hist_columns_per_matmul"]
+    assert per['{kernel="hist_nat_tpu"}'] == per_matmul
+    assert per['{kernel="hist_round_tpu"}'] == 0
+    assert per['{kernel="route_round_tpu"}'] == 0
 
 
 def test_gate_warning_names_the_formulation_that_runs(monkeypatch):

@@ -464,7 +464,7 @@ def test_swar_onehot_unpack_ordering():
            == np.asarray(bins_row)[0][None, :]).astype(np.int8)
     for oh_shift, marker in ((0, -128), (4, 8), (7, 1)):
         def kernel(bins_ref, out_ref, oh_shift=oh_shift):
-            out_ref[...] = _swar_onehot(bins_ref[...], B, blk, oh_shift)
+            out_ref[...] = _swar_onehot((bins_ref[...],), B, blk, oh_shift)
 
         oh = pl.pallas_call(
             kernel,
@@ -568,7 +568,8 @@ def _pallas_calls(closed) -> dict:
 # ------------------- the feature loop past FEATURE_UNROLL columns (PR 26)
 @pytest.fixture(scope="module")
 def wide():
-    """37 columns: two loop groups of 19, one column past the end."""
+    """37 columns at 64 bins: two loop groups of 20 (whole pairs), three
+    columns past the end."""
     rs = np.random.RandomState(7)
     N, F, B = 2 * HIST_BLK, 37, 64
     bins = jnp.asarray(rs.randint(0, B, (F, N)).astype(np.int32))
@@ -580,8 +581,10 @@ def test_feature_groups_and_slot_chunks():
     from lightgbm_tpu.learner.pallas_hist import (feature_groups,
                                                   hist_out_block)
 
-    assert feature_groups(28) == (1, 28) and feature_groups(32) == (1, 32)
-    assert feature_groups(33) == (2, 17) and feature_groups(137) == (5, 28)
+    assert feature_groups(28, 255) == (1, 28)
+    assert feature_groups(32, 255) == (1, 32)
+    assert feature_groups(33, 255) == (2, 17)
+    assert feature_groups(137, 255) == (5, 28)
     assert hist_out_block(24, 28, 255) == (24, 28 * 255)
     assert hist_out_block(24, 137, 255) == (5, 24, 28 * 255)
     # one call while the slots fit (every cell before the 137-column one)
