@@ -941,7 +941,7 @@ class Booster:
             gb.device_trees.append((arrays, None))
             k = mi % K
             for ss in [gb.train] + gb.valids:
-                dev = gb.dev if ss is gb.train else ss.dataset.device_arrays()
+                dev = gb._dev_of(ss.dataset)
                 if t.num_leaves > 1:
                     leaf = gb._traverse(arrays, dev["bins"], dev["nan_bin"], dev.get("bundle"))
                     ss.score = ss.score.at[k].add(arrays.leaf_value[leaf])

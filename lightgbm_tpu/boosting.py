@@ -396,9 +396,10 @@ class GBDT:
                 )
             self._mesh = make_mesh()
             self._parallel_mode = "data"
+            n_mesh = int(self._mesh.devices.size)
             blk = HIST_BLK
-            if HIST_BLK % n_dev != 0 or on_tpu():
-                blk = HIST_BLK * n_dev  # per-shard rows stay pallas-aligned
+            if HIST_BLK % n_mesh != 0 or on_tpu():
+                blk = HIST_BLK * n_mesh  # per-shard rows stay pallas-aligned
             train_set.ensure_row_block(blk)
             if jax.process_count() > 1:
                 # pre-partitioned ranks hold UNEVEN shards; NamedSharding
@@ -430,16 +431,27 @@ class GBDT:
                     f"features sharded over {n_dev} devices "
                     "(feature_parallel_tree_learner.cpp semantics)"
                 )
+        # where this Booster's data sets live: the training set's rows
+        # over the data mesh (tree_learner=data), and in ONE process the
+        # valid sets, labels and weights on the same mesh too (valid
+        # sets replicated), all resident per Dataset (dataset.py) as
+        # the one-chip copies are. A multi-process cluster keeps its
+        # per-rank copies of everything but the training rows.
+        self._data_mesh = self._mesh if self._parallel_mode == "data" \
+            else None
+        self._rows_mesh = self._data_mesh if jax.process_count() == 1 \
+            else None
         # objective/strategy init AFTER ensure_row_block: they cache
         # padded per-row arrays and must see the final row padding
         if self.objective is not None:
             with _gt.scope("boosting.objective_init"):
+                self.objective.mesh = self._rows_mesh
                 self.objective.init(train_set)
         self.strategy = create_sample_strategy(
             config, train_set.num_data, group=train_set.metadata.group
         )
         with _gt.scope("boosting.device_inputs"):
-            self.dev = train_set.device_arrays()
+            self.dev = train_set.device_arrays(self._data_mesh)
         from .binning import BinType
 
         cat_subset = any(
@@ -721,6 +733,9 @@ class GBDT:
         # per-tree wire estimate; refined by the data-parallel grower's
         # voting-aware wire_bytes_per_tree once it exists (below)
         self.voting_wire_bytes_est = None
+        # the wire the child histograms cross a data mesh on
+        # (learner/rounds.py hist_wire); "none" off a mesh
+        self.hist_wire_resolved = "none"
         with _gt.scope("boosting.score_init"):
             self.train = _ScoreSet(
                 train_set,
@@ -732,20 +747,21 @@ class GBDT:
             for m in self.train.metrics:
                 m.init(meta.label, meta.weight, meta.group)
             # the data set's own device copy, shared with the objective
-            self._label_dev = train_set.device_label()
+            self._label_dev = train_set.device_label(self._rows_mesh)
         self._boosted_from_average = False
         self._init_scores = [0.0] * self.num_class
         self._feat_rng = np.random.RandomState(config.feature_fraction_seed)
         if self._parallel_mode == "data":
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from .parallel.data_parallel import DataParallelGrower
+            from .parallel.data_parallel import shared_grower
 
             if jax.process_count() > 1:
-                # multi-controller cluster: the fused loop closes over
-                # the dataset arrays, which is illegal for arrays
-                # spanning non-addressable devices — ride the sync path
-                # (every jit takes the global arrays as arguments).
+                # multi-controller cluster: rides the per-iteration sync
+                # path (every jit takes the global arrays as arguments).
+                # Bringing it onto the fused, memoized step that a
+                # one-process mesh takes is out of scope so far: its
+                # valid sets, labels and scores are per-rank copies
+                # re-assembled per Booster below, not resident mesh
+                # copies of the Dataset
                 self._force_sync = True
                 self._force_sync_reason = (
                     "multi-process runs synchronize per iteration"
@@ -758,16 +774,24 @@ class GBDT:
                     )
                     self.config.bagging_freq = 0
 
-            self._dp = DataParallelGrower(self._mesh, self.spec)
+            # one grower per (mesh, spec) in the process; the training
+            # set's rows are on the mesh already (self.dev above: the
+            # Dataset's own resident mesh copy, pushed shard by shard)
+            self._dp = shared_grower(self._mesh, self.spec)
             if use_voting:
                 self.voting_wire_bytes_est = self._dp.wire_bytes_per_tree(
                     int(self.dev["bins"].shape[0])
                 )
-            with _gt.scope("boosting.device_inputs"):
-                self.dev = self._dp.shard_inputs(self.dev)
-            # free the unsharded device copies — this booster reads only
-            # self.dev for the train set; other boosters re-push fresh
-            train_set.invalidate_device_cache()
+            from .learner.rounds import hist_wire
+            from .obs.metrics import record_parallel_mesh
+
+            n_mesh = int(self._mesh.devices.size)
+            self.hist_wire_resolved = (
+                hist_wire(self._dp.spec,
+                          int(self.dev["bins"].shape[1]) // n_mesh)
+                if self.spec.rounds_slots > 0 else "psum_f32"
+            )
+            record_parallel_mesh(n_mesh, self.hist_wire_resolved)
             if jax.process_count() > 1:
                 from .parallel.multihost import global_rows
 
@@ -797,13 +821,6 @@ class GBDT:
                             setattr(o, attr, global_rows(
                                 np.asarray(a), self._mesh, axis=0
                             ))
-            else:
-                row = NamedSharding(self._mesh, P(None, "data"))
-                self.train.score = jax.device_put(self.train.score, row)
-                if self._label_dev is not None:
-                    self._label_dev = jax.device_put(
-                        self._label_dev, NamedSharding(self._mesh, P("data"))
-                    )
         elif self._parallel_mode == "feature":
             from .parallel.feature_parallel import FeatureParallelGrower
 
@@ -815,10 +832,15 @@ class GBDT:
     # ------------------------------------------------------------------
     def _record_collective_wire(self, n_trees: int) -> None:
         """Runtime collective wire accounting (docs/OBSERVABILITY.md):
-        count the estimated histogram-reduce payload for n_trees
-        freshly dispatched trees. Called only from host-side loop code
-        — never inside a trace, where it would tick once per compile
-        instead of once per dispatch."""
+        count an ESTIMATE of the histogram-reduce payload for n_trees
+        freshly dispatched trees: 4-byte lanes x channels x columns x
+        bins x leaves (data_parallel.wire_bytes_per_tree), whichever
+        wire the grower resolved to (the gauge
+        lgbmtpu_parallel_hist_wire names it: an int16 reduce-scatter
+        ships half of this, a psum of smaller children only less than
+        all leaves). Called only from host-side loop code — never
+        inside a trace, where it would tick once per compile instead of
+        once per dispatch."""
         if self._dp is None or self._parallel_mode != "data":
             return
         fn = getattr(self._dp, "wire_bytes_per_tree", None)
@@ -971,14 +993,16 @@ class GBDT:
                 self._node_key, it * self.num_class + k
             )
         if self._dp is not None:
-            # the mesh growers return the pair alone: their ladder is
-            # read from the trace (docs/OBSERVABILITY.md)
-            out = self._dp(
+            args = (
                 d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
                 gk, hk, mask, feat_mask, self.params, valid,
                 d.get("bundle"), rng_key, self._group_mat, self._cegb_info,
                 self._forced, gh_scale,
             )
+            if self._parallel_mode == "data":
+                # the rounds grower's round counts come back replicated
+                return self._dp(*args, with_stats=with_stats)
+            out = self._dp(*args)  # feature-parallel: the flat grower
             return (*out, None) if with_stats else out
         return grow_tree(
             d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
@@ -990,21 +1014,77 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def _init_score_arr(self, ds: BinnedDataset):
+        import jax
         import jax.numpy as jnp
 
         npad = ds.num_rows_padded()
         init = ds.metadata.init_score
+        # under a one-process data mesh the score is created where its
+        # rows live (the training set's sharded like them, a valid
+        # set's replicated), never moved there afterwards
+        where = self._score_sharding(ds)
         if init is None:
             # made on the device, and one per Booster: the fused step
             # donates its score, so it is never a data set's to share
-            return jnp.zeros((self.num_class, npad), jnp.float32)
+            return jnp.zeros((self.num_class, npad), jnp.float32,
+                             device=where)
         score = np.zeros((self.num_class, npad), dtype=np.float32)
         init = np.asarray(init, dtype=np.float32)
         if init.size == ds.num_data * self.num_class:
             score[:, : ds.num_data] = init.reshape(self.num_class, ds.num_data)
         else:
             score[:, : ds.num_data] = init[None, :]
-        return jnp.asarray(score)
+        return jnp.asarray(score) if where is None \
+            else jax.device_put(score, where)
+
+    def _score_sharding(self, ds: BinnedDataset):
+        """Sharding of a (K, rows) score of `ds` under the one-process
+        data mesh; None off it (the default device)."""
+        if self._rows_mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(
+            self._rows_mesh,
+            P(None, "data") if ds is self.train_set else P())
+
+    def _replicated(self, x):
+        """`x` on the device: replicated over the one-process data
+        mesh, or off it an array of the default device."""
+        import jax
+        import jax.numpy as jnp
+
+        if self._rows_mesh is None:
+            return jnp.asarray(x)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return jax.device_put(x, NamedSharding(self._rows_mesh, P()))
+
+    def _add_init(self, score, k: int, init: float):
+        """`score` with the init score of class k added to its row k.
+        Under the one-process data mesh as one elementwise add, which
+        stays where the score's rows are: the indexed update would
+        broadcast `init` to a whole row on ONE chip first (218 MB at
+        54.5M rows) and re-shard it."""
+        if self._rows_mesh is None:
+            return score.at[k].add(init)
+        col = np.zeros((self.num_class, 1), np.float32)
+        col[k] = init
+        return score + col
+
+    def _dev_of(self, ds: BinnedDataset) -> Dict[str, Any]:
+        """device_arrays() of one of this Booster's data sets, in the
+        layout this Booster computes on: the training set's is
+        self.dev; a valid set's is its one-chip copy, or under the
+        one-process data mesh its replicated mesh copy."""
+        if ds is self.train_set:
+            return self.dev
+        return ds.device_arrays(self._rows_mesh, shard_rows=False)
+
+    def _rows_of(self, ds: BinnedDataset, kind: str):
+        """The label or weight of `ds` in the same layout (_dev_of)."""
+        push = ds.device_label if kind == "label" else ds.device_weight
+        return push(self._rows_mesh, shard_rows=ds is self.train_set)
 
     def add_valid(self, valid_set: BinnedDataset, name: str) -> None:
         with _gt.scope("boosting.score_init"):
@@ -1115,7 +1195,7 @@ class GBDT:
                         -arrays.leaf_value[leaf]
                     )
                     for vs in self.valids:
-                        vdev = vs.dataset.device_arrays()
+                        vdev = self._dev_of(vs.dataset)
                         vleaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                         vs.score = vs.score.at[k].add(-arrays.leaf_value[vleaf])
                 log.warning(
@@ -1248,7 +1328,7 @@ class GBDT:
                     add_score(self.train.score[k], row_leaf, lv, one)
                 )
                 for vs in self.valids:
-                    vdev = vs.dataset.device_arrays()
+                    vdev = self._dev_of(vs.dataset)
                     leaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                     vs.score = vs.score.at[k].set(
                         add_score(vs.score[k], leaf, lv, one)
@@ -1362,7 +1442,7 @@ class GBDT:
                         add_score(self.train.score[k], row_leaf, final_leaf, one)
                     )
                     for vs in self.valids:
-                        vdev = vs.dataset.device_arrays()
+                        vdev = self._dev_of(vs.dataset)
                         leaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                         vs.score = vs.score.at[k].set(
                             add_score(vs.score[k], leaf, final_leaf, one)
@@ -1487,12 +1567,13 @@ class GBDT:
         eval_groups = []  # host group arrays (the host fallback's only)
         for ss in sets:
             names, hb = supported_names(ss.metrics)
-            # the train set's device arrays are self.dev (sharded under a
-            # mesh); don't re-push an unsharded copy through the cache
-            dev = self.dev if ss is self.train else ss.dataset.device_arrays()
+            # each set's resident copies in this Booster's layout (under
+            # a data mesh: the Dataset's mesh copies, never a one-chip
+            # copy moved over)
+            dev = self._dev_of(ss.dataset)
             meta = ss.dataset.metadata
-            label = ss.dataset.device_label()
-            weight = ss.dataset.device_weight()
+            label = self._rows_of(ss.dataset, "label")
+            weight = self._rows_of(ss.dataset, "weight")
             eval_specs.append((ss.name, tuple(names), tuple(hb)))
             eval_groups.append(meta.group)
             eval_arrs.append(
@@ -1502,7 +1583,7 @@ class GBDT:
         self._f_eval_sets = [(nm, _EvalNames(list(n), list(h)))
                              for nm, n, h in eval_specs]
         n_valid_sets = len(self.valids)
-        vdevs = [vs.dataset.device_arrays() for vs in self.valids]
+        vdevs = [self._dev_of(vs.dataset) for vs in self.valids]
         frac = c.feature_fraction
         F = ds.num_used_features
         n_feat = max(1, int(np.ceil(frac * F))) if frac < 1.0 else F
@@ -1522,16 +1603,19 @@ class GBDT:
         )
         # the step ends its eval row with the rounds grower's round
         # counts: one per ladder width, the routing-only rounds, and
-        # their total (a mesh grower returns none: _grow)
+        # their total (under a data mesh too: every shard counts the
+        # same rounds, data_parallel.py)
         ladder_ws = (
-            ladder_widths(self.spec)
-            if self.spec.rounds_slots > 0 and self._dp is None else ()
+            ladder_widths(self.spec) if self.spec.rounds_slots > 0 else ()
         )
         self._f_ladder_widths = ladder_ws
         if ladder_ws:
             from .obs.metrics import record_hist_schedule
 
+            # the schedule is a shard's: the rows one kernel call sees
             n_cols, n_rows = self.dev["bins"].shape
+            n_rows //= (int(data_mesh.devices.size) if data_mesh is not None
+                        else 1)
             record_hist_schedule(
                 hist_schedule(self.spec, n_rows, n_cols), n_cols)
         # memo eligibility must be known BEFORE tracing. Query groups do
@@ -1539,11 +1623,13 @@ class GBDT:
         # layout, grids and per-query statistics from `data` (shapes in
         # the key below), never from a closure. What does bar it bakes
         # data of THIS booster into the trace: a forced-splits plan,
-        # by-query bagging's group draw, a data mesh's shardings
+        # by-query bagging's group draw, the feature-parallel grower's
+        # own arrays. A data mesh does not: the step reads every array
+        # from `data`, and the mesh and the shardings are in the key
         memo_ok = (
             self._forced is None
             and not getattr(self.strategy, "by_query", False)
-            and self._dp is None
+            and self._parallel_mode != "feature"
         )
         if memo_ok:
             # the memoized executable outlives this booster — every
@@ -1559,12 +1645,30 @@ class GBDT:
                                          eval_groups)
             ]
 
+        # the step hands each score back where it found it (_init_score_arr),
+        # so a chunk's output state is its next input's layout and one
+        # executable serves every dispatch
+        score_at = self._score_sharding(ds)
+        state_rep = None  # every other leaf of the state: replicated
+        if score_at is not None:
+            state_rep = jax.sharding.NamedSharding(
+                data_mesh, jax.sharding.PartitionSpec())
+
         def step(state, data):
             # name the data mesh for the per-row Pallas kernels (score
             # update, valid traversal, leaf renewal) the step calls
             # outside the grower's shard_map
             with row_mesh(data_mesh):
-                return step_body(state, data)
+                new_state, trees, eval_row = step_body(state, data)
+            if score_at is not None:
+                pin = jax.lax.with_sharding_constraint
+                # every leaf replicated, then the training score over
+                # its rows: the last constraint on a value is the one
+                # that holds (the form the chip run of PR 32 measured)
+                new_state = jax.tree.map(
+                    lambda a: pin(a, state_rep), new_state)
+                new_state["score"] = pin(new_state["score"], score_at)
+            return new_state, trees, eval_row
 
         def step_body(state, data):
             score = state["score"]
@@ -1751,16 +1855,21 @@ class GBDT:
         # pytree structure with shapes+dtypes.
         key = None
         if memo_ok:
+            # under a data mesh a leaf's sharding (mesh devices, axis
+            # name, partition) is part of its fingerprint; off it the
+            # fingerprint is shape and dtype as before
             data_fp = jax.tree.map(
                 lambda a: (getattr(a, "shape", None),
-                           str(getattr(a, "dtype", type(a)))),
+                           str(getattr(a, "dtype", type(a))))
+                + ((str(getattr(a, "sharding", None)),)
+                   if data_mesh is not None else ()),
                 self._f_data,
             )
             key = (
                 type(self).__name__, K, track_train, self.spec,
                 type(objective).__name__, type(strategy).__name__,
                 str(sorted((k2, str(v)) for k2, v in c._values.items())),
-                str(eval_specs), str(data_fp), n_valid_sets,
+                str(eval_specs), str(data_fp), n_valid_sets, data_mesh,
             )
             cached = _FUSED_STEP_CACHE.get(key)
             if cached is not None:
@@ -1800,20 +1909,26 @@ class GBDT:
                     init = self.objective.boost_from_score(k)
                 if abs(init) > 1e-15:
                     init_scores[k] = init
-                    self.train.score = self.train.score.at[k].add(init)
+                    self.train.score = self._add_init(
+                        self.train.score, k, init)
                     for vs in self.valids:
-                        vs.score = vs.score.at[k].add(init)
+                        vs.score = self._add_init(vs.score, k, init)
                     log.info(f"Start training from score {init:f}")
         self._init_scores = init_scores
         with _gt.scope("boosting.build_step"):
             self._build_fused(track_train)
+        # under the one-process data mesh the small leaves of the state
+        # start where a chunk hands them back, replicated on the mesh:
+        # uncommitted on one chip they made a job's FIRST dispatch
+        # another program than its second (two traces, two compiles)
+        at = self._replicated
         self._fstate = {
             "score": self.train.score,
             "vscores": tuple(vs.score for vs in self.valids),
-            "it": jnp.int32(self.iter_),
-            "shrink": jnp.float32(self.shrinkage_rate),
-            "init": jnp.asarray(np.asarray(init_scores, np.float32)),
-            "stopped": jnp.asarray(False),
+            "it": at(jnp.int32(self.iter_)),
+            "shrink": at(jnp.float32(self.shrinkage_rate)),
+            "init": at(np.asarray(init_scores, np.float32)),
+            "stopped": at(np.asarray(False)),
         }
         # entries are (device rows, n_active): a chunk's (C, E) stack
         # whose first n_active rows are live — fused_collect slices on
@@ -1948,7 +2063,7 @@ class GBDT:
                     -arrays.leaf_value[leaf]
                 )
                 for vs in self.valids:
-                    vdev = vs.dataset.device_arrays()
+                    vdev = self._dev_of(vs.dataset)
                     vleaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                     vs.score = vs.score.at[k].add(-arrays.leaf_value[vleaf])
         del self._models[n_iters * K:]
@@ -2100,7 +2215,7 @@ class GBDT:
                 leaf = self._traverse(arrays, self.dev["bins"], self.dev["nan_bin"], self.dev.get("bundle"))
                 self.train.score = self.train.score.at[k].add(-arrays.leaf_value[leaf])
                 for vs in self.valids:
-                    vdev = vs.dataset.device_arrays()
+                    vdev = self._dev_of(vs.dataset)
                     vleaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                     vs.score = vs.score.at[k].add(-arrays.leaf_value[vleaf])
             else:
@@ -2469,7 +2584,7 @@ class DART(GBDT):
         """score[k] += scale * tree(arrays) over dataset ss."""
         import jax.numpy as jnp
 
-        dev = ss.dataset.device_arrays()
+        dev = self._dev_of(ss.dataset)
         leaf = self._traverse(arrays, dev["bins"], dev["nan_bin"], dev.get("bundle"))
         ss.score = ss.score.at[k].set(
             add_score(ss.score[k], leaf, arrays.leaf_value, jnp.float32(scale))
@@ -2683,7 +2798,7 @@ class RF(GBDT):
             sc = add_score(sc, row_leaf, arrays.leaf_value, jnp.float32(1.0))
             self.train.score = self.train.score.at[k].set(sc / (m + 1.0))
             for vs in self.valids:
-                vdev = vs.dataset.device_arrays()
+                vdev = self._dev_of(vs.dataset)
                 leaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                 vsc = vs.score[k] * m
                 vsc = add_score(vsc, leaf, arrays.leaf_value, jnp.float32(1.0))
@@ -2705,7 +2820,7 @@ class RF(GBDT):
             sc = self.train.score[k] * m - arrays.leaf_value[leaf]
             self.train.score = self.train.score.at[k].set(sc / (m - 1.0) if m > 1 else sc * 0)
             for vs in self.valids:
-                vdev = vs.dataset.device_arrays()
+                vdev = self._dev_of(vs.dataset)
                 vleaf = self._traverse(arrays, vdev["bins"], vdev["nan_bin"], vdev.get("bundle"))
                 vsc = vs.score[k] * m - arrays.leaf_value[vleaf]
                 vs.score = vs.score.at[k].set(vsc / (m - 1.0) if m > 1 else vsc * 0)

@@ -188,7 +188,10 @@ class StreamedBinnedDataset(BinnedDataset):
     bin_store: Optional[ChunkStore] = None
     ram_budget_mb: int = 0
 
-    def device_arrays(self) -> Dict[str, Any]:
+    def device_arrays(self, mesh=None, shard_rows: bool = True
+                      ) -> Dict[str, Any]:
+        if mesh is not None:
+            return self._mesh_arrays(mesh, shard_rows)
         if self._device is not None:
             return self._device
         import jax
@@ -253,30 +256,35 @@ class StreamedBinnedDataset(BinnedDataset):
             "rss_spread_mb": round(max(steady) - min(steady), 1),
         })
 
-        um = self.used_mappers()
-        from ..binning import BinType
-
-        f = self.num_used_features
-        nan_bin = np.array([m.nan_bin for m in um], dtype=np.int32)
-        num_bins = np.array([m.num_bin for m in um], dtype=np.int32)
-        is_cat = np.array([m.bin_type == BinType.CATEGORICAL for m in um])
-        mono = (
-            self.monotone_constraints.astype(np.int32)
-            if self.monotone_constraints is not None
-            else np.zeros(f, dtype=np.int32)
-        )
-        valid = np.zeros(npad, dtype=np.float32)
-        valid[: self.num_data] = 1.0
         self._device = {
             "bins": buf,
-            "valid": jnp.asarray(valid),
-            "nan_bin": jnp.asarray(nan_bin),
-            "num_bins": jnp.asarray(num_bins),
-            "mono": jnp.asarray(mono),
-            "is_cat": jnp.asarray(is_cat),
+            "valid": jnp.asarray(self._host_valid(0, npad)),
+            **{k: jnp.asarray(v) for k, v in self._host_tables().items()},
             "bundle": self._bundle_info(),
         }
         return self._device
+
+    def _mesh_arrays(self, mesh, shard_rows: bool) -> Dict[str, Any]:
+        """A data mesh's copy of a streamed set: the chunk-assembled
+        one-chip matrix laid over the mesh on the device (there is no
+        host matrix to push shard by shard from)."""
+        npad = self.num_rows_padded()
+        ent = self._mesh_dev.get((mesh, shard_rows))
+        if ent is not None and ent[0] == npad:
+            return ent[1]
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        one = self.device_arrays()
+        ax = mesh.axis_names[0] if shard_rows else None
+        rep = NamedSharding(mesh, P())
+        dev = jax.tree.map(lambda a: jax.device_put(a, rep), one)
+        dev["bins"] = jax.device_put(
+            one["bins"], NamedSharding(mesh, P(None, ax)))
+        dev["valid"] = jax.device_put(
+            one["valid"], NamedSharding(mesh, P(ax)))
+        self._mesh_dev[(mesh, shard_rows)] = (npad, dev)
+        return dev
 
     # ------------------------------------------------ host-matrix paths
     def materialize_bins(self) -> np.ndarray:

@@ -63,6 +63,12 @@ def _column_slab(data: np.ndarray, idx: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(part.T, dtype=np.float64)
 
 
+def _pushed_bytes(arr) -> int:
+    """Bytes that went host -> device for `arr`: every addressable
+    shard (a replicated array moves one copy per chip)."""
+    return sum(int(s.data.nbytes) for s in arr.addressable_shards)
+
+
 def _choose_bin_dtype(max_num_bin: int) -> Any:
     if max_num_bin <= 256:
         return np.uint8
@@ -141,7 +147,11 @@ class BinnedDataset:
     # is shared by every Booster built on it (device_label/_weight,
     # label_stat): kind -> (host array, padded rows, device array), and
     # the host statistics of the (label, weight) pair in _stats_of
-    _rows_dev: Dict[str, Any] = field(default_factory=dict, repr=False)
+    _rows_dev: Dict[Any, Any] = field(default_factory=dict, repr=False)
+    # the device copies laid over a data mesh (tree_learner=data in one
+    # process), beside the one-chip copy above and resident like it:
+    # (mesh, rows sharded or replicated) -> (padded rows, arrays)
+    _mesh_dev: Dict[Any, Any] = field(default_factory=dict, repr=False)
     # ranking: the query layout and what is derived from it and the
     # labels (rank_layout / rank_part), dropped with _device
     _rank: Dict[Any, Any] = field(default_factory=dict, repr=False)
@@ -709,21 +719,25 @@ class BinnedDataset:
         block multiple under a data mesh (data-parallel training). Must
         run before the first device push; drops any cached arrays."""
         if self.row_block % blk != 0:
-            g = np.gcd(self.row_block, blk)
-            self.row_block = self.row_block // g * blk
+            # a Python int: the padded row count ends up in array shapes
+            # and shard indices, where JAX wants ints, not np.int64
+            g = int(np.gcd(self.row_block, blk))
+            self.row_block = int(self.row_block) // g * int(blk)
             self.invalidate_device_cache()
 
     def invalidate_device_cache(self) -> None:
-        """Drop cached device arrays (next device_arrays() /
-        device_label() re-pushes). Used when padding changes or when a
-        mesh booster keeps its own sharded copies and the unsharded
-        ones would waste HBM."""
+        """Drop cached device arrays, the one-chip copy and every mesh
+        copy (next device_arrays() / device_label() re-pushes). Used
+        when the row padding changes; nothing a Booster does to train
+        calls it otherwise."""
         self._device = None
+        self._mesh_dev = {}
         self._rows_dev = {}
         self._rank = {}
 
     # ---------------- device arrays ----------------
-    def device_arrays(self) -> Dict[str, Any]:
+    def device_arrays(self, mesh=None, shard_rows: bool = True
+                      ) -> Dict[str, Any]:
         """Push the bin matrix + per-feature info to device (cached).
 
         Returns dict with:
@@ -736,58 +750,111 @@ class BinnedDataset:
           num_bins  (F,)   int32    — per-feature bin count
           mono      (F,)   int32    — monotone constraint per feature
           is_cat    (F,)   bool     — categorical flag
-        """
+
+        Without a mesh: one copy on the default device. With a data
+        mesh (tree_learner=data): a second resident copy keyed by (mesh,
+        shard_rows) and the row padding, built from the HOST arrays with
+        the sharding given to the transfer, so each chip receives its
+        own rows only: bins P(None, "data") and valid P("data") when
+        shard_rows (the training set), everything replicated otherwise
+        (a valid set: its traversal and metrics run whole on every
+        chip). Per-feature vectors are replicated either way."""
+        if mesh is not None:
+            return self._mesh_arrays(mesh, shard_rows)
         if self._device is not None:
             return self._device
         import jax.numpy as jnp
 
+        from .obs.metrics import record_dataset_push
+        from .timer import global_timer
+
         npad = self.num_rows_padded()
-        f = self.num_used_features
-        ncols = self.bins.shape[0]  # bundle columns (== f without EFB)
-        bins_fm = np.zeros((ncols, npad), dtype=np.int32)
-        bins_fm[:, : self.num_data] = self.bins
-        um = self.used_mappers()
-        nan_bin = np.array([m.nan_bin for m in um], dtype=np.int32)
-        num_bins = np.array([m.num_bin for m in um], dtype=np.int32)
-        is_cat = np.array([m.bin_type == BinType.CATEGORICAL for m in um])
-        mono = (
-            self.monotone_constraints.astype(np.int32)
-            if self.monotone_constraints is not None
-            else np.zeros(f, dtype=np.int32)
-        )
-        valid = np.zeros(npad, dtype=np.float32)
-        valid[: self.num_data] = 1.0
+        with global_timer.scope("dataset.device_push"):
+            bins = jnp.asarray(self._host_bins(0, npad))
+        record_dataset_push("bins", _pushed_bytes(bins))
         self._device = {
-            "bins": jnp.asarray(bins_fm),
-            "valid": jnp.asarray(valid),
-            "nan_bin": jnp.asarray(nan_bin),
-            "num_bins": jnp.asarray(num_bins),
-            "mono": jnp.asarray(mono),
-            "is_cat": jnp.asarray(is_cat),
+            "bins": bins,
+            "valid": jnp.asarray(self._host_valid(0, npad)),
+            **{k: jnp.asarray(v) for k, v in self._host_tables().items()},
             "bundle": self._bundle_info(),
         }
         return self._device
 
-    def device_label(self):
-        """Padded label on the device, pushed once per data set and
-        shared by every Booster (objective.label, GBDT._label_dev, the
-        fused step's eval arrays). Never donated, never sharded in
-        place. None without labels."""
-        return self._device_rows("label")
+    def _host_bins(self, lo: int, hi: int) -> np.ndarray:
+        """Padded rows [lo, hi) of the device bin matrix, on the host."""
+        out = np.zeros((self.bins.shape[0], hi - lo), dtype=np.int32)
+        n = min(max(self.num_data - lo, 0), hi - lo)
+        out[:, :n] = self.bins[:, lo:lo + n]
+        return out
 
-    def device_weight(self):
+    def _host_valid(self, lo: int, hi: int) -> np.ndarray:
+        out = np.zeros(hi - lo, dtype=np.float32)
+        out[:min(max(self.num_data - lo, 0), hi - lo)] = 1.0
+        return out
+
+    def _host_tables(self) -> Dict[str, np.ndarray]:
+        """The per-feature vectors of device_arrays(), on the host."""
+        um = self.used_mappers()
+        return {
+            "nan_bin": np.array([m.nan_bin for m in um], dtype=np.int32),
+            "num_bins": np.array([m.num_bin for m in um], dtype=np.int32),
+            "mono": (
+                self.monotone_constraints.astype(np.int32)
+                if self.monotone_constraints is not None
+                else np.zeros(self.num_used_features, dtype=np.int32)
+            ),
+            "is_cat": np.array(
+                [m.bin_type == BinType.CATEGORICAL for m in um]),
+        }
+
+    def _mesh_arrays(self, mesh, shard_rows: bool) -> Dict[str, Any]:
+        npad = self.num_rows_padded()
+        ent = self._mesh_dev.get((mesh, shard_rows))
+        if ent is not None and ent[0] == npad:
+            return ent[1]
+        from .obs.metrics import record_dataset_push
+        from .parallel.data_parallel import (check_shard_rows,
+                                             put_replicated, put_rows)
+        from .timer import global_timer
+
+        if shard_rows:
+            check_shard_rows(npad, mesh)
+        with global_timer.scope("dataset.device_push"):
+            bins = put_rows(self._host_bins, (self.bins.shape[0], npad),
+                            mesh, 1, shard_rows)
+        record_dataset_push("bins", _pushed_bytes(bins))
+        dev = {
+            "bins": bins,
+            "valid": put_rows(self._host_valid, (npad,), mesh, 0,
+                              shard_rows),
+            **put_replicated(self._host_tables(), mesh),
+            "bundle": put_replicated(self._bundle_info(), mesh),
+        }
+        self._mesh_dev[(mesh, shard_rows)] = (npad, dev)
+        return dev
+
+    def device_label(self, mesh=None, shard_rows: bool = True):
+        """Padded label on the device, pushed once per data set (and
+        per mesh layout, see device_arrays) and shared by every Booster
+        (objective.label, GBDT._label_dev, the fused step's eval
+        arrays). Never donated, never re-sharded in place. None without
+        labels."""
+        return self._device_rows("label", mesh, shard_rows)
+
+    def device_weight(self, mesh=None, shard_rows: bool = True):
         """Padded weight on the device (see device_label); None when
         unweighted."""
-        return self._device_rows("weight")
+        return self._device_rows("weight", mesh, shard_rows)
 
-    def _device_rows(self, kind: str):
-        from .obs.metrics import record_label_cache
+    def _device_rows(self, kind: str, mesh=None, shard_rows: bool = True):
+        from .obs.metrics import record_dataset_push, record_label_cache
 
         host = getattr(self.metadata, kind)
         if host is None:
             return None
         npad = self.num_rows_padded()
-        ent = self._rows_dev.get(kind)
+        key = kind if mesh is None else (kind, mesh, shard_rows)
+        ent = self._rows_dev.get(key)
         # keyed on the host array's IDENTITY (the entry keeps it alive,
         # so the id cannot be reused) and on the row padding: whoever
         # replaces metadata.label / .weight (Dataset.set_label, set_weight,
@@ -796,12 +863,20 @@ class BinnedDataset:
             record_label_cache(kind, hit=True)
             return ent[2]
         record_label_cache(kind, hit=False)
-        import jax.numpy as jnp
-
         # padded() is a fresh host array: on a CPU backend the device
         # array may alias it, never the caller's own label array
-        dev = jnp.asarray(self.padded(host))
-        self._rows_dev[kind] = (host, npad, dev)
+        padded = self.padded(host)
+        if mesh is None:
+            import jax.numpy as jnp
+
+            dev = jnp.asarray(padded)
+        else:
+            from .parallel.data_parallel import put_rows
+
+            dev = put_rows(lambda lo, hi: padded[lo:hi], (npad,), mesh, 0,
+                           shard_rows)
+        record_dataset_push("rows", _pushed_bytes(dev))
+        self._rows_dev[key] = (host, npad, dev)
         return dev
 
     # ---------------- ranking: per-Dataset query layout ----------------

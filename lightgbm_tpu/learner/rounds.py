@@ -66,7 +66,6 @@ from .histogram import (
     int8_oh_shift,
     root_sums,
     route_round,
-    rs_exact_ok,
     rs_wire_dtype,
 )
 from .grower import (
@@ -106,6 +105,34 @@ def ladder_widths(spec: GrowerSpec) -> Tuple[int, ...]:
     is the slot count itself."""
     slots = min(spec.rounds_slots, max(spec.num_leaves - 1, 1))  # top_k: k <= L
     return tuple(w for w in LADDER_RUNGS if w < slots) + (slots,)
+
+
+def hist_wire(spec: GrowerSpec, n_local_rows: int) -> str:
+    """The wire a data-mesh program's child histograms cross the mesh
+    on, decided from static facts alone (the spec and a shard's rows):
+    "none" off a mesh; "vote_*" under voting (elected columns only, in
+    the narrowest exact integer dtype, else f32); "rs_int16" /
+    "rs_int32" (integer reduce-scatter with per-rank feature ownership)
+    while the worst-case integer sums stay exact; else "psum_f32", the
+    whole (channels, F, B) f32 histogram of every smaller child."""
+    n = spec.axis_size
+    if spec.axis_name is None or n <= 1:
+        return "none"
+    dt = rs_wire_dtype(n_local_rows, n, spec.quant_levels)
+    if spec.voting_k:
+        return f"vote_{dt if spec.quant and dt else 'f32'}"
+    per_node = bool(spec.extra_trees or spec.ff_bynode or spec.cegb
+                    or spec.n_groups)
+    # voting ships a NARROWER payload than reduce-scatter (2k elected
+    # columns vs G/n owned); forced splits read arbitrary feature
+    # columns of arbitrary leaves and need full-width per-leaf
+    # histogram pools, not owned blocks
+    # dt: a dtype only while the integer sums fit (histogram.rs_exact_ok)
+    if (spec.quant and not spec.efb and not spec.has_cat
+            and not spec.cat_subset and not spec.mono_mode and not per_node
+            and not spec.n_forced and dt is not None):
+        return f"rs_{dt}"
+    return "psum_f32"
 
 
 # the label of routing-only rounds among the per-width round counts
@@ -346,17 +373,7 @@ def grow_tree_rounds(
     # histogram.rs_exact_ok; contract enforced by the jaxpr auditor
     # (analysis/jaxpr_audit.py rounds_quant_rs / _overflow entries).
     n_rs = spec.axis_size
-    use_rs = bool(
-        ax is not None and n_rs > 1 and spec.quant
-        and not spec.efb and not spec.has_cat and not spec.cat_subset
-        and not spec.mono_mode and not per_node
-        # voting ships a NARROWER payload than reduce-scatter (2k
-        # elected columns vs G/n owned); forced splits read arbitrary
-        # feature columns of arbitrary leaves and need full-width
-        # per-leaf histogram pools, not owned blocks
-        and not spec.voting_k and not spec.n_forced
-        and rs_exact_ok(N, n_rs, spec.quant_levels)
-    )
+    use_rs = hist_wire(spec, N).startswith("rs_")
     if use_voting:
         kG = min(spec.voting_k, G)
         k2 = min(2 * spec.voting_k, G)

@@ -56,6 +56,10 @@ class ObjectiveFunction:
         self.config = config
         self.label: Optional[jax.Array] = None
         self.weight: Optional[jax.Array] = None
+        # the data mesh the Booster lays the training rows over in one
+        # process (tree_learner=data): init then binds the data set's
+        # mesh copies of label and weight; None: the one-chip copies
+        self.mesh = None
 
     def init(self, dataset: BinnedDataset) -> None:
         """Bind to a data set. label / weight ARE the data set's device
@@ -74,8 +78,8 @@ class ObjectiveFunction:
         dataset.label_stat(
             ("check_label", type(self).__name__, self.num_class), checked
         )
-        self.label = dataset.device_label()
-        self.weight = dataset.device_weight()
+        self.label = dataset.device_label(self.mesh)
+        self.weight = dataset.device_weight(self.mesh)
         self._dataset = dataset
         self._meta = meta
         self._num_data = dataset.num_data

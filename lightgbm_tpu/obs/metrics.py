@@ -613,6 +613,37 @@ def record_collective_wire(entry: str, nbytes: int) -> None:
               labels=("entry",)).inc(nbytes, entry=entry)
 
 
+def record_parallel_mesh(size: int, wire: str) -> None:
+    """What a data-parallel Booster resolved to: the chips of its data
+    mesh, and the wire its child histograms cross the mesh on
+    (learner/rounds.py hist_wire: psum_f32, rs_int32, rs_int16,
+    vote_*): one series, 1 under the label of the wire in use."""
+    r = _default
+    if not r.enabled:
+        return
+    r.gauge("lgbmtpu_parallel_mesh_size",
+            "chips of the data mesh of the newest tree_learner=data "
+            "Booster").set(size)
+    g = r.gauge("lgbmtpu_parallel_hist_wire",
+                "1 on the wire the newest data-parallel Booster's child "
+                "histograms cross the mesh on", labels=("wire",))
+    g.clear()
+    g.set(1, wire=wire)
+
+
+def record_dataset_push(kind: str, nbytes: int) -> None:
+    """Host -> device bytes of a data set's resident copies
+    (dataset.BinnedDataset.device_arrays / device_label / device_weight):
+    kind is bins (a bin matrix: one copy per Dataset and layout while
+    it stays resident) or rows (a padded label or weight)."""
+    r = _default
+    if not r.enabled:
+        return
+    r.counter("lgbmtpu_dataset_push_bytes_total",
+              "host to device bytes of data set copies, by kind "
+              "(bins | rows)", labels=("kind",)).inc(nbytes, kind=kind)
+
+
 def record_grower_rounds(widths, rounds) -> None:
     """Rounds the rounds grower ran at each width of its slot ladder
     (learner/rounds.py ladder_widths) and, as width="route", the rounds

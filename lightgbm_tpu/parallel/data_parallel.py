@@ -20,8 +20,9 @@ sums provably fit.
 
 from __future__ import annotations
 
+from collections import OrderedDict
 from functools import partial
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,48 @@ def make_mesh(devices=None, axis_name: str = "data") -> Mesh:
     if devices is None:
         devices = jax.devices()
     return Mesh(np.asarray(devices), (axis_name,))
+
+
+# float32 holds every integer up to 2**24 and no odd one past it. The
+# growers count rows in float32 (the histograms' count channel, the split
+# records, TreeArrays): on one chip's rows that is exact in practice, but
+# a mesh's GLOBAL table passes it (54.5M rows over four chips), and a
+# node past 2**24 rows then hands its children counts that are a few
+# rows off: a small leaf split off a 27M-row node read 296,048 for
+# 296,043 rows (benchmark/references/tree_audit.py, PR 32).
+F32_EXACT_ROWS = 1 << 24
+
+
+def _recount_leaves(row_leaf, mask, num_leaves: int, axis_name: str):
+    """(L,) leaf counts from the rows themselves: each shard counts its
+    in-bag rows per leaf (exact: a shard's rows stay under 2**24 per
+    leaf), and the shards' counts are summed as integers. Exact for
+    every leaf that float32 can hold at all."""
+    from ..learner.histogram import seg_sum
+
+    local = seg_sum(mask[None, :], row_leaf, num_leaves)[0]
+    total = jax.lax.psum(jnp.round(local).astype(jnp.int32), axis_name)
+    return total.astype(jnp.float32)
+
+
+_GROWERS: "OrderedDict[Any, DataParallelGrower]" = OrderedDict()
+_GROWERS_MAX = 8
+
+
+def shared_grower(mesh: Mesh, spec: GrowerSpec) -> "DataParallelGrower":
+    """The data-parallel grower of (mesh, spec), built once per process
+    and shared by every Booster that resolves to the same pair: its
+    jit(shard_map) is then one object, so a second Booster re-traces
+    nothing (a fresh jit per Booster was a fresh trace per Booster)."""
+    key = (mesh, spec)
+    g = _GROWERS.get(key)
+    if g is None:
+        g = _GROWERS[key] = DataParallelGrower(mesh, spec)
+        while len(_GROWERS) > _GROWERS_MAX:
+            _GROWERS.popitem(last=False)
+    else:
+        _GROWERS.move_to_end(key)
+    return g
 
 
 class DataParallelGrower:
@@ -83,43 +126,66 @@ class DataParallelGrower:
         bins_spec = P(None, axis_name)  # bins are (F, N): rows on axis 1
         rep = P()
 
-        def fn(bins, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
-               feat_mask, params, valid, bundle, rng_key, group_mat, cegb,
-               forced, gh_scale):
+        def fn(with_stats, bins, nan_bin, num_bins, mono, is_cat, grad,
+               hess, mask, feat_mask, params, valid, bundle, rng_key,
+               group_mat, cegb, forced, gh_scale):
             # inside shard_map every kernel already sees one shard
             with row_mesh(None):
-                tree, row_leaf = grow_tree(
+                tree, row_leaf, *stats = grow_tree(
                     bins, nan_bin, num_bins, mono, is_cat, grad, hess,
                     mask, feat_mask, params, self.spec, valid=valid,
                     bundle=bundle, rng_key=rng_key, group_mat=group_mat,
                     cegb=cegb, forced=forced, gh_scale=gh_scale,
+                    with_stats=with_stats,
                 )
+                if bins.shape[1] * n > F32_EXACT_ROWS:
+                    tree = tree._replace(leaf_count=_recount_leaves(
+                        row_leaf, mask, self.spec.num_leaves, axis_name))
             # tree state is identical on all shards (computed from psum'd
             # histograms); mark it replicated for the out_spec
             tree = jax.tree.map(lambda a: jax.lax.pmean(a, axis_name) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
-            return tree, row_leaf
+            # so are the rounds grower's round counts: every shard runs
+            # the same ladder on the same global leaf counts
+            return (tree, row_leaf, *(st["rounds"] for st in stats))
 
         in_specs = (bins_spec, rep, rep, rep, rep, row, row, row, rep, rep,
                     row, rep, rep, rep, rep, rep, rep)
         out_specs = (jax.tree.map(lambda _: rep, _tree_arrays_structure(spec)), row)
-        self._fn = jax.jit(
-            jax.shard_map(
-                fn,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=False,
+
+        def build(with_stats: bool):
+            return jax.jit(
+                jax.shard_map(
+                    partial(fn, with_stats),
+                    mesh=mesh,
+                    in_specs=in_specs,
+                    out_specs=out_specs + (rep,) * with_stats,
+                    check_vma=False,
+                )
             )
-        )
+
+        self._fn = build(False)
+        # with the rounds grower's per-width round counts as a third
+        # output (boosting's fused step reads them for
+        # lgbmtpu_grower_rounds_total, as on one chip)
+        self._fn_stats = build(True) if self.spec.rounds_slots > 0 else None
 
     def __call__(self, bins, nan_bin, num_bins, mono, is_cat, grad, hess, mask,
                  feat_mask, params: SplitParams, valid, bundle=None,
                  rng_key=None, group_mat=None, cegb=None, forced=None,
-                 gh_scale=None) -> Tuple[TreeArrays, jax.Array]:
-        return self._fn(
+                 gh_scale=None, with_stats: bool = False):
+        """(tree, row_leaf); with_stats=True appends the grower's stats
+        as grow_tree does: {"rounds"} from the rounds grower, else
+        None."""
+        args = (
             bins, nan_bin, num_bins, mono, is_cat, grad, hess, mask, feat_mask,
             params, valid, bundle, rng_key, group_mat, cegb, forced, gh_scale,
         )
+        if not with_stats:
+            return self._fn(*args)
+        if self._fn_stats is None:
+            return (*self._fn(*args), None)
+        tree, row_leaf, rounds = self._fn_stats(*args)
+        return tree, row_leaf, {"rounds": rounds}
 
     def wire_bytes_per_tree(self, num_features: int) -> int:
         """Host-side estimate of the collective payload per grown tree:
@@ -151,54 +217,58 @@ class DataParallelGrower:
             self._wire_est[F] = est
         return est
 
-    def shard_inputs(self, dev: dict) -> dict:
-        """device_put the dataset arrays with the right shardings.
 
-        Multi-process clusters (jax.distributed): per-row arrays are
-        PROCESS-LOCAL shards assembled into global arrays
-        (pre_partition=true semantics, each rank contributed its rows);
-        single-process meshes device_put directly."""
-        from ..learner.histogram import HIST_BLK
+def check_shard_rows(n_rows: int, mesh: Mesh) -> None:
+    """A row-sharded array's rows a chip must be whole Pallas row
+    blocks: on a TPU anything else would send the histograms to the
+    einsum formulation, which is not a program to run there (GBDT pads
+    the training set to HIST_BLK x devices before the first push)."""
+    from ..learner.histogram import HIST_BLK
 
-        n_dev = self.mesh.devices.size
-        n_rows = dev["bins"].shape[1]
-        multiproc = jax.process_count() > 1
-        local_dev = n_dev // jax.process_count() if multiproc else n_dev
-        if on_tpu() and (n_rows // max(local_dev, 1)) % HIST_BLK != 0:
-            from .. import log
+    n_local = int(mesh.devices.size) // max(jax.process_count(), 1)
+    if on_tpu() and (n_rows // max(n_local, 1)) % HIST_BLK != 0:
+        raise ValueError(
+            f"per-shard rows ({n_rows}/{n_local}) are not a multiple of "
+            f"the pallas histogram block ({HIST_BLK}): pad the rows to "
+            "row_block * num_devices (BinnedDataset.ensure_row_block)"
+        )
 
-            log.warning(
-                f"per-shard rows ({n_rows}/{local_dev}) are not a multiple of "
-                f"the pallas histogram block ({HIST_BLK}); histograms will use "
-                f"the slow einsum fallback — pad rows to row_block*num_devices"
-            )
-        row = NamedSharding(self.mesh, P(self.axis_name))
-        rep = NamedSharding(self.mesh, P())
-        out = dict(dev)
-        if multiproc:
-            from .multihost import global_rows
 
-            def put_rep(a):
-                return jax.make_array_from_process_local_data(
-                    rep, np.asarray(a)
-                )
+def put_rows(host_rows, shape: Tuple[int, ...], mesh: Mesh, axis: int,
+             shard: bool = True) -> jax.Array:
+    """A device array of `shape` over `mesh` from the HOST:
+    `host_rows(lo, hi)` gives rows [lo, hi) along `axis`. Sharded over
+    the mesh's one axis when `shard` (each chip is sent its own rows
+    and nothing else: no chip stages the whole array, nothing is
+    re-sharded on the device), else replicated. In a multi-process
+    cluster this process's rows ARE its shard (pre_partition
+    semantics: shards concatenate in process order)."""
+    name = mesh.axis_names[0]
+    spec = [None] * len(shape)
+    if shard:
+        spec[axis] = name
+    sharding = NamedSharding(mesh, P(*spec))
+    n = shape[axis]
+    if jax.process_count() > 1:
+        return jax.make_array_from_process_local_data(
+            sharding, host_rows(0, n))
 
-            out["bins"] = global_rows(np.asarray(dev["bins"]), self.mesh, axis=1)
-            out["valid"] = global_rows(np.asarray(dev["valid"]), self.mesh, axis=0)
-        else:
+    def piece(index):
+        lo, hi, _ = index[axis].indices(n)
+        return host_rows(lo, hi)
 
-            def put_rep(a):
-                return jax.device_put(a, rep)
+    return jax.make_array_from_callback(tuple(shape), sharding, piece)
 
-            out["bins"] = jax.device_put(
-                dev["bins"], NamedSharding(self.mesh, P(None, self.axis_name))
-            )
-            out["valid"] = jax.device_put(dev["valid"], row)
-        for k in ("nan_bin", "num_bins", "mono", "is_cat"):
-            out[k] = put_rep(dev[k])
-        if dev.get("bundle") is not None:
-            out["bundle"] = jax.tree.map(put_rep, dev["bundle"])
-        return out
+
+def put_replicated(tree, mesh: Mesh):
+    """Small host (or device) arrays of a pytree, replicated over the
+    mesh; None stays None."""
+    rep = NamedSharding(mesh, P())
+    if jax.process_count() > 1:
+        return jax.tree.map(
+            lambda a: jax.make_array_from_process_local_data(
+                rep, np.asarray(a)), tree)
+    return jax.tree.map(lambda a: jax.device_put(a, rep), tree)
 
 
 def _tree_arrays_structure(spec: GrowerSpec) -> TreeArrays:

@@ -51,6 +51,25 @@ from lightgbm_tpu.analysis.pytest_plugin import (  # noqa: E402,F401
 )
 
 
+@pytest.fixture
+def mesh4(monkeypatch):
+    """tree_learner=data over FOUR of the eight virtual devices (the
+    four chips of one host): the Booster asks data_parallel.make_mesh
+    for its mesh, so steering that is steering the path, with no option
+    of the program. Returns the four devices."""
+    import jax
+
+    from lightgbm_tpu.parallel import data_parallel
+
+    devices = jax.devices()[:4]
+    real = data_parallel.make_mesh
+    monkeypatch.setattr(
+        data_parallel, "make_mesh",
+        lambda devs=None, axis_name="data": real(
+            devices if devs is None else devs, axis_name))
+    return devices
+
+
 def make_synthetic_regression(n=1000, n_features=10, seed=42):
     """Small regression fixture (reference tests utils.py pattern)."""
     rs = np.random.RandomState(seed)

@@ -128,14 +128,25 @@ def test_route_kernel_compiles_in_one_call(one_chip, no_compile_cache,
     assert not re.search(r"%(hist_round_tpu|hist_nat_tpu)\b", text)
 
 
-def test_root_kernel_compiles_at_137_columns(one_chip, no_compile_cache):
+# higgs-dp4.train (PR 32): one shard of 54,525,952 rows over the four
+# chips of a v5e:2x2, 28 columns. Only the root pass is compiled here
+# (3 s): the fused round at 28 x 255 takes 11 s at 8 slots and 73 s at
+# 48, over this suite's clock (ROADMAP D13), and is left to
+# `benchmark/tools/aot_kernels.py --config higgs-dp4` by hand.
+DP4_SHARD_ROWS = 13 * (1 << 20)
+
+
+@pytest.mark.parametrize("features,rows", [
+    (FEATURES, ROWS), (28, DP4_SHARD_ROWS)], ids=["137", "dp4-shard"])
+def test_root_kernel_compiles_at_137_columns(one_chip, no_compile_cache,
+                                             features, rows):
     from lightgbm_tpu.learner.pallas_hist import hist_nat_tpu
 
     fn = jax.jit(lambda b, g, s: hist_nat_tpu(b, g, s, 1, BINS, nat_ch=3))
     compiled = fn.lower(
-        _arg(one_chip, (FEATURES, ROWS), jnp.int32),
-        _arg(one_chip, (8, ROWS), jnp.float32),
-        _arg(one_chip, (ROWS,), jnp.int32)).compile()
+        _arg(one_chip, (features, rows), jnp.int32),
+        _arg(one_chip, (8, rows), jnp.float32),
+        _arg(one_chip, (rows,), jnp.int32)).compile()
     assert "hist_nat_tpu" in compiled.as_text()
 
 

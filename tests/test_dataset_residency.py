@@ -158,16 +158,8 @@ def _invalidate(ds, y1, w1):
     return None, None
 
 
-def _data_parallel_booster(ds, y1, w1):
-    # shards copies of the data set's arrays and drops the unsharded ones
-    bst = lgb.train(dict(PARAMS, tree_learner="data"), ds, num_boost_round=2)
-    assert bst._gbdt.tree_learner_resolved == "data"
-    assert not ds._binned._rows_dev
-    return None, None
-
-
 ROUTES = [_set_label, _set_label_same_memory, _set_weight, _set_field,
-          _row_padding, _invalidate, _data_parallel_booster]
+          _row_padding, _invalidate]
 
 
 @pytest.mark.parametrize("route", ROUTES, ids=lambda f: f.__name__.lstrip("_"))
@@ -203,6 +195,110 @@ def test_invalidation_route_misses_and_trains_on_the_new_arrays(route):
     # what the first Booster was handed is untouched (never written in
     # place, never donated): the old labels, padded with zeros
     assert np.array_equal(np.asarray(old_label)[:N], y0)
+
+
+def test_a_data_parallel_booster_leaves_the_one_chip_copies_resident(mesh4):
+    """A tree_learner=data Booster used to re-shard the Dataset's arrays
+    and throw the unsharded ones away; since PR 32 it takes the Dataset's
+    own mesh copies and drops nothing: a one-chip Booster afterwards
+    hits every cache and trains the same model."""
+    X, z, _rs = _problem(seed=7)
+    y = (z > 0).astype(np.float32)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False).construct()
+    before = _text(PARAMS, ds)
+    one_chip = ds._binned.device_arrays()
+    old_label = ds._binned.device_label()
+    bst = lgb.train(dict(PARAMS, tree_learner="data"), ds, num_boost_round=2)
+    g = bst._gbdt
+    assert g.tree_learner_resolved == "data"
+    assert g._mesh.devices.size == 4
+    c0 = _counts()
+    assert _text(PARAMS, ds) == before
+    d = _delta(c0)
+    assert d["label", "miss"] == 0 and d["stats", "miss"] == 0, d
+    assert ds._binned.device_arrays() is one_chip
+    assert ds._binned.device_label() is old_label
+    # the mesh copies sit beside them, and go when the cache is dropped
+    assert g.dev is ds._binned.device_arrays(g._mesh)
+    assert g._label_dev is ds._binned.device_label(g._mesh)
+    ds._binned.invalidate_device_cache()
+    assert ds._binned.device_arrays(g._mesh) is not g.dev
+
+
+DP = dict(PARAMS, tree_learner="data", tpu_growth_mode="rounds",
+          tpu_hist_dtype="int16", metric="auc")
+
+
+def _push_bytes():
+    c = default_registry().counter(
+        "lgbmtpu_dataset_push_bytes_total", labels=("kind",))
+    return {k: c.value(kind=k) for k in ("bins", "rows")}
+
+
+def test_mesh_copy_is_sharded_from_the_host_and_resident(mesh4):
+    """The bins of a data-parallel Booster sit on four distinct devices,
+    each shard a quarter of the padded rows; the Dataset's one-chip copy
+    is never built; a second lgb.train on the Dataset pushes no bin and
+    no label byte, and traces, lowers, compiles and loads nothing."""
+    from lightgbm_tpu.analysis.retrace import (compile_counters,
+                                                retrace_guard)
+
+    n = 4096
+    rs = np.random.RandomState(11)
+    X = rs.randn(n, F)
+    y = (X @ rs.randn(F) + 0.3 * rs.randn(n) > 0).astype(np.float64)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False).construct()
+    vs = lgb.Dataset(X[:1000], label=y[:1000], reference=ds).construct()
+    kw = dict(num_boost_round=8, valid_sets=[vs], valid_names=["v"])
+    # the padding a TPU run takes (whole Pallas row blocks a chip): the
+    # padded row count must stay a Python int through it
+    from lightgbm_tpu.learner.histogram import HIST_BLK
+
+    ds._binned.ensure_row_block(4 * HIST_BLK)
+    assert type(ds._binned.num_rows_padded()) is int
+
+    p0 = _push_bytes()
+    b1 = lgb.train(dict(DP), ds, **kw)
+    g = b1._gbdt
+    binned = ds._binned
+    assert binned._device is None and vs._binned._device is None
+    assert "label" not in binned._rows_dev  # nor a one-chip label
+    bins = g.dev["bins"]
+    npad = binned.num_rows_padded()
+    shards = bins.addressable_shards
+    assert {s.device for s in shards} == set(mesh4)
+    assert all(s.data.shape == (F, npad // 4) for s in shards)
+    assert np.array_equal(np.asarray(bins)[:, :n], binned.bins)
+    assert not np.asarray(bins)[:, n:].any()
+    # label and valid mask ride the rows; a valid set is replicated
+    assert g._label_dev.sharding == g.dev["valid"].sharding
+    assert g.objective.label is g._label_dev
+    vdev = g._dev_of(vs._binned)
+    assert vdev["bins"].sharding.is_fully_replicated
+    assert g.train.score.sharding.spec == (None, "data")
+    p1 = _push_bytes()
+    vpad = vs._binned.num_rows_padded()
+    # int32 bins: the train rows once, the valid rows once a device
+    assert p1["bins"] - p0["bins"] == F * 4 * (npad + 4 * vpad)
+    assert p1["rows"] - p0["rows"] == 4 * (npad + 4 * vpad)
+
+    c0 = compile_counters()
+    with retrace_guard(max_retraces=0, what="second data-parallel train"):
+        b2 = lgb.train(dict(DP), ds, **kw)
+    c1 = compile_counters()
+    for k in ("jaxpr_traces", "backend_compiles", "lower_s", "cache_load_s"):
+        assert c1[k] == c0[k], (k, c0[k], c1[k])
+    assert _push_bytes() == p1
+    assert b2._gbdt.dev is g.dev
+    assert b2._gbdt._dp is g._dp  # one grower per (mesh, spec)
+    assert b2._gbdt._f_program is g._f_program
+    # one program per chunk length: a job's first dispatch (fresh state)
+    # and its later ones (a chunk's output state) find the state's
+    # leaves under the same shardings
+    assert {n: f._cache_size() for n, f in g._f_program.chunks.items()} \
+        == {4: 1}
+    assert b2._gbdt.train.score is not g.train.score
+    assert b2.model_to_string() == b1.model_to_string()
 
 
 def test_a_subset_is_its_own_dataset():

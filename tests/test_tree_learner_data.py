@@ -316,3 +316,190 @@ def test_data_parallel_quant_reduce_scatter_wire():
     assert "reduce_scatter" in txt or "psum_scatter" in txt, (
         "integer reduce-scatter wire not found in the compiled grower"
     )
+
+
+# ---- PR 32: tree_learner=data on the NORMAL path (resident sharded
+# Dataset, memoized fused step, round counts), four of the eight
+# virtual devices. One shape and one parameter set for all of these, so
+# they share the mesh program's compile (and the persistent cache).
+DP4 = {**BASE, "min_data_in_leaf": 5, "tpu_growth_mode": "rounds",
+       "tpu_hist_dtype": "int16"}
+N4, F4, NV4 = 4096, 6, 1000
+
+
+def _problem4(seed):
+    rs = np.random.RandomState(seed)
+    X = rs.randn(N4, F4)
+    y = (X @ rs.randn(F4) + 0.3 * rs.randn(N4) > 0).astype(np.float64)
+    return X, y
+
+
+def _train4(X, y, extra, valid=True, rounds=4):
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    kw = {}
+    if valid:
+        vs = lgb.Dataset(X[:NV4], label=y[:NV4], reference=ds)
+        kw = dict(valid_sets=[vs], valid_names=["v"])
+    return lgb.train({**DP4, **extra}, ds, num_boost_round=rounds, **kw)
+
+
+def _assert_same_trees(got, want, leaf_rtol):
+    """Structure and leaf counts exact; leaf values within `leaf_rtol`."""
+    a, b = got._gbdt.models, want._gbdt.models
+    assert len(a) == len(b)
+    for ta, tb in zip(a, b):
+        assert ta.num_leaves == tb.num_leaves
+        for f in ("split_feature", "threshold", "decision_type",
+                  "left_child", "right_child", "leaf_count",
+                  "internal_count"):
+            np.testing.assert_array_equal(getattr(ta, f), getattr(tb, f), f)
+        np.testing.assert_allclose(ta.leaf_value, tb.leaf_value,
+                                   rtol=leaf_rtol, atol=1e-7)
+
+
+@pytest.mark.parametrize("valid", [True, False], ids=["valid", "no_valid"])
+def test_mesh_model_equals_the_one_device_rounds_program(mesh4, valid):
+    """Same data, same rounds program, int16 channels: the trees'
+    structure and every leaf count are exact. The integer histograms
+    cross the mesh exactly at this size (rs_int32), so the leaf values
+    differ only by the f32 sums of the true-gradient renewal, which a
+    mesh adds in another order (1.4e-5 relative seen over four trees):
+    1e-4 relative, stated."""
+    X, y = _problem4(31)
+    serial = _train4(X, y, {}, valid)
+    mesh = _train4(X, y, {"tree_learner": "data"}, valid)
+    g = mesh._gbdt
+    assert g.tree_learner_resolved == "data" and g._mesh.devices.size == 4
+    assert g.hist_wire_resolved == "rs_int32"
+    assert not g._force_sync and g._f_program is not None  # the fused loop
+    _assert_same_trees(mesh, serial, leaf_rtol=1e-4)
+
+
+def test_memoized_mesh_step_bakes_no_array_of_its_first_dataset(mesh4):
+    """A second Dataset of the same shapes and other data goes through
+    the first one's memoized step and yields ITS OWN trees: those a
+    process that never saw the first Dataset grows (the step memo and
+    the shared grower dropped, so the program is traced afresh from the
+    second Dataset alone)."""
+    from lightgbm_tpu.boosting import _FUSED_STEP_CACHE
+    from lightgbm_tpu.parallel import data_parallel
+
+    Xa, ya = _problem4(41)
+    Xb, yb = _problem4(43)
+    dp = {"tree_learner": "data"}
+    first = _train4(Xa, ya, dp)
+    second = _train4(Xb, yb, dp)
+    assert second._gbdt._f_program is first._gbdt._f_program
+    assert second._gbdt._dp is first._gbdt._dp
+    assert second.model_to_string() != first.model_to_string()
+    _FUSED_STEP_CACHE.clear()
+    data_parallel._GROWERS.clear()
+    fresh = _train4(Xb, yb, dp)
+    assert fresh._gbdt._f_program is not first._gbdt._f_program
+    assert second.model_to_string() == fresh.model_to_string()
+
+
+def test_mesh_grower_counts_the_one_device_growers_rounds(mesh4):
+    """lgbmtpu_grower_rounds_total ticks under a mesh as on one chip:
+    the same tree takes the same rounds at the same widths."""
+    from lightgbm_tpu.obs.metrics import default_registry
+
+    def rounds_of(extra):
+        c = default_registry().counter("lgbmtpu_grower_rounds_total",
+                                       labels=("width",))
+        widths = ("8", "14", "route")
+        before = [c.value(width=w) for w in widths]
+        bst = _train4(*_problem4(31), extra)
+        assert bst._gbdt._f_ladder_widths == (8, 14)
+        return [c.value(width=w) - b for w, b in zip(widths, before)]
+
+    one = rounds_of({})
+    assert sum(one) > 0 and one[2] >= 0
+    assert rounds_of({"tree_learner": "data"}) == one
+
+
+def test_cv_folds_of_equal_shape_share_one_mesh_step(mesh4):
+    from lightgbm_tpu.boosting import _FUSED_STEP_CACHE
+
+    X, y = _problem4(47)
+    ds = lgb.Dataset(X, label=y, free_raw_data=False)
+    n_before = len(_FUSED_STEP_CACHE)
+    res = lgb.cv({**DP4, "tree_learner": "data"}, ds, num_boost_round=3,
+                 nfold=4, stratified=False, shuffle=False,
+                 return_cvbooster=True)
+    boosters = res["cvbooster"].boosters
+    assert len(boosters) == 4
+    gs = [b._gbdt for b in boosters]
+    assert all(g.tree_learner_resolved == "data" for g in gs)
+    # four folds of 3,072 + 1,024 rows: one traced step, one grower
+    assert len({id(g._f_program) for g in gs}) == 1
+    assert len({id(g._dp) for g in gs}) == 1
+    assert len(_FUSED_STEP_CACHE) - n_before <= 1
+    assert len(res["valid auc-mean"]) == 3
+
+
+def test_mesh_recounts_leaves_past_the_float32_range(mesh4, monkeypatch):
+    """Past 2**24 global rows the float32 count arithmetic of the grower
+    is no longer exact, so the mesh grower recounts every leaf from the
+    rows (per shard, summed as integers). The limit is lowered here so
+    that a small table takes that path, with bagging so that the count
+    is of IN-BAG rows: the counts are the one-device program's."""
+    from lightgbm_tpu.boosting import _FUSED_STEP_CACHE
+    from lightgbm_tpu.parallel import data_parallel
+
+    def fresh():
+        _FUSED_STEP_CACHE.clear()
+        data_parallel._GROWERS.clear()
+
+    monkeypatch.setattr(data_parallel, "F32_EXACT_ROWS", 1024)
+    # the chip's kernels under the interpreter, on whole row blocks a
+    # shard (as a TPU run pads): the recount's seg_sum_tpu runs INSIDE
+    # the grower's shard_map and must not wrap itself in another
+    monkeypatch.setenv("LGBM_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(
+        lgb.basic.Dataset, "construct", _padded_to_whole_blocks(
+            lgb.basic.Dataset.construct))
+    fresh()
+    try:
+        X, y = _problem4(53)
+        bag = {"bagging_fraction": 0.7, "bagging_freq": 1}
+        serial = _train4(X, y, bag)
+        mesh = _train4(X, y, {**bag, "tree_learner": "data"})
+        assert mesh._gbdt.dev["bins"].shape[1] == 4 * 2048
+        jaxpr = str(_grower_jaxpr(mesh._gbdt))
+        assert "psum" in jaxpr and "i32[15]" in jaxpr  # the integer sum
+        _assert_same_trees(mesh, serial, leaf_rtol=1e-4)
+        counts = [int(t.leaf_count.sum()) for t in mesh._gbdt.models]
+        assert all(0 < c < N4 for c in counts), counts  # in-bag rows only
+    finally:
+        fresh()
+
+
+def _padded_to_whole_blocks(construct):
+    """Dataset.construct that pads the rows to 4 x HIST_BLK, what
+    GBDT.__init__ asks of a training set on a TPU."""
+    from lightgbm_tpu.learner.histogram import HIST_BLK
+
+    def padded(self, *a, **kw):
+        out = construct(self, *a, **kw)
+        self._binned.ensure_row_block(4 * HIST_BLK)
+        return out
+
+    return padded
+
+
+def _grower_jaxpr(g):
+    """The jaxpr of a data-parallel Booster's shared grower on its own
+    training arrays."""
+    import jax
+    import jax.numpy as jnp
+
+    d = g.dev
+    n = d["bins"].shape[1]
+    ones = jnp.ones(n, jnp.float32)
+    return jax.make_jaxpr(lambda *a: g._dp._fn(*a))(
+        d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
+        ones, ones, d["valid"], jnp.ones(d["bins"].shape[0], bool),
+        g.params, d["valid"], None, None, None, None, None,
+        jnp.asarray(np.float32([0.1, 0.1])),
+    )
