@@ -202,6 +202,17 @@ def hist_schedule(spec: GrowerSpec, n_rows: int, n_cols: int
                             routed, calls)
 
 
+def take_rows(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """table[idx] for a handful of rows of a large table, as one row
+    slice per index and not a gather: XLA lowers the gather to work
+    over the WHOLE table (element work over the bin matrix, 7 ms a
+    round at 2,000 x 400k, my chip run, PR 30; slices of the whole
+    histogram pool through VMEM, PERF.md section 6, PR 33)."""
+    return jnp.concatenate([
+        lax.dynamic_slice_in_dim(table, idx[k], 1, axis=0)
+        for k in range(idx.shape[0])])
+
+
 def spends_budget(n_cand: jax.Array, budget: jax.Array, slots: int
                   ) -> jax.Array:
     """Will a round of `n_cand` candidates end the tree by spending all
@@ -223,7 +234,6 @@ class _NState(NamedTuple):
     # with_stats=True), which the fused step reads for
     # lgbmtpu_grower_rounds_total{width}.
     pleaf: jax.Array  # (N,) int32 row -> leaf; invalid rows carry L
-    hist: jax.Array  # (L, 3, G, Bc) histogram pool
     leaf_g: jax.Array
     leaf_h: jax.Array
     leaf_c: jax.Array
@@ -240,13 +250,6 @@ class _NState(NamedTuple):
     leaf_groups: jax.Array  # (L, NG | 0) bool — legal constraint groups
     path_used: jax.Array  # (L, F | 0) bool — features on the leaf's path
     feat_used: jax.Array  # (F | 0,) bool — used anywhere (CEGB coupled)
-    # voting-parallel: hist_valid[leaf, f] = the stored histogram column
-    # holds GLOBAL (mesh-reduced) sums for feature f — all-True except
-    # under voting, where only elected columns cross the mesh. Child
-    # search and parent subtraction are masked to valid columns
-    # (permuted.py hist_valid, lifted onto the round-batched state).
-    # Zero-width when voting is off.
-    hist_valid: jax.Array  # (L, F | 0) bool
     # advanced monotone constraints: per-leaf per-feature bin range
     # (lo, hi], refined at each numeric split (left keeps hi=min(hi,
     # bin); right lo=max(lo, bin)). Two leaves can form a violating
@@ -257,6 +260,41 @@ class _NState(NamedTuple):
     leaf_fhi: jax.Array  # (L, F | 0) int32
     best: SplitRecord  # per-leaf best splits, fields (L,)
     tree: TreeArrays
+
+
+class _Pools(NamedTuple):
+    """The per-leaf tables a round reads whole and writes <= 2S rows
+    of. They ride the while loop BESIDE _NState: round_step's branches
+    take them as read-only operands and return only their round's rows
+    (_Children), which body() scatters into the carry once, in place.
+    Returned whole from a lax.switch / lax.cond branch, the compiler
+    transposed and copied the pool around every round (seven pool-sized
+    copies a round; PERF.md section 6, PR 33)."""
+
+    # the histogram pool, a leaf's (3, G, Bc) histogram as three FLAT
+    # rows (the kernels' own output form): one leaf is one contiguous
+    # block of the carry whatever the bin count (63 bins as a minor
+    # dimension pad to 128 lanes), a row slice reads it and a row
+    # scatter writes it, and the carry has no second layout to be
+    # turned to; only the rows a round reads are shaped back (pool_rows)
+    hist: jax.Array  # (L, 3, G*Bc)
+    # voting-parallel: valid[leaf, f] = the stored histogram column
+    # holds GLOBAL (mesh-reduced) sums for feature f — all-True except
+    # under voting, where only elected columns cross the mesh. Child
+    # search and parent subtraction are masked to valid columns
+    # (permuted.py hist_valid, lifted onto the round-batched state).
+    # Zero-width when voting is off.
+    valid: jax.Array  # (L, F | 0) bool
+
+
+class _Children(NamedTuple):
+    """What a round writes into _Pools: its left children, then its
+    right ones, padded to twice the program's slot count so that every
+    rung and the routing-only round return one shape."""
+
+    leaf: jax.Array  # (2S,) int32 pool rows; L = nothing to write
+    hist: jax.Array  # (2S, 3, G*Bc)
+    valid: jax.Array  # (2S, F | 0) bool
 
 
 @partial(jax.jit, static_argnames=("spec", "with_stats"))
@@ -519,7 +557,13 @@ def grow_tree_rounds(
                           penalty=pen0, rand_bin=rb0)
 
     Gc = Gn if use_rs else G  # pool feature width (owned block under rs)
-    hist = jnp.zeros((L, 3, Gc, Bc), jnp.float32).at[0].set(hist0)
+    hist = jnp.zeros((L, 3, Gc * Bc), jnp.float32).at[0].set(
+        hist0.reshape(3, -1))
+
+    def pool_rows(h):
+        """(..., 3, G*Bc) rows of the pool -> (..., 3, G, Bc)."""
+        return h.reshape(h.shape[:-1] + (Gc, Bc))
+
     best = _set_best(_empty_best(L, B), jnp.int32(0), rec0, rec0.gain)
 
     tree = TreeArrays(
@@ -572,7 +616,19 @@ def grow_tree_rounds(
     # keep a histogram in every round
     route_last = not spec.mono_mode
 
-    def body(s: _NState) -> _NState:
+    def child_best(h, g_, h__, c_, po, cmn, cmx, fm=None, rb=None,
+                   pen=None):
+        # under use_rs the tables are this rank's owned block and
+        # the winner is elected globally by the caller
+        return best_split(
+            exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
+            mono_t, iscat_t, params, fm_t if fm is None else fm,
+            cat_subset=spec.cat_subset, parent_output=po,
+            cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
+        )
+
+    def body(carry: Tuple[_Pools, _NState]) -> Tuple[_Pools, _NState]:
+        pools, s = carry
         budget0 = (L - 1) - s.i
         n_pos = jnp.sum(s.best.gain > 0.0).astype(jnp.int32)
         n_cand = jnp.minimum(budget0, n_pos)
@@ -590,38 +646,68 @@ def grow_tree_rounds(
             n_cand > jnp.asarray(widths[:-1], jnp.int32)
         ).astype(jnp.int32)
 
-        def ladder(st: _NState) -> _NState:
+        def ladder(pl: _Pools, st: _NState) -> Tuple[_NState, _Children]:
             return lax.switch(
                 bidx,
                 [partial(round_step, Sk=w, n_max=n_cand) for w in widths],
-                st,
+                pl, st,
             )
 
         if not route_last:
-            return ladder(s._replace(r=s.r.at[bidx].add(1).at[-1].add(1)))
-        # ---- the round that spends the last of the leaf budget routes
-        # rows only: after it cond() ends the loop on `i`, and nothing
-        # reads the children's histograms or their best splits (the
-        # reference builds none after its last Split either,
-        # serial_tree_learner.cpp Train). One branch at the full slot
-        # count serves every tree size: top-k of a wider k picks the
-        # same set, and without a histogram block the width costs the
-        # pass next to nothing. A cond AROUND the ladder's switch, not
-        # a fifth branch of it: as a fifth branch the compiler rounded
-        # the 137-column program's split gains differently in their
-        # sixth digit (PERF.md section 6, PR 29); around it, every
-        # model text is the parent's byte for byte.
-        last = spends_budget(n_cand, budget0, S)
-        ridx = jnp.where(last, len(widths), bidx).astype(jnp.int32)
-        return lax.cond(
-            last,
-            partial(round_step, Sk=S, n_max=n_cand, route_only=True),
-            ladder,
-            s._replace(r=s.r.at[ridx].add(1).at[-1].add(1)),
+            s2, ch = ladder(
+                pools, s._replace(r=s.r.at[bidx].add(1).at[-1].add(1)))
+        else:
+            # ---- the round that spends the last of the leaf budget
+            # routes rows only: after it cond() ends the loop on `i`,
+            # and nothing reads the children's histograms or their best
+            # splits (the reference builds none after its last Split
+            # either, serial_tree_learner.cpp Train). One branch at the
+            # full slot count serves every tree size: top-k of a wider
+            # k picks the same set, and without a histogram block the
+            # width costs the pass next to nothing. A cond AROUND the
+            # ladder's switch, not a fifth branch of it: as a fifth
+            # branch the compiler rounded the 137-column program's
+            # split gains differently in their sixth digit (PERF.md
+            # section 6, PR 29); around it, every model text is the
+            # parent's byte for byte.
+            last = spends_budget(n_cand, budget0, S)
+            ridx = jnp.where(last, len(widths), bidx).astype(jnp.int32)
+            s2, ch = lax.cond(
+                last,
+                partial(round_step, Sk=S, n_max=n_cand, route_only=True),
+                ladder,
+                pools, s._replace(r=s.r.at[ridx].add(1).at[-1].add(1)),
+            )
+        # ---- the round's one write of the pools: <= 2S rows into the
+        # loop's carry, in place (a left child keeps its parent's row,
+        # a right one takes a fresh row: no two ids meet; pad ids drop)
+        pools2 = _Pools(
+            hist=pools.hist.at[ch.leaf].set(ch.hist, mode="drop"),
+            valid=(pools.valid.at[ch.leaf].set(ch.valid, mode="drop")
+                   if use_voting else pools.valid),
         )
+        if spec.mono_mode:
+            # intermediate / advanced constraints, step 3 (round_step):
+            # re-search every live leaf's best split under the round's
+            # new bounds, from the pool as the round leaves it (one
+            # vmapped pass keeps shapes static; the reference
+            # recomputes a leaves_to_update set)
+            t2 = s2.tree
+            rec_all = jax.vmap(child_best)(
+                pool_rows(pools2.hist), s2.leaf_g, s2.leaf_h, s2.leaf_c,
+                t2.leaf_value, s2.leaf_min, s2.leaf_max,
+            )
+            d_ok = (spec.max_depth <= 0) | (
+                t2.leaf_depth < spec.max_depth)
+            s2 = s2._replace(best=rec_all._replace(
+                gain=jnp.where((iota_L <= s2.i) & d_ok, rec_all.gain,
+                               NEG_INF)
+            ))
+        return pools2, s2
 
-    def round_step(s: _NState, Sk: int, n_max=None,
-                   route_only: bool = False) -> _NState:
+    def round_step(pools: _Pools, s: _NState, Sk: int, n_max=None,
+                   route_only: bool = False
+                   ) -> Tuple[_NState, _Children]:
         t = s.tree
         i = s.i
         S = Sk  # kernel width for this round (see the ladder above)
@@ -653,8 +739,8 @@ def grow_tree_rounds(
             fl = forced.leaf[fi]
             ff = forced.feature[fi]
             fb = forced.bin[fi]
-            fh = exp_hist(s.hist[fl], s.leaf_g[fl], s.leaf_h[fl],
-                          s.leaf_c[fl])
+            fh = exp_hist(pool_rows(pools.hist[fl]), s.leaf_g[fl],
+                          s.leaf_h[fl], s.leaf_c[fl])
             cg_f = jnp.cumsum(fh[0, ff])
             chs_f = jnp.cumsum(fh[1, ff])
             cc_f = jnp.cumsum(fh[2, ff])
@@ -904,13 +990,8 @@ def grow_tree_rounds(
             ).astype(jnp.int32)  # (S, 16)
             if use_routed:
                 # the routing pass sees the round's split columns as a
-                # table of their own: slot s's column is its row s. One
-                # row slice per slot, not bins_fm[col_s]: XLA lowers
-                # that gather to element work over the whole matrix
-                # (7 ms a round at 2,000 x 400k, my chip run, PR 30)
-                table = jnp.concatenate([
-                    lax.dynamic_slice_in_dim(bins_fm, col_s[k], 1, axis=0)
-                    for k in range(S)])  # (S, N)
+                # table of their own: slot s's column is its row s
+                table = take_rows(bins_fm, col_s)  # (S, N)
                 coh = jnp.eye(S, dtype=jnp.float32)
             else:
                 table = bins_fm
@@ -1031,35 +1112,57 @@ def grow_tree_rounds(
             .at[drop_new].set(rec.right_c, mode="drop")
         leaf_parent2 = jnp.where(sel, node_id, s.leaf_parent) \
             .at[drop_new].set(node_id, mode="drop")
+
+        def children(leaf, hist, valid) -> _Children:
+            """The round's pool rows at the program's full 2S."""
+            pad = [(0, 2 * widths[-1] - leaf.shape[0])]
+            return _Children(
+                leaf=jnp.pad(leaf, pad, constant_values=L),
+                hist=jnp.pad(hist, pad + [(0, 0)] * 2),
+                valid=jnp.pad(valid, pad + [(0, 0)]),
+            )
+
         if route_only:
             # the tree and the rows' leaves are all a last round leaves
-            # behind; the pool, the best splits and the constraint
-            # carries pass through, read by nobody
+            # behind; the best splits and the constraint carries pass
+            # through, read by nobody, and no pool row is written
             return s._replace(
                 i=i + n_split, pleaf=pleaf_new, leaf_g=leaf_g2,
                 leaf_h=leaf_h2, leaf_c=leaf_c2, leaf_parent=leaf_parent2,
                 tree=tree_new,
+            ), children(
+                jnp.zeros(0, jnp.int32),
+                jnp.zeros((0,) + pools.hist.shape[1:], jnp.float32),
+                jnp.zeros((0,) + pools.valid.shape[1:], bool),
             )
 
         # ---- per-slot child hists: smaller from the pass, larger by
-        # subtraction; scatter both into the pool. Work stays O(S), not
-        # O(L) — only the <= S split leaves are touched.
+        # subtraction; body() scatters both into the pool. Work stays
+        # O(S), not O(L) — only the <= S split leaves are touched.
         sl_c = sl_i  # (S,) clipped for gathers (computed above)
-        parent_s = s.hist[sl_c]  # (S, 3, G, Bc)
-        large_s = parent_s - slot_hists
-        ls_s = left_smaller[sl_c][:, None, None, None]
-        left_s = jnp.where(ls_s, slot_hists, large_s)
-        right_s = jnp.where(ls_s, large_s, slot_hists)
-        hist = s.hist.at[sel_leaf].set(left_s, mode="drop")
-        hist = hist.at[new_id_s].set(right_s, mode="drop")
+        # (elementwise on whole histograms, so on flat (S, 3*G*Bc)
+        # views: what the pool takes and what the search reads are
+        # two shapes of the one result. On 4-D operands or on pool
+        # rows the CPU backend fused the fused and the routed program
+        # differently and their model texts parted in a last digit;
+        # the chip reads the same either way: PERF.md section 6, PR 33)
+        slot_flat = slot_hists.reshape(S, -1)
+        parent_s = take_rows(pools.hist, sl_c).reshape(S, -1)
+        large_s = parent_s - slot_flat
+        ls_s = left_smaller[sl_c][:, None]
+        left_s = jnp.where(ls_s, slot_flat, large_s)
+        right_s = jnp.where(ls_s, large_s, slot_flat)
+        ch_flat = jnp.concatenate([left_s, right_s])  # (2S, 3*G*Bc)
+        ch_hist = ch_flat.reshape(2 * S, 3, Gc, Bc)
+        ch_leaf = jnp.concatenate([sel_leaf, new_id_s])
 
-        hist_valid2 = s.hist_valid
+        ch_valid = jnp.zeros((2 * S,) + pools.valid.shape[1:], bool)
         if use_voting:
             # the smaller child's histogram holds global sums exactly at
             # the elected columns; the larger sibling's subtraction is
             # additionally only sound where the PARENT's stored column
             # was global (permuted.py valid_small / valid_large)
-            valid_parent_s = s.hist_valid[sl_c]  # (S, F)
+            valid_parent_s = pools.valid[sl_c]  # (S, F)
             valid_small = jnp.broadcast_to(
                 elected[None, :], valid_parent_s.shape
             )
@@ -1067,38 +1170,21 @@ def grow_tree_rounds(
             ls_v = left_smaller[sl_c][:, None]
             valid_left = jnp.where(ls_v, valid_small, valid_large)
             valid_right = jnp.where(ls_v, valid_large, valid_small)
-            hist_valid2 = (
-                s.hist_valid.at[sel_leaf].set(valid_left, mode="drop")
-                .at[new_id_s].set(valid_right, mode="drop")
-            )
+            # only columns whose stored sums are global may be
+            # searched — unelected columns hold local/garbage sums
+            ch_valid = jnp.concatenate([valid_left, valid_right])
 
         # ---- best splits for the new children, batched over 2S ----
-        def child_best(h, g_, h__, c_, po, cmn, cmx, fm=None, rb=None,
-                       pen=None):
-            # under use_rs the tables are this rank's owned block and
-            # the winner is elected globally by the caller
-            return best_split(
-                exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
-                mono_t, iscat_t, params, fm_t if fm is None else fm,
-                cat_subset=spec.cat_subset, parent_output=po,
-                cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
-            )
-
         anc_in2, anc_left2 = s.anc_in, s.anc_left
         flo2, fhi2 = s.leaf_flo, s.leaf_fhi
         lg2, pu2, fu2 = s.leaf_groups, s.path_used, s.feat_used
         if not spec.mono_mode:
-            ch_hist = jnp.concatenate([left_s, right_s])  # (2S, 3, G, Bc)
             ch_g = jnp.concatenate([rec.left_g[sl_c], rec.right_g[sl_c]])
             ch_h = jnp.concatenate([rec.left_h[sl_c], rec.right_h[sl_c]])
             ch_c = jnp.concatenate([rec.left_c[sl_c], rec.right_c[sl_c]])
             ch_po = jnp.concatenate([lo[sl_c], ro[sl_c]])
             ch_mn = jnp.concatenate([lmin[sl_c], rmin[sl_c]])
             ch_mx = jnp.concatenate([lmax[sl_c], rmax[sl_c]])
-            if use_voting:
-                # only columns whose stored sums are global may be
-                # searched — unelected columns hold local/garbage sums
-                ch_valid = jnp.concatenate([valid_left, valid_right])
             if per_node:
                 # per-node candidate machinery for this round's 2S
                 # children (permuted.py node_candidates semantics)
@@ -1150,7 +1236,6 @@ def grow_tree_rounds(
                 jnp.concatenate([depth_ok_s, depth_ok_s]), ch_rec.gain,
                 NEG_INF
             )
-            ch_leaf = jnp.concatenate([sel_leaf, new_id_s])
 
             def scat(dst, val):
                 return dst.at[ch_leaf].set(val, mode="drop")
@@ -1297,21 +1382,14 @@ def grow_tree_rounds(
             cmin_mat = jnp.where(in_l & dec, Rmax, cmin_mat)
             nmax = jnp.min(cmax_mat, axis=1)  # (L,)
             nmin = jnp.max(cmin_mat, axis=1)
-
-            rec_all = jax.vmap(child_best)(
-                hist, leaf_g2, leaf_h2, leaf_c2, leaf_out2, nmin, nmax
-            )
-            d_ok = (spec.max_depth <= 0) | (
-                tree_new.leaf_depth < spec.max_depth)
-            best2 = rec_all._replace(
-                gain=jnp.where(valid_leaf & d_ok, rec_all.gain, NEG_INF)
-            )
+            # step 3 reads the pool as this round leaves it: body()
+            # re-searches after its scatter
+            best2 = s.best
 
         return _NState(
             i=i + n_split,
             r=s.r,
             pleaf=pleaf_new,
-            hist=hist,
             leaf_g=leaf_g2,
             leaf_h=leaf_h2,
             leaf_c=leaf_c2,
@@ -1323,37 +1401,37 @@ def grow_tree_rounds(
             leaf_groups=lg2,
             path_used=pu2,
             feat_used=fu2,
-            hist_valid=hist_valid2,
             leaf_flo=flo2,
             leaf_fhi=fhi2,
             best=best2,
             tree=tree_new,
-        )
+        ), children(ch_leaf, ch_flat.reshape(2 * S, 3, -1), ch_valid)
 
-    def _forced_valid(s: _NState):
+    def _forced_valid(pools: _Pools, s: _NState):
         """Is step s.i a forced split with both children non-empty?"""
         fi = jnp.minimum(s.i, spec.n_forced - 1)
         fl = forced.leaf[fi]
         ff = forced.feature[fi]
         fb = forced.bin[fi]
-        fh = exp_hist(s.hist[fl], s.leaf_g[fl], s.leaf_h[fl], s.leaf_c[fl])
+        fh = exp_hist(pool_rows(pools.hist[fl]), s.leaf_g[fl],
+                      s.leaf_h[fl], s.leaf_c[fl])
         lc = jnp.cumsum(fh[2, ff])[fb]
         return (s.i < forced.n) & (lc > 0) & (s.leaf_c[fl] - lc > 0)
 
-    def cond(s: _NState) -> jax.Array:
+    def cond(carry: Tuple[_Pools, _NState]) -> jax.Array:
+        pools, s = carry
         keep = jnp.max(s.best.gain) > 0.0
         if spec.n_forced:
             # only continue for a forced step that can actually split
             # (both children non-empty) — the round body falls back to
             # the best-gain split otherwise, which `keep` already guards
-            keep = keep | _forced_valid(s)
+            keep = keep | _forced_valid(pools, s)
         return (s.i < L - 1) & keep
 
     state = _NState(
         i=jnp.int32(0),
         r=jnp.zeros(len(widths) + 2, jnp.int32),
         pleaf=jnp.where(valid_f > 0, 0, L).astype(jnp.int32),
-        hist=hist,
         leaf_g=jnp.zeros(L, jnp.float32).at[0].set(root[0]),
         leaf_h=jnp.zeros(L, jnp.float32).at[0].set(root[1]),
         leaf_c=jnp.zeros(L, jnp.float32).at[0].set(root[2]),
@@ -1365,9 +1443,6 @@ def grow_tree_rounds(
         leaf_groups=lg0,
         path_used=pu0,
         feat_used=fu0,
-        # root histogram always crosses the mesh in full, so every
-        # column starts globally valid
-        hist_valid=jnp.ones((L, F if use_voting else 0), bool),
         leaf_flo=jnp.full(
             (L, F if spec.mono_mode == 2 else 0), -1, jnp.int32
         ),
@@ -1377,7 +1452,11 @@ def grow_tree_rounds(
         best=best,
         tree=tree,
     )
-    final = lax.while_loop(cond, body, state)
+    # root histogram always crosses the mesh in full, so every column
+    # starts globally valid
+    pools0 = _Pools(hist=hist,
+                    valid=jnp.ones((L, F if use_voting else 0), bool))
+    _, final = lax.while_loop(cond, body, (pools0, state))
 
     row_leaf = final.pleaf
     if valid is not None:

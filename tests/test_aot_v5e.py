@@ -232,3 +232,101 @@ def test_rank_marks_keep_their_names_in_the_compiled_program(
     text = jax.jit(f).lower(
         _arg(one_chip, (4096,), jnp.float32)).compile().as_text()
     assert "%rank_grad_begin_tpu" in text and "%rank_grad_end_tpu" in text
+
+
+# ------------------------------------- the whole grower (PR 33): how a
+# round writes the histogram pool. The first whole-program compile for
+# a described chip in the repo: the rounds grower at a narrow ROUTED
+# shape (576 columns x 63 bins = 2 feature blocks of 288, 8 row blocks,
+# 255 leaves, 48 slots, int16 channels), 20-30 s here; the wide cell's
+# own shape (2,000 x 63 x 196 row blocks) takes 45-55 s and shows the
+# same structure (PERF.md section 6, PR 33, has the recipe).
+POOL_ROWS, POOL_FEATURES, POOL_LEAVES = 8 * 2048, 576, 255
+
+
+def _while_body_instructions(text):
+    """(opcode, result elements, line) of every instruction in the
+    computations an optimised HLO module's `while` bodies reach."""
+    import math
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    calls = re.compile(
+        r"(?:calls|to_apply|body|condition|true_computation|"
+        r"false_computation)=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+    todo = [m.group(1) for lines in comps.values() for ln in lines
+            if " while(" in ln
+            for m in [re.search(r"body=%?([\w.\-]+)", ln)]]
+    assert todo, "no while loop in the compiled grower"
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            for one, many in calls.findall(ln):
+                todo += [one] if one else [
+                    n.strip().lstrip("%") for n in many.split(",")]
+    instr = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]"
+                       r"(?:\{[^}]*\})? ([\w\-]+)\(")
+    out = []
+    for c in sorted(seen):
+        for ln in comps[c]:
+            m = instr.match(ln)
+            if m and m.group(1):
+                n = math.prod(int(d) for d in m.group(1).split(","))
+                out.append((m.group(2), n, ln.strip()))
+    return out
+
+
+def test_no_pool_sized_copy_in_a_round_of_the_compiled_grower(
+        one_chip, no_compile_cache, monkeypatch):
+    """Inside the grower's while body nothing of the histogram pool's
+    size is copied or transposed: a round reads its parents' rows,
+    and writes its children's rows into the loop's carry in place. The
+    parent of PR 33 had SEVEN such ops here (the pool turned to the
+    scatters' layout before the ladder's switch, turned back in every
+    rung, copied once more in the 32- and 48-slot rungs): 41 ms of the
+    wide cell's 791 ms a tree."""
+    import sys
+
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner import GrowerSpec, make_split_params
+    from lightgbm_tpu.learner.rounds import grow_tree_rounds, hist_schedule
+
+    monkeypatch.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
+                        "_use_pallas", lambda: True)
+    F, N, L, B = POOL_FEATURES, POOL_ROWS, POOL_LEAVES, WIDE_BINS
+    spec = GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1,
+                      rounds_slots=48, quant=True, quant_levels=256,
+                      has_cat=False)
+    sched = hist_schedule(spec, N, F)
+    assert sched.routed and sched.plan.blocks == 2
+    params = jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype),
+                          make_split_params(Config({})))
+    cols = [_arg(one_chip, (F,), jnp.int32)] * 3 + [
+        _arg(one_chip, (F,), jnp.bool_)]
+    rows = [_arg(one_chip, (N,), jnp.float32)] * 3
+    text = jax.jit(
+        lambda bins, nan, nb, mono, cat, g, h, m, fm, p, sc:
+        grow_tree_rounds.__wrapped__(bins, nan, nb, mono, cat, g, h, m, fm,
+                                     p, spec, gh_scale=sc)
+    ).lower(_arg(one_chip, (F, N), jnp.int32), *cols, *rows,
+            _arg(one_chip, (F,), jnp.bool_), params,
+            _arg(one_chip, (2,), jnp.float32)).compile().as_text()
+    assert "%route_round_tpu" in text and "%hist_nat_tpu" in text
+    body = _while_body_instructions(text)
+    pool = L * 3 * F * B
+    assert any(n == pool for _, n, _ in body)  # the carry is in there
+    moved = [ln for op, n, ln in body
+             if n == pool and op in ("copy", "transpose")]
+    assert not moved, "\n".join(ln[:200] for ln in moved)

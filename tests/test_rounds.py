@@ -253,3 +253,69 @@ def test_rounds_interaction_constraints_structural():
 
     for t in model["tree_info"]:
         walk(t["tree_structure"], set())
+
+
+# ------------------------------------------------ how a round writes the pool
+_POOL_L, _POOL_F, _POOL_B, _POOL_N = 255, 4, 16, 512
+_POOL_SIZE = _POOL_L * 3 * _POOL_F * _POOL_B
+
+
+@pytest.fixture(scope="module")
+def pool_body():
+    """The while body of a small 255-leaf, 48-slot grower's jaxpr."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.analysis.jaxpr_audit import iter_eqns
+    from lightgbm_tpu.learner.rounds import grow_tree_rounds
+
+    spec = GrowerSpec(num_leaves=_POOL_L, num_bins=_POOL_B, max_depth=-1,
+                      rounds_slots=48, quant=True, quant_levels=256,
+                      has_cat=False)
+    F, N = _POOL_F, _POOL_N
+    closed = jax.make_jaxpr(
+        lambda b, g, h, sc: grow_tree_rounds(
+            b, jnp.full(F, -1, jnp.int32), jnp.full(F, _POOL_B, jnp.int32),
+            jnp.zeros(F, jnp.int32), jnp.zeros(F, bool), g, h,
+            jnp.ones(N, jnp.float32), jnp.ones(F, bool),
+            make_split_params(Config({})), spec, gh_scale=sc)
+    )(jnp.zeros((F, N), jnp.int32), jnp.zeros(N, jnp.float32),
+      jnp.ones(N, jnp.float32), jnp.ones(2, jnp.float32))
+    (loop,) = [e for e in iter_eqns(closed) if e.primitive.name == "while"]
+    return loop.params["body_jaxpr"].jaxpr
+
+
+@pytest.mark.parametrize("branch", ["8", "16", "32", "48", "route"])
+def test_round_writes_the_pool_once_in_the_carry(pool_body, branch):
+    """No rung of the ladder's switch and neither side of the routing
+    round's cond returns the histogram pool: a branch hands back its
+    round's <= 2S child rows, and the body scatters them into the
+    loop's carry ONCE, after the cond (returned whole from a branch,
+    the compiled grower turned and copied the pool seven times a round:
+    PERF.md section 6, PR 33)."""
+    (outer,) = [e for e in pool_body.eqns if e.primitive.name == "cond"]
+    route, ladder = (outer.params["branches"][1].jaxpr,
+                     outer.params["branches"][0].jaxpr)
+    (switch,) = [e for e in ladder.eqns if e.primitive.name == "cond"]
+    rungs = dict(zip(["8", "16", "32", "48"],
+                     (b.jaxpr for b in switch.params["branches"])))
+    assert len(rungs) == len(switch.params["branches"]) == 4
+    taken = route if branch == "route" else rungs[branch]
+    assert not any(e.primitive.name == "cond" for e in route.eqns)
+    # the pool goes IN (a read-only operand) and does not come out
+    assert sum(v.aval.size == _POOL_SIZE for v in taken.invars) == 1
+    for j in (taken, ladder):
+        assert _POOL_SIZE not in {v.aval.size for v in j.outvars}
+    assert _POOL_SIZE not in {v.aval.size for v in outer.outvars}
+    # every branch returns the same 2S padded rows and their ids
+    rows = 2 * 48 * 3 * _POOL_F * _POOL_B
+    assert sum(v.aval.size == rows for v in taken.outvars) == 1
+    # one scatter into the pool in the whole body, after the cond, and
+    # it is the carry's own
+    after = pool_body.eqns[pool_body.eqns.index(outer) + 1:]
+    writes = [e for e in pool_body.eqns
+              if e.primitive.name.startswith("scatter")
+              and e.outvars[0].aval.size == _POOL_SIZE]
+    assert len(writes) == 1 and writes[0] in after
+    assert writes[0].invars[0] in pool_body.invars
+    assert writes[0].outvars[0] in pool_body.outvars
