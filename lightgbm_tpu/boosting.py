@@ -33,7 +33,7 @@ from .learner.grower import TreeArrays, add_score
 from .metrics import Metric, create_metrics
 from .objectives import ObjectiveFunction, create_objective
 from .sample_strategy import create_sample_strategy
-from .timer import global_timer as _gt
+from .timer import device_phase, global_timer as _gt
 from .tree import Tree, traverse_tree_bins
 
 # canonical per-round host phase names (docs/OBSERVABILITY.md): the
@@ -898,8 +898,9 @@ class GBDT:
         spec.quant machinery (scales recovered before gain math), and
         renews leaf outputs from the TRUE gradients so the public
         semantics stay within stochastic-rounding noise of bf16x2."""
-        gq, hq, scale = self._quantize(gk, hk, it, k,
-                                       num_bins=self._hist_levels)
+        with device_phase("learner.quantize"):
+            gq, hq, scale = self._quantize(gk, hk, it, k,
+                                           num_bins=self._hist_levels)
         arrays, row_leaf, *stats = self._grow(
             gq, hq, mask, feat_mask, valid, it, k, gh_scale=scale,
             bins=bins, tables=tables, with_stats=with_stats,
@@ -932,7 +933,8 @@ class GBDT:
             return self._grow(gk, hk, mask, feat_mask, valid, it, k,
                               bins=bins, tables=tables,
                               with_stats=with_stats)
-        gq, hq, scale = self._quantize(gk, hk, it, k)
+        with device_phase("learner.quantize"):
+            gq, hq, scale = self._quantize(gk, hk, it, k)
         if self.spec.quant:
             # rounds grower consumes the integer levels directly: exact
             # int histogram sums in 3 channels/slot (48 slots/MXU pass)
@@ -963,13 +965,14 @@ class GBDT:
         passes its traced jit-argument copy)."""
         from .learner.renewal import renew_leaf_values
 
-        resid = (self._label_dev if label is None else label) - score_k
-        return arrays._replace(
-            leaf_value=renew_leaf_values(
-                arrays.leaf_value, row_leaf, resid, renew_w * mask,
-                renew_alpha, self.spec.num_leaves,
+        with device_phase("boosting.renew"):
+            resid = (self._label_dev if label is None else label) - score_k
+            return arrays._replace(
+                leaf_value=renew_leaf_values(
+                    arrays.leaf_value, row_leaf, resid, renew_w * mask,
+                    renew_alpha, self.spec.num_leaves,
+                )
             )
-        )
 
     # ------------------------------------------------------------------
     def _grow(self, gk, hk, mask, feat_mask, valid, it=0, k=0, gh_scale=None,
@@ -1698,7 +1701,8 @@ class GBDT:
             for a, v in data["obj_arrs"].items():
                 setattr(objective, a, v)
             try:
-                g, h = _obj_grads(objective, s_for_grad, it)
+                with device_phase("objective.gradients"):
+                    g, h = _obj_grads(objective, s_for_grad, it)
             finally:
                 for a, v in saved.items():
                     setattr(objective, a, v)
@@ -1749,23 +1753,25 @@ class GBDT:
                         data["renew_w"],
                         label=data["obj_arrs"]["label"],
                     )
-                lv = arrays.leaf_value * (shrink * ok)
-                one = jnp.float32(1.0)
-                score = score.at[k].set(
-                    add_score(score[k], row_leaf, lv, one)
-                )
+                with device_phase("boosting.score_update"):
+                    lv = arrays.leaf_value * (shrink * ok)
+                    one = jnp.float32(1.0)
+                    score = score.at[k].set(
+                        add_score(score[k], row_leaf, lv, one)
+                    )
                 new_vs = []
                 for vi in range(n_valid_sets):
-                    vleaf = traverse(
-                        arrays, data["vbins"][vi],
-                        data["vtables"][vi]["nan_bin"],
-                        data["vtables"][vi].get("bundle"),
-                    )
-                    new_vs.append(
-                        vscores[vi].at[k].set(
-                            add_score(vscores[vi][k], vleaf, lv, one)
+                    with device_phase("metrics.valid_eval"):
+                        vleaf = traverse(
+                            arrays, data["vbins"][vi],
+                            data["vtables"][vi]["nan_bin"],
+                            data["vtables"][vi].get("bundle"),
                         )
-                    )
+                        new_vs.append(
+                            vscores[vi].at[k].set(
+                                add_score(vscores[vi][k], vleaf, lv, one)
+                            )
+                        )
                 vscores = tuple(new_vs)
                 # stored tree carries the boost-from-average bias on
                 # the first iteration only (AddBias, gbdt.cpp:424);
@@ -1774,11 +1780,12 @@ class GBDT:
                 trees.append(arrays._replace(leaf_value=lv_stored))
             # metric evaluation entirely on device
             eval_scores = ([score] if track_train_eval else []) + list(vscores)
-            rows = [f(s) for f, s in zip(evals, eval_scores)]
-            eval_row = (
-                # `rows` is a host list: truthiness = len, not a tracer
-                jnp.concatenate(rows) if rows else jnp.zeros(0, jnp.float32)  # lint: allow[tracer-branch]
-            )
+            with device_phase("metrics.valid_eval"):
+                rows = [f(s) for f, s in zip(evals, eval_scores)]
+                eval_row = (
+                    # `rows` is a host list: truthiness = len, not a tracer
+                    jnp.concatenate(rows) if rows else jnp.zeros(0, jnp.float32)  # lint: allow[tracer-branch]
+                )
             # gradient/hessian norm summaries ride the eval row's tail
             # (two scalars; fused_collect slices them off) so the
             # flight recorder gets per-round gh norms from the fused

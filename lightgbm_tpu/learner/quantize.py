@@ -135,12 +135,14 @@ def renew_leaf_with_true_gradients(leaf_value, row_leaf, grad, hess, mask,
     (gradient_discretizer RenewIntGradTreeOutput)."""
     import jax.numpy as jnp
 
+    from ..timer import device_phase
     from .histogram import seg_sum
     from .split import leaf_output
 
     L = num_leaves
-    idx = jnp.where((row_leaf >= 0) & (mask > 0), row_leaf, L)
-    sums = seg_sum(jnp.stack([grad * mask, hess * mask]), idx, L)
-    sum_g, sum_h = sums[0], sums[1]
-    renewed = leaf_output(sum_g, sum_h, params)
-    return jnp.where(sum_h > 0, renewed, leaf_value)
+    with device_phase("boosting.renew"):
+        idx = jnp.where((row_leaf >= 0) & (mask > 0), row_leaf, L)
+        sums = seg_sum(jnp.stack([grad * mask, hess * mask]), idx, L)
+        sum_g, sum_h = sums[0], sums[1]
+        renewed = leaf_output(sum_g, sum_h, params)
+        return jnp.where(sum_h > 0, renewed, leaf_value)

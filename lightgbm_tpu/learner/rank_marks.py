@@ -9,7 +9,19 @@ and `<what>_end_tpu`, tied into the dataflow: what the bracketed code
 reads comes out of the first, what it produces goes through the second.
 The device time of the work is the span from a begin mark to the next
 end mark. Off the TPU (and outside the Pallas interpreter) `mark` is
-the identity."""
+the identity.
+
+The marks time an INTERVAL and cost device work (a barrier and an
+`a + zero` on every array they order). A boosting round is named
+without them: `timer.device_phase` puts the phase into every
+instruction's `op_name`, and the trace embeds the compiled module that
+maps an event's instruction back to it
+(benchmark/harness/device_phases.py). A mark inside a round would be a
+pass over the histogram pool, and every mark changes what XLA fuses.
+The marks stay because two benchmark metrics read them
+(`objective.rank_grad_ms_per_tree`, `metrics.rank_eval_ms_per_tree`);
+the phases `objective.gradients` and `metrics.valid_eval` cover the same
+work, marks included."""
 
 from __future__ import annotations
 

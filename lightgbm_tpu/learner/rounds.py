@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .bundle import BundleInfo, decode_feature_bins, expand_hist
-from ..timer import global_timer
+from ..timer import device_phase, global_timer
 from .histogram import (
     HistPlan,
     _pallas_ok,
@@ -465,7 +465,8 @@ def grow_tree_rounds(
             (per child when fields are vectors; ties -> lowest rank,
             matching parallel_tree_learner.h:209)."""
             rec = rec._replace(feature=rec.feature + ridx * Gn)
-            stacked = jax.tree.map(lambda a: lax.all_gather(a, ax), rec)
+            with device_phase("parallel.reduce"):
+                stacked = jax.tree.map(lambda a: lax.all_gather(a, ax), rec)
             if stacked.gain.ndim == 1:  # root: scalar fields
                 w = jnp.argmax(stacked.gain)
                 return jax.tree.map(lambda a: a[w], stacked)
@@ -493,38 +494,50 @@ def grow_tree_rounds(
         cegb, F,
     )
 
+    # device phases of the root pass (timer.DEVICE_PHASES): packing the
+    # channels is learner.quantize, the root's totals and its histogram
+    # learner.hist, what crosses the mesh parallel.reduce
     if spec.quant:
-        gh8 = build_gh8_quant(grad * mask, hess * mask, mask)  # (8, N)
-        scale3 = jnp.stack(
-            [gh_scale[0], gh_scale[1], jnp.float32(1.0)]
-        )  # (3,)
-        s8 = jnp.sum(gh8, axis=1)
-        root = jnp.stack([s8[0], s8[1], s8[2]])
-        if ax is not None:
-            root = lax.psum(root, ax)
-        root = root * scale3
-        hist0 = hist_nat_slots(
-            bins_fm, gh8, jnp.zeros(N, jnp.int32), 1, Bc, quant=True,
-            int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
-        )[0]
-        if use_rs:
-            hist0 = rs_hist(hist0)  # (3, Gn, Bc) owned block, int wire
-        elif ax is not None:
-            hist0 = lax.psum(hist0, ax)
-        hist0 = hist0 * scale3[:, None, None]
+        with device_phase("learner.quantize"):
+            gh8 = build_gh8_quant(grad * mask, hess * mask, mask)  # (8, N)
+            scale3 = jnp.stack(
+                [gh_scale[0], gh_scale[1], jnp.float32(1.0)]
+            )  # (3,)
+        with device_phase("learner.hist"):
+            s8 = jnp.sum(gh8, axis=1)
+            root = jnp.stack([s8[0], s8[1], s8[2]])
+            if ax is not None:
+                with device_phase("parallel.reduce"):
+                    root = lax.psum(root, ax)
+            root = root * scale3
+            hist0 = hist_nat_slots(
+                bins_fm, gh8, jnp.zeros(N, jnp.int32), 1, Bc, quant=True,
+                int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
+            )[0]
+            with device_phase("parallel.reduce"):
+                if use_rs:
+                    hist0 = rs_hist(hist0)  # (3, Gn, Bc) owned block, int wire
+                elif ax is not None:
+                    hist0 = lax.psum(hist0, ax)
+            hist0 = hist0 * scale3[:, None, None]
     else:
         scale3 = None
-        gh8 = build_gh8(grad * mask, hess * mask, mask)  # (8, N)
-        root = root_sums(gh8, ax)
-        if use_routed:
-            # the single-leaf kernel holds the whole table's tile
-            hist0 = hist_nat_slots(bins_fm, gh8, jnp.zeros(N, jnp.int32),
-                                   1, Bc, plan=nat_plan)[0]
-        else:
-            hist0 = histogram(bins_fm, gh8, Bc)
-        if ax is not None:
-            hist0 = lax.psum(hist0, ax)
-    root_out = leaf_output(root[0], root[1], params)
+        with device_phase("learner.quantize"):
+            gh8 = build_gh8(grad * mask, hess * mask, mask)  # (8, N)
+        with device_phase("learner.hist"):
+            root = root_sums(gh8, ax)
+            if use_routed:
+                # the single-leaf kernel holds the whole table's tile
+                hist0 = hist_nat_slots(
+                    bins_fm, gh8, jnp.zeros(N, jnp.int32), 1, Bc,
+                    plan=nat_plan)[0]
+            else:
+                hist0 = histogram(bins_fm, gh8, Bc)
+            if ax is not None:
+                with device_phase("parallel.reduce"):
+                    hist0 = lax.psum(hist0, ax)
+    with device_phase("learner.split_search"):
+        root_out = leaf_output(root[0], root[1], params)
     if per_node:
         lg0 = jnp.ones((L, NG), bool)
         pu0 = jnp.zeros((L, F), bool)
@@ -542,48 +555,52 @@ def grow_tree_rounds(
         nb_t, nan_t = my_block(num_bins_p), my_block(nan_bin_p)
         mono_t, iscat_t = my_block(mono_p), my_block(is_cat_p)
         fm_t = my_block(feat_mask_p)
-        rec0 = select_global_rec(best_split(
-            hist0, root[0], root[1], root[2], nb_t, nan_t, mono_t,
-            iscat_t, params, fm_t, cat_subset=spec.cat_subset,
-            parent_output=root_out))
+        with device_phase("learner.split_search"):
+            rec0 = select_global_rec(best_split(
+                hist0, root[0], root[1], root[2], nb_t, nan_t, mono_t,
+                iscat_t, params, fm_t, cat_subset=spec.cat_subset,
+                parent_output=root_out))
     else:
         nb_t, nan_t, mono_t, iscat_t, fm_t = (
             num_bins, nan_bin, mono, is_cat, feat_mask)
-        rec0 = best_split(exp_hist(hist0, root[0], root[1], root[2]),
-                          root[0], root[1], root[2], num_bins, nan_bin,
-                          mono, is_cat, params, fm0,
-                          cat_subset=spec.cat_subset,
-                          parent_output=root_out,
-                          penalty=pen0, rand_bin=rb0)
+        with device_phase("learner.split_search"):
+            rec0 = best_split(exp_hist(hist0, root[0], root[1], root[2]),
+                              root[0], root[1], root[2], num_bins, nan_bin,
+                              mono, is_cat, params, fm0,
+                              cat_subset=spec.cat_subset,
+                              parent_output=root_out,
+                              penalty=pen0, rand_bin=rb0)
 
     Gc = Gn if use_rs else G  # pool feature width (owned block under rs)
-    hist = jnp.zeros((L, 3, Gc * Bc), jnp.float32).at[0].set(
-        hist0.reshape(3, -1))
+    with device_phase("learner.pool_write"):
+        hist = jnp.zeros((L, 3, Gc * Bc), jnp.float32).at[0].set(
+            hist0.reshape(3, -1))
 
     def pool_rows(h):
         """(..., 3, G*Bc) rows of the pool -> (..., 3, G, Bc)."""
         return h.reshape(h.shape[:-1] + (Gc, Bc))
 
-    best = _set_best(_empty_best(L, B), jnp.int32(0), rec0, rec0.gain)
+    with device_phase("learner.select"):
+        best = _set_best(_empty_best(L, B), jnp.int32(0), rec0, rec0.gain)
 
-    tree = TreeArrays(
-        num_nodes=jnp.int32(0),
-        node_feature=jnp.zeros(L - 1, jnp.int32),
-        node_bin=jnp.zeros(L - 1, jnp.int32),
-        node_gain=jnp.zeros(L - 1, jnp.float32),
-        node_default_left=jnp.zeros(L - 1, bool),
-        node_cat=jnp.zeros(L - 1, bool),
-        node_cat_mask=jnp.zeros((L - 1, B), bool),
-        node_left=jnp.zeros(L - 1, jnp.int32),
-        node_right=jnp.zeros(L - 1, jnp.int32),
-        node_value=jnp.zeros(L - 1, jnp.float32),
-        node_weight=jnp.zeros(L - 1, jnp.float32),
-        node_count=jnp.zeros(L - 1, jnp.float32),
-        leaf_value=jnp.zeros(L, jnp.float32).at[0].set(root_out),
-        leaf_weight=jnp.zeros(L, jnp.float32).at[0].set(root[1]),
-        leaf_count=jnp.zeros(L, jnp.float32).at[0].set(root[2]),
-        leaf_depth=jnp.zeros(L, jnp.int32),
-    )
+        tree = TreeArrays(
+            num_nodes=jnp.int32(0),
+            node_feature=jnp.zeros(L - 1, jnp.int32),
+            node_bin=jnp.zeros(L - 1, jnp.int32),
+            node_gain=jnp.zeros(L - 1, jnp.float32),
+            node_default_left=jnp.zeros(L - 1, bool),
+            node_cat=jnp.zeros(L - 1, bool),
+            node_cat_mask=jnp.zeros((L - 1, B), bool),
+            node_left=jnp.zeros(L - 1, jnp.int32),
+            node_right=jnp.zeros(L - 1, jnp.int32),
+            node_value=jnp.zeros(L - 1, jnp.float32),
+            node_weight=jnp.zeros(L - 1, jnp.float32),
+            node_count=jnp.zeros(L - 1, jnp.float32),
+            leaf_value=jnp.zeros(L, jnp.float32).at[0].set(root_out),
+            leaf_weight=jnp.zeros(L, jnp.float32).at[0].set(root[1]),
+            leaf_count=jnp.zeros(L, jnp.float32).at[0].set(root[2]),
+            leaf_depth=jnp.zeros(L, jnp.int32),
+        )
 
     valid_f = jnp.ones(N, jnp.float32) if valid is None else valid
     iota_L = jnp.arange(L, dtype=jnp.int32)
@@ -620,31 +637,34 @@ def grow_tree_rounds(
                    pen=None):
         # under use_rs the tables are this rank's owned block and
         # the winner is elected globally by the caller
-        return best_split(
-            exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
-            mono_t, iscat_t, params, fm_t if fm is None else fm,
-            cat_subset=spec.cat_subset, parent_output=po,
-            cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
-        )
+        with device_phase("learner.split_search"):
+            return best_split(
+                exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
+                mono_t, iscat_t, params, fm_t if fm is None else fm,
+                cat_subset=spec.cat_subset, parent_output=po,
+                cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
+            )
 
     def body(carry: Tuple[_Pools, _NState]) -> Tuple[_Pools, _NState]:
         pools, s = carry
-        budget0 = (L - 1) - s.i
-        n_pos = jnp.sum(s.best.gain > 0.0).astype(jnp.int32)
-        n_cand = jnp.minimum(budget0, n_pos)
-        if spec.n_forced:
-            # forced phase: ONE split per round so Tree::Split leaf
-            # numbering matches the BFS plan's precomputed ids (the
-            # plan was laid out for sequential growth); n_pos can be 0
-            # here — the forced split doesn't need positive gain
-            n_cand = jnp.where(
-                s.i < forced.n, jnp.int32(1), n_cand
-            )
-        if tail_exact:
-            n_cand = jnp.minimum(n_cand, jnp.maximum((budget0 + 1) // 2, 1))
-        bidx = jnp.sum(
-            n_cand > jnp.asarray(widths[:-1], jnp.int32)
-        ).astype(jnp.int32)
+        with device_phase("learner.select"):
+            budget0 = (L - 1) - s.i
+            n_pos = jnp.sum(s.best.gain > 0.0).astype(jnp.int32)
+            n_cand = jnp.minimum(budget0, n_pos)
+            if spec.n_forced:
+                # forced phase: ONE split per round so Tree::Split leaf
+                # numbering matches the BFS plan's precomputed ids (the
+                # plan was laid out for sequential growth); n_pos can be
+                # 0 here — the forced split doesn't need positive gain
+                n_cand = jnp.where(
+                    s.i < forced.n, jnp.int32(1), n_cand
+                )
+            if tail_exact:
+                n_cand = jnp.minimum(
+                    n_cand, jnp.maximum((budget0 + 1) // 2, 1))
+            bidx = jnp.sum(
+                n_cand > jnp.asarray(widths[:-1], jnp.int32)
+            ).astype(jnp.int32)
 
         def ladder(pl: _Pools, st: _NState) -> Tuple[_NState, _Children]:
             return lax.switch(
@@ -681,11 +701,12 @@ def grow_tree_rounds(
         # ---- the round's one write of the pools: <= 2S rows into the
         # loop's carry, in place (a left child keeps its parent's row,
         # a right one takes a fresh row: no two ids meet; pad ids drop)
-        pools2 = _Pools(
-            hist=pools.hist.at[ch.leaf].set(ch.hist, mode="drop"),
-            valid=(pools.valid.at[ch.leaf].set(ch.valid, mode="drop")
-                   if use_voting else pools.valid),
-        )
+        with device_phase("learner.pool_write"):
+            pools2 = _Pools(
+                hist=pools.hist.at[ch.leaf].set(ch.hist, mode="drop"),
+                valid=(pools.valid.at[ch.leaf].set(ch.valid, mode="drop")
+                       if use_voting else pools.valid),
+            )
         if spec.mono_mode:
             # intermediate / advanced constraints, step 3 (round_step):
             # re-search every live leaf's best split under the round's
@@ -697,17 +718,26 @@ def grow_tree_rounds(
                 pool_rows(pools2.hist), s2.leaf_g, s2.leaf_h, s2.leaf_c,
                 t2.leaf_value, s2.leaf_min, s2.leaf_max,
             )
-            d_ok = (spec.max_depth <= 0) | (
-                t2.leaf_depth < spec.max_depth)
-            s2 = s2._replace(best=rec_all._replace(
-                gain=jnp.where((iota_L <= s2.i) & d_ok, rec_all.gain,
-                               NEG_INF)
-            ))
+            with device_phase("learner.select"):
+                d_ok = (spec.max_depth <= 0) | (
+                    t2.leaf_depth < spec.max_depth)
+                s2 = s2._replace(best=rec_all._replace(
+                    gain=jnp.where((iota_L <= s2.i) & d_ok, rec_all.gain,
+                                   NEG_INF)
+                ))
         return pools2, s2
 
     def round_step(pools: _Pools, s: _NState, Sk: int, n_max=None,
                    route_only: bool = False
                    ) -> Tuple[_NState, _Children]:
+        # what of a round no inner phase names is selection and the
+        # tree's bookkeeping (timer.DEVICE_PHASES: the innermost names
+        # the op)
+        with device_phase("learner.select"):
+            return _round(pools, s, Sk, n_max, route_only)
+
+    def _round(pools: _Pools, s: _NState, Sk: int, n_max,
+               route_only: bool) -> Tuple[_NState, _Children]:
         t = s.tree
         i = s.i
         S = Sk  # kernel width for this round (see the ladder above)
@@ -958,151 +988,156 @@ def grow_tree_rounds(
             otherwise — then the dequantization scale. Returns the
             reduced hists and the elected (F,) mask (None off voting)."""
             el = None
-            if use_voting:
-                sh, el = vote_reduce(sh)
-            elif use_rs:
-                sh = rs_hist(sh)  # int wire, owned block
-            elif ax is not None:
-                sh = lax.psum(sh, ax)
+            with device_phase("parallel.reduce"):
+                if use_voting:
+                    sh, el = vote_reduce(sh)
+                elif use_rs:
+                    sh = rs_hist(sh)  # int wire, owned block
+                elif ax is not None:
+                    sh = lax.psum(sh, ax)
             if spec.quant:
                 sh = sh * scale3[:, None, None]
             return sh, el
 
-        if use_fused or use_routed:
-            zs = jnp.zeros(S, jnp.int32)
-            if spec.efb:
-                efb_cols = [bundle.off_lo[feat_s], bundle.mfb[feat_s],
-                            bundle.width[feat_s]]
+        with device_phase("learner.route"):
+            if use_fused or use_routed:
+                zs = jnp.zeros(S, jnp.int32)
+                if spec.efb:
+                    efb_cols = [bundle.off_lo[feat_s], bundle.mfb[feat_s],
+                                bundle.width[feat_s]]
+                else:
+                    efb_cols = [zs, jnp.full(S, -1, jnp.int32), zs]
+                params16 = jnp.stack(
+                    [
+                        sel_leaf, col_s,
+                        rec.bin[sl_i],
+                        rec.default_left[sl_i].astype(jnp.int32),
+                        nan_s,
+                        left_smaller[sl_i].astype(jnp.int32),
+                        new_id_s,
+                    ] + efb_cols + [
+                        rec.is_cat[sl_i].astype(jnp.int32),  # col 10
+                    ] + [zs] * 5,
+                    axis=1,
+                ).astype(jnp.int32)  # (S, 16)
+                if use_routed:
+                    # the routing pass sees the round's split columns as a
+                    # table of their own: slot s's column is its row s
+                    table = take_rows(bins_fm, col_s)  # (S, N)
+                    coh = jnp.eye(S, dtype=jnp.float32)
+                else:
+                    table = bins_fm
+                    coh = (
+                        col_s[:, None]
+                        == jnp.arange(G, dtype=jnp.int32)[None, :]
+                    ).astype(jnp.float32)  # (S, G)
+                if spec.has_cat:
+                    cm_s = rec.cat_mask[sl_i].astype(jnp.int8)  # (S, B)
+                    if Bc > B:  # kernel bin space is the bundle width
+                        cm_s = jnp.pad(cm_s, ((0, 0), (0, Bc - B)))
+                else:
+                    cm_s = None
+                if route_only:
+                    pleaf_new = route_round(
+                        table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
+                        cat_mask=cm_s,
+                    )
+                elif use_routed:
+                    pleaf_new, hslot = route_round(
+                        table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
+                        cat_mask=cm_s, with_slot=True,
+                    )
+                    with device_phase("learner.hist"):
+                        slot_hists = hist_nat_slots(
+                            bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
+                            int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
+                        )  # (S, 3, G, Bc)
+                        slot_hists, elected = reduce_slots(slot_hists)
+                else:
+                    with device_phase("learner.hist"):
+                        slot_hists, pleaf_new = hist_round(
+                            bins_fm, gh8, s.pleaf, params16, coh, S, Bc,
+                            quant=spec.quant, int8=use_int8, oh_shift=oh_shift,
+                            efb=spec.efb, cat_mask=cm_s,
+                        )
+                        slot_hists, elected = reduce_slots(slot_hists)
             else:
-                efb_cols = [zs, jnp.full(S, -1, jnp.int32), zs]
-            params16 = jnp.stack(
-                [
-                    sel_leaf, col_s,
-                    rec.bin[sl_i],
-                    rec.default_left[sl_i].astype(jnp.int32),
-                    nan_s,
-                    left_smaller[sl_i].astype(jnp.int32),
-                    new_id_s,
-                ] + efb_cols + [
-                    rec.is_cat[sl_i].astype(jnp.int32),  # col 10
-                ] + [zs] * 5,
-                axis=1,
-            ).astype(jnp.int32)  # (S, 16)
-            if use_routed:
-                # the routing pass sees the round's split columns as a
-                # table of their own: slot s's column is its row s
-                table = take_rows(bins_fm, col_s)  # (S, N)
-                coh = jnp.eye(S, dtype=jnp.float32)
-            else:
-                table = bins_fm
-                coh = (
-                    col_s[:, None]
-                    == jnp.arange(G, dtype=jnp.int32)[None, :]
-                ).astype(jnp.float32)  # (S, G)
-            if spec.has_cat:
-                cm_s = rec.cat_mask[sl_i].astype(jnp.int8)  # (S, B)
-                if Bc > B:  # kernel bin space is the bundle width
-                    cm_s = jnp.pad(cm_s, ((0, 0), (0, Bc - B)))
-            else:
-                cm_s = None
-            if route_only:
-                pleaf_new = route_round(
-                    table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
-                    cat_mask=cm_s,
-                )
-            elif use_routed:
-                pleaf_new, hslot = route_round(
-                    table, s.pleaf, params16, coh, S, Bc, efb=spec.efb,
-                    cat_mask=cm_s, with_slot=True,
-                )
-                slot_hists = hist_nat_slots(
-                    bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
-                    int8=use_int8, oh_shift=oh_shift, plan=nat_plan,
-                )  # (S, 3, G, Bc)
-                slot_hists, elected = reduce_slots(slot_hists)
-            else:
-                slot_hists, pleaf_new = hist_round(
-                    bins_fm, gh8, s.pleaf, params16, coh, S, Bc,
-                    quant=spec.quant, int8=use_int8, oh_shift=oh_shift,
-                    efb=spec.efb, cat_mask=cm_s,
-                )
-                slot_hists, elected = reduce_slots(slot_hists)
-        else:
-            pack_cols = [
-                col_s.astype(jnp.float32),  # 0: device bin column
-                rec.bin[sl_i].astype(jnp.float32),  # 1: threshold bin
-                rec.default_left[sl_i].astype(jnp.float32),  # 2
-                rec.is_cat[sl_i].astype(jnp.float32),  # 3
-                nan_s.astype(jnp.float32),  # 4: NaN bin (-1 = none)
-                iota_S.astype(jnp.float32),  # 5: histogram slot index
-                left_smaller[sl_i].astype(jnp.float32),  # 6
-                jnp.ones(S, jnp.float32),  # 7: membership indicator
-                feat_s.astype(jnp.float32),  # 8: true feature id (EFB)
-                new_id_s.astype(jnp.float32),  # 9: new (right) leaf id
-            ]
-            pack = jnp.stack(pack_cols, axis=1) * live[:, None]  # (S, 10)
-            memb = (s.pleaf[:, None] == sel_leaf[None, :])  # (N, S)
-            # HIGHEST precision: the default TPU matmul multiplies f32
-            # in bf16, which would corrupt packed ids above 256 — the
-            # exact case the f32 pack exists for
-            vals = lax.dot_general(
-                memb.astype(jnp.float32), pack, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST,
-            )  # (N, 10); rows outside every selected leaf are all-zero
-            in_split = vals[:, 7] > 0.5
-            col_row = vals[:, 0].astype(jnp.int32)
-            bin_row = vals[:, 1].astype(jnp.int32)
-            dl_row = vals[:, 2] > 0.5
-            cat_row = vals[:, 3] > 0.5
-            nan_row = vals[:, 4].astype(jnp.int32)
-            rank_row = vals[:, 5].astype(jnp.int32)
-            small_row = vals[:, 6] > 0.5
-            # masked select of each row's split column (no 2D gather)
-            col_sel = (col_row[None, :]
-                       == jnp.arange(G, dtype=jnp.int32)[:, None])
-            fbins = jnp.sum(jnp.where(col_sel, bins_fm, 0), axis=0)
-            if spec.efb:
-                f_row = vals[:, 8].astype(jnp.int32)
-                fbins = decode_feature_bins(fbins, f_row, bundle)
-            if spec.has_cat:
-                # category-set membership as a bin-one-hot contraction:
-                # hit[r] = cat_mask[slot(r), fbins[r]] without the
-                # (L*B,) flat gather
-                ob = (fbins[:, None]
-                      == jnp.arange(B, dtype=jnp.int32)[None, :])
-                cm_sel = (rec.cat_mask[sl_i].astype(jnp.bfloat16)
-                          * live[:, None])  # (S, B)
-                hits = lax.dot_general(
-                    ob.astype(jnp.bfloat16), cm_sel,
-                    (((1,), (1,)), ((), ())),
+                pack_cols = [
+                    col_s.astype(jnp.float32),  # 0: device bin column
+                    rec.bin[sl_i].astype(jnp.float32),  # 1: threshold bin
+                    rec.default_left[sl_i].astype(jnp.float32),  # 2
+                    rec.is_cat[sl_i].astype(jnp.float32),  # 3
+                    nan_s.astype(jnp.float32),  # 4: NaN bin (-1 = none)
+                    iota_S.astype(jnp.float32),  # 5: histogram slot index
+                    left_smaller[sl_i].astype(jnp.float32),  # 6
+                    jnp.ones(S, jnp.float32),  # 7: membership indicator
+                    feat_s.astype(jnp.float32),  # 8: true feature id (EFB)
+                    new_id_s.astype(jnp.float32),  # 9: new (right) leaf id
+                ]
+                pack = jnp.stack(pack_cols, axis=1) * live[:, None]  # (S, 10)
+                memb = (s.pleaf[:, None] == sel_leaf[None, :])  # (N, S)
+                # HIGHEST precision: the default TPU matmul multiplies f32
+                # in bf16, which would corrupt packed ids above 256 — the
+                # exact case the f32 pack exists for
+                vals = lax.dot_general(
+                    memb.astype(jnp.float32), pack, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                )  # (N, S)
-                cat_hit = jnp.sum(hits * memb, axis=1) > 0.5
-            else:
-                cat_hit = jnp.zeros_like(in_split)
-            go_left = jnp.where(
-                cat_row,
-                cat_hit,
-                (fbins <= bin_row)
-                | (dl_row & (fbins == nan_row) & (nan_row >= 0)),
-            )
-            new_id_row = vals[:, 9].astype(jnp.int32)
-            pleaf_new = jnp.where(
-                in_split & ~go_left, new_id_row, s.pleaf
-            ).astype(jnp.int32)
-
-            if not route_only:
-                # ---- smaller-child histograms: one slot-packed pass ----
-                go_small = go_left == small_row
-                hslot = jnp.where(
-                    in_split & go_small, rank_row, S
+                    precision=lax.Precision.HIGHEST,
+                )  # (N, 10); rows outside every selected leaf are all-zero
+                in_split = vals[:, 7] > 0.5
+                col_row = vals[:, 0].astype(jnp.int32)
+                bin_row = vals[:, 1].astype(jnp.int32)
+                dl_row = vals[:, 2] > 0.5
+                cat_row = vals[:, 3] > 0.5
+                nan_row = vals[:, 4].astype(jnp.int32)
+                rank_row = vals[:, 5].astype(jnp.int32)
+                small_row = vals[:, 6] > 0.5
+                # masked select of each row's split column (no 2D gather)
+                col_sel = (col_row[None, :]
+                           == jnp.arange(G, dtype=jnp.int32)[:, None])
+                fbins = jnp.sum(jnp.where(col_sel, bins_fm, 0), axis=0)
+                if spec.efb:
+                    f_row = vals[:, 8].astype(jnp.int32)
+                    fbins = decode_feature_bins(fbins, f_row, bundle)
+                if spec.has_cat:
+                    # category-set membership as a bin-one-hot contraction:
+                    # hit[r] = cat_mask[slot(r), fbins[r]] without the
+                    # (L*B,) flat gather
+                    ob = (fbins[:, None]
+                          == jnp.arange(B, dtype=jnp.int32)[None, :])
+                    cm_sel = (rec.cat_mask[sl_i].astype(jnp.bfloat16)
+                              * live[:, None])  # (S, B)
+                    hits = lax.dot_general(
+                        ob.astype(jnp.bfloat16), cm_sel,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )  # (N, S)
+                    cat_hit = jnp.sum(hits * memb, axis=1) > 0.5
+                else:
+                    cat_hit = jnp.zeros_like(in_split)
+                go_left = jnp.where(
+                    cat_row,
+                    cat_hit,
+                    (fbins <= bin_row)
+                    | (dl_row & (fbins == nan_row) & (nan_row >= 0)),
+                )
+                new_id_row = vals[:, 9].astype(jnp.int32)
+                pleaf_new = jnp.where(
+                    in_split & ~go_left, new_id_row, s.pleaf
                 ).astype(jnp.int32)
-                slot_hists = hist_nat_slots(
-                    bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
-                    int8=use_int8, oh_shift=oh_shift,
-                )  # (S, 3, G, Bc)
-                slot_hists, elected = reduce_slots(slot_hists)
+
+                if not route_only:
+                    # ---- smaller-child histograms: one slot-packed pass ----
+                    with device_phase("learner.hist"):
+                        go_small = go_left == small_row
+                        hslot = jnp.where(
+                            in_split & go_small, rank_row, S
+                        ).astype(jnp.int32)
+                        slot_hists = hist_nat_slots(
+                            bins_fm, gh8, hslot, S, Bc, quant=spec.quant,
+                            int8=use_int8, oh_shift=oh_shift,
+                        )  # (S, 3, G, Bc)
+                        slot_hists, elected = reduce_slots(slot_hists)
 
         leaf_g2 = jnp.where(sel, rec.left_g, s.leaf_g) \
             .at[drop_new].set(rec.right_g, mode="drop")
@@ -1115,12 +1150,13 @@ def grow_tree_rounds(
 
         def children(leaf, hist, valid) -> _Children:
             """The round's pool rows at the program's full 2S."""
-            pad = [(0, 2 * widths[-1] - leaf.shape[0])]
-            return _Children(
-                leaf=jnp.pad(leaf, pad, constant_values=L),
-                hist=jnp.pad(hist, pad + [(0, 0)] * 2),
-                valid=jnp.pad(valid, pad + [(0, 0)]),
-            )
+            with device_phase("learner.pool_write"):
+                pad = [(0, 2 * widths[-1] - leaf.shape[0])]
+                return _Children(
+                    leaf=jnp.pad(leaf, pad, constant_values=L),
+                    hist=jnp.pad(hist, pad + [(0, 0)] * 2),
+                    valid=jnp.pad(valid, pad + [(0, 0)]),
+                )
 
         if route_only:
             # the tree and the rows' leaves are all a last round leaves
@@ -1139,22 +1175,23 @@ def grow_tree_rounds(
         # ---- per-slot child hists: smaller from the pass, larger by
         # subtraction; body() scatters both into the pool. Work stays
         # O(S), not O(L) — only the <= S split leaves are touched.
-        sl_c = sl_i  # (S,) clipped for gathers (computed above)
-        # (elementwise on whole histograms, so on flat (S, 3*G*Bc)
-        # views: what the pool takes and what the search reads are
-        # two shapes of the one result. On 4-D operands or on pool
-        # rows the CPU backend fused the fused and the routed program
-        # differently and their model texts parted in a last digit;
-        # the chip reads the same either way: PERF.md section 6, PR 33)
-        slot_flat = slot_hists.reshape(S, -1)
-        parent_s = take_rows(pools.hist, sl_c).reshape(S, -1)
-        large_s = parent_s - slot_flat
-        ls_s = left_smaller[sl_c][:, None]
-        left_s = jnp.where(ls_s, slot_flat, large_s)
-        right_s = jnp.where(ls_s, large_s, slot_flat)
-        ch_flat = jnp.concatenate([left_s, right_s])  # (2S, 3*G*Bc)
-        ch_hist = ch_flat.reshape(2 * S, 3, Gc, Bc)
-        ch_leaf = jnp.concatenate([sel_leaf, new_id_s])
+        with device_phase("learner.subtract"):
+            sl_c = sl_i  # (S,) clipped for gathers (computed above)
+            # (elementwise on whole histograms, so on flat (S, 3*G*Bc)
+            # views: what the pool takes and what the search reads are
+            # two shapes of the one result. On 4-D operands or on pool
+            # rows the CPU backend fused the fused and the routed program
+            # differently and their model texts parted in a last digit;
+            # the chip reads the same either way: PERF.md section 6, PR 33)
+            slot_flat = slot_hists.reshape(S, -1)
+            parent_s = take_rows(pools.hist, sl_c).reshape(S, -1)
+            large_s = parent_s - slot_flat
+            ls_s = left_smaller[sl_c][:, None]
+            left_s = jnp.where(ls_s, slot_flat, large_s)
+            right_s = jnp.where(ls_s, large_s, slot_flat)
+            ch_flat = jnp.concatenate([left_s, right_s])  # (2S, 3*G*Bc)
+            ch_hist = ch_flat.reshape(2 * S, 3, Gc, Bc)
+            ch_leaf = jnp.concatenate([sel_leaf, new_id_s])
 
         ch_valid = jnp.zeros((2 * S,) + pools.valid.shape[1:], bool)
         if use_voting:
@@ -1420,47 +1457,51 @@ def grow_tree_rounds(
 
     def cond(carry: Tuple[_Pools, _NState]) -> jax.Array:
         pools, s = carry
-        keep = jnp.max(s.best.gain) > 0.0
-        if spec.n_forced:
-            # only continue for a forced step that can actually split
-            # (both children non-empty) — the round body falls back to
-            # the best-gain split otherwise, which `keep` already guards
-            keep = keep | _forced_valid(pools, s)
-        return (s.i < L - 1) & keep
+        with device_phase("learner.select"):
+            keep = jnp.max(s.best.gain) > 0.0
+            if spec.n_forced:
+                # only continue for a forced step that can actually
+                # split (both children non-empty) — the round body falls
+                # back to the best-gain split otherwise, which `keep`
+                # already guards
+                keep = keep | _forced_valid(pools, s)
+            return (s.i < L - 1) & keep
 
-    state = _NState(
-        i=jnp.int32(0),
-        r=jnp.zeros(len(widths) + 2, jnp.int32),
-        pleaf=jnp.where(valid_f > 0, 0, L).astype(jnp.int32),
-        leaf_g=jnp.zeros(L, jnp.float32).at[0].set(root[0]),
-        leaf_h=jnp.zeros(L, jnp.float32).at[0].set(root[1]),
-        leaf_c=jnp.zeros(L, jnp.float32).at[0].set(root[2]),
-        leaf_parent=jnp.full(L, -1, jnp.int32),
-        leaf_min=jnp.full(L, -BIG, jnp.float32),
-        leaf_max=jnp.full(L, BIG, jnp.float32),
-        anc_in=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
-        anc_left=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
-        leaf_groups=lg0,
-        path_used=pu0,
-        feat_used=fu0,
-        leaf_flo=jnp.full(
-            (L, F if spec.mono_mode == 2 else 0), -1, jnp.int32
-        ),
-        leaf_fhi=jnp.full(
-            (L, F if spec.mono_mode == 2 else 0), B, jnp.int32
-        ),
-        best=best,
-        tree=tree,
-    )
-    # root histogram always crosses the mesh in full, so every column
-    # starts globally valid
-    pools0 = _Pools(hist=hist,
-                    valid=jnp.ones((L, F if use_voting else 0), bool))
+    with device_phase("learner.select"):
+        state = _NState(
+            i=jnp.int32(0),
+            r=jnp.zeros(len(widths) + 2, jnp.int32),
+            pleaf=jnp.where(valid_f > 0, 0, L).astype(jnp.int32),
+            leaf_g=jnp.zeros(L, jnp.float32).at[0].set(root[0]),
+            leaf_h=jnp.zeros(L, jnp.float32).at[0].set(root[1]),
+            leaf_c=jnp.zeros(L, jnp.float32).at[0].set(root[2]),
+            leaf_parent=jnp.full(L, -1, jnp.int32),
+            leaf_min=jnp.full(L, -BIG, jnp.float32),
+            leaf_max=jnp.full(L, BIG, jnp.float32),
+            anc_in=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
+            anc_left=jnp.zeros((L, L - 1 if spec.mono_mode else 0), bool),
+            leaf_groups=lg0,
+            path_used=pu0,
+            feat_used=fu0,
+            leaf_flo=jnp.full(
+                (L, F if spec.mono_mode == 2 else 0), -1, jnp.int32
+            ),
+            leaf_fhi=jnp.full(
+                (L, F if spec.mono_mode == 2 else 0), B, jnp.int32
+            ),
+            best=best,
+            tree=tree,
+        )
+        # root histogram always crosses the mesh in full, so every column
+        # starts globally valid
+        pools0 = _Pools(hist=hist,
+                        valid=jnp.ones((L, F if use_voting else 0), bool))
     _, final = lax.while_loop(cond, body, (pools0, state))
 
     row_leaf = final.pleaf
     if valid is not None:
-        row_leaf = jnp.where(valid > 0, row_leaf, -1)
+        with device_phase("learner.route"):
+            row_leaf = jnp.where(valid > 0, row_leaf, -1)
     if with_stats:
         return final.tree, row_leaf, {"widths": widths, "rounds": final.r}
     return final.tree, row_leaf

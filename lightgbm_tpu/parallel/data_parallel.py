@@ -33,6 +33,7 @@ from .._backend import on_tpu
 from ..learner.grower import GrowerSpec, TreeArrays, grow_tree
 from ..learner.histogram import row_mesh
 from ..learner.split import SplitParams
+from ..timer import device_phase
 
 
 def make_mesh(devices=None, axis_name: str = "data") -> Mesh:
@@ -59,9 +60,10 @@ def _recount_leaves(row_leaf, mask, num_leaves: int, axis_name: str):
     every leaf that float32 can hold at all."""
     from ..learner.histogram import seg_sum
 
-    local = seg_sum(mask[None, :], row_leaf, num_leaves)[0]
-    total = jax.lax.psum(jnp.round(local).astype(jnp.int32), axis_name)
-    return total.astype(jnp.float32)
+    with device_phase("parallel.reduce"):
+        local = seg_sum(mask[None, :], row_leaf, num_leaves)[0]
+        total = jax.lax.psum(jnp.round(local).astype(jnp.int32), axis_name)
+        return total.astype(jnp.float32)
 
 
 _GROWERS: "OrderedDict[Any, DataParallelGrower]" = OrderedDict()
@@ -143,7 +145,8 @@ class DataParallelGrower:
                         row_leaf, mask, self.spec.num_leaves, axis_name))
             # tree state is identical on all shards (computed from psum'd
             # histograms); mark it replicated for the out_spec
-            tree = jax.tree.map(lambda a: jax.lax.pmean(a, axis_name) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+            with device_phase("parallel.reduce"):
+                tree = jax.tree.map(lambda a: jax.lax.pmean(a, axis_name) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
             # so are the rounds grower's round counts: every shard runs
             # the same ladder on the same global leaf counts
             return (tree, row_leaf, *(st["rounds"] for st in stats))

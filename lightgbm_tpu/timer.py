@@ -17,6 +17,12 @@ nested under the scopes that enclose it on its thread. The session is
 the switch; with none active an annotation costs well under a
 microsecond and records nothing.
 
+Scopes are HOST spans. DEVICE work is named from inside the program by
+`device_phase` (below): a `jax.named_scope` whose name rides the
+compiled module's `op_name` metadata, which the profiler's trace embeds
+beside the ops it timed. No scope here opens one: the compiled module
+does not depend on whether a timer is enabled.
+
 Enable summary-at-exit with env LIGHTGBM_TPU_TIMETAG=1 (the analog of
 the reference's compile-time USE_TIMETAG), with the `timetag` config /
 CLI param, or at runtime via `global_timer.enable()` — unlike the
@@ -41,6 +47,44 @@ from typing import Callable, Dict, Iterator, List, Optional
 # prefix of this program's host events in a profiler trace ("bench:" is
 # the benchmark harness's)
 TRACE_PREFIX = "lgbm:"
+
+# prefix of this program's DEVICE phases: the name-stack entries that
+# `device_phase` puts into the `op_name` of every op traced inside it
+DEVICE_PREFIX = "lgbm."
+# the whole vocabulary (docs/OBSERVABILITY.md "Device phases" says where
+# each opens and which benchmark metric reads it). Scopes nest; the
+# innermost names the op.
+DEVICE_PHASES = (
+    "objective.gradients",
+    "learner.quantize",
+    "learner.select",
+    "learner.route",
+    "learner.hist",
+    "parallel.reduce",
+    "learner.subtract",
+    "learner.split_search",
+    "learner.pool_write",
+    "boosting.renew",
+    "boosting.score_update",
+    "metrics.valid_eval",
+)
+
+
+def device_phase(name: str):
+    """`jax.named_scope("lgbm.<name>")`: every op TRACED inside carries
+    the phase in its `op_name`, the name stack XLA keeps as metadata of
+    the compiled module. No op, no barrier, the same executable. A
+    profiler trace embeds the module it ran (plane `/host:metadata`),
+    so a trace's `XLA Ops` events, which are named by HLO instruction,
+    map back to the phase that traced them
+    (`benchmark/harness/device_phases.py`). A name outside
+    `DEVICE_PHASES` fails where the scope is opened, at trace time."""
+    import jax
+
+    if name not in DEVICE_PHASES:
+        raise KeyError(f"unknown device phase {name!r}; the vocabulary "
+                       f"is timer.DEVICE_PHASES")
+    return jax.named_scope(DEVICE_PREFIX + name)
 
 # active span sinks: obs.tracing installs `(name, start_s, dur_s) ->
 # None` here while recording, and obs.recorder adds its per-round
@@ -123,9 +167,10 @@ class Timer:
 
         The region is always a `TraceAnnotation` (module docstring);
         the stopwatch and the sinks run only when the timer is enabled
-        or a sink is installed. `jax.named_scope` only prefixes the
-        HLO metadata of whatever is TRACED inside the region (op names
-        in a compiled module); it writes no event anywhere."""
+        or a sink is installed. A scope is a HOST span and names no
+        device work: what is traced inside it compiles to the same
+        module, `op_name`s included, whether the timer is on or off
+        (device work is named by `device_phase`)."""
         import jax
 
         with jax.profiler.TraceAnnotation(TRACE_PREFIX + name):
@@ -134,8 +179,7 @@ class Timer:
                 yield
                 return
             t0 = time.perf_counter()
-            with jax.named_scope(name.replace(" ", "_")):
-                yield
+            yield
             if block:
                 _sync_devices()
             dt = time.perf_counter() - t0

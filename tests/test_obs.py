@@ -4,6 +4,7 @@ manifests, bench_serve artifact, and the no-callback re-audit."""
 
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
 import re
@@ -393,6 +394,173 @@ def test_spans_without_a_profiler_record_and_change_nothing(profiled_job):
     # (empty, unless an earlier test of this process left entries)
     assert after == before
     assert profiled_job["bare"] == profiled_job["profiled"]
+
+
+# ------------------------------------------------------------ device phases
+_PHASE_TOKEN = re.compile(r"lgbm\.([a-z_.]+)")
+
+
+def _drop_traces():
+    import jax
+
+    boosting._FUSED_STEP_CACHE.clear()
+    jax.clear_caches()
+
+
+@contextlib.contextmanager
+def _compiling_here():
+    """No persistent compile cache inside: an executable loaded from it
+    carries the op_names of whichever program filled it (the cache key
+    strips debug info), and a jit's first dispatch hands what it loaded
+    to every later `.lower().compile()` of the same trace."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _fused_step_text(params, X, y, group=None):
+    """(compiled text of the one-round fused step, model text) of a tiny
+    job with one valid set on the chip's default program (rounds grower,
+    int16 channels, true-gradient leaf renewal)."""
+    kw = {"group": group} if group is not None else {}
+    ds = lgb.Dataset(X, label=y, free_raw_data=False, **kw)
+    vkw = {"group": group[:len(group) // 2]} if group is not None else {}
+    nv = sum(vkw["group"]) if vkw else 200
+    vs = lgb.Dataset(X[:nv], label=y[:nv], reference=ds,
+                     free_raw_data=False, **vkw)
+    with _compiling_here():
+        bst = lgb.train(
+            {"verbosity": -1, "num_leaves": 7, "min_data_in_leaf": 5,
+             "tpu_growth_mode": "rounds", "tpu_hist_dtype": "int16",
+             **params},
+            ds, num_boost_round=2, valid_sets=[vs], valid_names=["valid"])
+        g = bst._gbdt
+        text = g._f_program.chunk(1).lower(
+            g._fstate, g._f_data).compile().as_text()
+    return text, bst.model_to_string()
+
+
+def _op_names(text):
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("case", ["binary", "lambdarank", "data_mesh"])
+def test_fused_step_carries_every_phase_it_can_reach(case, monkeypatch):
+    """The compiled fused step names its device work from inside: every
+    phase of timer.DEVICE_PHASES that the configuration can reach is in
+    some instruction's op_name, and no `lgbm.` token lies outside the
+    vocabulary (the benchmark's readers search for that prefix)."""
+    from lightgbm_tpu import config
+    from lightgbm_tpu.timer import DEVICE_PHASES, DEVICE_PREFIX
+
+    assert DEVICE_PREFIX == "lgbm." and 10 <= len(DEVICE_PHASES) <= 14
+    rs = np.random.RandomState(11)
+    X = rs.randn(600, 5)
+    _drop_traces()
+    if case == "lambdarank":
+        group = [20] * 30
+        y = rs.randint(0, 4, 600).astype(np.float32)
+        params = {"objective": "lambdarank", "metric": "ndcg",
+                  "eval_at": [3]}
+    else:
+        group = None
+        y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+        params = {"objective": "binary", "metric": "auc"}
+        if case == "data_mesh":
+            params["tree_learner"] = "data"
+    monkeypatch.setattr(config, "DEFAULT_CHUNK_LADDER", (1,))
+    text, _ = _fused_step_text(params, X, y, group)
+    found = {m for n in _op_names(text) for m in _PHASE_TOKEN.findall(n)}
+    assert found <= set(DEVICE_PHASES), found - set(DEVICE_PHASES)
+    want = set(DEVICE_PHASES)
+    if case != "data_mesh":
+        want -= {"parallel.reduce"}  # nothing crosses a mesh of one
+    assert found >= want, want - found
+    # scopes nest and the innermost names the op
+    assert any("lgbm.learner.route/lgbm.learner.hist" in n.replace(
+        "vmap(", "").replace(")", "") or "lgbm.learner.select/" in n
+        for n in _op_names(text))
+    _drop_traces()
+
+
+def test_device_phases_change_no_equation_and_no_model(monkeypatch):
+    """A named scope is metadata: with `device_phase` a null context the
+    fused step has the same instructions under other names, and the
+    model text is the same byte for byte."""
+    from lightgbm_tpu import config, timer
+    from lightgbm_tpu.learner import rounds as rounds_mod
+    from lightgbm_tpu.parallel import data_parallel
+
+    rs = np.random.RandomState(11)
+    X = rs.randn(600, 5)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    params = {"objective": "binary", "metric": "auc"}
+    _drop_traces()
+    monkeypatch.setattr(config, "DEFAULT_CHUNK_LADDER", (1,))
+    text, model = _fused_step_text(params, X, y)
+    assert any("lgbm." in n for n in _op_names(text))
+    for mod in (timer, boosting, rounds_mod, data_parallel):
+        monkeypatch.setattr(mod, "device_phase",
+                            lambda name: contextlib.nullcontext())
+    _drop_traces()
+    bare_text, bare_model = _fused_step_text(params, X, y)
+    assert not {m for n in _op_names(bare_text)
+                for m in _PHASE_TOKEN.findall(n)}
+    assert bare_model == model
+
+    def instructions(t):
+        """(result shape, opcode) of every instruction, in order: XLA
+        names an instruction after its metadata, so names differ."""
+        found = (re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", ln)
+                 for ln in t.splitlines())
+        return [m.groups() for m in found if m]
+
+    assert len(instructions(text)) > 1000
+    assert instructions(bare_text) == instructions(text)
+    _drop_traces()
+
+
+def test_device_phase_fails_at_trace_time_outside_the_vocabulary():
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.timer import device_phase
+
+    def f(x):
+        with device_phase("nonsense"):
+            return x + 1
+
+    with pytest.raises(KeyError, match="nonsense"):
+        jax.jit(f).lower(jnp.zeros(3))
+    with device_phase("learner.hist"):
+        pass  # outside a trace a scope is nothing
+
+
+def test_timer_scope_names_no_device_work():
+    """Timer.scope is a HOST span: what is traced inside it compiles to
+    the same module, op_names included, with the timer on or off."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.timer import Timer
+
+    def text(enabled):
+        t = Timer()
+        t.enabled = enabled
+        with t.scope("round: fused step"):
+            return jax.jit(lambda x: jnp.tanh(x) * 2).lower(
+                jnp.zeros(8)).compile().as_text()
+
+    on, off = _op_names(text(True)), _op_names(text(False))
+    assert on == off and any(n.endswith("/tanh") for n in on)
+    assert not any("round" in n for n in on)
 
 
 def test_compile_counters_keep_seconds_by_stage():

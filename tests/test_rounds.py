@@ -319,3 +319,54 @@ def test_round_writes_the_pool_once_in_the_carry(pool_body, branch):
     assert len(writes) == 1 and writes[0] in after
     assert writes[0].invars[0] in pool_body.invars
     assert writes[0].outvars[0] in pool_body.outvars
+
+
+def test_round_sections_are_traced_under_their_device_phase(pool_body):
+    """The grower names its device work from inside (timer.device_phase):
+    the loop body's equations carry the phase of their section in their
+    name stack, the innermost last; the pool's one scatter is
+    `learner.pool_write`, and what carries none is the loop's own
+    plumbing (the ladder's switch, the routing round's cond, the round
+    counters)."""
+    import re
+
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    from lightgbm_tpu.timer import DEVICE_PHASES, DEVICE_PREFIX
+
+    def phase(eqn, above=None):
+        found = re.findall(re.escape(DEVICE_PREFIX) + r"([a-z_.]+)",
+                           str(eqn.source_info.name_stack))
+        return found[-1] if found else above
+
+    by_phase = {}
+
+    def walk(jaxpr, above):
+        # a sub-jaxpr's name stacks are relative to the equation that
+        # holds it: its phase reaches down
+        for e in jaxpr.eqns:
+            here = phase(e, above)
+            by_phase.setdefault(here, []).append(e.primitive.name)
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    if isinstance(sub, (ClosedJaxpr, Jaxpr)):
+                        walk(getattr(sub, "jaxpr", sub), here)
+
+    walk(pool_body, None)
+    n_eqns = sum(len(v) for v in by_phase.values())
+    assert set(by_phase) - {None} == {
+        "learner.select", "learner.route", "learner.hist",
+        "learner.subtract", "learner.split_search", "learner.pool_write"}
+    assert set(by_phase) - {None} <= set(DEVICE_PHASES)
+    (write,) = [e for e in pool_body.eqns
+                if e.primitive.name.startswith("scatter")
+                and e.outvars[0].aval.size == _POOL_SIZE]
+    assert phase(write) == "learner.pool_write"
+    assert "top_k" in by_phase["learner.select"]
+    assert "top_k" not in by_phase["learner.split_search"]
+    assert len(by_phase[None]) < 0.02 * n_eqns
+    assert set(by_phase[None]) <= {
+        "cond", "scatter-add", "select_n", "convert_element_type", "add",
+        "lt", "le", "ge", "gt", "and", "sub", "min", "eq", "pjit", "jit",
+        "broadcast_in_dim", "squeeze", "mul", "concatenate", "reshape",
+        "dynamic_slice", "dynamic_update_slice", "clamp"}
