@@ -715,6 +715,8 @@ class GBDT:
                 m.bin_type == BinType.CATEGORICAL
                 for m in train_set.used_mappers()
             ),
+            has_nan=any(m.nan_bin >= 0 for m in train_set.used_mappers()),
+            has_mono=bool(mono_any),
         )
         self.params = make_split_params(config)
         # ---- provenance for the flight recorder / run manifest
@@ -1612,9 +1614,10 @@ class GBDT:
             ladder_widths(self.spec) if self.spec.rounds_slots > 0 else ()
         )
         self._f_ladder_widths = ladder_ws
-        if ladder_ws:
-            from .obs.metrics import record_hist_schedule
+        from .obs.metrics import record_hist_schedule, record_split_search
 
+        record_split_search(self.spec.search)
+        if ladder_ws:
             # the schedule is a shard's: the rows one kernel call sees
             n_cols, n_rows = self.dev["bins"].shape
             n_rows //= (int(data_mesh.devices.size) if data_mesh is not None

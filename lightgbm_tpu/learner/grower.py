@@ -42,7 +42,10 @@ from .histogram import (
     histogram,
     root_sums,
 )
-from .split import BIG, NEG_INF, SplitParams, SplitRecord, best_split, leaf_output
+from .split import (
+    BIG, NEG_INF, SearchDirections, SplitParams, SplitRecord, best_split,
+    leaf_output,
+)
 
 
 class GrowerSpec(NamedTuple):
@@ -145,6 +148,17 @@ class GrowerSpec(NamedTuple):
     # statically — the (L*B,) mask gather it replaces is an element
     # gather per row, ~10 ms/round at 1M rows on the chip
     has_cat: bool = True
+    # some used column has a NaN bin / carries a monotone constraint:
+    # like has_cat, facts of the Dataset that decide which directions
+    # the split search traces (split.SearchDirections)
+    has_nan: bool = True
+    has_mono: bool = True
+
+    @property
+    def search(self) -> SearchDirections:
+        return SearchDirections(
+            default_left=self.has_nan, categorical=self.has_cat,
+            cat_subset=self.cat_subset, monotone_test=self.has_mono)
 
 
 class CegbInfo(NamedTuple):
@@ -469,7 +483,7 @@ def _grow_tree_flat(
         best_split(exp_hist(hist0, root[0], root[1], root[2]),
                    root[0], root[1], root[2], num_bins, nan_bin,
                    mono, is_cat, params, feat_mask,
-                   cat_subset=spec.cat_subset, parent_output=root_out))
+                   dirs=spec.search, parent_output=root_out))
 
     hist = jnp.zeros((L, 3, G, Bc), jnp.float32).at[0].set(hist0)
     best = _set_best(_empty_best(L, B), jnp.int32(0), rec0, rec0.gain)
@@ -650,13 +664,13 @@ def _grow_tree_flat(
             exp_hist(left_hist, rec.left_g, rec.left_h, rec.left_c),
             rec.left_g, rec.left_h, rec.left_c,
             num_bins, nan_bin, mono, is_cat, params, feat_mask,
-            cat_subset=spec.cat_subset, parent_output=lo,
+            dirs=spec.search, parent_output=lo,
             cmin=lmin, cmax=lmax))
         br = select_global(best_split(
             exp_hist(right_hist, rec.right_g, rec.right_h, rec.right_c),
             rec.right_g, rec.right_h, rec.right_c,
             num_bins, nan_bin, mono, is_cat, params, feat_mask,
-            cat_subset=spec.cat_subset, parent_output=ro,
+            dirs=spec.search, parent_output=ro,
             cmin=rmin, cmax=rmax))
         depth_ok = (spec.max_depth <= 0) | (depth_new < spec.max_depth)
         best2 = _set_best(s.best, l, bl, jnp.where(depth_ok, bl.gain, NEG_INF))

@@ -228,7 +228,7 @@ def grow_tree_permuted(
     rec0 = best_split(exp_hist(hist0, root[0], root[1], root[2]),
                       root[0], root[1], root[2], num_bins, nan_bin,
                       mono, is_cat, params, fm0,
-                      cat_subset=spec.cat_subset, parent_output=root_out,
+                      dirs=spec.search, parent_output=root_out,
                       penalty=pen0, rand_bin=rb0)
 
     hist = jnp.zeros((L, 3, G, Bc), jnp.float32).at[0].set(hist0)
@@ -485,7 +485,7 @@ def grow_tree_permuted(
                 exp_hist(small_hist, lsum3[0], lsum3[1], lsum3[2]),
                 lsum3[0], lsum3[1], lsum3[2], num_bins,
                 nan_bin, mono, is_cat, params, feat_mask,
-                cat_subset=spec.cat_subset,
+                dirs=spec.search,
             )  # (F,) local per-feature gains
             if spec.efb:
                 col_gain = jnp.full(G, NEG_INF).at[bundle.bundle_of].max(
@@ -569,13 +569,13 @@ def grow_tree_permuted(
                 exp_hist(left_hist, rec.left_g, rec.left_h, rec.left_c),
                 rec.left_g, rec.left_h, rec.left_c,
                 num_bins, nan_bin, mono, is_cat, params, fm_l,
-                cat_subset=spec.cat_subset, parent_output=lo,
+                dirs=spec.search, parent_output=lo,
                 cmin=lmin, cmax=lmax, penalty=pen_l, rand_bin=rb_l)
             br = best_split(
                 exp_hist(right_hist, rec.right_g, rec.right_h, rec.right_c),
                 rec.right_g, rec.right_h, rec.right_c,
                 num_bins, nan_bin, mono, is_cat, params, fm_r,
-                cat_subset=spec.cat_subset, parent_output=ro,
+                dirs=spec.search, parent_output=ro,
                 cmin=rmin, cmax=rmax, penalty=pen_r, rand_bin=rb_r)
             depth_ok = (spec.max_depth <= 0) | (depth_new < spec.max_depth)
             best2 = _set_best(
@@ -634,7 +634,7 @@ def grow_tree_permuted(
                 return best_split(
                     exp_hist(h_, g_, hh_, c_), g_, hh_, c_, num_bins,
                     nan_bin, mono, is_cat, params, feat_mask,
-                    cat_subset=spec.cat_subset, parent_output=po_,
+                    dirs=spec.search, parent_output=po_,
                     cmin=mn_, cmax=mx_,
                 )
 

@@ -558,7 +558,7 @@ def grow_tree_rounds(
         with device_phase("learner.split_search"):
             rec0 = select_global_rec(best_split(
                 hist0, root[0], root[1], root[2], nb_t, nan_t, mono_t,
-                iscat_t, params, fm_t, cat_subset=spec.cat_subset,
+                iscat_t, params, fm_t, dirs=spec.search,
                 parent_output=root_out))
     else:
         nb_t, nan_t, mono_t, iscat_t, fm_t = (
@@ -567,7 +567,7 @@ def grow_tree_rounds(
             rec0 = best_split(exp_hist(hist0, root[0], root[1], root[2]),
                               root[0], root[1], root[2], num_bins, nan_bin,
                               mono, is_cat, params, fm0,
-                              cat_subset=spec.cat_subset,
+                              dirs=spec.search,
                               parent_output=root_out,
                               penalty=pen0, rand_bin=rb0)
 
@@ -641,7 +641,7 @@ def grow_tree_rounds(
             return best_split(
                 exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
                 mono_t, iscat_t, params, fm_t if fm is None else fm,
-                cat_subset=spec.cat_subset, parent_output=po,
+                dirs=spec.search, parent_output=po,
                 cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
             )
 
@@ -940,7 +940,7 @@ def grow_tree_rounds(
                 return feature_best_gains(
                     exp_hist(h, g_, h__, c_), g_, h__, c_, num_bins,
                     nan_bin, mono, is_cat, params, feat_mask,
-                    cat_subset=spec.cat_subset,
+                    dirs=spec.search,
                 )
 
             lg_s = jax.vmap(slot_gains)(

@@ -3,7 +3,7 @@
 Reimplements the split-gain math of the reference threshold scan
 (src/treelearner/feature_histogram.hpp:832 FindBestThresholdSequentially,
 CUDA analog src/treelearner/cuda/cuda_best_split_finder.cu) as cumulative
-sums over the bin axis plus a masked argmax — no sequential per-bin loop:
+sums over the bin axis plus masked reductions — no sequential per-bin loop:
 
 - L1/L2 regularization via ThresholdL1 soft-thresholding
   (feature_histogram.hpp GetLeafGain/CalculateSplittedLeafOutput),
@@ -14,9 +14,13 @@ sums over the bin axis plus a masked argmax — no sequential per-bin loop:
   milestone,
 - min_data_in_leaf / min_sum_hessian_in_leaf / min_gain_to_split masks,
 - monotone-constraint candidate masking (basic method),
-- tie-break: argmax over arrays laid out (dir, F, B) flattened picks the
-  lowest flat index, matching the reference's first-feature-wins
-  strictly-greater update order.
+- tie-break: every candidate carries a key made of iotas (its place in
+  the reference's scan order within its column); reductions keep the
+  higher gain and, on equal gains, the lower key, then the first column:
+  the reference's first-feature-wins strictly-greater update order,
+- only the directions the Dataset can have are traced
+  (SearchDirections), and the planes lie bins-major where the columns
+  fill the chip's lanes better than the bins (columns_on_lanes).
 
 Gains are stored shifted by (parent_gain + min_gain_to_split) so that
 "> 0" means a valid improving split, as in the reference SplitInfo.
@@ -28,6 +32,7 @@ from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 # plain float (NOT jnp.float32): a module-level device constant would
 # initialize the jax backend at import time — taking the chip before
@@ -169,8 +174,6 @@ def _cat_subset_scan(g, h, c, num_bins, nan_bin, is_cat, sum_g, sum_h, sum_c,
     {b : valid_bin[f,b] and (inv_rank[f,b] <= i if dir==0 else
     inv_rank[f,b] >= used[f]-1-i)}.
     """
-    from jax import lax
-
     F, B = g.shape
     bidx = jnp.arange(B)[None, :]
     valid_bin = (
@@ -247,6 +250,17 @@ def _cat_subset_scan(g, h, c, num_bins, nan_bin, is_cat, sum_g, sum_h, sum_c,
     return gains, ok, jnp.stack([lg, lh, lc]), inv_rank, valid_bin, used
 
 
+class SearchDirections(NamedTuple):
+    """What a split can be on this Dataset: the search traces only
+    these (default-right always). Static facts of the INPUT, filled
+    into GrowerSpec by the host; the defaults are the general case."""
+
+    default_left: bool = True  # some used column has a NaN bin
+    categorical: bool = True  # some used column is categorical
+    cat_subset: bool = False  # ... wider than max_cat_to_onehot
+    monotone_test: bool = True  # some column has a monotone constraint
+
+
 def best_split(
     hist: jax.Array,  # (3, F, B) f32 — (grad, hess, count) channels
     sum_g: jax.Array,
@@ -258,7 +272,7 @@ def best_split(
     is_cat: jax.Array,  # (F,) bool
     params: SplitParams,
     feat_mask: Optional[jax.Array] = None,  # (F,) bool — ColSampler feature_fraction
-    cat_subset: bool = False,  # static: dataset has large-cardinality cats
+    dirs: SearchDirections = SearchDirections(),  # static
     parent_output: jax.Array = 0.0,  # the leaf's current output (smoothing)
     cmin: jax.Array = -BIG,  # monotone-constraint interval of the leaf
     cmax: jax.Array = BIG,
@@ -269,14 +283,14 @@ def best_split(
     """Find the best split of a leaf with given histogram and totals."""
     return _best_split_impl(
         hist, sum_g, sum_h, sum_c, num_bins, nan_bin, mono, is_cat, params,
-        feat_mask, cat_subset, parent_output, cmin, cmax, penalty, rand_bin,
+        feat_mask, dirs, parent_output, cmin, cmax, penalty, rand_bin,
     )[0]
 
 
 def feature_best_gains(
     hist, sum_g, sum_h, sum_c, num_bins, nan_bin, mono, is_cat, params,
-    feat_mask=None, cat_subset: bool = False, parent_output=0.0,
-    cmin=-BIG, cmax=BIG,
+    feat_mask=None, dirs: SearchDirections = SearchDirections(),
+    parent_output=0.0, cmin=-BIG, cmax=BIG,
 ):
     """Per-feature best (shifted) gain: max over thresholds/directions.
 
@@ -285,32 +299,101 @@ def feature_best_gains(
     computed on the LOCAL (un-reduced) histogram."""
     return _best_split_impl(
         hist, sum_g, sum_h, sum_c, num_bins, nan_bin, mono, is_cat, params,
-        feat_mask, cat_subset, parent_output, cmin, cmax,
+        feat_mask, dirs, parent_output, cmin, cmax,
     )[1]
+
+
+def columns_on_lanes(F: int, B: int) -> bool:
+    """The search's layout, from the table's shape where the program is
+    built: the (column, bin) planes lie bins-major with the COLUMNS on
+    the chip's 128 lanes where the columns fill whole lane tiles better
+    than the bins do (2,000 x 63: 98% against 49%; 28 x 255: 22% against
+    99.6%). Bins-major, the cumulative sums and the per-column
+    reductions run across vector registers and only the last reduction
+    crosses lanes."""
+
+    def fill(n):
+        return n / (-(-n // 128) * 128)
+
+    return fill(F) > fill(B)
+
+
+def _prefer(x, y):
+    """Of two candidates (gain, key, *left sums) the higher gain; on
+    equal gains the lower key."""
+    first = (x[0] > y[0]) | ((x[0] == y[0]) & (x[1] < y[1]))
+    return tuple(jnp.where(first, a, b) for a, b in zip(x, y))
 
 
 def _best_split_impl(
     hist, sum_g, sum_h, sum_c, num_bins, nan_bin, mono, is_cat, params,
-    feat_mask, cat_subset: bool, parent_output, cmin, cmax,
+    feat_mask, dirs: SearchDirections, parent_output, cmin, cmax,
     penalty=None, rand_bin=None,
 ):
     _, F, B = hist.shape
-    g = hist[0]
-    h = hist[1]
-    c = hist[2]
-    bin_idx = jnp.arange(B, dtype=jnp.int32)[None, :]  # (1, B)
+    hist_fb = hist
+    # `ax`: the bin axis of a (column, bin) plane (columns_on_lanes)
+    ax = 0 if columns_on_lanes(F, B) else 1
+    if ax == 0:
+        hist = jnp.swapaxes(hist, 1, 2)  # (3, B, F)
+    if not dirs.monotone_test:
+        # no constrained column: every leaf's interval is (-BIG, BIG)
+        cmin = cmax = None
+    bin_idx = jnp.expand_dims(jnp.arange(B, dtype=jnp.int32), 1 - ax)
+    nan_b = jnp.expand_dims(nan_bin, ax)
+    nb = jnp.expand_dims(num_bins, ax)
+    has_nan = nan_b >= 0
+    # thresholds are t in [0, last - 1]: never the NaN bin (the last one)
+    last_col = jnp.where(nan_bin >= 0, num_bins - 2, num_bins - 1)  # (F,)
 
-    has_nan = (nan_bin >= 0)[:, None]  # (F, 1)
-    nan_g = jnp.where(has_nan[:, 0], jnp.take_along_axis(g, jnp.maximum(nan_bin, 0)[:, None], axis=1)[:, 0], 0.0)[:, None]
-    nan_h = jnp.where(has_nan[:, 0], jnp.take_along_axis(h, jnp.maximum(nan_bin, 0)[:, None], axis=1)[:, 0], 0.0)[:, None]
-    nan_c = jnp.where(has_nan[:, 0], jnp.take_along_axis(c, jnp.maximum(nan_bin, 0)[:, None], axis=1)[:, 0], 0.0)[:, None]
+    # ---- tie-breaking mirrors the reference scan order exactly
+    # (feature_histogram.hpp:396-441 FindBestThresholdSequentially):
+    # the REVERSE scan runs first (t descending -> on equal gain the
+    # HIGHEST threshold wins, and it owns the default-left direction),
+    # the forward scan second and replacing only on strictly greater
+    # gain; missing-type-None features run ONLY the reverse scan. A
+    # candidate's KEY is its place in that order within its column,
+    # `reindexed bin * D + direction`: the bin axis counted from the top
+    # for the default-left direction and, in columns with no NaN bin
+    # (whose single reference scan is the reverse one), for the
+    # default-right one; directions in the order dl, dr, cat[, subset
+    # asc, desc]. First column, then lowest key, wins a tie.
+    # Categorical-subset deviation from the reference on EXACT float
+    # ties only: it scans all ascending subset prefixes before any
+    # descending one (feature_histogram.cpp:276), while this order
+    # interleaves directions per prefix length.
+    order = [name for name, on in (
+        ("dl", dirs.default_left), ("dr", True), ("cat", dirs.categorical),
+        ("asc", dirs.cat_subset), ("desc", dirs.cat_subset)) if on]
+    D = len(order)
 
-    # ---- numerical: cumulative left sums, threshold t keeps bins <= t left.
-    cg = jnp.cumsum(g, axis=1)
-    ch = jnp.cumsum(h, axis=1)
-    cc = jnp.cumsum(c, axis=1)
+    parent_gain = jnp.where(
+        params.path_smooth > 0.0,
+        leaf_gain_given_output(sum_g, sum_h, params, parent_output),
+        leaf_gain(sum_g, sum_h, params),
+    )
+    shift = parent_gain + params.min_gain_to_split
 
-    def eval_lr(lg, lh, lc):
+    def column_best(name, gains, ok, pos, left, axis):
+        """One direction's best candidate per column (gain, key, left
+        sums): masked, shifted, then ONE reduction over the bin axis."""
+        if feat_mask is not None:
+            ok = ok & jnp.expand_dims(feat_mask, axis)
+        gains = jnp.where(ok, gains - shift, NEG_INF)
+        if penalty is not None:
+            # CEGB DeltaGain (cost_effective_gradient_boosting.hpp:79):
+            # per-feature acquisition cost subtracted from every candidate
+            gains = gains - jnp.expand_dims(penalty, axis)
+        keys = jnp.broadcast_to(pos * D + order.index(name), gains.shape)
+        return lax.reduce(
+            (gains, keys, *left),
+            (jnp.float32(-jnp.inf), jnp.int32(2 ** 31 - 1))
+            + (jnp.float32(0.0),) * 3, _prefer, (axis,))
+
+    def direction(name, left, ok, pos):
+        """A left / right direction on the planes: gain and validity of
+        every candidate from its left sums, then the columns' best."""
+        lg, lh, lc = left
         rg = sum_g - lg
         rh = sum_h - lh
         rc = sum_c - lc
@@ -318,152 +401,93 @@ def _best_split_impl(
             lg, lh, params, lc, parent_output, cmin, cmax
         ) + leaf_gain(rg, rh, params, rc, parent_output, cmin, cmax)
         ok = (
-            (lc >= params.min_data_in_leaf)
+            ok
+            & (lc >= params.min_data_in_leaf)
             & (rc >= params.min_data_in_leaf)
             & (lh >= params.min_sum_hessian_in_leaf)
             & (rh >= params.min_sum_hessian_in_leaf)
         )
-        # monotone basic: candidate-level output ordering
-        lo = leaf_output(lg, lh, params, lc, parent_output, cmin, cmax)
-        ro = leaf_output(rg, rh, params, rc, parent_output, cmin, cmax)
-        m = mono[:, None]
-        ok &= jnp.where(m > 0, lo <= ro, True)
-        ok &= jnp.where(m < 0, lo >= ro, True)
-        return gains, ok, (lg, lh, lc)
+        if dirs.monotone_test:
+            # monotone basic: candidate-level output ordering
+            lo = leaf_output(lg, lh, params, lc, parent_output, cmin, cmax)
+            ro = leaf_output(rg, rh, params, rc, parent_output, cmin, cmax)
+            m = jnp.expand_dims(mono, ax)
+            ok &= jnp.where(m > 0, lo <= ro, True)
+            ok &= jnp.where(m < 0, lo >= ro, True)
+        return column_best(name, gains, ok, pos, left, ax)
 
-    # NaN bin (last bin) is never <= t for valid t, so cum excludes it.
-    # default right: missing stays right.
-    gain_dr, ok_dr, _ = eval_lr(cg, ch, cc)
-    # default left: NaN bin mass joins the left side.
-    gain_dl, ok_dl, _ = eval_lr(cg + nan_g, ch + nan_h, cc + nan_c)
-    # only evaluate the default-left variant when the feature has a NaN bin
-    ok_dl &= has_nan
-
-    # threshold validity: t in [0, num_bin-2], excluding the NaN bin itself
-    last_real = jnp.where(nan_bin[:, None] >= 0, num_bins[:, None] - 2, num_bins[:, None] - 1)
-    t_ok = bin_idx < last_real
-    num_mask = (~is_cat)[:, None] & t_ok
-    ok_dr &= num_mask
-    ok_dl &= num_mask
-
-    # ---- categorical one-vs-rest: bin t alone goes left. With the
-    # sorted-subset path enabled, one-hot applies only to features with
-    # num_bin <= max_cat_to_onehot (feature_histogram.cpp:182 use_onehot);
-    # without it (legacy callers) every categorical stays one-vs-rest.
-    gain_cat, ok_cat, _ = eval_lr(g, h, c)
-    ok_cat &= (
-        is_cat[:, None]
-        & (bin_idx < num_bins[:, None])
-        & (bin_idx != nan_bin[:, None])
-    )
-    if cat_subset:
-        ok_cat &= (num_bins <= params.max_cat_to_onehot)[:, None]
-
+    # ---- numerical: cumulative left sums, threshold t keeps bins <= t
+    # left (the NaN bin is never <= t for valid t, so they exclude it).
+    # ONE sum of the three channels where the bins are major (adds across
+    # vector registers); a channel at a time where they are minor: past
+    # 128 bins XLA scans in two levels, and per channel that is the order
+    # of additions the recorded model texts have
+    cum = jnp.cumsum(hist, axis=1) if ax == 0 else jnp.stack(
+        [jnp.cumsum(plane, axis=1) for plane in hist])
+    last_real = jnp.expand_dims(last_col, ax)
+    num_ok = jnp.expand_dims(~is_cat, ax) & (bin_idx < last_real)
     if rand_bin is not None:
         # extra_trees: one random numerical threshold per feature per
         # node (col_sampler / feature_histogram extra-trees scan); the
-        # categorical directions keep their full search. Applied in
-        # ORIGINAL bin space, before the tie-break reindexing below.
-        rb_ok = bin_idx == rand_bin[:, None]
-        ok_dr &= rb_ok
-        ok_dl &= rb_ok
-
-    parent_gain_plain = leaf_gain(sum_g, sum_h, params)
-    parent_gain = jnp.where(
-        params.path_smooth > 0.0,
-        leaf_gain_given_output(sum_g, sum_h, params, parent_output),
-        parent_gain_plain,
-    )
-    shift = parent_gain + params.min_gain_to_split
-
-    # ---- tie-breaking mirrors the reference scan order exactly
-    # (feature_histogram.hpp:396-441 FindBestThresholdSequentially):
-    # the REVERSE scan runs first (t descending -> on equal gain the
-    # HIGHEST threshold wins, and it owns the default-left direction),
-    # the forward scan second and replacing only on strictly greater
-    # gain; missing-type-None features run ONLY the reverse scan. We
-    # express this inside one argmax by reindexing the bin axis so the
-    # preferred candidate of any tie has the lowest flat index: the
-    # default-left direction is stored bin-flipped and stacked first,
-    # and the default-right direction is bin-flipped for features with
-    # no NaN bin (whose single reference scan is the reverse one).
-    no_nan = ~has_nan  # (F, 1)
-    bin_rev = jnp.clip(last_real - 1 - bin_idx, 0, B - 1)  # (F, B)
-
-    def flipb(a):
-        return jnp.take_along_axis(a, bin_rev, axis=1)
-
-    gain_dl_s = flipb(gain_dl)
-    ok_dl_s = flipb(ok_dl)
-    gain_dr_s = jnp.where(no_nan, flipb(gain_dr), gain_dr)
-    ok_dr_s = jnp.where(no_nan, flipb(ok_dr), ok_dr)
-
-    # stack: dir axis LAST in flat order (F, B, D) so ties break on
-    # feature, then (reindexed) bin, then
-    # (dl, dr, cat[, cat_asc, cat_desc]). Categorical-subset deviation
-    # from the reference on EXACT float ties only: it scans all
-    # ascending subset prefixes before any descending one
-    # (feature_histogram.cpp:276), while this order interleaves
-    # directions per prefix length.
-    dirs = [gain_dl_s, gain_dr_s, gain_cat]
-    oks = [ok_dl_s, ok_dr_s, ok_cat]
-    if cat_subset:
+        # categorical directions keep their full search
+        num_ok &= bin_idx == jnp.expand_dims(rand_bin, ax)
+    from_top = last_real - 1 - bin_idx
+    # default right: missing stays right
+    best = direction("dr", cum, num_ok,
+                     jnp.where(has_nan, bin_idx, from_top))
+    if dirs.default_left:
+        # default left: the NaN bin's mass joins the left side, in the
+        # columns that have one
+        nan3 = jnp.sum(jnp.where(bin_idx == nan_b, hist, 0.0), axis=1 + ax,
+                       keepdims=True)
+        best = _prefer(best, direction(
+            "dl", cum + nan3, num_ok & has_nan, from_top))
+    if dirs.categorical:
+        # categorical one-vs-rest: bin t alone goes left. With the
+        # sorted-subset path enabled, one-hot applies only to features
+        # with num_bin <= max_cat_to_onehot (feature_histogram.cpp:182
+        # use_onehot); without it every categorical stays one-vs-rest.
+        ok = jnp.expand_dims(is_cat, ax) & (bin_idx < nb) & (bin_idx != nan_b)
+        if dirs.cat_subset:
+            ok &= jnp.expand_dims(num_bins <= params.max_cat_to_onehot, ax)
+        best = _prefer(best, direction("cat", hist, ok, bin_idx))
+    if dirs.cat_subset:
         big = is_cat & (num_bins > params.max_cat_to_onehot)
         cs_gain, cs_ok, cs_sums, inv_rank, valid_bin, cs_used = _cat_subset_scan(
-            g, h, c, num_bins, nan_bin, big, sum_g, sum_h, sum_c, params,
-            parent_output, cmin, cmax,
+            hist_fb[0], hist_fb[1], hist_fb[2], num_bins, nan_bin, big,
+            sum_g, sum_h, sum_c, params, parent_output, cmin, cmax,
         )
-        dirs += [cs_gain[:, :, 0], cs_gain[:, :, 1]]
-        oks += [cs_ok[:, :, 0], cs_ok[:, :, 1]]
-    D = len(dirs)
-    gains = jnp.stack(dirs, axis=-1) - shift  # (F, B, D)
-    ok = jnp.stack(oks, axis=-1)
-    if feat_mask is not None:
-        ok &= feat_mask[:, None, None]
-    gains = jnp.where(ok, gains, NEG_INF)
-    if penalty is not None:
-        # CEGB DeltaGain (cost_effective_gradient_boosting.hpp:79):
-        # per-feature acquisition cost subtracted from every candidate
-        gains = gains - penalty[:, None, None]
+        prefix = jnp.arange(B, dtype=jnp.int32)[None, :]
+        for k, name in enumerate(("asc", "desc")):
+            best = _prefer(best, column_best(
+                name, cs_gain[:, :, k], cs_ok[:, :, k], prefix,
+                cs_sums[:, :, :, k], 1))
 
-    flat = gains.reshape(-1)
-    idx = jnp.argmax(flat)
-    best_gain = flat[idx]
-    f = (idx // (B * D)).astype(jnp.int32)
-    b = ((idx // D) % B).astype(jnp.int32)
-    d = (idx % D).astype(jnp.int32)
-    default_left = d == 0
-    cat = d >= 2
+    # ---- the winner: the first column that attains the best gain
+    col_gain, col_key = best[:2]  # (F,)
+    f = jnp.argmax(col_gain).astype(jnp.int32)
+    d = col_key[f] % D
+    default_left = (d == order.index("dl")) if dirs.default_left else \
+        jnp.bool_(False)
+    cat = ~default_left & (d != order.index("dr"))
     # undo the tie-break bin reindexing (numerical dirs only)
-    lr_f = last_real[f, 0]
-    was_flipped = (d == 0) | ((d == 1) & (nan_bin[f] < 0))
-    b = jnp.where(
-        was_flipped & ~cat, jnp.clip(lr_f - 1 - b, 0, B - 1), b
-    ).astype(jnp.int32)
-
-    lg_num = cg[f, b] + jnp.where(default_left, nan_g[f, 0], 0.0)
-    lh_num = ch[f, b] + jnp.where(default_left, nan_h[f, 0], 0.0)
-    lc_num = cc[f, b] + jnp.where(default_left, nan_c[f, 0], 0.0)
-    lg = jnp.where(cat, g[f, b], lg_num)
-    lh = jnp.where(cat, h[f, b], lh_num)
-    lc = jnp.where(cat, c[f, b], lc_num)
+    b = col_key[f] // D
+    b = jnp.clip(
+        jnp.where(default_left | (~cat & (nan_bin[f] < 0)),
+                  last_col[f] - 1 - b, b),
+        0, B - 1).astype(jnp.int32)
     # one-hot left set: the single winning bin
     cat_mask = (jnp.arange(B, dtype=jnp.int32) == b) & cat
-
-    if cat_subset:
-        is_sub = d >= 3
-        asc = d == 3
-        lg = jnp.where(is_sub, cs_sums[0, f, b, d - 3], lg)
-        lh = jnp.where(is_sub, cs_sums[1, f, b, d - 3], lh)
-        lc = jnp.where(is_sub, cs_sums[2, f, b, d - 3], lc)
+    if dirs.cat_subset:
         rank_f = inv_rank[f]
         sub_mask = jnp.where(
-            asc, rank_f <= b, rank_f >= cs_used[f] - 1 - b
+            d == order.index("asc"), rank_f <= b, rank_f >= cs_used[f] - 1 - b
         ) & valid_bin[f]
-        cat_mask = jnp.where(is_sub, sub_mask, cat_mask)
+        cat_mask = jnp.where(d >= order.index("asc"), sub_mask, cat_mask)
 
+    lg, lh, lc = (side[f] for side in best[2:])
     rec = SplitRecord(
-        gain=best_gain,
+        gain=col_gain[f],
         feature=f,
         bin=b,
         default_left=default_left,
@@ -476,4 +500,4 @@ def _best_split_impl(
         right_h=sum_h - lh,
         right_c=sum_c - lc,
     )
-    return rec, jnp.max(gains, axis=(1, 2))
+    return rec, col_gain

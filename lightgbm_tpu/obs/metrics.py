@@ -714,6 +714,23 @@ def record_hist_schedule(sched, n_cols: int) -> None:
         calls.set(n, width=width)
 
 
+def record_split_search(dirs) -> None:
+    """Gauge of the split search's traced directions
+    (learner/split.py SearchDirections: static facts of the Dataset),
+    set where the fused step is built: 1 where the program traces the
+    direction, else 0; default-right always is."""
+    r = _default
+    if not r.enabled:
+        return
+    g = r.gauge("lgbmtpu_split_search_directions",
+                "1 where the split search traces the direction or test "
+                "(the Dataset can have such a split), else 0",
+                labels=("kind",))
+    g.set(1, kind="default_right")
+    for kind, on in dirs._asdict().items():
+        g.set(int(on), kind=kind)
+
+
 def record_label_cache(kind: str, hit: bool) -> None:
     """One lookup of a data set's label-sized residency
     (dataset.BinnedDataset.device_label / device_weight / label_stat):

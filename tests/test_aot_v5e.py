@@ -244,9 +244,14 @@ def test_rank_marks_keep_their_names_in_the_compiled_program(
 POOL_ROWS, POOL_FEATURES, POOL_LEAVES = 8 * 2048, 576, 255
 
 
-def _while_body_instructions(text):
+_SHAPE = r"(\w+)\[([\d,]*)\]"
+
+
+def _while_body_instructions(text, fused=True):
     """(opcode, result elements, line) of every instruction in the
-    computations an optimised HLO module's `while` bodies reach."""
+    computations an optimised HLO module's `while` bodies reach; with
+    `fused=False` only of those that run as instructions of their own
+    (a fusion's inside is registers, not arrays)."""
     import math
     import re
 
@@ -273,6 +278,8 @@ def _while_body_instructions(text):
             continue
         seen.add(c)
         for ln in comps[c]:
+            if not fused and " fusion(" in ln:
+                continue
             for one, many in calls.findall(ln):
                 todo += [one] if one else [
                     n.strip().lstrip("%") for n in many.split(",")]
@@ -288,8 +295,43 @@ def _while_body_instructions(text):
     return out
 
 
-def test_no_pool_sized_copy_in_a_round_of_the_compiled_grower(
-        one_chip, no_compile_cache, monkeypatch):
+@pytest.fixture(scope="module")
+def grower_text(one_chip, no_compile_cache):
+    """The optimised HLO of the whole one-chip grower at the routed
+    576 x 63 shape, a plain numerical table (no categorical column, no
+    NaN bin, no monotone constraint): 25-30 s, compiled once."""
+    import sys
+
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner import GrowerSpec, make_split_params
+    from lightgbm_tpu.learner.rounds import grow_tree_rounds, hist_schedule
+
+    F, N, L, B = POOL_FEATURES, POOL_ROWS, POOL_LEAVES, WIDE_BINS
+    spec = GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1,
+                      rounds_slots=48, quant=True, quant_levels=256,
+                      has_cat=False, has_nan=False, has_mono=False)
+    params = jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype),
+                          make_split_params(Config({})))
+    cols = [_arg(one_chip, (F,), jnp.int32)] * 3 + [
+        _arg(one_chip, (F,), jnp.bool_)]
+    rows = [_arg(one_chip, (N,), jnp.float32)] * 3
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
+                   "_use_pallas", lambda: True)
+        sched = hist_schedule(spec, N, F)
+        assert sched.routed and sched.plan.blocks == 2
+        text = jax.jit(
+            lambda bins, nan, nb, mono, cat, g, h, m, fm, p, sc:
+            grow_tree_rounds.__wrapped__(bins, nan, nb, mono, cat, g, h, m,
+                                         fm, p, spec, gh_scale=sc)
+        ).lower(_arg(one_chip, (F, N), jnp.int32), *cols, *rows,
+                _arg(one_chip, (F,), jnp.bool_), params,
+                _arg(one_chip, (2,), jnp.float32)).compile().as_text()
+    assert "%route_round_tpu" in text and "%hist_nat_tpu" in text
+    return text
+
+
+def test_no_pool_sized_copy_in_a_round_of_the_compiled_grower(grower_text):
     """Inside the grower's while body nothing of the histogram pool's
     size is copied or transposed: a round reads its parents' rows,
     and writes its children's rows into the loop's carry in place. The
@@ -297,36 +339,62 @@ def test_no_pool_sized_copy_in_a_round_of_the_compiled_grower(
     scatters' layout before the ladder's switch, turned back in every
     rung, copied once more in the 32- and 48-slot rungs): 41 ms of the
     wide cell's 791 ms a tree."""
-    import sys
-
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.learner import GrowerSpec, make_split_params
-    from lightgbm_tpu.learner.rounds import grow_tree_rounds, hist_schedule
-
-    monkeypatch.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
-                        "_use_pallas", lambda: True)
-    F, N, L, B = POOL_FEATURES, POOL_ROWS, POOL_LEAVES, WIDE_BINS
-    spec = GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1,
-                      rounds_slots=48, quant=True, quant_levels=256,
-                      has_cat=False)
-    sched = hist_schedule(spec, N, F)
-    assert sched.routed and sched.plan.blocks == 2
-    params = jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype),
-                          make_split_params(Config({})))
-    cols = [_arg(one_chip, (F,), jnp.int32)] * 3 + [
-        _arg(one_chip, (F,), jnp.bool_)]
-    rows = [_arg(one_chip, (N,), jnp.float32)] * 3
-    text = jax.jit(
-        lambda bins, nan, nb, mono, cat, g, h, m, fm, p, sc:
-        grow_tree_rounds.__wrapped__(bins, nan, nb, mono, cat, g, h, m, fm,
-                                     p, spec, gh_scale=sc)
-    ).lower(_arg(one_chip, (F, N), jnp.int32), *cols, *rows,
-            _arg(one_chip, (F,), jnp.bool_), params,
-            _arg(one_chip, (2,), jnp.float32)).compile().as_text()
-    assert "%route_round_tpu" in text and "%hist_nat_tpu" in text
-    body = _while_body_instructions(text)
-    pool = L * 3 * F * B
+    body = _while_body_instructions(grower_text)
+    pool = POOL_LEAVES * 3 * POOL_FEATURES * WIDE_BINS
     assert any(n == pool for _, n, _ in body)  # the carry is in there
     moved = [ln for op, n, ln in body
              if n == pool and op in ("copy", "transpose")]
     assert not moved, "\n".join(ln[:200] for ln in moved)
+
+
+def test_the_child_search_moves_no_candidates_in_the_compiled_grower(
+        grower_text):
+    """The children's split search finds its winner by reductions over
+    the candidates where they lie: no gather over a round's children x
+    columns x bins cells (the parent took four (columns, bins) arrays a
+    child along the bin axis for the tie-break), no array of them with
+    the directions as a 3-wide minor dimension (the parent stacked
+    three), no mask of them written out as an array (the parent: four a
+    round), and XLA's estimated cycles under `lgbm.learner.split_search`
+    at most a third of the parent's 6,327,147 at this shape: this tree
+    reads 1,035,884 (PR 35; a prototype of the issue read 1,009,138)."""
+    import re
+
+    cells = {2 * s * POOL_FEATURES * WIDE_BINS * k
+             for s in (8, 16, 32, 48) for k in (1, 3)}
+    shapes = {name: dims for name, _, dims in re.findall(
+        r"%?([\w.\-]+) = " + _SHAPE, grower_text)}
+
+    def elements(dims):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        return n
+
+    gathers = []
+    for op, n, ln in _while_body_instructions(grower_text):
+        if op != "gather":
+            continue
+        args = re.search(r" gather\(([^)]*)\)", ln).group(1)
+        sizes = [n] + [elements(shapes.get(a.strip().lstrip("%"), ""))
+                       for a in args.split(",")]
+        if cells & set(sizes):
+            gathers.append(ln)
+    assert not gathers, "\n".join(ln[:200] for ln in gathers)
+    arrays = _while_body_instructions(grower_text, fused=False)
+    assert any(n in cells for _, n, _ in arrays)  # the candidates are there
+    stacked = [ln for _, n, ln in arrays if n in cells
+               and re.search(r"= \w+\[[\d,]*,3\]", ln)]
+    assert not stacked, "\n".join(ln[:200] for ln in stacked)
+    masks = [ln for _, n, ln in arrays
+             if n in cells and re.search(r"= pred\[", ln)]
+    assert not masks, "\n".join(ln[:200] for ln in masks)
+    cycles = 0
+    for ln in grower_text.splitlines():
+        cyc = re.search(r'estimated_cycles":"(\d+)', ln)
+        name = re.search(r'op_name="([^"]*)"', ln)
+        if cyc and name and re.findall(
+                r"lgbm\.([a-z_.]+)", name.group(1))[-1:] == [
+                    "learner.split_search"]:
+            cycles += int(cyc.group(1))
+    assert 0 < cycles <= 6_327_147 // 3, cycles

@@ -17,7 +17,7 @@ import pytest
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.learner.split import best_split
+from lightgbm_tpu.learner.split import SearchDirections, best_split
 
 from test_learner import _params
 
@@ -101,7 +101,7 @@ def test_cat_subset_matches_reference_oracle():
         jnp.zeros(F, jnp.int32),
         jnp.ones(F, bool),
         params,
-        cat_subset=True,
+        dirs=SearchDirections(cat_subset=True),
     )
     oracle_gain, oracle_set = _oracle_cat_subset(g, h, c, pd)
     parent = g.sum() ** 2 / (h.sum() + 1e-15)

@@ -162,3 +162,62 @@ def test_fused_step_memo_includes_ranking():
                "verbosity": -1, "metric": "ndcg", "eval_at": [3]},
               ds, num_boost_round=3, valid_sets=[ds], valid_names=["t"])
     assert len(_FUSED_STEP_CACHE) == 1
+
+
+_KINDS_JOB = """
+import sys, numpy as np, lightgbm_tpu as lgb
+rs = np.random.RandomState(11)
+X = rs.randn(2048, 6)
+y = ((X @ rs.randn(6) + 0.3 * rs.randn(2048)) > 0).astype(np.float64)
+kind = sys.argv[1]
+cat = []
+if kind == "nan":
+    X[rs.rand(2048) < 0.2, 2] = np.nan
+if kind == "cat":
+    X[:, 2] = rs.randint(0, 4, 2048)
+    cat = [2]
+ds = lgb.Dataset(X, label=y, categorical_feature=cat, free_raw_data=False)
+bst = lgb.train({"objective": "binary", "num_leaves": 15, "verbosity": -1,
+                 "max_bin": 15, "min_data_in_leaf": 5}, ds,
+                num_boost_round=6)
+"""
+
+
+@pytest.mark.parametrize("kind,fact,reads", [
+    ("nan", "has_nan", (1, 1, 0, 0, 0)), ("cat", "has_cat", (1, 0, 1, 0, 0))])
+def test_column_kinds_are_in_the_memo_key_and_the_directions_gauge(
+        kind, fact, reads):
+    """Two Datasets of equal shapes in one process, the second with a NaN
+    (a categorical) column: the split search traces another direction
+    list, so the second job may not reuse the first's memoized step; its
+    model is the one a fresh process trains."""
+    import subprocess
+    import sys
+
+    from lightgbm_tpu.boosting import _FUSED_STEP_CACHE
+    from lightgbm_tpu.obs.metrics import default_registry
+
+    def gauge():
+        g = default_registry().snapshot()["lgbmtpu_split_search_directions"]
+        return tuple(int(g['{kind="%s"}' % k]) for k in (
+            "default_right", "default_left", "categorical", "cat_subset",
+            "monotone_test"))
+
+    def job(k):
+        scope = {}
+        exec(compile(_KINDS_JOB.replace("sys.argv[1]", repr(k)), "job",
+                     "exec"), scope)
+        return scope["bst"]
+
+    _FUSED_STEP_CACHE.clear()
+    plain = job("plain")
+    assert len(_FUSED_STEP_CACHE) == 1 and gauge() == (1, 0, 0, 0, 0)
+    other = job(kind)
+    assert len(_FUSED_STEP_CACHE) == 2 and gauge() == reads
+    # the column's kind is ALL that parts the two programs
+    assert other._gbdt.spec == plain._gbdt.spec._replace(**{fact: True})
+    fresh = subprocess.run(
+        [sys.executable, "-c",
+         _KINDS_JOB + "sys.stdout.write(bst.model_to_string())", kind],
+        capture_output=True, text=True, timeout=600, check=True).stdout
+    assert other.model_to_string() == fresh
