@@ -195,9 +195,32 @@ class Dataset:
 
     # ------------------------------------------------------------------
     def _resolve_categorical(self, feature_names: List[str]) -> List[int]:
+        """Column indices of the categorical features: the constructor's
+        ``categorical_feature`` if given, else the Dataset's ``params``
+        (``categorical_feature`` or an alias; "0,1,2", "name:a,b" or a
+        list), as the reference reads it for every input. Given both,
+        the constructor wins and the parameter is ignored with the
+        reference's warning."""
+        from .config import resolve_alias
+
         cf = self.categorical_feature
-        if cf == "auto" or cf is None:
-            return []
+        in_params = [v for k, v in self.params.items()
+                     if resolve_alias(k) == "categorical_feature"
+                     and v not in (None, "", "auto")]
+        if cf == "auto" or cf is None or cf == "":
+            if not in_params:
+                return []
+            cf = in_params[-1]
+            if isinstance(cf, str):
+                from .parsers import _resolve_columns
+
+                return _resolve_columns(cf, feature_names)
+        elif in_params:
+            log.warning(
+                "categorical_feature keyword has been found in `params` "
+                "and will be ignored.\nPlease use categorical_feature "
+                "argument of the Dataset constructor to pass this "
+                "parameter.")
         out = []
         for c in cf:
             if isinstance(c, str):
@@ -277,6 +300,31 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._binned is not None:
             return self
+        self._construct()
+        if self.reference is None:
+            self._record_columns()
+        return self
+
+    def _record_columns(self) -> None:
+        """The column-kind gauges of a training Dataset
+        (obs/metrics.py record_dataset_columns)."""
+        from .binning import BinType
+        from .obs.metrics import default_registry, record_dataset_columns
+
+        if not default_registry().enabled:
+            return  # no pass over the bins for gauges nobody reads
+        b = self._binned
+        um = b.used_mappers()
+        other = None
+        if b.bundle_layout is None and b.bins.shape[0] == len(um):
+            other = sum(
+                int(np.count_nonzero(b.bins[i] == m.nan_bin))
+                for i, m in enumerate(um)
+                if m.bin_type == BinType.CATEGORICAL)
+        record_dataset_columns(
+            um, Config(self.params).max_cat_to_onehot, other)
+
+    def _construct(self) -> "Dataset":
         if self.data is None:
             log.fatal("Cannot construct Dataset: raw data was freed")
         from .timer import global_timer as _gt

@@ -369,7 +369,8 @@ class BinMapper:
                     "ignored for a categorical feature"
                 )
             return BinMapper._categorical(
-                clean, na_cnt, total_sample_cnt, max_bin, use_missing
+                clean, na_cnt, total_sample_cnt, max_bin, use_missing,
+                min_data_in_bin,
             )
 
         # missing type resolution (reference FindBin :120-160)
@@ -435,10 +436,51 @@ class BinMapper:
         total_sample_cnt: int,
         max_bin: int,
         use_missing: bool,
+        min_data_in_bin: int = 3,
     ) -> "BinMapper":
-        # reference FindBin categorical path: categories sorted by count desc,
-        # keep up to max_bin-1 (cut categories covering <0.1% at the tail),
-        # bin 0 holds the most frequent category; negative values -> NaN-ish.
+        """Categorical bins after the reference's BinMapper::FindBin
+        categorical branch (src/io/bin.cpp; the reference tree is not on
+        this machine: recalled, SURVEY.md section 2 is the record).
+
+        The rule implemented. Values are truncated to integers; a
+        negative value counts as NaN. Categories are taken by DESCENDING
+        sample count (equal counts: the smaller code first) and each
+        gets a bin of its own, until
+
+        - the next category has fewer than ``min_data_in_bin`` sampled
+          rows and at least two categories are kept (the rare tail), or
+        - the kept categories cover 99% of the non-missing sample
+          (``round(0.99 x count)``) AND the bins have reached
+          ``min(max_bin, categories [+ 1 if the sample holds a NaN])``, or
+        - the column has ``max_bin`` bins INCLUDING the other bin.
+
+        Every other value (a cut category, a category the sample never
+        held, a negative value, NaN) falls into ONE other bin, which
+        every categorical mapper has, and which no split sends left:
+        the one-vs-rest and the sorted-subset search skip it
+        (learner/split.py), a saved model's bitsets hold category
+        VALUES of kept bins only (tree.py), and prediction sends a
+        value outside a node's bitset right. ``missing_type`` is NaN
+        when the sample put anything there, else None, as the reference
+        has it.
+
+        Departures from the reference, each named:
+
+        - the other bin is the LAST bin (``nan_bin`` = ``num_bin - 1``),
+          where the reference (since its v3) keeps bin 0 for it: the
+          growers, the kernels and the device traversal all know a
+          column's missing bin as its last one. Bin 0 is the most
+          frequent category and is shared with no other value.
+        - ``max_bin`` is a hard cap. The reference's loop runs ``while
+          (used < 99% || num_bin < max_bin)`` and so walks PAST
+          ``max_bin`` on a column whose first ``max_bin`` categories
+          cover under 99% (it then stores wider bins); here the
+          histogram's bin axis is ``max_bin`` wide and the rest of the
+          tail goes to the other bin.
+        - ``use_missing=false`` does not remove the other bin: a cut or
+          unseen category still needs a bin no kept category owns.
+        - no "sparse categorical values" warning.
+        """
         ints = clean.astype(np.int64)
         neg_mask = ints < 0
         if np.any(neg_mask):
@@ -447,24 +489,31 @@ class BinMapper:
         cats, cnts = np.unique(ints, return_counts=True)
         order = np.argsort(-cnts, kind="stable")
         cats, cnts = cats[order], cnts[order]
-        keep = min(len(cats), max_bin - 1 if (use_missing and na_cnt > 0) else max_bin)
-        # drop ultra-rare tail categories (reference cuts cumulative 99% + cnt>=2 logic simplified)
-        cats, cnts = cats[:keep], cnts[:keep]
-        missing_type = MissingType.NAN if (use_missing and na_cnt > 0) else MissingType.NONE
-        num_bin = len(cats) + (1 if missing_type == MissingType.NAN else 0)
+        rest_cnt = int(total_sample_cnt - na_cnt)
+        cut_cnt = int(np.floor(rest_cnt * 0.99 + 0.5))
+        soft_bins = min(len(cats) + (1 if na_cnt > 0 else 0), max_bin)
+        keep = used_cnt = 0
+        while (keep < len(cats) and keep + 1 < max_bin
+               and (used_cnt < cut_cnt or keep + 1 < soft_bins)):
+            if cnts[keep] < min_data_in_bin and keep > 1:
+                break
+            used_cnt += int(cnts[keep])
+            keep += 1
+        cats = cats[:keep]
+        full = keep == len(order) and na_cnt == 0
         m = BinMapper(
             upper_bounds=np.array([np.inf]),
             bin_type=BinType.CATEGORICAL,
-            missing_type=missing_type,
+            missing_type=MissingType.NONE if full else MissingType.NAN,
             categories=tuple(int(c) for c in cats),
-            num_bin=max(1, num_bin),
-            is_trivial=(num_bin <= 1),
-            min_value=float(cats.min()) if len(cats) else 0.0,
-            max_value=float(cats.max()) if len(cats) else 0.0,
+            num_bin=keep + 1,
+            is_trivial=(keep <= 1 and full) or keep == 0,
+            min_value=float(cats.min()) if keep else 0.0,
+            max_value=float(cats.max()) if keep else 0.0,
         )
         m._cat_to_bin = {int(c): i for i, c in enumerate(cats)}
         m.most_freq_bin = 0
-        m.default_bin = m._cat_to_bin.get(0, 0)
+        m.default_bin = m._cat_to_bin.get(0, keep)
         return m
 
     # ---- value -> bin ----
@@ -472,22 +521,19 @@ class BinMapper:
         """Vectorized ValueToBin (reference bin.h:161)."""
         values = np.asarray(values, dtype=np.float64).ravel()
         if self.bin_type == BinType.CATEGORICAL:
-            out = np.zeros(len(values), dtype=np.int32)
-            nan_bin = self.num_bin - 1 if self.missing_type == MissingType.NAN else 0
-            c2b = self._cat_to_bin or {}
+            # a value no kept category owns (cut, unseen, negative,
+            # NaN) goes to the other bin, the last one (_categorical)
+            other = self.num_bin - 1
             ints = np.where(np.isnan(values), -1, values).astype(np.int64)
-            # vectorized dict lookup
-            if c2b:
-                keys = np.fromiter(c2b.keys(), dtype=np.int64)
-                vals = np.fromiter(c2b.values(), dtype=np.int32)
-                sorter = np.argsort(keys)
-                keys, vals = keys[sorter], vals[sorter]
-                idx = np.searchsorted(keys, ints)
-                idx = np.clip(idx, 0, len(keys) - 1)
-                found = keys[idx] == ints
-                out = np.where(found, vals[idx], nan_bin).astype(np.int32)
-            out[ints < 0] = nan_bin
-            return out
+            keys = np.asarray(self.categories, dtype=np.int64)
+            if not len(keys):
+                return np.full(len(values), other, dtype=np.int32)
+            sorter = np.argsort(keys)
+            idx = np.clip(np.searchsorted(keys, ints, sorter=sorter),
+                          0, len(keys) - 1)
+            bins = sorter[idx]
+            return np.where(keys[bins] == ints, bins, other).astype(
+                np.int32)
         nan_target = (
             self.num_bin - 1 if self.missing_type == MissingType.NAN
             else self.default_bin
@@ -522,6 +568,11 @@ class BinMapper:
 
     @property
     def nan_bin(self) -> int:
+        """The bin no threshold or category set sends left by itself: a
+        numerical column's NaN bin if it has one (-1 if not), a
+        categorical column's other bin, which it always has."""
+        if self.bin_type == BinType.CATEGORICAL:
+            return self.num_bin - 1
         return self.num_bin - 1 if self.missing_type == MissingType.NAN else -1
 
     def feature_info_str(self) -> str:

@@ -34,6 +34,10 @@ from .metrics import Metric, create_metrics
 from .objectives import ObjectiveFunction, create_objective
 from .sample_strategy import create_sample_strategy
 from .timer import device_phase, global_timer as _gt
+
+# float32 holds every integer up to 2**24 and every EVEN one up to 2**25:
+# past that a node's row count may have no float32 value at all (_grow)
+F32_EVEN_ROWS = 1 << 25
 from .tree import Tree, traverse_tree_bins
 
 # canonical per-round host phase names (docs/OBSERVABILITY.md): the
@@ -715,7 +719,12 @@ class GBDT:
                 m.bin_type == BinType.CATEGORICAL
                 for m in train_set.used_mappers()
             ),
-            has_nan=any(m.nan_bin >= 0 for m in train_set.used_mappers()),
+            # a categorical column's other bin never goes left: only a
+            # NUMERICAL column's NaN bin makes default-left a direction
+            has_nan=any(
+                m.nan_bin >= 0 and m.bin_type != BinType.CATEGORICAL
+                for m in train_set.used_mappers()
+            ),
             has_mono=bool(mono_any),
         )
         self.params = make_split_params(config)
@@ -1009,13 +1018,30 @@ class GBDT:
                 return self._dp(*args, with_stats=with_stats)
             out = self._dp(*args)  # feature-parallel: the flat grower
             return (*out, None) if with_stats else out
-        return grow_tree(
+        tree, row_leaf, *stats = grow_tree(
             d["bins"], d["nan_bin"], d["num_bins"], d["mono"], d["is_cat"],
             gk, hk, mask, feat_mask, self.params, self.spec, valid=valid,
             bundle=d.get("bundle"), rng_key=rng_key,
             group_mat=self._group_mat, cegb=self._cegb_info,
             forced=self._forced, gh_scale=gh_scale, with_stats=with_stats,
         )
+        if self.train_set.num_data > F32_EVEN_ROWS:
+            import jax.numpy as jnp
+
+            # the grower carries counts in float32: a node of such a
+            # table can hold a count float32 has no value for, and the
+            # error rides the larger child down to a leaf (my chip run,
+            # PR 36: 2 of 255 leaf counts off by a few rows at 34.6M
+            # rows). The leaves' counts are taken from the rows instead,
+            # as the data-parallel grower does past 2**24 rows a mesh
+            # (data_parallel._recount_leaves): a leaf under 2**24 in-bag
+            # rows counts exactly.
+            from .learner.histogram import seg_sum
+
+            with device_phase("learner.select"):
+                tree = tree._replace(leaf_count=jnp.round(seg_sum(
+                    mask[None, :], row_leaf, self.spec.num_leaves)[0]))
+        return (tree, row_leaf, *stats)
 
     # ------------------------------------------------------------------
     def _init_score_arr(self, ds: BinnedDataset):
@@ -1130,6 +1156,8 @@ class GBDT:
         import jax
         import jax.numpy as jnp
 
+        from .obs.metrics import record_tree_splits
+
         with _gt.scope("materialize host trees (readback)"):
             fetched = jax.device_get(self._pending)
         meta = self._pending_meta
@@ -1216,6 +1244,8 @@ class GBDT:
                     # device leaf_value already carries shrinkage + bias
                     tree = Tree.from_arrays(a, self.train_set, 1.0)
                     tree.shrinkage = shrink
+                    record_tree_splits(tree, self.train_set.mappers,
+                                       self.config.max_cat_to_onehot)
                 else:
                     tree = Tree(num_leaves=1, shrinkage=1.0)
                     tree.leaf_value = np.array([bias], np.float64)

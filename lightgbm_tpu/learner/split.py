@@ -8,7 +8,8 @@ sums over the bin axis plus masked reductions — no sequential per-bin loop:
 - L1/L2 regularization via ThresholdL1 soft-thresholding
   (feature_histogram.hpp GetLeafGain/CalculateSplittedLeafOutput),
 - missing-value handling: NaN bin is the last bin of a feature; both
-  default directions are evaluated (the reference's double scan),
+  default directions are evaluated (the reference's double scan; the
+  default-right one reaches "every value left, missing alone right"),
 - categorical features use one-vs-rest splits (bin == t goes left);
   the sorted-subset search (feature_histogram.hpp:449) is a later
   milestone,
@@ -433,7 +434,15 @@ def _best_split_impl(
         num_ok &= bin_idx == jnp.expand_dims(rand_bin, ax)
     from_top = last_real - 1 - bin_idx
     # default right: missing stays right
-    best = direction("dr", cum, num_ok,
+    dr_ok = num_ok
+    if dirs.default_left:
+        # a column with a NaN bin has one threshold more, as in the
+        # reference's forward scan: every value left, missing alone right
+        dr_ok = num_ok | (jnp.expand_dims(~is_cat, ax) & has_nan
+                          & (bin_idx == last_real))
+        if rand_bin is not None:
+            dr_ok &= bin_idx == jnp.expand_dims(rand_bin, ax)
+    best = direction("dr", cum, dr_ok,
                      jnp.where(has_nan, bin_idx, from_top))
     if dirs.default_left:
         # default left: the NaN bin's mass joins the left side, in the
@@ -500,4 +509,14 @@ def _best_split_impl(
         right_h=sum_h - lh,
         right_c=sum_c - lc,
     )
+    if dirs.categorical:
+        # the record of a categorical search is MATERIALISED here: fused
+        # with its consumers in the rounds grower, XLA:TPU (libtpu
+        # 0.0.34) builds a category mask that is not the set the left
+        # sums were taken over (my chip runs, PR 36: 29 of 39 leaf
+        # counts of one tree off at 40,960 rows, the masks subsets of
+        # the searched sets, none off with this barrier, with a host
+        # callback on the record, on the CPU, or in the search alone;
+        # PERF.md section 6). Numerical tables trace no barrier.
+        rec = lax.optimization_barrier(rec)
     return rec, col_gain

@@ -731,6 +731,66 @@ def record_split_search(dirs) -> None:
         g.set(int(on), kind=kind)
 
 
+def record_dataset_columns(mappers, max_cat_to_onehot: int,
+                           cat_other_rows=None) -> None:
+    """Gauges of a training Dataset's used columns by kind, set when it
+    is constructed (basic.Dataset.construct, from used_mappers()):
+    numerical (all of them), with_nan (the numerical ones with a NaN
+    bin: what makes default-left a direction), categorical (all), and
+    cat_subset (the categorical ones wider than max_cat_to_onehot: the
+    sorted-subset search); and the training rows x categorical columns
+    that sit in an other bin (binning.BinMapper._categorical: cut,
+    unseen, negative, NaN), where the host bin matrix is unbundled."""
+    r = _default
+    if not r.enabled:
+        return
+    from ..binning import BinType
+
+    cats = [m for m in mappers if m.bin_type == BinType.CATEGORICAL]
+    nums = [m for m in mappers if m.bin_type != BinType.CATEGORICAL]
+    g = r.gauge("lgbmtpu_dataset_columns",
+                "used columns of the newest training Dataset, by kind "
+                "(numerical | with_nan | categorical | cat_subset)",
+                labels=("kind",))
+    g.set(len(nums), kind="numerical")
+    g.set(sum(m.nan_bin >= 0 for m in nums), kind="with_nan")
+    g.set(len(cats), kind="categorical")
+    g.set(sum(m.num_bin > max_cat_to_onehot for m in cats),
+          kind="cat_subset")
+    if cat_other_rows is not None:
+        r.gauge("lgbmtpu_dataset_cat_other_rows",
+                "training rows x categorical columns that sit in an "
+                "other bin (no kept category owns the value)"
+                ).set(cat_other_rows)
+
+
+def record_tree_splits(tree, mappers, max_cat_to_onehot: int) -> None:
+    """Splits of one host tree by kind, counted where the fused collect
+    builds the host trees (boosting._materialize: host arrays already
+    read back, no device op): numerical (missing goes right),
+    default_left (numerical, the default-left bit set), cat_onehot and
+    cat_subset (a categorical split on a column of at most / more than
+    max_cat_to_onehot bins: one-vs-rest / the sorted-subset scan,
+    whatever the size of the set it found)."""
+    r = _default
+    if not r.enabled or not len(tree.split_feature):
+        return
+    n = dict.fromkeys(
+        ("numerical", "default_left", "cat_onehot", "cat_subset"), 0)
+    for f, dt in zip(tree.split_feature, tree.decision_type):
+        if int(dt) & 1:
+            wide = mappers[int(f)].num_bin > max_cat_to_onehot
+            n["cat_subset" if wide else "cat_onehot"] += 1
+        else:
+            n["default_left" if int(dt) & 2 else "numerical"] += 1
+    c = r.counter("lgbmtpu_tree_splits_total",
+                  "splits of the trees built, by kind (numerical | "
+                  "default_left | cat_onehot | cat_subset)",
+                  labels=("kind",))
+    for kind, count in n.items():
+        c.inc(float(count), kind=kind)
+
+
 def record_label_cache(kind: str, hit: bool) -> None:
     """One lookup of a data set's label-sized residency
     (dataset.BinnedDataset.device_label / device_weight / label_stat):

@@ -137,6 +137,10 @@ class Tree:
                 if ndl[i]:
                     dt |= _DEFAULT_LEFT_MASK
                 thresholds[i] = m.bin_to_value(int(nb[i]))
+                if m.nan_bin >= 0 and int(nb[i]) == m.nan_bin - 1:
+                    # every value left, missing alone right: no value is
+                    # above this threshold (the reference's AvoidInf)
+                    thresholds[i] = 1e300
             decision[i] = dt
         t.threshold = thresholds
         t.decision_type = decision
@@ -164,8 +168,8 @@ class Tree:
         ci = int(self.threshold[node])
         lo, hi = self.cat_boundaries[ci], self.cat_boundaries[ci + 1]
         words = self.cat_threshold[lo:hi]
-        iv = values.astype(np.int64)
-        ok = (iv >= 0) & (iv < 32 * len(words)) & ~np.isnan(values)
+        iv = np.where(np.isnan(values), -1, values).astype(np.int64)
+        ok = (iv >= 0) & (iv < 32 * len(words))
         ivc = np.clip(iv, 0, max(0, 32 * len(words) - 1))
         bits = (words[ivc // 32] >> (ivc % 32).astype(np.uint32)) & 1
         return ok & (bits == 1)

@@ -398,3 +398,48 @@ def test_the_child_search_moves_no_candidates_in_the_compiled_grower(
                     "learner.split_search"]:
             cycles += int(cyc.group(1))
     assert 0 < cycles <= 6_327_147 // 3, cycles
+
+
+def test_categorical_grower_compiles_at_the_expo_shape(one_chip,
+                                                       no_compile_cache):
+    """The whole one-chip grower with every fact a table of categorical
+    columns and missing values switches on (`has_cat`, `has_nan`,
+    `cat_subset`: the round kernels' category test at 48 slots x 255
+    bins, the routing-only round with its masks, the sorted-subset
+    scan) at the `expo-cat` cell's 8 columns, for a described v5e (PR
+    36: 21 s; Mosaic refuses nothing). The fused kernel holds the whole
+    table: no routed rounds, one call a pass."""
+    import sys
+
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner import GrowerSpec, make_split_params
+    from lightgbm_tpu.learner.rounds import grow_tree_rounds, hist_schedule
+
+    F, N, L, B = 8, 1 << 20, 255, 255
+    spec = GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1,
+                      rounds_slots=48, quant=True, quant_levels=256,
+                      has_cat=True, has_nan=True, cat_subset=True,
+                      has_mono=False)
+    assert spec.search == (True, True, True, False)
+    params = jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype),
+                          make_split_params(Config({})))
+    cols = [_arg(one_chip, (F,), jnp.int32)] * 3 + [
+        _arg(one_chip, (F,), jnp.bool_)]
+    rows = [_arg(one_chip, (N,), jnp.float32)] * 3
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
+                   "_use_pallas", lambda: True)
+        sched = hist_schedule(spec, N, F)
+        assert sched.fused and not sched.routed
+        assert all(n == 1 for _, n in sched.calls)
+        text = jax.jit(
+            lambda bins, nan, nb, mono, cat, g, h, m, fm, p, sc:
+            grow_tree_rounds.__wrapped__(bins, nan, nb, mono, cat, g, h, m,
+                                         fm, p, spec, gh_scale=sc)
+        ).lower(_arg(one_chip, (F, N), jnp.int32), *cols, *rows,
+                _arg(one_chip, (F,), jnp.bool_), params,
+                _arg(one_chip, (2,), jnp.float32)).compile().as_text()
+    # four ladder rungs' fused kernels with their (slots, 255) s8 masks,
+    # and the routing-only round
+    assert "%hist_round_tpu" in text and "%route_round_tpu" in text
+    assert "s8[48,255]" in text

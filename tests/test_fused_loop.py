@@ -174,7 +174,10 @@ cat = []
 if kind == "nan":
     X[rs.rand(2048) < 0.2, 2] = np.nan
 if kind == "cat":
-    X[:, 2] = rs.randint(0, 4, 2048)
+    # three codes + the other bin = 4 bins = max_cat_to_onehot: the
+    # column stays one-vs-rest (a fourth code would make it a subset
+    # column, as in the reference, whose bin count holds the other bin)
+    X[:, 2] = rs.randint(0, 3, 2048)
     cat = [2]
 ds = lgb.Dataset(X, label=y, categorical_feature=cat, free_raw_data=False)
 bst = lgb.train({"objective": "binary", "num_leaves": 15, "verbosity": -1,

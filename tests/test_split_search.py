@@ -85,7 +85,9 @@ def _parent_best_split_impl(
     last_real = jnp.where(nan_bin[:, None] >= 0, num_bins[:, None] - 2, num_bins[:, None] - 1)
     t_ok = bin_idx < last_real
     num_mask = (~is_cat)[:, None] & t_ok
-    ok_dr &= num_mask
+    # (PR 36: the forward scan's last threshold, missing alone right)
+    ok_dr &= num_mask | ((~is_cat)[:, None] & has_nan
+                         & (bin_idx == last_real))
     ok_dl &= num_mask
 
     # ---- categorical one-vs-rest: bin t alone goes left. With the
