@@ -38,7 +38,7 @@ from .timer import device_phase, global_timer as _gt
 # float32 holds every integer up to 2**24 and every EVEN one up to 2**25:
 # past that a node's row count may have no float32 value at all (_grow)
 F32_EVEN_ROWS = 1 << 25
-from .tree import Tree, traverse_tree_bins
+from .tree import Tree, num_cat_words, traverse_tree_bins
 
 # canonical per-round host phase names (docs/OBSERVABILITY.md): the
 # eager loops (fast/sync) emit the three phases each iteration; the
@@ -1644,9 +1644,13 @@ class GBDT:
             ladder_widths(self.spec) if self.spec.rounds_slots > 0 else ()
         )
         self._f_ladder_widths = ladder_ws
-        from .obs.metrics import record_hist_schedule, record_split_search
+        from .obs.metrics import (
+            record_hist_schedule, record_split_search,
+            record_traverse_cat_words)
 
         record_split_search(self.spec.search)
+        record_traverse_cat_words(
+            num_cat_words(self.spec.num_bins) if self.spec.has_cat else 0)
         if ladder_ws:
             # the schedule is a shard's: the rows one kernel call sees
             n_cols, n_rows = self.dev["bins"].shape

@@ -731,6 +731,19 @@ def record_split_search(dirs) -> None:
         g.set(int(on), kind=kind)
 
 
+def record_traverse_cat_words(words: int) -> None:
+    """Gauge of the word rows a node's category set adds to the valid
+    traversal's per-node table (tree.num_cat_words), set beside the
+    search's directions where the fused step is built; 0: the traversal
+    traces no category test (an all-numerical Dataset)."""
+    r = _default
+    if not r.enabled:
+        return
+    r.gauge("lgbmtpu_traverse_cat_words",
+            "bit-word rows of a node's category set in the traversal's "
+            "per-node table; 0: no category test traced").set(words)
+
+
 def record_dataset_columns(mappers, max_cat_to_onehot: int,
                            cat_other_rows=None) -> None:
     """Gauges of a training Dataset's used columns by kind, set when it

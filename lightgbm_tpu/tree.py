@@ -415,6 +415,35 @@ def tree_to_arrays(t: Tree, dataset: "BinnedDataset") -> "TreeArrays":
     )
 
 
+# bins of a node's category set that one f32 row of the traversal's
+# per-node table carries: take_cols contracts at Precision.HIGHEST
+# against a 0/1 one-hot, so a non-negative integer below 2**24 comes
+# back exact; a power of two makes a bin's word and bit a shift and a mask
+_CAT_WORD_SHIFT = 4
+CAT_WORD_BITS = 1 << _CAT_WORD_SHIFT
+
+
+def num_cat_words(num_bins: int) -> int:
+    """Rows the category sets add to the traversal's per-node table."""
+    return -(-num_bins // CAT_WORD_BITS)
+
+
+def cat_mask_words(node_cat_mask):
+    """(max_nodes, B) bool sets of left-going bins -> (W, max_nodes) f32
+    bit words, W = num_cat_words(B): bin b of a node is bit
+    b % CAT_WORD_BITS of its word b // CAT_WORD_BITS, the last word
+    padded with zeros. Once a tree (tiny)."""
+    import jax.numpy as jnp
+
+    max_nodes, B = node_cat_mask.shape
+    W = num_cat_words(B)
+    bits = jnp.pad(node_cat_mask, ((0, 0), (0, W * CAT_WORD_BITS - B)))
+    weights = jnp.left_shift(1, jnp.arange(CAT_WORD_BITS, dtype=jnp.int32))
+    words = jnp.sum(
+        bits.reshape(max_nodes, W, CAT_WORD_BITS) * weights, axis=-1)
+    return words.T.astype(jnp.float32)
+
+
 def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
                        has_cat: bool = True):
     """Device traversal of a grown tree over a BINNED matrix -> per-row leaf.
@@ -426,13 +455,17 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
     the fused iteration). Per pass, the rows' current-node parameters
     (feature column, threshold bin, default direction, children, NaN
     bin) come from ONE one-hot MXU contraction against a packed
-    per-node table (take_cols — a (N,) take from an (L,) table costs
-    ~1 ms per 1M rows on TPU, the contraction ~0.1 ms), and each row's
-    split-feature bin is a masked select over the column axis. With
+    per-node table (take_cols — this chip has no vector gather: a (N,)
+    element take from a small table read 4 ms per 1M rows on a v5e,
+    250M elements a second, the contraction ~0.3 ms: PERF.md section
+    6, PR 36 and PR 37), and each row's split-feature bin is a masked
+    select over the column axis. A categorical node's set of left-going bins rides
+    the same table as bit words (cat_mask_words), so the row's verdict
+    is a word select and a bit test on the VPU, not a gather. With
     `bundle` (EFB datasets) the matrix columns are bundles, decoded per
     row from small per-feature tables. `has_cat=False` (all-numerical
-    dataset) statically skips the category-set test and its (L*B,)
-    flat gather.
+    dataset) statically skips the category-set test and keeps the
+    table at its 8 rows.
     """
     import jax.numpy as jnp
     from jax import lax
@@ -457,6 +490,9 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
         arrays.node_right.astype(jnp.float32),  # 6
         node_nan.astype(jnp.float32),  # 7 (-1 = none)
     ])  # (8, max_nodes)
+    if has_cat:
+        # 8..: the node's category set, (W, max_nodes) bit words
+        pack = jnp.concatenate([pack, cat_mask_words(arrays.node_cat_mask)])
 
     def cond(s):
         it, row_node = s
@@ -465,7 +501,7 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
     def body(s):
         it, row_node = s
         k = jnp.maximum(row_node, 0)  # clamp: leaf rows produce dead lanes
-        v = take_cols(pack, k)  # (8, N)
+        v = take_cols(pack, k)  # (8, N); (8 + W, N) with categories
         col = v[0].astype(jnp.int32)
         f = v[1].astype(jnp.int32)
         # masked select of each row's split-feature bin over the column
@@ -481,8 +517,14 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
             (v[3] > 0.5) & (fbins == fnan) & (fnan >= 0)
         )
         if has_cat:
-            B = arrays.node_cat_mask.shape[1]
-            cat_hit = arrays.node_cat_mask.reshape(-1)[k * B + fbins]
+            # the row's word of its node's set by a masked select over
+            # the W word rows (a bin past them matches none: right),
+            # then its bit: no gather, no (B, N) intermediate
+            words = v[8:]
+            sel_w = (fbins >> _CAT_WORD_SHIFT)[None, :] == jnp.arange(
+                words.shape[0], dtype=jnp.int32)[:, None]  # (W, N)
+            word = jnp.sum(jnp.where(sel_w, words, 0.0), axis=0).astype(jnp.int32)
+            cat_hit = ((word >> (fbins & (CAT_WORD_BITS - 1))) & 1) == 1
             go_left = jnp.where(v[4] > 0.5, cat_hit, num_go_left)
         else:
             go_left = num_go_left

@@ -443,3 +443,37 @@ def test_categorical_grower_compiles_at_the_expo_shape(one_chip,
     # and the routing-only round
     assert "%hist_round_tpu" in text and "%route_round_tpu" in text
     assert "s8[48,255]" in text
+
+
+@pytest.mark.parametrize("has_cat,table_rows", [(True, 8 + 16), (False, 8)],
+                         ids=["cat", "plain"])
+def test_valid_traversal_compiles_at_the_expo_shape(one_chip,
+                                                    no_compile_cache,
+                                                    has_cat, table_rows):
+    """`tree.traverse_tree_bins` over the `expo-cat` cell's 2^20 valid
+    rows x 8 columns for a described v5e: with categorical columns the
+    ONE `take_small_tpu` a level returns the node's 16 category words
+    under its 8 parameters (PR 37), and the compiled walk holds no
+    gather; a numerical table keeps the 8-row call."""
+    import sys
+
+    from lightgbm_tpu.learner import GrowerSpec
+    from lightgbm_tpu.parallel.data_parallel import _tree_arrays_structure
+    from lightgbm_tpu.tree import traverse_tree_bins
+
+    G, N, L, B = 8, 1 << 20, 255, 255
+    arrays = jax.tree.map(
+        lambda x: _arg(one_chip, x.shape, x.dtype),
+        _tree_arrays_structure(
+            GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1)))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
+                   "_use_pallas", lambda: True)
+        text = jax.jit(
+            lambda a, b, n: traverse_tree_bins(a, b, n, has_cat=has_cat)
+        ).lower(arrays, _arg(one_chip, (G, N), jnp.int32),
+                _arg(one_chip, (G,), jnp.int32)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "take_small_tpu" in ln]
+    assert calls and all(f"f32[{table_rows},{N}]" in ln for ln in calls)
+    assert " gather(" not in text

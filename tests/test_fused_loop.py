@@ -212,11 +212,19 @@ def test_column_kinds_are_in_the_memo_key_and_the_directions_gauge(
                      "exec"), scope)
         return scope["bst"]
 
+    def cat_words():
+        # rows the category sets add to the valid traversal's table
+        snap = default_registry().snapshot()["lgbmtpu_traverse_cat_words"]
+        return int(snap[""])
+
     _FUSED_STEP_CACHE.clear()
     plain = job("plain")
     assert len(_FUSED_STEP_CACHE) == 1 and gauge() == (1, 0, 0, 0, 0)
+    assert cat_words() == 0
     other = job(kind)
     assert len(_FUSED_STEP_CACHE) == 2 and gauge() == reads
+    # 15 bins + the other bin ride one 16-bit word
+    assert cat_words() == (1 if kind == "cat" else 0)
     # the column's kind is ALL that parts the two programs
     assert other._gbdt.spec == plain._gbdt.spec._replace(**{fact: True})
     fresh = subprocess.run(

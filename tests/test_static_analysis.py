@@ -242,6 +242,52 @@ def test_eqn_budget_contract_red_to_green():
     assert not audit_jaxpr(closed, [within_budget(None)], "nobudget").ok
 
 
+def _gather_operand_sizes(lowered_text: str) -> list:
+    """Element counts of the operand of every gather of a lowering."""
+    import math
+    import re
+
+    sizes = []
+    for line in lowered_text.splitlines():
+        if "stablehlo.gather" not in line:
+            continue
+        operand = re.search(r":\s*\(tensor<([0-9x]*)x?[a-z]", line).group(1)
+        sizes.append(math.prod(int(d) for d in operand.split("x") if d))
+    return sizes
+
+
+def test_no_element_gather_of_the_category_sets_red_to_green():
+    """The valid traversal tests a row's category by bit words from its
+    per-node table (tree.cat_mask_words, PR 37): its lowering holds no
+    gather over the (max_nodes x B) category sets, which cost this chip
+    4 ms per 1M rows a level (PERF.md section 6). Red: the flat element
+    gather the traversal used to trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.learner import GrowerSpec
+    from lightgbm_tpu.parallel.data_parallel import _tree_arrays_structure
+    from lightgbm_tpu.tree import traverse_tree_bins
+
+    L, B, G, N = 255, 255, 8, 4096
+    s = jax.ShapeDtypeStruct
+    arrays = _tree_arrays_structure(
+        GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1))
+    bins, nan_bin = s((G, N), jnp.int32), s((G,), jnp.int32)
+
+    red = jax.jit(lambda m, k, b: m.reshape(-1)[k * B + b]).lower(
+        arrays.node_cat_mask, s((N,), jnp.int32), s((N,), jnp.int32))
+    assert (L - 1) * B in _gather_operand_sizes(red.as_text())
+
+    for has_cat in (True, False):
+        text = jax.jit(traverse_tree_bins, static_argnames="has_cat").lower(
+            arrays, bins, nan_bin, has_cat=has_cat).as_text()
+        sizes = _gather_operand_sizes(text)
+        assert (L - 1) * B not in sizes, sizes
+        # what is gathered is per-node or per-column: never per (node, bin)
+        assert all(n <= 32 * (L - 1) for n in sizes), sizes
+
+
 def test_rs_exact_ok_bounds():
     """The overflow/exactness gate (ADVICE r5 medium) as pure policy:
     global rows * levels < 2^31 AND local rows * levels < 2^24."""
