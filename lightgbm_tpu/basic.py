@@ -322,7 +322,8 @@ class Dataset:
                 for i, m in enumerate(um)
                 if m.bin_type == BinType.CATEGORICAL)
         record_dataset_columns(
-            um, Config(self.params).max_cat_to_onehot, other)
+            um, Config(self.params).max_cat_to_onehot, other,
+            None if b.bundle_layout is None else b.bundle_layout.groups)
 
     def _construct(self) -> "Dataset":
         if self.data is None:

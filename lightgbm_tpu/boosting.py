@@ -1245,7 +1245,8 @@ class GBDT:
                     tree = Tree.from_arrays(a, self.train_set, 1.0)
                     tree.shrinkage = shrink
                     record_tree_splits(tree, self.train_set.mappers,
-                                       self.config.max_cat_to_onehot)
+                                       self.config.max_cat_to_onehot,
+                                       self.train_set.bundled_features())
                 else:
                     tree = Tree(num_leaves=1, shrinkage=1.0)
                     tree.leaf_value = np.array([bias], np.float64)
@@ -1648,7 +1649,8 @@ class GBDT:
             record_hist_schedule, record_split_search,
             record_traverse_cat_words)
 
-        record_split_search(self.spec.search)
+        record_split_search(self.spec.search, bundle_layout=bool(
+            self.spec.efb and self.spec.rounds_slots > 0))
         record_traverse_cat_words(
             num_cat_words(self.spec.num_bins) if self.spec.has_cat else 0)
         if ladder_ws:

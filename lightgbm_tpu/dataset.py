@@ -77,6 +77,48 @@ def _choose_bin_dtype(max_num_bin: int) -> Any:
     return np.int32
 
 
+class _BundleClock:
+    """Grouping plus encoding of EFB bundles: the timer scope
+    `dataset.bundle` and the program's counter of its seconds
+    (obs/metrics.record_dataset_bundle); `rows` = the sample rows the
+    grouping counted conflicts on, None where nothing was grouped (a
+    valid set encodes with its reference's layout). As a context
+    manager, or start() / stop() around code that is not one block;
+    stop() without a start() does nothing."""
+
+    def __init__(self, rows: Optional[int] = None) -> None:
+        self.rows = rows
+        self._scope = None
+
+    def start(self) -> "_BundleClock":
+        if self._scope is None:
+            import time
+
+            from .timer import global_timer
+
+            self._t0 = time.perf_counter()
+            self._scope = global_timer.scope("dataset.bundle")
+            self._scope.__enter__()
+        return self
+
+    def stop(self) -> None:
+        if self._scope is None:
+            return
+        import time
+
+        from .obs.metrics import record_dataset_bundle
+
+        self._scope.__exit__(None, None, None)
+        self._scope = None
+        record_dataset_bundle(time.perf_counter() - self._t0, self.rows)
+
+    def __enter__(self) -> "_BundleClock":
+        return self.start()
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
+
+
 def bin_chunk(proto: "BinnedDataset", chunk: np.ndarray, dtype) -> np.ndarray:
     """Bin one (rows, features) float chunk with a constructed dataset's
     mappers (+ EFB encode) -> (G, rows) device-column matrix. Shared by
@@ -207,6 +249,7 @@ class BinnedDataset:
                 sample_idx = np.sort(rng.choice(num_data, sample_cnt, replace=False))
                 sample = data[sample_idx]
             else:
+                sample_idx = None
                 sample = data
             max_bin_by_feature = list(config.max_bin_by_feature)
             from .binning import load_forced_bins
@@ -282,18 +325,22 @@ class BinnedDataset:
                 from .bundling import encode
 
                 um = [mappers[f] for f in used]
-                merged, _ = encode(
-                    bins, bundle_layout,
-                    [m.num_bin for m in um],
-                    [m.most_freq_bin for m in um],
-                    _choose_bin_dtype(bundle_layout.col_bins),
-                )
+                with _BundleClock():
+                    merged, _ = encode(
+                        bins, bundle_layout,
+                        [m.num_bin for m in um],
+                        [m.most_freq_bin for m in um],
+                        _choose_bin_dtype(bundle_layout.col_bins),
+                    )
                 bins = merged
         elif config.enable_bundle and len(used) > 1:
             from .bundling import bundle_features
 
             um = [mappers[f] for f in used]
-            res = bundle_features(bins, um, config.max_bin)
+            with _BundleClock(rows=(num_data if sample_idx is None
+                                    else len(sample_idx))):
+                res = bundle_features(bins, um, config.max_bin,
+                                      sample_idx=sample_idx)
             if res is not None:
                 bins, bundle_layout, bundle_expand = res
                 log.info(
@@ -377,6 +424,7 @@ class BinnedDataset:
             if sample_cnt < num_data:
                 idx = np.sort(rng.choice(num_data, sample_cnt, replace=False))
                 s_csc = data.tocsr()[idx].tocsc()
+                s_csc.sort_indices()
             else:
                 s_csc = csc
             mb_list = list(config.max_bin_by_feature)
@@ -416,22 +464,13 @@ class BinnedDataset:
                     )
                 mono = np.array([mc[f] for f in used], dtype=np.int8)
 
-        # per-used-feature nonzero (rows, bins) + non-default row sets
+        # per-used-feature nonzero (rows, bins)
         nz = []
-        nd_rows: List[Optional[np.ndarray]] = []
         for f in used:
             f = int(f)
             lo, hi = csc.indptr[f], csc.indptr[f + 1]
-            rows = csc.indices[lo:hi]
-            b = mappers[f].values_to_bins(csc.data[lo:hi])
-            nz.append((rows, b))
-            m = mappers[f]
-            # mergeable only when the implicit zeros sit in the
-            # most-freq bin (merged columns never store that bin)
-            if m.most_freq_bin == m.default_bin:
-                nd_rows.append(np.asarray(rows[b != m.most_freq_bin]))
-            else:
-                nd_rows.append(None)
+            nz.append((csc.indices[lo:hi],
+                       mappers[f].values_to_bins(csc.data[lo:hi])))
 
         from .bundling import (
             build_expand_idx,
@@ -441,6 +480,7 @@ class BinnedDataset:
 
         um = [mappers[int(f)] for f in used]
         u_bins = [m.num_bin for m in um]
+        clock = _BundleClock()
         if reference is not None:
             bundle_layout = reference.bundle_layout
             bundle_expand = reference.bundle_expand
@@ -450,10 +490,30 @@ class BinnedDataset:
             )
             layout = bundle_layout
         elif config.enable_bundle and len(used) > 1:
+            clock = _BundleClock(rows=s_csc.shape[0]).start()
+            # the sample's non-default row sets (module bundling's
+            # docstring: conflicts are counted on the sample's rows);
+            # mergeable only when the implicit zeros sit in the
+            # most-freq bin (merged columns never store that bin)
+            nd_rows: List[Optional[np.ndarray]] = []
+            for i, f in enumerate(used):
+                f = int(f)
+                m = mappers[f]
+                if m.most_freq_bin != m.default_bin:
+                    nd_rows.append(None)
+                    continue
+                if s_csc is csc:
+                    rows, b = nz[i]
+                else:
+                    lo, hi = s_csc.indptr[f], s_csc.indptr[f + 1]
+                    rows = s_csc.indices[lo:hi]
+                    b = m.values_to_bins(s_csc.data[lo:hi])
+                nd_rows.append(np.asarray(rows[b != m.most_freq_bin]))
             groups = find_groups_sparse(
-                nd_rows, u_bins, num_data,
+                nd_rows, u_bins, s_csc.shape[0],
                 max(config.max_bin + 1, 256),  # same cap as the dense path
             )
+            del nd_rows
             if all(len(g) == 1 for g in groups):
                 layout = None
                 groups = [[i] for i in range(len(used))]
@@ -470,6 +530,8 @@ class BinnedDataset:
 
         col_bins = layout.col_bins if layout is not None else max_num_bin
         dtype = _choose_bin_dtype(max(col_bins, max_num_bin))
+        if layout is not None:
+            clock.start()  # grouping (above) plus encoding
         G = len(groups)
         bins = np.zeros((G, num_data), dtype=dtype)
         mfb = np.full(len(used), -1, np.int32)
@@ -513,6 +575,7 @@ class BinnedDataset:
             else:
                 bundle_expand = reference.bundle_expand
             bundle_layout = layout
+        clock.stop()
 
         meta = Metadata(
             label=None if label is None else np.asarray(label, dtype=np.float32).ravel(),
@@ -945,13 +1008,33 @@ class BinnedDataset:
             [m.num_bin - (1 if lay.mfb[i] >= 0 else 0) for i, m in enumerate(um)],
             dtype=np.int32,
         )
+        from .binning import BinType
+        from .bundling import cell_tables
+        from .learner.bundle import BundleCells
+
+        cells = cell_tables(
+            lay, [m.num_bin for m in um], [m.nan_bin for m in um],
+            [m.bin_type == BinType.CATEGORICAL for m in um], self.col_bins)
         return BundleInfo(
             bundle_of=jnp.asarray(lay.bundle_of),
             off_lo=jnp.asarray(lay.off_lo),
             mfb=jnp.asarray(lay.mfb),
             expand_idx=jnp.asarray(self.bundle_expand),
             width=jnp.asarray(width),
+            cells=BundleCells(*(jnp.asarray(t) for t in cells)),
         )
+
+    def bundled_features(self) -> Optional[frozenset]:
+        """Original feature ids that share a device column with another
+        (EFB), or None without bundles."""
+        if self.bundle_layout is None:
+            return None
+        if getattr(self, "_bundled", None) is None:
+            self._bundled = frozenset(
+                int(self.used_features[i])
+                for grp in self.bundle_layout.groups if len(grp) > 1
+                for i in grp)
+        return self._bundled
 
     @property
     def col_bins(self) -> int:

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .bundle import BundleInfo, decode_feature_bins, expand_hist
+from .bundle import BundleInfo, best_split_bundled, decode_feature_bins
 from ..timer import device_phase, global_timer
 from .histogram import (
     HistPlan,
@@ -480,10 +480,34 @@ def grow_tree_rounds(
     else:
         Gn = G
 
-    def exp_hist(h, g_sum, h_sum, c_sum):
+    def feature_left(h, g_sum, h_sum, c_sum, f, t):
+        """Left sums (g, h, count) of feature f at threshold bin t from
+        one leaf's pool row (3, G, Bc): a forced split's one candidate.
+        In a bundle, the member's range in its column, and its
+        most-frequent bin's mass (the leaf's totals less what the range
+        stores) on the left from t = that bin on."""
+        if not spec.efb:
+            return tuple(jnp.cumsum(h[k, f])[t] for k in range(3))
+        col = h[:, bundle.bundle_of[f]]  # (3, Bc)
+        lo, m = bundle.off_lo[f], bundle.mfb[f]
+        p = jnp.arange(col.shape[-1], dtype=jnp.int32)
+        past = (m >= 0) & (t >= m)
+        upto = jnp.where(m >= 0, lo + t - past.astype(jnp.int32), t)
+        stored = (p >= lo) & (p < lo + bundle.width[f])
+        left = jnp.sum(jnp.where(stored & (p <= upto), col, 0.0), axis=1)
+        mass = jnp.stack([g_sum, h_sum, c_sum]) - jnp.sum(
+            jnp.where(stored, col, 0.0), axis=1)
+        left = left + jnp.where(past, mass, 0.0)
+        return left[0], left[1], left[2]
+
+    def search(h, *args, **kw):
+        """best_split over one leaf's pool row; on a bundled Dataset in
+        the bundle's own (column, merged bin) layout (bundle.py: no
+        per-feature expansion)."""
         if spec.efb:
-            return expand_hist(h, g_sum, h_sum, c_sum, bundle)
-        return h
+            return best_split_bundled(h, *args[:8], bundle.cells, B,
+                                      *args[8:], **kw)[0]
+        return best_split(h, *args, **kw)
 
     # shared per-node machinery (grower.make_node_candidates), vmapped
     # over each round's children; the draw ORDER differs from
@@ -564,12 +588,11 @@ def grow_tree_rounds(
         nb_t, nan_t, mono_t, iscat_t, fm_t = (
             num_bins, nan_bin, mono, is_cat, feat_mask)
         with device_phase("learner.split_search"):
-            rec0 = best_split(exp_hist(hist0, root[0], root[1], root[2]),
-                              root[0], root[1], root[2], num_bins, nan_bin,
-                              mono, is_cat, params, fm0,
-                              dirs=spec.search,
-                              parent_output=root_out,
-                              penalty=pen0, rand_bin=rb0)
+            rec0 = search(hist0, root[0], root[1], root[2], num_bins,
+                          nan_bin, mono, is_cat, params, fm0,
+                          dirs=spec.search,
+                          parent_output=root_out,
+                          penalty=pen0, rand_bin=rb0)
 
     Gc = Gn if use_rs else G  # pool feature width (owned block under rs)
     with device_phase("learner.pool_write"):
@@ -638,8 +661,8 @@ def grow_tree_rounds(
         # under use_rs the tables are this rank's owned block and
         # the winner is elected globally by the caller
         with device_phase("learner.split_search"):
-            return best_split(
-                exp_hist(h, g_, h__, c_), g_, h__, c_, nb_t, nan_t,
+            return search(
+                h, g_, h__, c_, nb_t, nan_t,
                 mono_t, iscat_t, params, fm_t if fm is None else fm,
                 dirs=spec.search, parent_output=po,
                 cmin=cmn, cmax=cmx, penalty=pen, rand_bin=rb,
@@ -769,12 +792,9 @@ def grow_tree_rounds(
             fl = forced.leaf[fi]
             ff = forced.feature[fi]
             fb = forced.bin[fi]
-            fh = exp_hist(pool_rows(pools.hist[fl]), s.leaf_g[fl],
-                          s.leaf_h[fl], s.leaf_c[fl])
-            cg_f = jnp.cumsum(fh[0, ff])
-            chs_f = jnp.cumsum(fh[1, ff])
-            cc_f = jnp.cumsum(fh[2, ff])
-            flg, flh, flc = cg_f[fb], chs_f[fb], cc_f[fb]
+            flg, flh, flc = feature_left(
+                pool_rows(pools.hist[fl]), s.leaf_g[fl], s.leaf_h[fl],
+                s.leaf_c[fl], ff, fb)
             fpg, fph, fpn = s.leaf_g[fl], s.leaf_h[fl], s.leaf_c[fl]
             gain_f = (
                 leaf_gain(flg, flh, params)
@@ -937,23 +957,22 @@ def grow_tree_rounds(
             lsum = jnp.sum(local[:, :, 0, :], axis=-1)  # (S, 3)
 
             def slot_gains(h, g_, h__, c_):
+                if spec.efb:  # the best gain of each bundle column
+                    return best_split_bundled(
+                        h, g_, h__, c_, num_bins, nan_bin, mono, is_cat,
+                        params, bundle.cells, B, feat_mask,
+                        dirs=spec.search)[1]
                 return feature_best_gains(
-                    exp_hist(h, g_, h__, c_), g_, h__, c_, num_bins,
+                    h, g_, h__, c_, num_bins,
                     nan_bin, mono, is_cat, params, feat_mask,
                     dirs=spec.search,
                 )
 
             lg_s = jax.vmap(slot_gains)(
                 local, lsum[:, 0], lsum[:, 1], lsum[:, 2]
-            )  # (S, F) local per-feature gains
+            )  # (S, F | G) local per-column gains
             lg_s = jnp.where(take[:, None], lg_s, NEG_INF)  # dead slots
-            fgain = jnp.max(lg_s, axis=0)  # (F,) best over live slots
-            if spec.efb:
-                col_gain = jnp.full(G, NEG_INF).at[bundle.bundle_of].max(
-                    fgain
-                )
-            else:
-                col_gain = fgain
+            col_gain = jnp.max(lg_s, axis=0)  # best over live slots
             _, topi = lax.top_k(col_gain, kG)
             in_topk = jnp.zeros(G, bool).at[topi].set(True)
             votes = lax.psum(in_topk.astype(jnp.float32), ax)
@@ -1450,9 +1469,8 @@ def grow_tree_rounds(
         fl = forced.leaf[fi]
         ff = forced.feature[fi]
         fb = forced.bin[fi]
-        fh = exp_hist(pool_rows(pools.hist[fl]), s.leaf_g[fl],
-                      s.leaf_h[fl], s.leaf_c[fl])
-        lc = jnp.cumsum(fh[2, ff])[fb]
+        lc = feature_left(pool_rows(pools.hist[fl]), s.leaf_g[fl],
+                          s.leaf_h[fl], s.leaf_c[fl], ff, fb)[2]
         return (s.i < forced.n) & (lc > 0) & (s.leaf_c[fl] - lc > 0)
 
     def cond(carry: Tuple[_Pools, _NState]) -> jax.Array:

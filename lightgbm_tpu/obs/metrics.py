@@ -714,14 +714,21 @@ def record_hist_schedule(sched, n_cols: int) -> None:
         calls.set(n, width=width)
 
 
-def record_split_search(dirs) -> None:
+def record_split_search(dirs, bundle_layout: bool = False) -> None:
     """Gauge of the split search's traced directions
     (learner/split.py SearchDirections: static facts of the Dataset),
     set where the fused step is built: 1 where the program traces the
-    direction, else 0; default-right always is."""
+    direction, else 0; default-right always is. Beside it, 1 where the
+    search reads EFB bundle histograms in their own (column, merged bin)
+    layout (learner/bundle.best_split_bundled: the rounds grower on a
+    bundled Dataset), else 0."""
     r = _default
     if not r.enabled:
         return
+    r.gauge("lgbmtpu_split_bundle_layout",
+            "1 where the split search reads bundle histograms in the "
+            "bundle layout (no per-feature expansion), else 0").set(
+        int(bundle_layout))
     g = r.gauge("lgbmtpu_split_search_directions",
                 "1 where the split search traces the direction or test "
                 "(the Dataset can have such a split), else 0",
@@ -744,8 +751,26 @@ def record_traverse_cat_words(words: int) -> None:
             "per-node table; 0: no category test traced").set(words)
 
 
+def record_dataset_bundle(seconds: float, rows=None) -> None:
+    """EFB grouping plus encoding of one Dataset (dataset._BundleClock,
+    the timer scope ``dataset.bundle``): its seconds into the process's
+    counter, and, where a training Dataset was grouped, the rows its
+    conflicts were counted on (the bin-construction sample)."""
+    r = _default
+    if not r.enabled:
+        return
+    r.counter("lgbmtpu_dataset_bundle_seconds_total",
+              "seconds of EFB grouping and encoding (timer scope "
+              "dataset.bundle), training and valid sets").inc(
+        float(seconds))
+    if rows is not None:
+        r.gauge("lgbmtpu_dataset_bundle_rows",
+                "rows the newest grouping counted feature conflicts on "
+                "(the bin-construction sample)").set(rows)
+
+
 def record_dataset_columns(mappers, max_cat_to_onehot: int,
-                           cat_other_rows=None) -> None:
+                           cat_other_rows=None, bundle_groups=None) -> None:
     """Gauges of a training Dataset's used columns by kind, set when it
     is constructed (basic.Dataset.construct, from used_mappers()):
     numerical (all of them), with_nan (the numerical ones with a NaN
@@ -753,7 +778,10 @@ def record_dataset_columns(mappers, max_cat_to_onehot: int,
     cat_subset (the categorical ones wider than max_cat_to_onehot: the
     sorted-subset search); and the training rows x categorical columns
     that sit in an other bin (binning.BinMapper._categorical: cut,
-    unseen, negative, NaN), where the host bin matrix is unbundled."""
+    unseen, negative, NaN), where the host bin matrix is unbundled;
+    bundle (device columns that hold more than one feature: EFB bundles)
+    and merged_feature (the features in them), from `bundle_groups`
+    (bundling.BundleLayout.groups; None: no bundle)."""
     r = _default
     if not r.enabled:
         return
@@ -763,8 +791,12 @@ def record_dataset_columns(mappers, max_cat_to_onehot: int,
     nums = [m for m in mappers if m.bin_type != BinType.CATEGORICAL]
     g = r.gauge("lgbmtpu_dataset_columns",
                 "used columns of the newest training Dataset, by kind "
-                "(numerical | with_nan | categorical | cat_subset)",
+                "(numerical | with_nan | categorical | cat_subset | "
+                "bundle | merged_feature)",
                 labels=("kind",))
+    merged = [grp for grp in (bundle_groups or ()) if len(grp) > 1]
+    g.set(len(merged), kind="bundle")
+    g.set(sum(len(grp) for grp in merged), kind="merged_feature")
     g.set(len(nums), kind="numerical")
     g.set(sum(m.nan_bin >= 0 for m in nums), kind="with_nan")
     g.set(len(cats), kind="categorical")
@@ -777,17 +809,26 @@ def record_dataset_columns(mappers, max_cat_to_onehot: int,
                 ).set(cat_other_rows)
 
 
-def record_tree_splits(tree, mappers, max_cat_to_onehot: int) -> None:
+def record_tree_splits(tree, mappers, max_cat_to_onehot: int,
+                       bundled=None) -> None:
     """Splits of one host tree by kind, counted where the fused collect
     builds the host trees (boosting._materialize: host arrays already
     read back, no device op): numerical (missing goes right),
     default_left (numerical, the default-left bit set), cat_onehot and
     cat_subset (a categorical split on a column of at most / more than
     max_cat_to_onehot bins: one-vs-rest / the sorted-subset scan,
-    whatever the size of the set it found)."""
+    whatever the size of the set it found). Where the Dataset has EFB
+    bundles, `bundled` is the set of original features that share a
+    device column with another, and a second counter takes the splits
+    on them."""
     r = _default
     if not r.enabled or not len(tree.split_feature):
         return
+    if bundled is not None:
+        r.counter("lgbmtpu_tree_bundled_splits_total",
+                  "splits of the trees built whose feature shares an EFB "
+                  "bundle column with another feature").inc(float(sum(
+                      int(f) in bundled for f in tree.split_feature)))
     n = dict.fromkeys(
         ("numerical", "default_left", "cat_onehot", "cat_subset"), 0)
     for f, dt in zip(tree.split_feature, tree.decision_type):

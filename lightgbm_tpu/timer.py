@@ -63,6 +63,7 @@ DEVICE_PHASES = (
     "parallel.reduce",
     "learner.subtract",
     "learner.split_search",
+    "learner.unbundle",
     "learner.pool_write",
     "boosting.renew",
     "boosting.score_update",

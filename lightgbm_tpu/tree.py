@@ -462,10 +462,13 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
     select over the column axis. A categorical node's set of left-going bins rides
     the same table as bit words (cat_mask_words), so the row's verdict
     is a word select and a bit test on the VPU, not a gather. With
-    `bundle` (EFB datasets) the matrix columns are bundles, decoded per
-    row from small per-feature tables. `has_cat=False` (all-numerical
-    dataset) statically skips the category-set test and keeps the
-    table at its 8 rows.
+    `bundle` (EFB datasets) the matrix columns are bundles: the node's
+    feature's (mfb, off_lo, width) ride the table as three rows more and
+    each row's bin is decoded from them (bundle.decode_bins; three
+    per-row element gathers of the per-feature tables cost 260 of the
+    sparse cell's 279 ms a tree of valid eval, PERF.md section 6, PR
+    38). `has_cat=False` (all-numerical dataset) statically skips the
+    category-set test and keeps the table at its 8 rows.
     """
     import jax.numpy as jnp
     from jax import lax
@@ -482,7 +485,7 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
     node_nan = nan_bin[arrays.node_feature]
     pack = jnp.stack([
         node_col.astype(jnp.float32),  # 0: device bin column
-        arrays.node_feature.astype(jnp.float32),  # 1: feature id (EFB)
+        arrays.node_feature.astype(jnp.float32),  # 1: feature id (unread)
         arrays.node_bin.astype(jnp.float32),  # 2
         arrays.node_default_left.astype(jnp.float32),  # 3
         arrays.node_cat.astype(jnp.float32),  # 4
@@ -490,8 +493,14 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
         arrays.node_right.astype(jnp.float32),  # 6
         node_nan.astype(jnp.float32),  # 7 (-1 = none)
     ])  # (8, max_nodes)
+    if bundle is not None:
+        # 8..10: how the node's feature sits in its bundle column
+        pack = jnp.concatenate([pack, jnp.stack([
+            t[arrays.node_feature].astype(jnp.float32)
+            for t in (bundle.mfb, bundle.off_lo, bundle.width)])])
+    n_base = pack.shape[0]
     if has_cat:
-        # 8..: the node's category set, (W, max_nodes) bit words
+        # then the node's category set, (W, max_nodes) bit words
         pack = jnp.concatenate([pack, cat_mask_words(arrays.node_cat_mask)])
 
     def cond(s):
@@ -501,17 +510,17 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
     def body(s):
         it, row_node = s
         k = jnp.maximum(row_node, 0)  # clamp: leaf rows produce dead lanes
-        v = take_cols(pack, k)  # (8, N); (8 + W, N) with categories
+        v = take_cols(pack, k)  # (rows of pack, N)
         col = v[0].astype(jnp.int32)
-        f = v[1].astype(jnp.int32)
         # masked select of each row's split-feature bin over the column
         # axis: sum of G per-column selects (VPU), no 2D gather
         sel = col[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]  # (G, N)
         fbins = jnp.sum(jnp.where(sel, bins_fm, 0), axis=0)
         if bundle is not None:
-            from .learner.bundle import decode_feature_bins
+            from .learner.bundle import decode_bins
 
-            fbins = decode_feature_bins(fbins, f, bundle)  # vector f
+            fbins = decode_bins(fbins, *(v[r].astype(jnp.int32)
+                                         for r in (8, 9, 10)))
         fnan = v[7].astype(jnp.int32)
         num_go_left = (fbins <= v[2].astype(jnp.int32)) | (
             (v[3] > 0.5) & (fbins == fnan) & (fnan >= 0)
@@ -520,7 +529,7 @@ def traverse_tree_bins(arrays: "TreeArrays", bins_fm, nan_bin, bundle=None,
             # the row's word of its node's set by a masked select over
             # the W word rows (a bin past them matches none: right),
             # then its bit: no gather, no (B, N) intermediate
-            words = v[8:]
+            words = v[n_base:]
             sel_w = (fbins >> _CAT_WORD_SHIFT)[None, :] == jnp.arange(
                 words.shape[0], dtype=jnp.int32)[:, None]  # (W, N)
             word = jnp.sum(jnp.where(sel_w, words, 0.0), axis=0).astype(jnp.int32)

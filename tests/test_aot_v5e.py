@@ -445,35 +445,110 @@ def test_categorical_grower_compiles_at_the_expo_shape(one_chip,
     assert "s8[48,255]" in text
 
 
-@pytest.mark.parametrize("has_cat,table_rows", [(True, 8 + 16), (False, 8)],
-                         ids=["cat", "plain"])
+@pytest.mark.parametrize("has_cat,bundled,table_rows",
+                         [(True, False, 8 + 16), (False, False, 8),
+                          (False, True, 8 + 3)],
+                         ids=["cat", "plain", "bundled"])
 def test_valid_traversal_compiles_at_the_expo_shape(one_chip,
                                                     no_compile_cache,
-                                                    has_cat, table_rows):
+                                                    has_cat, bundled,
+                                                    table_rows):
     """`tree.traverse_tree_bins` over the `expo-cat` cell's 2^20 valid
     rows x 8 columns for a described v5e: with categorical columns the
     ONE `take_small_tpu` a level returns the node's 16 category words
     under its 8 parameters (PR 37), and the compiled walk holds no
-    gather; a numerical table keeps the 8-row call."""
+    gather; a numerical table keeps the 8-row call. At the
+    `allstate-efb` cell's 46 bundle columns of 4,228 features (PR 38)
+    the node's (mfb, off_lo, width) ride the same call as three rows
+    more: the only gathers left read the per-feature tables once a tree,
+    none a row."""
+    import re
     import sys
 
     from lightgbm_tpu.learner import GrowerSpec
+    from lightgbm_tpu.learner.bundle import BundleInfo
     from lightgbm_tpu.parallel.data_parallel import _tree_arrays_structure
     from lightgbm_tpu.tree import traverse_tree_bins
 
-    G, N, L, B = 8, 1 << 20, 255, 255
+    G, N, L, B = (46 if bundled else 8), 1 << 20, 255, 255
+    F = 4228 if bundled else G
     arrays = jax.tree.map(
         lambda x: _arg(one_chip, x.shape, x.dtype),
         _tree_arrays_structure(
             GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1)))
+    binfo = (BundleInfo(*(_arg(one_chip, (F,), jnp.int32) for _ in range(3)),
+                        expand_idx=_arg(one_chip, (F, B), jnp.int32),
+                        width=_arg(one_chip, (F,), jnp.int32))
+             if bundled else None)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
                    "_use_pallas", lambda: True)
         text = jax.jit(
-            lambda a, b, n: traverse_tree_bins(a, b, n, has_cat=has_cat)
+            lambda a, b, n, bi: traverse_tree_bins(a, b, n, bundle=bi,
+                                                   has_cat=has_cat)
         ).lower(arrays, _arg(one_chip, (G, N), jnp.int32),
-                _arg(one_chip, (G,), jnp.int32)).compile().as_text()
+                _arg(one_chip, (F,), jnp.int32), binfo
+                ).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "take_small_tpu" in ln]
     assert calls and all(f"f32[{table_rows},{N}]" in ln for ln in calls)
-    assert " gather(" not in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    if not bundled:
+        assert not gathers
+    assert not [ln for ln in gathers
+                if re.search(rf"\[(\d+,)*{N}\]\S* gather\(", ln)]
+
+
+def test_bundled_grower_compiles_at_the_allstate_shape(one_chip,
+                                                       no_compile_cache):
+    """The whole one-chip grower on an EFB-bundled Dataset at the
+    `allstate-efb` cell's shape (PR 38): 4,228 features in 46 device
+    columns of 256 merged bins, 48 slots, for a described v5e. The split
+    search reads the (3, 46, 256) bundle histograms in their own layout:
+    no instruction of the compiled module holds the (F, Bf) per-feature
+    expansion, and the search's unbundling has its own phase."""
+    import re
+    import sys
+
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner import GrowerSpec, make_split_params
+    from lightgbm_tpu.learner.bundle import BundleCells, BundleInfo
+    from lightgbm_tpu.learner.rounds import grow_tree_rounds, hist_schedule
+
+    F, G, N, L, B, Bc = 4228, 46, 1 << 20, 255, 255, 256
+    spec = GrowerSpec(num_leaves=L, num_bins=B, max_depth=-1,
+                      rounds_slots=48, quant=True, quant_levels=256,
+                      has_cat=False, has_nan=False, has_mono=False,
+                      efb=True, col_bins=Bc)
+    params = jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype),
+                          make_split_params(Config({})))
+    cols = [_arg(one_chip, (F,), jnp.int32)] * 3 + [
+        _arg(one_chip, (F,), jnp.bool_)]
+    rows = [_arg(one_chip, (N,), jnp.float32)] * 3
+    i32, b8 = jnp.int32, jnp.bool_
+    cells = BundleCells(*(
+        _arg(one_chip, (G, Bc), dt) for dt in
+        (i32, i32, b8, b8, b8, b8, b8, b8, b8, i32, i32)),
+        col_feat=_arg(one_chip, (G,), i32),
+        direct=_arg(one_chip, (G, 1), b8))
+    binfo = BundleInfo(*(_arg(one_chip, (F,), i32) for _ in range(3)),
+                       expand_idx=_arg(one_chip, (F, B), i32),
+                       width=_arg(one_chip, (F,), i32), cells=cells)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys.modules["lightgbm_tpu.learner.histogram"],
+                   "_use_pallas", lambda: True)
+        sched = hist_schedule(spec, N, G)
+        assert sched.fused and not sched.routed
+        text = jax.jit(
+            lambda bins, nan, nb, mono, cat, g, h, m, fm, p, sc, bi:
+            grow_tree_rounds.__wrapped__(bins, nan, nb, mono, cat, g, h, m,
+                                         fm, p, spec, bundle=bi,
+                                         gh_scale=sc)
+        ).lower(_arg(one_chip, (G, N), jnp.int32), *cols, *rows,
+                _arg(one_chip, (F,), jnp.bool_), params,
+                _arg(one_chip, (2,), jnp.float32), binfo
+                ).compile().as_text()
+    assert "%hist_round_tpu" in text and "%route_round_tpu" in text
+    assert "lgbm.learner.unbundle" in text
+    # no per-feature expansion of a histogram: F x Bf = 1,078,140
+    assert not re.search(r"\[(\d+,)*3,4228,255\]|1078140", text)

@@ -314,7 +314,8 @@ def test_column_gauges_and_the_split_counter(table):
     cols = _series("lgbmtpu_dataset_columns")
     assert {k: int(v) for k, v in cols.items()} == {
         '{kind="numerical"}': 2, '{kind="with_nan"}': 1,
-        '{kind="categorical"}': 6, '{kind="cat_subset"}': 6}
+        '{kind="categorical"}': 6, '{kind="cat_subset"}': 6,
+        '{kind="bundle"}': 0, '{kind="merged_feature"}': 0}
     b = ds._binned
     other = sum(int((b.bins[j] == b.mappers[j].nan_bin).sum())
                 for j in (0, 1, 2, 4, 5, 6))

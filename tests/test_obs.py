@@ -451,7 +451,8 @@ def _op_names(text):
     return re.findall(r'op_name="([^"]*)"', text)
 
 
-@pytest.mark.parametrize("case", ["binary", "lambdarank", "data_mesh"])
+@pytest.mark.parametrize("case", ["binary", "lambdarank", "data_mesh",
+                                  "bundled"])
 def test_fused_step_carries_every_phase_it_can_reach(case, monkeypatch):
     """The compiled fused step names its device work from inside: every
     phase of timer.DEVICE_PHASES that the configuration can reach is in
@@ -471,6 +472,11 @@ def test_fused_step_carries_every_phase_it_can_reach(case, monkeypatch):
                   "eval_at": [3]}
     else:
         group = None
+        if case == "bundled":
+            # 40 one-hot columns of one 40-level code beside the five
+            # numerical ones: EFB merges the 40 into one device column
+            code = rs.randint(0, 40, 600)
+            X = np.hstack([X, np.eye(40)[code]])
         y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
         params = {"objective": "binary", "metric": "auc"}
         if case == "data_mesh":
@@ -482,6 +488,8 @@ def test_fused_step_carries_every_phase_it_can_reach(case, monkeypatch):
     want = set(DEVICE_PHASES)
     if case != "data_mesh":
         want -= {"parallel.reduce"}  # nothing crosses a mesh of one
+    if case != "bundled":
+        want -= {"learner.unbundle"}  # only a bundled Dataset has it
     assert found >= want, want - found
     # scopes nest and the innermost names the op
     assert any("lgbm.learner.route/lgbm.learner.hist" in n.replace(
