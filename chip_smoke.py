@@ -149,7 +149,7 @@ def kernel_selfcheck(bins, num_bins: int) -> list:
 
     layouts = [
         # name, slots the program packs per pass, quant, int8, gh8
-        ("nat_ch=3 bf16 (int16 default)", 48, True, False,
+        ("nat_ch=3 s8 limbs (int16 default)", 48, True, False,
          build_gh8_quant(rand_levels(-128, 128), rand_levels(0, 256), ones)),
         ("nat_ch=3 int8 (quantized)", 48, True, True,
          build_gh8_quant(rand_levels(-2, 2), rand_levels(0, 4), ones)),

@@ -225,6 +225,8 @@ def hist_nat_slots(
     F, N = bins_fm.shape
     nat_ch = 3 if quant else NAT_CH
     use_i8 = bool(int8 and quant)
+    # quant levels past s8 (the int16 program): two s8 limbs a level
+    use_limbs = bool(quant and not use_i8)
     # VMEM guard (hist_plan): the slot axis in chunks and, past one
     # bins tile, the feature axis in blocks, so that a call's resident
     # output block stays within its share of the stated scoped limit
@@ -248,7 +250,7 @@ def hist_nat_slots(
                     bins_fm, gh8, local, sc, num_bins,
                     interpret=_interpret_pallas(), nat_ch=nat_ch,
                     int8=use_i8, oh_shift=oh_shift,
-                    feat_block=plan.feat_block,
+                    feat_block=plan.feat_block, limbs=use_limbs,
                 )  # (sc*nat_ch, F*B)
                 o = out.reshape(sc, nat_ch, F, num_bins)
                 if quant:
@@ -499,7 +501,8 @@ def hist_round(
 ):
     """Fused round step -> ((S, 3, F, B) f32 histograms, (N,) new
     row->leaf). Callers must check can_hist_round first; histogram
-    sums are exact (integer s32 on the int8 path, rescaled here).
+    sums are exact (integer s32 on the int8 path, rescaled here; per
+    row block in s32 on the limb path, pallas_hist._nat_kernel).
 
     When S exceeds the one-chunk VMEM schedule, the slot axis is
     chunked: every chunk sees the ORIGINAL row->leaf vector and only
@@ -512,6 +515,7 @@ def hist_round(
     F, N = bins_fm.shape
     nat_ch = 3 if quant else NAT_CH
     use_int8 = bool(int8 and quant)
+    use_limbs = bool(quant and not use_int8)  # as in hist_nat_slots
     s_max = _round_s_max(F, num_bins, quant, use_int8) or num_slots
     outs = []
     pl_new = None
@@ -521,7 +525,7 @@ def hist_round(
             col_onehot[c0:c0 + sc], sc, num_bins, nat_ch,
             int8=use_int8, oh_shift=oh_shift, efb=efb,
             cat_mask=None if cat_mask is None else cat_mask[c0:c0 + sc],
-            interpret=_interpret_pallas(),
+            interpret=_interpret_pallas(), limbs=use_limbs,
         )
         outs.append(out_c.reshape(sc, nat_ch, F, num_bins))
         pl_new = pl_c if pl_new is None else pl_new + (pl_c - pleaf)

@@ -22,6 +22,15 @@ an earlier version transposed the bins tile per block, which cost
 quantized path). The channel axis is padded 3 -> 8 (bf16x2-split
 grad/hess + count, see histogram.build_gh8) to match the f32 sublane
 tile; accumulation rides the grid-constant output block.
+
+The MXU operand is one of three. bf16 with the compare one-hot above;
+s8 with the byte-SWAR one-hot (_swar_onehot, 1.65x the compare's rate)
+for quantized levels within +/-127, summed in an s32 block; and, for
+the int16 program's 256-level channels on every pass, the same s8
+operands with each level split into two limbs (_limb_lhs), whose s32
+sums are recombined exactly per row block and added into the f32
+block: the bf16 path's histograms to the bit (_nat_kernel has the
+argument). The bf16 operand is left to the bf16x2 program.
 """
 
 from __future__ import annotations
@@ -249,7 +258,7 @@ def _oh_iota_init(shape: tuple, int8: bool):
 def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
                 *, F: int, B: int, blk: int, S: int, nat_ch: int,
                 int8: bool = False, oh_shift: int = 0,
-                feat_block: int = 0):
+                feat_block: int = 0, limbs: bool = False):
     """Slot-packed natural-order histogram: rows carry a slot id; the
     weight matrix W packs (slot x channel) onto the MXU's M axis —
     W[(s, c), r] = gh[c, r] * (slot[r] == s) — so one (S*nat_ch, blk) @
@@ -270,6 +279,19 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     block sum 127 * blk << 2^31; cross-block accumulation rides the s32
     output block.
 
+    With `limbs` (the int16 program's 256-level channels, which s8
+    cannot hold) the MXU is the int8 one too: each level is split into
+    two s8 limbs, v = 16 * hi + lo (_limb_lhs), stacked on the M axis,
+    and contracted with the same SWAR one-hot; each row block's two s32
+    limb sums are recombined exactly in s32 and added into the f32
+    output block (_limb_sum). That is the bf16 path's arithmetic to the
+    bit: the limbs are taken of the levels as bf16 holds them
+    (_bf16_levels), and there a block's product is an exact integer as
+    well (|v| <= 256, 2048 rows: below 2^24 in the MXU's f32
+    accumulator) and enters the f32 block in the same order.
+    Cross-block sums stay in f32 (a cell of the hessian channel passes
+    2^31 long before a pass ends).
+
     With `feat_block` (a table too wide for one bins tile,
     histogram.hist_plan) the grid is (feature blocks, row blocks): the
     bins tile and the output block are ONE feature block's, resident
@@ -288,31 +310,17 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
-        iota_ref[...] = _oh_iota_init(iota_ref.shape, int8)
+        iota_ref[...] = _oh_iota_init(iota_ref.shape, int8 or limbs)
 
     iota = iota_ref[...]  # VMEM-persistent one-hot iota (step-invariant)
     slot = slot_ref[0, :]  # (blk,) int32
     gh = gh_ref[...]  # (CH, blk) f32; rows 0..nat_ch-1 are live
     iota_s = lax.broadcasted_iota(jnp.int32, (S, blk), 0)
-    if int8:
-        # Mosaic has no elementwise i8 multiply (only the MXU dot is
-        # int8-legal): mask the levels in i32, then narrow to s8
+    if int8 or limbs:
         sl32 = (slot[None, :] == iota_s).astype(jnp.int32)  # (S, blk)
-        g32 = gh[:nat_ch, :].astype(jnp.int32)  # (nat_ch, blk)
-        W = (sl32[:, None, :] * g32[None, :, :]).reshape(
-            S * nat_ch, blk
-        ).astype(jnp.int8)
-        # SWAR one-hot (see _swar_onehot): 1.65x the compare+cast rate
-        # on the VPU-bound end; sums come out scaled by the byte value
-        def contribution(*bins_rows):
-            oh = _swar_onehot(bins_rows, B, blk, oh_shift, iota_p=iota)
-            return lax.dot_general(
-                W, oh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-
-        _accum_features(bins_ref, out_ref, contribution, F=F, B=B,
-                        last=last)
+        _accum_s8(bins_ref, out_ref, sl32, gh[:nat_ch, :], B=B, blk=blk,
+                  oh_shift=oh_shift, iota=iota, limbs=limbs, F=F,
+                  last=last)
         return
     sl = (slot[None, :] == iota_s).astype(jnp.bfloat16)  # (S, blk)
     g5 = gh[:nat_ch, :].astype(jnp.bfloat16)  # (nat_ch, blk)
@@ -321,6 +329,75 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     _accum_hist_nt(bins_ref, W, out_ref, F=F, B=B, blk=blk,
                    dt=jnp.bfloat16, acc_t=jnp.float32, iota_bT=iota,
                    last=last)
+
+
+def _accum_s8(bins_ref, out_ref, sel32, gh, *, F: int, B: int, blk: int,
+              oh_shift: int, iota, limbs: bool, last=None):
+    """The int8 MXU's feature loop: W[(s, c), r] = level(gh[c, r]) *
+    sel32[s, r] as s8 (or as its two limbs, _limb_lhs), contracted with
+    the byte-SWAR one-hot (1.65x the compare+cast rate on the VPU-bound
+    end) into s32. The int8 path adds the s32 products into an s32
+    block, scaled by the one-hot's byte value (_swar_divisor); the limb
+    path recombines them into an f32 block, unscaled (_limb_sum).
+    Mosaic has no elementwise i8 multiply (only the MXU dot is
+    int8-legal): the levels are masked in i32, then narrowed."""
+    S, ch = sel32.shape[0], gh.shape[0]
+    if limbs:
+        W = _limb_lhs(sel32, _bf16_levels(gh))
+    else:
+        g32 = gh.astype(jnp.int32)
+        W = (sel32[:, None, :] * g32[None, :, :]).reshape(
+            S * ch, blk).astype(jnp.int8)
+
+    def contribution(*bins_rows):
+        oh = _swar_onehot(bins_rows, B, blk, oh_shift, iota_p=iota)
+        res = lax.dot_general(
+            W, oh, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        return _limb_sum(res, S * ch, oh_shift) if limbs else res
+
+    _accum_features(bins_ref, out_ref, contribution, F=F, B=B, last=last)
+
+
+# a level v of the int16 program's channels (grad in [-129, 129], hess
+# in [0, 256], count in {0, 1}: _bf16_levels) as two s8 limbs: v =
+# LIMB_BASE * hi + lo with lo = v & 15 in [0, 15] and hi = v >> 4
+# (floor) in [-9, 16]
+LIMB_SHIFT = 4
+LIMB_BASE = 1 << LIMB_SHIFT
+
+
+def _bf16_levels(gh):
+    """(ch, blk) f32 integer levels -> i32, as the bf16 operand holds
+    them: the limb path's W is the bf16 path's to the bit. The
+    quantizer's stochastic rounding (quantize.discretize_gradients_int)
+    lands one level past its range about 1.5e-5 of the time at the top
+    (x + u rounds up in f32): grad +-129, which bf16 holds, and hess
+    257, which it holds as 256."""
+    return gh.astype(jnp.bfloat16).astype(jnp.float32).astype(jnp.int32)
+
+
+def _limb_lhs(sel32, g32):
+    """(S, blk) 0/1 slot masks and (ch, blk) i32 levels -> the (2 S ch,
+    blk) s8 W of the limb path: the low limbs' rows (s, c), then the
+    high limbs' in the same order. The masks select whole levels, so
+    masking the limbs is limbing the masked level."""
+    S, blk = sel32.shape
+    ch = g32.shape[0]
+    limb = jnp.stack([g32 & (LIMB_BASE - 1), g32 >> LIMB_SHIFT])
+    W = sel32[None, :, None, :] * limb[:, None, :, :]  # (2, S, ch, blk)
+    return W.reshape(2 * S * ch, blk).astype(jnp.int8)
+
+
+def _limb_sum(res, M: int, oh_shift: int):
+    """(2M, w) s32 limb sums of one row block (_limb_lhs rows, scaled by
+    the SWAR one-hot's byte) -> the block's (M, w) f32 level sums,
+    exactly: a block's level sum is at most 256 x 2048 = 2^19 in
+    magnitude (_bf16_levels), so 16 hi + lo = that x the byte (2^7 at oh_shift 0) is an
+    exact s32 and an exact f32, and the power-of-two divide is exact."""
+    c = res[M:] * LIMB_BASE + res[:M]
+    return c.astype(jnp.float32) * (1.0 / _swar_divisor(oh_shift))
 
 
 def _swar_divisor(oh_shift: int) -> float:
@@ -349,7 +426,7 @@ _ARBITRARY_2D = pltpu.CompilerParams(
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "blk", "interpret", "nat_ch",
-                     "int8", "oh_shift", "feat_block"),
+                     "int8", "oh_shift", "feat_block", "limbs"),
 )
 def hist_nat_tpu(
     bins_fm: jax.Array,  # (F, N) int32, natural row order
@@ -363,11 +440,14 @@ def hist_nat_tpu(
     int8: bool = False,
     oh_shift: int = 0,
     feat_block: int = 0,  # columns per feature block; 0: the whole table
+    limbs: bool = False,  # 256-level integer channels on the int8 MXU
 ) -> jax.Array:
     """(S*nat_ch, F*B) f32 packed per-slot channel histograms (exact
-    integer sums computed in s32 when int8). One pass over the bin
-    matrix at any F: past one bins tile (`feat_block`, see _nat_kernel)
-    the feature axis is the grid's leading dimension."""
+    integer sums computed in s32 when int8, per row block in s32 limbs
+    when `limbs`: see _nat_kernel). One pass over the bin matrix at
+    any F: past one bins tile (`feat_block`, see _nat_kernel) the
+    feature axis is the grid's leading dimension."""
+    assert not (int8 and limbs)
     F, N = bins_fm.shape
     assert N % blk == 0, (N, blk)
     assert gh8.shape == (CH, N), gh8.shape
@@ -405,7 +485,7 @@ def hist_nat_tpu(
     out = pl.pallas_call(
         functools.partial(_nat_kernel, F=F, B=B, blk=blk, S=S, nat_ch=nat_ch,
                           int8=int8, oh_shift=oh_shift,
-                          feat_block=feat_block),
+                          feat_block=feat_block, limbs=limbs),
         grid=grid,
         in_specs=[bins_spec, rows(CH), rows(1)],
         out_specs=out_spec,
@@ -413,7 +493,7 @@ def hist_nat_tpu(
             out_shape, jnp.int32 if int8 else jnp.float32
         ),
         scratch_shapes=[
-            pltpu.VMEM(_oh_iota_shape(B, blk, int8), jnp.int32),
+            pltpu.VMEM(_oh_iota_shape(B, blk, int8 or limbs), jnp.int32),
         ],
         compiler_params=_ARBITRARY if nfb == 1 else _ARBITRARY_2D,
         interpret=interpret,
@@ -485,7 +565,7 @@ def _round_kernel(
     # route_only: pleaf | pleaf out | iota scratch
     F: int, B: int, blk: int, S: int, nat_ch: int, int8: bool,
     oh_shift: int, efb: bool, has_cat: bool, route_only: bool = False,
-    with_slot: bool = False,
+    with_slot: bool = False, limbs: bool = False,
 ):
     """Fused round step: partition decision + slot-packed histograms
     in ONE data pass. With `route_only` (the round that spends the last
@@ -516,7 +596,7 @@ def _round_kernel(
     - the new row->leaf vector is written as a second blocked output;
     - the smaller-child side picks each row's histogram slot, and the
       slot-packed W build + one-hot contraction proceed as in
-      _nat_kernel (SWAR one-hot on the int8 path).
+      _nat_kernel (SWAR one-hot on the int8 and the limb paths).
 
     params columns (S, 16) i32: 0 sel_leaf, 1 device column, 2
     threshold bin, 3 default_left, 4 NaN bin (-1 none), 5 left-smaller,
@@ -538,7 +618,7 @@ def _round_kernel(
     # bins one-hots, then the byte-SWAR iota of the int8 bins one-hots
     # and of the cat one-hot, each only where the mode builds such a
     # one-hot. All written once at step 0, VMEM-resident after.
-    want_cmp, want_swar = _round_iotas(int8, has_cat, route_only)
+    want_cmp, want_swar = _round_iotas(int8 or limbs, has_cat, route_only)
     iota_cmp_ref = scratch.pop(0) if want_cmp else None
     iota_swar_ref = scratch.pop(0) if want_swar else None
 
@@ -614,21 +694,11 @@ def _round_kernel(
         slot_out_ref[...] = S + jnp.sum(
             jnp.where(side, iota_s - S, 0), axis=0, keepdims=True)
         return
-    if int8:
+    if int8 or limbs:
         side_i = side.astype(jnp.int32)
-        g32 = gh[:nat_ch, :].astype(jnp.int32)
-        W = (side_i[:, None, :] * g32[None, :, :]).reshape(
-            S * nat_ch, blk).astype(jnp.int8)
-
-        def contribution(*bins_rows):
-            oh = _swar_onehot(bins_rows, B, blk, oh_shift,
-                              iota_p=iota_swar)
-            return lax.dot_general(
-                W, oh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-
-        _accum_features(bins_ref, out_ref, contribution, F=F, B=B)
+        _accum_s8(bins_ref, out_ref, side_i, gh[:nat_ch, :], F=F, B=B,
+                  blk=blk,
+                  oh_shift=oh_shift, iota=iota_swar, limbs=limbs)
     else:
         sideb = side.astype(jnp.bfloat16)
         gb = gh[:nat_ch, :].astype(jnp.bfloat16)
@@ -638,18 +708,19 @@ def _round_kernel(
                        iota_bT=iota_cmp_ref[...])
 
 
-def _round_iotas(int8: bool, has_cat: bool, route_only: bool) -> tuple:
+def _round_iotas(swar: bool, has_cat: bool, route_only: bool) -> tuple:
     """(compare iota?, byte-SWAR iota?) of a round kernel's scratch:
-    the bf16 histogram one-hots compare against the first, the int8
-    ones and the categorical own-bin one-hot unpack the second."""
-    return (not route_only and not int8,
-            has_cat or (int8 and not route_only))
+    the bf16 histogram one-hots compare against the first, the byte-SWAR
+    ones (`swar`: the int8 and the limb paths) and the categorical
+    own-bin one-hot unpack the second."""
+    return (not route_only and not swar,
+            has_cat or (swar and not route_only))
 
 
 def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
                 num_slots: int, num_bins: int, nat_ch: int, int8: bool,
                 oh_shift: int, efb: bool, blk: int, interpret: bool,
-                with_slot: bool = False):
+                with_slot: bool = False, limbs: bool = False):
     """The one pallas_call behind hist_round_tpu and route_round_tpu:
     `gh8 is None` asks for the routing pass alone (_round_kernel's
     route_only), which has neither the gradient input nor the
@@ -668,7 +739,8 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
     scratch = [
         pltpu.VMEM(_oh_iota_shape(num_bins, blk, swar), jnp.int32)
         for swar, want in zip((False, True),
-                              _round_iotas(int8, has_cat, route_only))
+                              _round_iotas(int8 or limbs, has_cat,
+                                           route_only))
         if want
     ]
 
@@ -696,7 +768,7 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
         functools.partial(
             _round_kernel, F=F, B=num_bins, blk=blk, S=S, nat_ch=nat_ch,
             int8=int8, oh_shift=oh_shift, efb=efb, has_cat=has_cat,
-            route_only=route_only, with_slot=with_slot,
+            route_only=route_only, with_slot=with_slot, limbs=limbs,
         ),
         grid=(N // blk,),
         in_specs=[spec for spec, _ in ins],
@@ -718,7 +790,7 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "nat_ch", "int8", "oh_shift",
-                     "efb", "blk", "interpret"),
+                     "efb", "blk", "interpret", "limbs"),
 )
 def hist_round_tpu(
     bins_fm: jax.Array,  # (F, N) int32, natural row order
@@ -735,15 +807,20 @@ def hist_round_tpu(
     cat_mask=None,  # (S, B) s8 per-slot category sets, or None
     blk: int = HIST_BLK,
     interpret: bool = False,
+    limbs: bool = False,  # 256-level integer channels on the int8 MXU
 ):
     """One fused pass -> ((S*nat_ch, F*B) histograms, (N,) new row->leaf).
 
     int8 histogram sums come back scaled by -(128 >> oh_shift) (SWAR
-    one-hot bytes); callers divide once on the (S*ch, F*B) output."""
+    one-hot bytes); callers divide once on the (S*ch, F*B) output.
+    `limbs` sums come back as f32 level sums, those of the bf16 path to
+    the bit (_nat_kernel)."""
+    assert not (int8 and limbs)
     return _round_call(
         bins_fm, gh8, pleaf, params, col_onehot, cat_mask,
         num_slots=num_slots, num_bins=num_bins, nat_ch=nat_ch, int8=int8,
-        oh_shift=oh_shift, efb=efb, blk=blk, interpret=interpret)
+        oh_shift=oh_shift, efb=efb, blk=blk, interpret=interpret,
+        limbs=limbs)
 
 
 @functools.partial(

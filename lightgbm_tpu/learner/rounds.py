@@ -160,6 +160,11 @@ class HistSchedule(NamedTuple):
     # that width), ...): ROOT_LABEL, then ladder_widths; empty where no
     # kernel runs
     calls: Tuple[Tuple[str, int], ...]
+    # every pass runs on the int8 MXU as two s8 limbs a level
+    # (pallas_hist._limb_lhs): a quantized program off the int8 path,
+    # whose levels s8 cannot hold (histogram.hist_round and
+    # hist_nat_slots derive the same from quant and int8)
+    limbs: bool
 
 
 def hist_schedule(spec: GrowerSpec, n_rows: int, n_cols: int
@@ -199,7 +204,7 @@ def hist_schedule(spec: GrowerSpec, n_rows: int, n_cols: int
             calls = ((ROOT_LABEL, 1),) + tuple(
                 (str(w), n_calls(w)) for w in widths)
         return HistSchedule(plan, Bc, use_int8, oh_shift or 0, fused,
-                            routed, calls)
+                            routed, calls, bool(spec.quant and not use_int8))
 
 
 def take_rows(table: jax.Array, idx: jax.Array) -> jax.Array:

@@ -669,8 +669,8 @@ def record_hist_schedule(sched, n_cols: int) -> None:
     kernel multiplies them (whole loop groups) and the columns one
     matmul of its feature loop contracts (2: the pair at 33..64 bins);
     per pass width (root, then the slot ladder) the kernel calls that
-    stream the rows. Nothing where no kernel runs (an XLA-formulation
-    backend)."""
+    stream the rows and whether its histogram runs on limbs. Nothing
+    where no kernel runs (an XLA-formulation backend)."""
     r = _default
     if not r.enabled or not sched.calls:
         return
@@ -712,6 +712,13 @@ def record_hist_schedule(sched, n_cols: int) -> None:
                     labels=("width",))
     for width, n in sched.calls:
         calls.set(n, width=width)
+    limbs = r.gauge("lgbmtpu_hist_limbs",
+                    "1 where a pass of this width (root, then the slot "
+                    "ladder) runs its 256-level channels on the int8 MXU "
+                    "as s8 limbs, else 0", labels=("width",))
+    limbs.clear()  # the newest program's widths alone
+    for width, _ in sched.calls:
+        limbs.set(int(sched.limbs), width=width)
 
 
 def record_split_search(dirs, bundle_layout: bool = False) -> None:
