@@ -27,7 +27,8 @@ The MXU operand is one of three. bf16 with the compare one-hot above;
 s8 with the byte-SWAR one-hot (_swar_onehot, 1.65x the compare's rate)
 for quantized levels within +/-127, summed in an s32 block; and, for
 the int16 program's 256-level channels on every pass, the same s8
-operands with each level split into two limbs (_limb_lhs), whose s32
+operands with each level split into two limbs (_limb_lhs: five s8 rows
+a slot, the count channel, in {0, 1}, having no high limb), whose s32
 sums are recombined exactly per row block and added into the f32
 block: the bf16 path's histograms to the bit (_nat_kernel has the
 argument). The bf16 operand is left to the bf16x2 program.
@@ -255,8 +256,8 @@ def _oh_iota_init(shape: tuple, int8: bool):
     return bg
 
 
-def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
-                *, F: int, B: int, blk: int, S: int, nat_ch: int,
+def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref, *hi_ref,
+                F: int, B: int, blk: int, S: int, nat_ch: int,
                 int8: bool = False, oh_shift: int = 0,
                 feat_block: int = 0, limbs: bool = False):
     """Slot-packed natural-order histogram: rows carry a slot id; the
@@ -284,8 +285,16 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     two s8 limbs, v = 16 * hi + lo (_limb_lhs), stacked on the M axis,
     and contracted with the same SWAR one-hot; each row block's two s32
     limb sums are recombined exactly in s32 and added into the f32
-    output block (_limb_sum). That is the bf16 path's arithmetic to the
-    bit: the limbs are taken of the levels as bf16 holds them
+    output block (_limb_sum). W has five rows a slot, not six: the
+    count channel is the in-bag mask, in {0, 1} on every path that
+    reaches a kernel (bagging, GOSS and by-query masks times the
+    Dataset's padding `valid`, a mesh shard's rows included:
+    rounds.py builds it as build_gh8_quant(grad * mask, hess * mask,
+    mask)), so its high limb is zero on every row and is neither built
+    nor multiplied; its level sum is its low limb's. `hi_ref` is the
+    VMEM scratch that interleaves the high limbs' sums back into (slot,
+    channel) rows (_limb_hi_shape). That is the bf16 path's arithmetic
+    to the bit: the limbs are taken of the levels as bf16 holds them
     (_bf16_levels), and there a block's product is an exact integer as
     well (|v| <= 256, 2048 rows: below 2^24 in the MXU's f32
     accumulator) and enters the f32 block in the same order.
@@ -299,6 +308,7 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     need is a function of the block and not of F; gh and the slot
     vector are re-read per block (36 B a row against 4 B x block
     columns of bins) and W is rebuilt from them."""
+    hi_ref = hi_ref[0] if limbs else None
     last = None
     if feat_block:
         i = pl.program_id(1)
@@ -311,6 +321,8 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
         iota_ref[...] = _oh_iota_init(iota_ref.shape, int8 or limbs)
+        if limbs:
+            hi_ref[...] = jnp.zeros_like(hi_ref)
 
     iota = iota_ref[...]  # VMEM-persistent one-hot iota (step-invariant)
     slot = slot_ref[0, :]  # (blk,) int32
@@ -320,7 +332,7 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
         sl32 = (slot[None, :] == iota_s).astype(jnp.int32)  # (S, blk)
         _accum_s8(bins_ref, out_ref, sl32, gh[:nat_ch, :], B=B, blk=blk,
                   oh_shift=oh_shift, iota=iota, limbs=limbs, F=F,
-                  last=last)
+                  last=last, hi_ref=hi_ref)
         return
     sl = (slot[None, :] == iota_s).astype(jnp.bfloat16)  # (S, blk)
     g5 = gh[:nat_ch, :].astype(jnp.bfloat16)  # (nat_ch, blk)
@@ -332,15 +344,16 @@ def _nat_kernel(bins_ref, gh_ref, slot_ref, out_ref, iota_ref,
 
 
 def _accum_s8(bins_ref, out_ref, sel32, gh, *, F: int, B: int, blk: int,
-              oh_shift: int, iota, limbs: bool, last=None):
+              oh_shift: int, iota, limbs: bool, last=None, hi_ref=None):
     """The int8 MXU's feature loop: W[(s, c), r] = level(gh[c, r]) *
-    sel32[s, r] as s8 (or as its two limbs, _limb_lhs), contracted with
-    the byte-SWAR one-hot (1.65x the compare+cast rate on the VPU-bound
-    end) into s32. The int8 path adds the s32 products into an s32
-    block, scaled by the one-hot's byte value (_swar_divisor); the limb
-    path recombines them into an f32 block, unscaled (_limb_sum).
-    Mosaic has no elementwise i8 multiply (only the MXU dot is
-    int8-legal): the levels are masked in i32, then narrowed."""
+    sel32[s, r] as s8 (or as its limbs, five rows a slot: _limb_lhs),
+    contracted with the byte-SWAR one-hot (1.65x the compare+cast rate
+    on the VPU-bound end) into s32. The int8 path adds the s32 products
+    into an s32 block, scaled by the one-hot's byte value
+    (_swar_divisor); the limb path recombines them through `hi_ref`
+    into an f32 block, unscaled (_limb_sum). Mosaic has no elementwise
+    i8 multiply (only the MXU dot is int8-legal): the levels are masked
+    in i32, then narrowed."""
     S, ch = sel32.shape[0], gh.shape[0]
     if limbs:
         W = _limb_lhs(sel32, _bf16_levels(gh))
@@ -355,7 +368,7 @@ def _accum_s8(bins_ref, out_ref, sel32, gh, *, F: int, B: int, blk: int,
             W, oh, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
-        return _limb_sum(res, S * ch, oh_shift) if limbs else res
+        return _limb_sum(res, hi_ref, S, oh_shift) if limbs else res
 
     _accum_features(bins_ref, out_ref, contribution, F=F, B=B, last=last)
 
@@ -363,9 +376,15 @@ def _accum_s8(bins_ref, out_ref, sel32, gh, *, F: int, B: int, blk: int,
 # a level v of the int16 program's channels (grad in [-129, 129], hess
 # in [0, 256], count in {0, 1}: _bf16_levels) as two s8 limbs: v =
 # LIMB_BASE * hi + lo with lo = v & 15 in [0, 15] and hi = v >> 4
-# (floor) in [-9, 16]
+# (floor) in [-9, 16]. The count's hi is 0 on every row, so W carries
+# LIMB_ROWS s8 rows a slot: the three low limbs and the high limbs of
+# grad and hess (_limb_lhs)
 LIMB_SHIFT = 4
 LIMB_BASE = 1 << LIMB_SHIFT
+LIMB_ROWS = 5
+# lanes of the limb path's interleave scratch (_limb_hi_shape): Mosaic
+# strides sublanes only in a memref exactly one vreg of lanes wide
+_LIMB_LANES = 128
 
 
 def _bf16_levels(gh):
@@ -379,24 +398,49 @@ def _bf16_levels(gh):
 
 
 def _limb_lhs(sel32, g32):
-    """(S, blk) 0/1 slot masks and (ch, blk) i32 levels -> the (2 S ch,
-    blk) s8 W of the limb path: the low limbs' rows (s, c), then the
-    high limbs' in the same order. The masks select whole levels, so
-    masking the limbs is limbing the masked level."""
+    """(S, blk) 0/1 slot masks and (3, blk) i32 levels (grad, hess,
+    count) -> the (LIMB_ROWS S, blk) s8 W of the limb path: the low
+    limbs' rows (s, c), then the high limbs of grad for every slot, then
+    those of hess. The count, last, is in {0, 1} (_nat_kernel) and has
+    no high limb. The masks select whole levels, so masking the limbs
+    is limbing the masked level."""
     S, blk = sel32.shape
     ch = g32.shape[0]
-    limb = jnp.stack([g32 & (LIMB_BASE - 1), g32 >> LIMB_SHIFT])
-    W = sel32[None, :, None, :] * limb[:, None, :, :]  # (2, S, ch, blk)
-    return W.reshape(2 * S * ch, blk).astype(jnp.int8)
+    lo = sel32[:, None, :] * (g32 & (LIMB_BASE - 1))[None, :, :]
+    hi = (g32[:ch - 1] >> LIMB_SHIFT)[:, None, :] * sel32[None, :, :]
+    W = jnp.concatenate([lo.reshape(S * ch, blk),
+                         hi.reshape((ch - 1) * S, blk)])
+    return W.astype(jnp.int8)
 
 
-def _limb_sum(res, M: int, oh_shift: int):
-    """(2M, w) s32 limb sums of one row block (_limb_lhs rows, scaled by
-    the SWAR one-hot's byte) -> the block's (M, w) f32 level sums,
-    exactly: a block's level sum is at most 256 x 2048 = 2^19 in
-    magnitude (_bf16_levels), so 16 hi + lo = that x the byte (2^7 at oh_shift 0) is an
-    exact s32 and an exact f32, and the power-of-two divide is exact."""
-    c = res[M:] * LIMB_BASE + res[:M]
+def _limb_hi_shape(S: int, B: int) -> tuple:
+    """The limb path's interleave scratch: one matmul's high limb sums
+    in (slot, channel) rows, by 128-lane chunks of its output lanes
+    (the one-hot tile's rows, _oh_iota_shape). The count's rows are
+    zeroed at grid step 0 and never written."""
+    w = columns_per_matmul(B) * column_stride(B)
+    return (-(-w // _LIMB_LANES), 3 * S, _LIMB_LANES)
+
+
+def _limb_sum(res, hi_ref, S: int, oh_shift: int):
+    """(LIMB_ROWS S, w) s32 limb sums of one row block (_limb_lhs rows,
+    scaled by the SWAR one-hot's byte) -> the block's (3 S, w) f32 level
+    sums in (slot, channel) rows, exactly. The high rows of grad and
+    hess are stored at sublane stride 3 into `hi_ref` (_limb_hi_shape),
+    whose count rows stay zero, and read back whole: (S, 3, w) high sums
+    beside the low rows. A block's level sum is at most 256 x 2048 =
+    2^19 in magnitude (_bf16_levels), so 16 hi + lo = that x the byte
+    (2^7 at oh_shift 0) is an exact s32 and an exact f32, and the
+    power-of-two divide is exact."""
+    M, w = 3 * S, res.shape[1]
+    for q in range(hi_ref.shape[0]):
+        a, b = q * _LIMB_LANES, min(w, (q + 1) * _LIMB_LANES)
+        for c in range(2):
+            hi_ref[q, pl.ds(c, S, stride=3), :b - a] = (
+                res[M + c * S : M + (c + 1) * S, a:b])
+    hi = jnp.concatenate([hi_ref[q] for q in range(hi_ref.shape[0])],
+                         axis=1)
+    c = hi[:, :w] * LIMB_BASE + res[:M]
     return c.astype(jnp.float32) * (1.0 / _swar_divisor(oh_shift))
 
 
@@ -494,7 +538,7 @@ def hist_nat_tpu(
         ),
         scratch_shapes=[
             pltpu.VMEM(_oh_iota_shape(B, blk, int8 or limbs), jnp.int32),
-        ],
+        ] + ([pltpu.VMEM(_limb_hi_shape(S, B), jnp.int32)] if limbs else []),
         compiler_params=_ARBITRARY if nfb == 1 else _ARBITRARY_2D,
         interpret=interpret,
     )(bins_fm, gh8, slot.reshape(1, N))
@@ -617,10 +661,12 @@ def _round_kernel(
     # scratch layout (_round_iotas): the compare iota of the bf16
     # bins one-hots, then the byte-SWAR iota of the int8 bins one-hots
     # and of the cat one-hot, each only where the mode builds such a
-    # one-hot. All written once at step 0, VMEM-resident after.
+    # one-hot. All written once at step 0, VMEM-resident after. Last,
+    # on the limb path, its interleave scratch (_limb_hi_shape).
     want_cmp, want_swar = _round_iotas(int8 or limbs, has_cat, route_only)
     iota_cmp_ref = scratch.pop(0) if want_cmp else None
     iota_swar_ref = scratch.pop(0) if want_swar else None
+    hi_ref = scratch.pop(0) if limbs and not route_only else None
 
     @pl.when(i == 0)
     def _init():
@@ -630,6 +676,8 @@ def _round_kernel(
             iota_cmp_ref[...] = _oh_iota_init(iota_cmp_ref.shape, False)
         if iota_swar_ref is not None:
             iota_swar_ref[...] = _oh_iota_init(iota_swar_ref.shape, True)
+        if hi_ref is not None:
+            hi_ref[...] = jnp.zeros_like(hi_ref)
 
     iota_swar = None if iota_swar_ref is None else iota_swar_ref[...]
     pleaf = pleaf_ref[...]  # (1, blk) i32
@@ -698,7 +746,8 @@ def _round_kernel(
         side_i = side.astype(jnp.int32)
         _accum_s8(bins_ref, out_ref, side_i, gh[:nat_ch, :], F=F, B=B,
                   blk=blk,
-                  oh_shift=oh_shift, iota=iota_swar, limbs=limbs)
+                  oh_shift=oh_shift, iota=iota_swar, limbs=limbs,
+                  hi_ref=hi_ref)
     else:
         sideb = side.astype(jnp.bfloat16)
         gb = gh[:nat_ch, :].astype(jnp.bfloat16)
@@ -743,6 +792,8 @@ def _round_call(bins_fm, gh8, pleaf, params, col_onehot, cat_mask, *,
                                            route_only))
         if want
     ]
+    if limbs and not route_only:
+        scratch.append(pltpu.VMEM(_limb_hi_shape(S, num_bins), jnp.int32))
 
     def const(shape):
         return pl.BlockSpec(shape, lambda i: (0,) * len(shape),

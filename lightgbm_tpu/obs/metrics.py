@@ -669,12 +669,14 @@ def record_hist_schedule(sched, n_cols: int) -> None:
     kernel multiplies them (whole loop groups) and the columns one
     matmul of its feature loop contracts (2: the pair at 33..64 bins);
     per pass width (root, then the slot ladder) the kernel calls that
-    stream the rows and whether its histogram runs on limbs. Nothing
-    where no kernel runs (an XLA-formulation backend)."""
+    stream the rows, whether its histogram runs on limbs and the s8
+    rows a slot it then issues. Nothing where no kernel runs (an
+    XLA-formulation backend)."""
     r = _default
     if not r.enabled or not sched.calls:
         return
-    from ..learner.pallas_hist import columns_per_matmul, feature_groups
+    from ..learner.pallas_hist import (LIMB_ROWS, columns_per_matmul,
+                                       feature_groups)
 
     groups, per_group = feature_groups(n_cols, sched.num_bins)
     per = columns_per_matmul(sched.num_bins)
@@ -716,9 +718,16 @@ def record_hist_schedule(sched, n_cols: int) -> None:
                     "1 where a pass of this width (root, then the slot "
                     "ladder) runs its 256-level channels on the int8 MXU "
                     "as s8 limbs, else 0", labels=("width",))
+    limb_rows = r.gauge("lgbmtpu_hist_limb_rows",
+                        "s8 MXU rows a slot that a pass of this width "
+                        "issues on limbs (5: the count channel has no "
+                        "high limb), 0 where it does not run limbs",
+                        labels=("width",))
     limbs.clear()  # the newest program's widths alone
+    limb_rows.clear()
     for width, _ in sched.calls:
         limbs.set(int(sched.limbs), width=width)
+        limb_rows.set(LIMB_ROWS if sched.limbs else 0, width=width)
 
 
 def record_split_search(dirs, bundle_layout: bool = False) -> None:

@@ -1,13 +1,15 @@
 """The int16 program's histogram passes on the int8 MXU as two s8 limbs
-(pallas_hist._limb_lhs / _limb_sum): element for element the bf16
+(pallas_hist._limb_lhs / _limb_sum), five s8 rows a slot since the
+count channel, in {0, 1}, has no high limb: element for element the bf16
 path's f32 histograms, under the Pallas interpreter. Each row block's
 limb sums are recombined exactly in s32 and added into the f32 output
 block in the order the bf16 path adds its exact block products, so the
 two must agree to the bit, not to a tolerance. The schedule puts
 every pass of the 256-level program on limbs and no pass of the int8
-or bf16x2 programs (rounds.hist_schedule), and the gauge
-lgbmtpu_hist_limbs{width} says so. The limb kernels at the cells'
-real shapes for a described v5e: test_aot_v5e.py."""
+or bf16x2 programs (rounds.hist_schedule), and the gauges
+lgbmtpu_hist_limbs{width} and lgbmtpu_hist_limb_rows{width} say so.
+The limb kernels at the cells' real shapes for a described v5e:
+test_aot_v5e.py."""
 
 import numpy as np
 import pytest
@@ -29,7 +31,10 @@ def _levels(rs, n, variant="plain"):
     by row block) and hess 256, so a block's partial is at its largest;
     `overflow` draws the one level past each end that the quantizer's
     stochastic rounding can land on, grad +-129 and hess 257 (which the
-    bf16 operand holds as 256)."""
+    bf16 operand holds as 256); `bagged` draws the count, the in-bag
+    mask, from {0, 1} and zeroes grad and hess where it is 0, as the
+    rounds grower builds them (build_gh8_quant(grad * mask, hess * mask,
+    mask))."""
     if variant == "extreme":
         g = np.where((np.arange(n) // HIST_BLK) % 2 == 0, 128.0, -128.0)
         h = np.full(n, 256.0)
@@ -37,9 +42,13 @@ def _levels(rs, n, variant="plain"):
         top = 1 if variant == "overflow" else 0
         g = rs.randint(-128 - top, 129 + top, n).astype(np.float64)
         h = rs.randint(0, 257 + top, n).astype(np.float64)
-    return build_gh8_quant(jnp.asarray(g, jnp.float32),
-                           jnp.asarray(h, jnp.float32),
-                           jnp.ones(n, jnp.float32))
+    mask = np.ones(n, np.float32)
+    if variant == "bagged":
+        mask = (rs.rand(n) < 0.6).astype(np.float32)
+        assert 0 < mask.sum() < n
+    return build_gh8_quant(jnp.asarray(g * mask, jnp.float32),
+                           jnp.asarray(h * mask, jnp.float32),
+                           jnp.asarray(mask))
 
 
 def _nat_oracle(bins, gh8, slot, S, B):
@@ -97,6 +106,9 @@ def _round_operands(rs, F, B, S, variant, n):
     ("nat", 2, 255, 1, "extreme"),
     ("round", 3, 255, 16, "overflow"),
     ("nat", 3, 255, 8, "overflow"),
+    ("round", 3, 255, 24, "bagged"),  # the rank cell's 48-slot chunk
+    ("nat", 3, 255, 16, "bagged"),
+    ("nat", 40, 63, 8, "bagged"),  # two loop groups, the pair
 ])
 def test_limb_pass_equals_the_bf16_pass(kernel, columns, bins, slots,
                                         variant):
@@ -130,6 +142,11 @@ def test_limb_pass_equals_the_bf16_pass(kernel, columns, bins, slots,
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     if extreme and kernel == "nat":  # the largest block partials occur
         assert float(jnp.max(jnp.abs(out))) == 256 * n
+    # the W the limb path builds: five s8 rows a slot
+    sel = jnp.asarray(rs.randint(0, 2, (slots, n)), jnp.int32)
+    W = pallas_hist._limb_lhs(sel, pallas_hist._bf16_levels(gh8[:3]))
+    assert W.shape == (pallas_hist.LIMB_ROWS * slots, n) == (5 * slots, n)
+    assert W.dtype == jnp.int8
 
 
 def _spec(program):
@@ -151,9 +168,11 @@ def test_schedule_puts_the_int16_passes_on_limbs(interp, program, limbs):
     assert sched.fused and sched.limbs is limbs
     assert sched.calls[0][0] == "root" and len(sched.calls) >= 4
     record_hist_schedule(sched, 28)
-    gauge = default_registry().snapshot()["lgbmtpu_hist_limbs"]
-    assert {k: v for k, v in gauge.items()} == {
+    snap = default_registry().snapshot()
+    assert dict(snap["lgbmtpu_hist_limbs"]) == {
         f'{{width="{w}"}}': int(limbs) for w, _ in sched.calls}
+    assert dict(snap["lgbmtpu_hist_limb_rows"]) == {
+        f'{{width="{w}"}}': 5 if limbs else 0 for w, _ in sched.calls}
 
 
 def _kernels_on(monkeypatch, limbs=None):
